@@ -49,7 +49,7 @@ _TIMER_CTORS = frozenset({"threading.Timer", "Timer"})
 _POOL_DISPATCH_ATTRS = frozenset({"run_all", "submit"})
 
 # attr names that lower their first argument through a compile path
-# (traced-purity scope — mirrors parallel/dispatch + compat.shard_map)
+# (traced-purity scope — mirrors parallel/dispatch + jax.shard_map)
 _LOWERING_ATTRS = frozenset({
     "jit", "shard_map", "lower", "jit_under_mesh", "pallas_call",
 })

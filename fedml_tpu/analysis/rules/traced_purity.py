@@ -1,7 +1,7 @@
 """traced-purity: no host calls inside jit/pjit/shard_map-lowered code.
 
 Provenance: every engine program lowers through ``parallel/dispatch.lower``
-(or ``jax.jit`` / ``compat.shard_map`` directly — sim/engine.py, PR 7), and
+(or ``jax.jit`` / ``jax.shard_map`` directly — sim/engine.py, PR 7), and
 a host call inside a traced body is a classic silent bug: ``time.time()``
 burns ONE timestamp into the compiled graph forever, ``np.random`` draws
 once at trace time and replays the same "random" numbers every call,
@@ -10,7 +10,7 @@ likewise. jax.debug.print / jax.random are the traced-safe counterparts.
 
 Scope: per module — functions (a) decorated with ``jax.jit`` /
 ``partial(jax.jit, ...)``, or (b) passed by NAME as the first argument to
-``jax.jit`` / ``compat.shard_map`` / ``dispatch.lower`` /
+``jax.jit`` / ``jax.shard_map`` / ``dispatch.lower`` /
 ``jit_under_mesh`` / ``pallas_call``, plus every ``def`` nested inside
 them. No interprocedural analysis: a helper called from a traced body is
 only scanned if it is itself lowered — the rule catches the direct form.

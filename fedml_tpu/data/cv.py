@@ -11,6 +11,7 @@ FederatedArrays. Augmentation (crop/flip/cutout) runs on-device — see
 
 from __future__ import annotations
 
+import logging
 import os
 import pickle
 import tarfile
@@ -173,6 +174,8 @@ def load_cifar(
     if raw is None:
         if not allow_synthetic:
             raise FileNotFoundError(f"{dataset} files not found under {data_dir}")
+        logging.warning("%s: files absent under %s; using synthetic "
+                        "cifar-like fixture", dataset, data_dir)
         raw = _synthetic_cifar_like(nclass, seed=seed)
 
     (x, y), (xt, yt), class_num = raw

@@ -1352,6 +1352,9 @@ def parse_with_config(parser: argparse.ArgumentParser, argv=None):
 
 
 def main(argv=None):
+    from fedml_tpu.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     parser = add_args(argparse.ArgumentParser("fedml_tpu unified entry"))
     args = parse_with_config(parser, argv)
     history = run(args)

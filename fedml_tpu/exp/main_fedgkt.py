@@ -132,6 +132,9 @@ def _final_metrics(gkt, cvars_list, svars, client_batches) -> dict:
 
 
 def main(argv=None):
+    from fedml_tpu.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     args = add_args(argparse.ArgumentParser("fedml_tpu fedgkt entry")).parse_args(argv)
     return run(args)
 

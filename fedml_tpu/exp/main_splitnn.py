@@ -113,6 +113,9 @@ def run(args) -> dict:
 
 
 def main(argv=None):
+    from fedml_tpu.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     args = add_args(argparse.ArgumentParser("fedml_tpu splitnn entry")).parse_args(argv)
     return run(args)
 

@@ -92,6 +92,9 @@ def run(args) -> dict:
 
 
 def main(argv=None):
+    from fedml_tpu.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     args = add_args(
         argparse.ArgumentParser("fedml_tpu turboaggregate entry")
     ).parse_args(argv)

@@ -206,6 +206,9 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    from fedml_tpu.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     args = add_args(argparse.ArgumentParser("femnist+cnn baseline repro")).parse_args(argv)
     return run(args)
 

@@ -41,11 +41,11 @@ class MultiHeadSelfAttention(nn.Module):
     # tensor-parallel layout the partition rules put on the qkv kernel, so
     # each model shard attends over its own heads. Requires tracing under
     # the plan's mesh (parallel/dispatch.py provides the context). GSPMD
-    # partitions the xla attention path by heads on its own; the pallas
-    # flash kernel is an opaque custom call to the partitioner, so the
-    # flash path routes through ops.attention.flash_attention_head_parallel
-    # (a per-rank shard_map over this axis, with a gathered-xla fallback
-    # when heads don't divide it).
+    # partitions the xla attention path by heads on its own; Mosaic refuses
+    # to have the pallas flash kernel partitioned, so the flash path routes
+    # through ops.attention.flash_attention_head_parallel (under any
+    # sharded plan a shard_map over the whole mesh, heads split over this
+    # axis, with a gathered-xla fallback when heads don't divide it).
     mp_axis: str | None = None
     # flash kernel tile sizes, tuned on a v5e at T=1024, D_head=128: a tall
     # 256-row query block with the whole 1024-key sequence in one block beat
@@ -72,9 +72,9 @@ class MultiHeadSelfAttention(nn.Module):
             k = constrain(k, hspec)
             v = constrain(v, hspec)
         if self.attn_impl == "flash":
-            # head-parallel under a TP plan (mp_axis set + active mesh):
-            # each model rank runs the pallas kernel on its local heads;
-            # plain kernel otherwise — see flash_attention_head_parallel
+            # under a sharded plan (active mesh) the kernel runs per device
+            # in a shard_map, on its local heads when mp_axis is set; plain
+            # kernel otherwise — see flash_attention_head_parallel
             o = flash_attention_head_parallel(
                 q, k, v, axis=self.mp_axis, causal=True,
                 block_q=self.block_q, block_k=self.block_k)

@@ -12,8 +12,9 @@ reuses the same math.
 Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim).
 Forward runs the pallas kernel; backward is a custom VJP that recomputes
 attention blockwise with plain XLA ops — O(T) memory in both directions.
-On non-TPU backends the kernel runs in interpreter mode so the full test
-suite exercises it on the 8-device CPU mesh.
+On the CPU backend the kernel runs in interpreter mode so the full test
+suite exercises it on the 8-device CPU mesh; on TPU it is Mosaic-compiled;
+any other backend is refused (see :func:`_interpret_on`).
 """
 
 from __future__ import annotations
@@ -27,10 +28,39 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _pick_block(t: int, preferred: int) -> int:
+def _interpret_on(platform: str) -> bool:
+    """Whether the pallas kernel runs interpreted on ``platform``: yes on
+    the CPU (the test suite), no on TPU (Mosaic). Anything else raises —
+    guessing either way would hide which program actually ran."""
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"flash attention: no pallas lowering chosen for backend {platform!r} "
+        "(interpreted on 'cpu', Mosaic-compiled on 'tpu'); use "
+        "attn_impl='xla' there"
+    )
+
+
+def _pick_block(t: int, preferred: int, dtype) -> int:
+    """Largest divisor of ``t`` not above ``preferred``. The block is the
+    second-to-last (sublane) dimension of a VMEM tile, so it must be the
+    whole axis or a multiple of the dtype's sublane tile (8 rows of 32 bits:
+    8 for f32, 16 for bf16, 32 for 8-bit) — Mosaic refuses anything else,
+    and the CPU interpreter must refuse it too or the suite passes shapes
+    the chip cannot compile."""
     b = min(preferred, t)
     while t % b:
         b -= 1
+    sublane = 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+    if b != t and b % sublane:
+        raise ValueError(
+            f"flash attention: T={t} with preferred block {preferred} gives "
+            f"block {b}, which is neither the whole axis nor a multiple of "
+            f"the {sublane}-row sublane tile for {jnp.dtype(dtype).name}; "
+            f"pad T to a multiple of {sublane} or pick a block that divides it"
+        )
     return b
 
 
@@ -109,8 +139,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, sm_scale, 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     b, h, t, d = q.shape
     t_k = k.shape[2]
-    block_q = _pick_block(t, block_q)
-    block_k = _pick_block(t_k, block_k)
+    block_q = _pick_block(t, block_q, q.dtype)
+    block_k = _pick_block(t_k, block_k, k.dtype)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t_k, d)
     vf = v.reshape(b * h, t_k, d)
@@ -145,7 +175,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 def _blockwise_bwd(q, k, v, out, g, causal, sm_scale, block_k):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    block_k = _pick_block(t_k, block_k)
+    block_k = _pick_block(t_k, block_k, k.dtype)
     nkb = t_k // block_k
     qf = q.astype(jnp.float32)
     gf = g.astype(jnp.float32)
@@ -217,13 +247,13 @@ def flash_attention(
 ):
     """Blockwise fused attention for ``[B, H, T, D]`` inputs.
 
-    Forward = pallas kernel (interpreter mode off-TPU); backward = blockwise
+    Forward = pallas kernel (interpreter mode on the CPU); backward = blockwise
     recomputation in plain XLA — O(T·block) memory in both directions, the
     [T, T] score matrix is never materialized.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret_on(jax.default_backend())
     return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
 
 
@@ -238,37 +268,42 @@ def flash_attention_head_parallel(
     block_q: int = 128,
     block_k: int = 128,
 ):
-    """:func:`flash_attention` on a tensor-parallel sharded plan: each
-    ``axis`` rank runs the pallas kernel on its LOCAL heads.
+    """:func:`flash_attention` inside a global-view (pjit) program over a
+    multi-device mesh: the pallas kernel runs per device under a
+    ``shard_map``, on its LOCAL heads when the plan is tensor-parallel.
 
-    The pallas kernel is an opaque custom call to the XLA SPMD partitioner,
-    so under a sharded plan the unwrapped kernel forces a gather to full
-    heads per device — the exact memory blow-up the plan exists to avoid.
-    Wrapping it in a head-parallel ``shard_map`` over the model axis keeps
-    the ``[B, H_local, T, D]`` blocks resident: attention is head-local math
-    (softmax normalizes per head), so the per-rank kernel computes bits
-    identical to the full-head kernel's.
+    Mosaic refuses to be partitioned: lowering a pallas call raises "Mosaic
+    kernels cannot be automatically partitioned" in a multi-device pjit
+    program, and just the same under a shard_map that is manual over only
+    SOME mesh axes (seen on the v5e, 4 chips, PR 21; the CPU interpreter
+    accepts both). So whenever a multi-device mesh is active the kernel is
+    wrapped in a shard_map manual over EVERY mesh axis:
+
+    - heads split over ``axis`` when the plan is tensor-parallel — attention
+      is head-local math (softmax normalizes per head), so the per-rank
+      kernel computes bits identical to the full-head kernel's and the
+      ``[B, H_local, T, D]`` blocks stay resident;
+    - everything else replicated: each device runs the kernel on what it
+      holds, which is what the partitioner does with an op it cannot split
+      (gather-for-compute plans, ``axis=None``). A cohort vmap adds its
+      ``spmd_axis_name`` to the specs by itself.
 
     Resolution order at trace time:
 
-    - no ``axis``, no active mesh, ``axis`` not on the mesh, or a 1-way
-      axis → the plain kernel (unsharded behavior, bit-identical);
-    - heads divide the axis → per-rank kernel under ``compat.shard_map``;
-    - heads do NOT divide the axis → :func:`attention_reference` (plain XLA
-      — the partitioner can split *its* einsums head-wise) with a loud
-      warning, because silently gathering the kernel would defeat the plan.
+    - no active mesh (eager use, or a client-mapped shard_map program, which
+      is already manual) or a 1-device mesh → the plain kernel;
+    - heads do NOT divide a >1-way ``axis`` → :func:`attention_reference`
+      (plain XLA — the partitioner can split *its* einsums head-wise) with
+      a loud warning, because silently gathering the kernel would defeat
+      the plan;
+    - otherwise → the kernel under the all-axes ``jax.shard_map``.
     """
-    from fedml_tpu.parallel import compat
+    from fedml_tpu.parallel.mesh import current_mesh
 
-    mesh = compat.current_mesh()
-    if (
-        axis is None
-        or mesh is None
-        or axis not in mesh.axis_names
-        or mesh.shape[axis] == 1
-    ):
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal, sm_scale, block_q, block_k)
-    n_ranks = int(mesh.shape[axis])
+    n_ranks = int(mesh.shape[axis]) if axis in mesh.axis_names else 1
     n_heads = q.shape[1]
     if n_heads % n_ranks:
         import logging
@@ -284,14 +319,13 @@ def flash_attention_head_parallel(
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
     from jax.sharding import PartitionSpec
 
-    hspec = PartitionSpec(None, axis, None, None)
-    return compat.shard_map(
+    hspec = PartitionSpec(None, axis if n_ranks > 1 else None, None, None)
+    return jax.shard_map(
         functools.partial(
             flash_attention, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k,
         ),
-        mesh=mesh, in_specs=(hspec,) * 3, out_specs=hspec,
-        axis_names={axis}, check_vma=False,
+        mesh=mesh, in_specs=(hspec,) * 3, out_specs=hspec, check_vma=False,
     )(q, k, v)
 
 

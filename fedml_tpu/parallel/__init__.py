@@ -7,8 +7,6 @@
   models").
 - :mod:`fedml_tpu.parallel.dispatch` — pjit-when-sharded /
   shard_map-when-mapped compile dispatcher.
-- :mod:`fedml_tpu.parallel.compat` — jax.shard_map API shim for legacy
-  runtimes.
 """
 
 from fedml_tpu.parallel.dispatch import lower, plan_is_sharded  # noqa: F401

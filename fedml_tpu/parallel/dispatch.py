@@ -7,15 +7,13 @@ the program's in/out PartitionSpecs and picks the lowering —
 - **pjit** (``jax.jit`` with explicit ``in_shardings``/``out_shardings``)
   when any spec partitions an axis beyond the mapped (client) axes. The
   program body is then *global-view*: GSPMD partitions the math, honoring
-  ``with_sharding_constraint`` pins, and buffer donation rides the modern
-  jit path (the legacy shard_map donation bug, sim/engine.py, does not
-  apply here). Calls run under the mesh context so bare-PartitionSpec
-  constraints inside model code (models/transformer.py ``mp_axis``)
-  resolve.
-- **shard_map** (the engine's existing manual lowering via
-  parallel/compat.py) when the plan is purely client-mapped — per-device
-  bodies with explicit collectives, which sidesteps the XLA SPMD
-  limitation on vmapped grouped convolutions.
+  ``with_sharding_constraint`` pins. Calls run under the mesh context so
+  bare-PartitionSpec constraints inside model code (models/transformer.py
+  ``mp_axis``) resolve.
+- **shard_map** (the engine's manual lowering, ``jax.shard_map``) when
+  the plan is purely client-mapped — per-device bodies with explicit
+  collectives, which sidesteps the XLA SPMD limitation on vmapped grouped
+  convolutions.
 
 The two lowerings expect different bodies (manual bodies read
 ``lax.axis_index``; global bodies index with ``jnp.arange``), so the
@@ -32,7 +30,6 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
-from fedml_tpu.parallel import compat
 from fedml_tpu.parallel.mesh import CLIENT_AXIS, named_sharding
 
 Pytree = Any
@@ -106,7 +103,7 @@ def lower(
     out_specs,
     donate_argnums: tuple = (),
     mapped_axes=MAPPED_AXES,
-    check_vma: bool | None = False,
+    check_vma: bool = False,
 ) -> Lowered:
     """Lower ``fn`` for ``mesh`` according to its PartitionSpecs.
 
@@ -131,7 +128,7 @@ def lower(
             donate_argnums=donate_argnums,
         )
         return Lowered(jitted, "pjit", mesh, tuple(donate_argnums))
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=in_specs,

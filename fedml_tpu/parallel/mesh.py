@@ -95,6 +95,23 @@ def parse_mesh_shape(text: str | None):
     return (clients, model)
 
 
+def current_mesh() -> Mesh | None:
+    """The mesh of the innermost active ``with mesh:`` context, or ``None``.
+
+    This is how a traced op discovers the mesh the surrounding program is
+    being lowered under (``parallel/dispatch.py`` enters the mesh context
+    around every pjit trace) — e.g. the head-parallel flash wrap in
+    ``ops/attention.py`` decides at trace time whether to nest a per-rank
+    ``shard_map`` over the model axis. jax 0.9.0 has no public reader for
+    the ``with mesh:`` context (``jax.sharding.get_mesh`` only sees
+    ``jax.set_mesh`` and refuses to run under ``jit``), so this reads the
+    thread-local the context manager writes."""
+    from jax._src import mesh as mesh_lib
+
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
+
+
 def named_sharding(mesh: Mesh, spec) -> NamedSharding:
     """Build a NamedSharding from a PartitionSpec on ``mesh``, validating
     that every axis the spec names exists on the mesh — a typo'd axis name

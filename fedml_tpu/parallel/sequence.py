@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from fedml_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 SP_AXIS = "sp"
@@ -69,7 +68,7 @@ def make_sp_lm_train_step(model, optimizer: optax.GradientTransformation, mesh: 
     batch_spec = {"x": P(None, sp_axis), "y": P(None, sp_axis), "mask": P(None, sp_axis)}
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec, P()),
         out_specs=(P(), P(), P()),

@@ -612,14 +612,10 @@ class FedSim:
         # per-client mode: the model state is itself a stacked [C, ...] pytree
         # sharded over the clients axis, in and out of the round program
         var_spec = cohort_spec if self._per_client else P()
-        # Donating the model argument miscompiles under the legacy
-        # jax.experimental.shard_map lowering: aliased outputs read recycled
-        # buffers — deterministically garbage for the per-client stack, and
-        # intermittently corrupted broadcast-mode params under full-suite
-        # memory pressure. Donate only on runtimes with the current
-        # jax.shard_map API. (The pjit programs below are unaffected; they
-        # gate donation on the backend implementing it instead.)
-        self._donate = (0,) if hasattr(jax, "shard_map") else ()
+        # shard_map round programs donate the model argument on every
+        # backend; the pjit programs below donate only where the backend
+        # implements it (XLA:CPU does not)
+        self._donate = (0,)
         if self._spmd:
             # Two-program sharded round: a pjit TRAIN program emits the
             # cohort's update stack at a program boundary, then a pjit
@@ -763,8 +759,7 @@ class FedSim:
                     )
                     buf_args = (5, 6, 7, 8)
                 # pjit programs gate donation on the backend implementing
-                # it, like agg_donate above (the legacy shard_map lowering
-                # bug does not apply to pjit)
+                # it, like agg_donate above
                 pjit_donate = jax.default_backend() != "cpu"
                 self._packed_pass_fn = displib.lower(
                     pass_impl, mesh=self.mesh,
@@ -797,22 +792,17 @@ class FedSim:
                 # the buf program, consumed once per pass, then by the
                 # aggregation) — donate them so passes update the stack in
                 # place instead of holding two [C_pad, model] copies live.
-                # Same legacy-lowering guard as self._donate (see the
-                # donation note above).
-                buf_donate = buf_args if hasattr(jax, "shard_map") else ()
                 self._packed_pass_fn = displib.lower(
                     pass_impl, mesh=self.mesh,
                     in_specs=pass_specs,
                     out_specs=(cohort_spec,) * 4,
-                    donate_argnums=buf_donate,
+                    donate_argnums=buf_args,
                 )
                 self._packed_agg_fn = displib.lower(
                     self._packed_agg_impl, mesh=self.mesh,
                     in_specs=(P(), P()) + (cohort_spec,) * 6 + (P(),),
                     out_specs=(P(), P(), P()),
-                    donate_argnums=(
-                        (2, 3, 4, 5) if hasattr(jax, "shard_map") else ()
-                    ),
+                    donate_argnums=(2, 3, 4, 5),
                 )
 
         self._test_batches = None
@@ -2016,8 +2006,7 @@ class FedSim:
         # enqueue BOTH eval programs before fetching anything: JAX dispatch
         # is async, so the train and test programs overlap on device and the
         # host pays ONE round-trip (device_get) instead of four synchronous
-        # float() fetches — on remote-attached chips (tunneled TPU) the
-        # per-fetch latency, not the inference FLOPs, dominates eval time
+        # float() fetches
         train_m = (
             self._eval_gather_fn(variables, self._dataset, self._train_eval_idx)
             if self._train_eval_idx is not None

@@ -15,7 +15,8 @@ def main(job: str, rank: int, world: int, npz_path: str) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/fedml_tpu_jax_cache")
+    # the compile cache directory arrives as $JAX_COMPILATION_CACHE_DIR from
+    # the parent test (the suite's own string), which jax reads by itself
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     import numpy as np

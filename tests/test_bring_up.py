@@ -1,0 +1,93 @@
+"""Start-up decisions that pick which program the chip runs (PR 21). Nothing
+here compiles a model: tier-1 has no room (ROADMAP Design 6)."""
+
+import os
+import tempfile
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fedml_tpu.core import compile_cache
+from fedml_tpu.ops.attention import _interpret_on, _pick_block
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_cache_dir_left_alone_when_env_var_set(monkeypatch):
+    updates = []
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append(name))
+    compile_cache.configure_compile_cache()
+    assert "jax_compilation_cache_dir" not in updates
+    assert compile_cache.cache_dir_to_set(
+        {compile_cache.ENV_VAR: "/somewhere/else"}, None) is None
+
+
+def test_cache_dir_default_is_one_fixed_path_under_the_checkout():
+    path = compile_cache.cache_dir_to_set({}, None)
+    # equal to a path computed from this file's location alone: no pid, no
+    # time, no temp dir can be in it, and no respelling (jax keys on the string)
+    assert path == os.path.join(REPO, ".jax_cache")
+    assert os.path.isabs(path) and path == os.path.normpath(path)
+    assert not path.startswith(tempfile.gettempdir() + os.sep)
+    assert compile_cache.cache_dir_to_set({}, None) == path
+    # a directory some caller already configured (this suite's conftest) wins
+    assert compile_cache.cache_dir_to_set({}, "/already/configured") is None
+
+
+def test_cache_floor_lowered_only_from_jax_default():
+    assert compile_cache.floor_to_set(1.0) == 0.0
+    assert compile_cache.floor_to_set(0.5) is None  # the suite's own choice
+
+
+def test_flash_interpret_decision_is_a_function_of_the_platform():
+    assert _interpret_on("cpu") is True
+    assert _interpret_on("tpu") is False
+    for other in ("gpu", "cuda", "METAL"):
+        with pytest.raises(RuntimeError, match=other):
+            _interpret_on(other)
+
+
+def test_pick_block_refuses_what_mosaic_refuses():
+    with pytest.raises(ValueError, match=r"T=1000.*block 250.*8-row"):
+        _pick_block(1000, 256, jnp.float32)
+    assert _pick_block(1024, 256, jnp.bfloat16) == 256
+    # a block equal to the axis is always a legal tile (the ring-attention
+    # dry run's T=64 under the default 128 tiles)
+    assert _pick_block(64, 128, jnp.float32) == 64
+    assert _pick_block(64, 128, jnp.bfloat16) == 64
+    # bf16 packs 16 rows to a sublane tile
+    assert _pick_block(32, 8, jnp.float32) == 8
+    with pytest.raises(ValueError, match="16-row"):
+        _pick_block(32, 8, jnp.bfloat16)
+
+
+def test_flash_wrap_lowers_for_tpu_under_a_sharded_plan(monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically, nor under a
+    shard_map manual over only some mesh axes: jax raises at lowering, which
+    the interpreted kernel never reaches. So lower FOR the TPU from here
+    (no compile) and meet the refusal the chip gave in PR 21."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import fedml_tpu.ops.attention as att
+
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                ("clients", "model"))
+    rep = NamedSharding(mesh, P())
+    q = jax.ShapeDtypeStruct((2, 2, 128, 128), jnp.bfloat16)
+
+    def lowered_for_tpu(fn):
+        with mesh:
+            return jax.jit(fn, in_shardings=(rep,) * 3, out_shardings=rep) \
+                .trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        lowered_for_tpu(lambda q, k, v: att.flash_attention(q, k, v, True))
+    for axis in (None, "model"):  # gather-for-compute plan, tensor-parallel plan
+        text = lowered_for_tpu(lambda q, k, v: att.flash_attention_head_parallel(
+            q, k, v, axis=axis, causal=True))
+        assert "tpu_custom_call" in text

@@ -148,6 +148,8 @@ def test_splitnn_real_processes(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = (str(worker_path.parent.parent) + os.pathsep
                          + env.get("PYTHONPATH", ""))
+    # the workers share this suite's compile cache, under the same string
+    env["JAX_COMPILATION_CACHE_DIR"] = jax.config.jax_compilation_cache_dir
     for r, batches in enumerate(cb, start=1):
         npz = tmp_path / f"client{r}.npz"
         np.savez(npz, **{k: np.asarray(v) for k, v in batches.items()})

@@ -10,7 +10,6 @@ import pytest
 from functools import partial
 from jax.sharding import PartitionSpec as P
 
-from fedml_tpu.parallel.compat import shard_map
 
 from fedml_tpu.ops.attention import attention_reference, flash_attention
 from fedml_tpu.parallel.ring_attention import ring_attention
@@ -54,7 +53,7 @@ def test_ring_attention_exact(rng, causal):
     q, k, v = _qkv(rng, t=64)
 
     ring = partial(ring_attention, axis_name="sp", causal=causal)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         ring,
         mesh=mesh,
         in_specs=(P(None, None, "sp"), P(None, None, "sp"), P(None, None, "sp")),

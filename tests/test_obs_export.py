@@ -119,10 +119,8 @@ def test_syncbn_matches_pooled_stats():
         out, _ = net.apply(v, xb, mutable=["batch_stats"])
         return out
 
-    from fedml_tpu.parallel.compat import shard_map as compat_shard_map
-
     out = jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             sharded, mesh=mesh, in_specs=(P(), P("silo")), out_specs=P("silo"),
             check_vma=False,
         )

@@ -5,6 +5,7 @@ must never outlive a run (even one that dies mid-round). Also covers the
 vectorized cohort builder against its per-client-loop oracle."""
 
 import dataclasses
+import json
 import threading
 
 import jax
@@ -123,10 +124,14 @@ def test_run_rounds_pipelined_matches_serial(tmp_path):
     assert _no_prefetch_threads()
 
 
+def _rounds_on_file(path):
+    return [json.loads(line)["round"] for line in path.read_text().splitlines()]
+
+
 def test_prefetch_shutdown_on_midrun_exception(tmp_path):
     """An exception mid-run must not leak the staging thread or wedge a
     subsequent run_rounds; completed-but-undrained rounds are salvaged
-    into the partial report."""
+    into the metrics file, and then the failure propagates."""
     from fedml_tpu.exp._loop import run_rounds
 
     train, test, trainer = _fixture()
@@ -143,8 +148,9 @@ def test_prefetch_shutdown_on_midrun_exception(tmp_path):
         return orig(r, root)
 
     sim.stage_round = boom
-    records, _ = run_rounds(sim, cfg, str(tmp_path / "m.jsonl"))
-    assert [r["round"] for r in records] == [0, 1, 2]
+    with pytest.raises(RuntimeError, match="staging blew up"):
+        run_rounds(sim, cfg, str(tmp_path / "m.jsonl"))
+    assert _rounds_on_file(tmp_path / "m.jsonl") == [0, 1, 2]
     assert _no_prefetch_threads()
     # the engine (and a fresh prefetch thread) still works afterwards
     sim.stage_round = orig
@@ -155,7 +161,7 @@ def test_prefetch_shutdown_on_midrun_exception(tmp_path):
 
 def test_eval_failure_keeps_drained_rounds(tmp_path):
     """An eval_record failure must not lose rounds that trained fine: the
-    pipelined partial report ends exactly where the serial one does."""
+    pipelined partial record ends exactly where the serial one does."""
     from fedml_tpu.exp._loop import run_rounds
 
     train, test, trainer = _fixture()
@@ -171,9 +177,10 @@ def test_eval_failure_keeps_drained_rounds(tmp_path):
         sim.eval_record = lambda v: (_ for _ in ()).throw(
             RuntimeError("eval blew up")
         )
-        recs, _ = run_rounds(sim, cfg, str(tmp_path / f"d{depth}.jsonl"))
+        with pytest.raises(RuntimeError, match="eval blew up"):
+            run_rounds(sim, cfg, str(tmp_path / f"d{depth}.jsonl"))
         sim.eval_record = orig
-        return [r["round"] for r in recs]
+        return _rounds_on_file(tmp_path / f"d{depth}.jsonl")
 
     # eval fires at round 3; rounds 0-2 completed and must be reported
     assert partial_records(1) == partial_records(0) == [0, 1, 2]

@@ -104,7 +104,6 @@ def test_cross_silo_table_combos_end_to_end(tmp_path, dataset, model):
         "--fixture_train_n", "400", "--fixture_test_n", "100",
         "--client_num_in_total", "4", "--batch_size", "8",
         "--epochs", "1", "--comm_round", "1", "--frequency_of_the_test", "1",
-        "--round_sleep", "0",
         "--metrics_out", str(tmp_path / "m.jsonl"),
         "--out", str(tmp_path / "R.md"),
     ])

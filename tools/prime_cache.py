@@ -3,36 +3,31 @@
 Most of the suite's cold wall-clock is XLA:CPU compilation of federated
 round programs; many tests rebuild the same program shapes. This script
 compiles the highest-cost SHARED programs once so a following
-``pytest -m "not slow"`` run is close to its warm-cache time (~5 min on a
-single core) instead of the cold 20+ min.
+``pytest -m "not slow"`` run starts from a partly warm cache instead of a
+fully cold one (cold is 20+ min).
 
 Usage (fresh clone):
-    python tools/prime_cache.py          # ~3-6 min single-core, one-time
+    python tools/prime_cache.py
     python -m pytest tests/ -q -m "not slow"
 
-The cache lives at $FEDML_TPU_JAX_CACHE (default /tmp/fedml_tpu_jax_cache)
-— the same directory tests/conftest.py configures — and is content-addressed,
-so priming is idempotent and safe to re-run.
+jax hashes the cache directory STRING into every key, so priming only
+helps a reader that spells the directory the same way. This script imports
+``tests/conftest.py`` for exactly that: the platform, the eight virtual
+devices and the suite's own directory string ($JAX_COMPILATION_CACHE_DIR
+when set). Priming is idempotent and safe to re-run.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [_REPO, os.path.join(_REPO, "tests")]
 
+import conftest  # noqa: E402,F401 — cpu platform, 8 devices, the suite's cache dir
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get("FEDML_TPU_JAX_CACHE", "/tmp/fedml_tpu_jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 def _t(label, fn):
@@ -91,10 +86,10 @@ def main():
     # 4. the distributed-manager local_train jit (fedavg_distributed tests)
     def dist_local():
         from fedml_tpu.core.trainer import ClientTrainer, make_local_train
-        from fedml_tpu.models.lr import LogisticRegression
+        from fedml_tpu.models.linear import LogisticRegression
 
         trainer = ClientTrainer(
-            module=LogisticRegression(input_dim=8, class_num=2),
+            module=LogisticRegression(num_classes=2),
             optimizer=optax.sgd(0.1), epochs=1,
         )
         batches = {

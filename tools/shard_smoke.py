@@ -15,7 +15,7 @@ Two arms run by default on XLA:CPU host devices:
   the whole mesh given to its model, ``cohort_execution="scan"``) vs the
   single-device program.
 
-    JAX_PLATFORMS=cpu python tools/shard_smoke.py [--bench] [--packed]
+    JAX_PLATFORMS=cpu python tools/shard_smoke.py [--packed]
 
 ``--packed`` runs the packed-lane composition arms instead (docs/
 PERFORMANCE.md "Packed lanes on sharded plans"): ``pack_lanes`` on the
@@ -27,9 +27,6 @@ model math. (Packed vs padded on one mesh is pack_smoke's separate
 contract and carries its own transformer fusion caveat, so the packed
 arms pin against packed twins, not padded ones.) Tier-1 runs this arm
 in-process (tests/test_shard_parallel.py).
-
-``--bench`` additionally reports sharded vs unsharded rounds/sec as one
-JSON line (bench.py's shard A/B rides this on CPU-fallback runs).
 """
 
 from __future__ import annotations
@@ -108,25 +105,15 @@ def _assert_same(label, sharded, unsharded):
 def main(argv=None) -> int:
     import dataclasses
     import json
-    import time
 
     import jax
 
+    from fedml_tpu.core.compile_cache import configure_compile_cache
     from fedml_tpu.parallel.mesh import client_mesh
     from fedml_tpu.sim.engine import FedSim, SimConfig
 
-    # persistent XLA compile cache (the test suite's repo-local gitignored
-    # dir): standalone and bench-subprocess runs skip recompiling the round
-    # programs tier-1 already built, and vice versa
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("FEDML_TPU_JAX_CACHE",
-                                     os.path.join(
-                                         os.path.dirname(os.path.dirname(
-                                             os.path.abspath(__file__))),
-                                         ".jax_cache")))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    configure_compile_cache()
 
-    bench = bool(argv) and "--bench" in argv
     packed = bool(argv) and "--packed" in argv
     devices = jax.devices()
     if len(devices) < 4:
@@ -145,9 +132,7 @@ def main(argv=None) -> int:
 
     def run(c, mesh=None):
         sim = FedSim(trainer, train, test, c, mesh=mesh)
-        t0 = time.perf_counter()
-        v, h = sim.run()
-        return (v, h), time.perf_counter() - t0, sim
+        return sim.run(), sim
 
     if packed:
         # Packed-lane composition arms: pack_lanes on a sharded plan vs the
@@ -157,20 +142,20 @@ def main(argv=None) -> int:
         # is pack_smoke's separate contract and carries its own transformer
         # fusion caveat).
         pack_cfg = dataclasses.replace(cfg, pack_lanes=2)
-        res_p, _, sim_p = run(dataclasses.replace(
+        res_p, sim_p = run(dataclasses.replace(
             pack_cfg, mesh_shape=(2, 2), shard_rules="transformer_fsdp"
         ))
         assert sim_p._pack and sim_p._spmd, "packed arm must compose"
         assert sim_p.shard_summary()["mode"] == "pjit", sim_p.shard_summary()
-        res_pu, _, _ = run(pack_cfg, mesh=client_mesh(devices[:2]))
+        res_pu, _ = run(pack_cfg, mesh=client_mesh(devices[:2]))
         _assert_same("packed 2x2 fsdp", res_p, res_pu)
 
         # the flagship geometry with lanes: one client shard, the whole
         # model axis to each lane step, vs the 1-device packed program
-        res_p2, _, _ = run(dataclasses.replace(
+        res_p2, _ = run(dataclasses.replace(
             pack_cfg, mesh_shape=(1, 4), shard_rules="transformer_fsdp"
         ))
-        res_pu2, _, _ = run(pack_cfg, mesh=client_mesh(devices[:1]))
+        res_pu2, _ = run(pack_cfg, mesh=client_mesh(devices[:1]))
         _assert_same("packed 1x4 fsdp", res_p2, res_pu2)
         metric_keys = sorted(k for k in res_pu[1][-1] if k != "round_time")
         print(
@@ -178,26 +163,25 @@ def main(argv=None) -> int:
             f"packed-unsharded on {metric_keys} and final variables "
             "(2x2 fsdp + 1x4 arms)"
         )
-        if not bench:
-            return 0
+        return 0
 
     # arm 1: 2x2 clients x model, FSDP-gather rules, vs 2-client-shard
     # unsharded (same client-axis extent -> same padding and rng chains)
     shard_cfg = dataclasses.replace(
         cfg, mesh_shape=(2, 2), shard_rules="transformer_fsdp"
     )
-    res_s, dt_s, sim_s = run(shard_cfg)
-    res_u, dt_u, _ = run(cfg, mesh=client_mesh(devices[:2]))
+    res_s, sim_s = run(shard_cfg)
+    res_u, _ = run(cfg, mesh=client_mesh(devices[:2]))
     assert sim_s.shard_summary()["mode"] == "pjit", sim_s.shard_summary()
     _assert_same("2x2 fsdp", res_s, res_u)
 
     # arm 2: the flagship geometry — one client at a time (scan cohort),
     # the whole 1x4 mesh given to its model — vs the 1-device program
     scan_cfg = dataclasses.replace(cfg, cohort_execution="scan")
-    res_s2, _, _ = run(dataclasses.replace(
+    res_s2, _ = run(dataclasses.replace(
         scan_cfg, mesh_shape=(1, 4), shard_rules="transformer_fsdp"
     ))
-    res_u2, _, _ = run(scan_cfg, mesh=client_mesh(devices[:1]))
+    res_u2, _ = run(scan_cfg, mesh=client_mesh(devices[:1]))
     _assert_same("1x4 scan fsdp", res_s2, res_u2)
 
     metric_keys = sorted(k for k in res_u[1][-1] if k != "round_time")
@@ -205,13 +189,6 @@ def main(argv=None) -> int:
         f"shard smoke OK: {ROUNDS} rounds, sharded == unsharded on "
         f"{metric_keys} and final variables (2x2 fsdp + 1x4 scan arms)"
     )
-    if bench:
-        print(json.dumps({
-            "shard_rounds_per_sec": round(ROUNDS / dt_s, 3),
-            "unsharded_rounds_per_sec": round(ROUNDS / dt_u, 3),
-            "shard_mesh": [2, 2],
-            "shard_rules": "transformer_fsdp",
-        }))
     return 0
 
 
