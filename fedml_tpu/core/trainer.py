@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import optax
 
 from fedml_tpu.core import scan as scanlib
+from fedml_tpu.obs import trace
 
 Pytree = Any
 Batch = dict[str, jnp.ndarray]
@@ -202,7 +203,8 @@ class ClientTrainer:
             rngs={"dropout": rng},
         )
         logits, new_model_state = out
-        loss = self.loss_and_metrics[0](logits, batch)
+        with jax.named_scope(trace.SCOPE_LOSS):
+            loss = self.loss_and_metrics[0](logits, batch)
         if self.prox_mu > 0.0:
             from fedml_tpu.core import tree as treelib
 
@@ -214,21 +216,27 @@ class ClientTrainer:
                    batch: Batch, rng: jax.Array):
         params = variables["params"]
         model_state = {k: v for k, v in variables.items() if k != "params"}
-        (loss, new_model_state), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
-            params, model_state, global_params, batch, rng
-        )
-        # A fully-padded batch (mask all zero) must be a no-op: gradients are
-        # already zero there, but guard optimizer statistics too.
-        has_data = jnp.sum(batch["mask"]) > 0
-        updates, new_opt_state = self.optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        new_params = jax.tree.map(lambda n, o: jnp.where(has_data, n, o), new_params, params)
-        new_opt_state = jax.tree.map(
-            lambda n, o: jnp.where(has_data, n, o), new_opt_state, opt_state
-        )
-        new_model_state = jax.tree.map(
-            lambda n, o: jnp.where(has_data, n, o), new_model_state, model_state
-        )
+        # jax marks the backward ops ``transpose(jvp(...))`` inside the scope,
+        # which is what splits forward from backward in a device trace
+        with jax.named_scope(trace.SCOPE_FWD_BWD):
+            (loss, new_model_state), grads = jax.value_and_grad(
+                self.loss_fn, has_aux=True
+            )(params, model_state, global_params, batch, rng)
+        with jax.named_scope(trace.SCOPE_OPT):
+            # A fully-padded batch (mask all zero) must be a no-op: gradients
+            # are already zero there, but guard optimizer statistics too.
+            has_data = jnp.sum(batch["mask"]) > 0
+            updates, new_opt_state = self.optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            new_params = jax.tree.map(
+                lambda n, o: jnp.where(has_data, n, o), new_params, params
+            )
+            new_opt_state = jax.tree.map(
+                lambda n, o: jnp.where(has_data, n, o), new_opt_state, opt_state
+            )
+            new_model_state = jax.tree.map(
+                lambda n, o: jnp.where(has_data, n, o), new_model_state, model_state
+            )
         return {"params": new_params, **new_model_state}, new_opt_state, loss
 
     # -- evaluation ------------------------------------------------------------

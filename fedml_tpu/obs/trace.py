@@ -26,6 +26,13 @@ Design constraints:
 - **Thread-safe.** Spans land from the driver thread, the prefetch staging
   thread, and every comm worker thread; each thread gets its own track id
   (Chrome ``tid``) so Perfetto renders the pipeline overlap visually.
+- **One clock with the device.** Once a tracer has been installed, every
+  live span also opens a ``jax.profiler.TraceAnnotation``: while jax's
+  profiler records, the span is an event on its thread's line of the
+  profile's host plane, on the clock of the device's ops, with its
+  attributes as stats. The device programs carry the phase scopes below
+  (:data:`SCOPES`), and jax's own build events become ``jax/compile`` /
+  ``jax/cache_load`` spans under the span that needed the program.
 
 Usage::
 
@@ -60,11 +67,42 @@ __all__ = [
     "span", "event", "counter", "gauge", "trace_to", "wire_ctx",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
+    "SCOPES", "FLASH_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
 JSONL_TRACE_NAME = "trace.jsonl"
 CHROME_TRACE_NAME = "trace.chrome.json"
 META_EVENT_NAME = "trace/meta"
+
+# Phase scopes of the device programs (``jax.named_scope`` at the sites named
+# in docs/OBSERVABILITY.md): metadata on the compiled ops, so a profiler
+# trace can say which phase an op belongs to. benchmark/scope_reduce.py
+# keeps its own copy of these names and a test holds the two equal, so a
+# rename fails a test and not a metric.
+SCOPE_GATHER = "fed/gather"
+SCOPE_FWD_BWD = "fed/fwd_bwd"
+SCOPE_LOSS = "fed/loss"
+SCOPE_OPT = "fed/opt"
+SCOPE_AGGREGATE = "fed/aggregate"
+SCOPE_EVAL = "fed/eval"
+SCOPE_PACK_PASS = "fed/pack_pass"
+SCOPE_FLASH_FWD = "attn/flash_fwd"
+SCOPE_BLOCKWISE_BWD = "attn/blockwise_bwd"
+SCOPES = (
+    SCOPE_GATHER, SCOPE_FWD_BWD, SCOPE_LOSS, SCOPE_OPT, SCOPE_AGGREGATE,
+    SCOPE_EVAL, SCOPE_PACK_PASS, SCOPE_FLASH_FWD, SCOPE_BLOCKWISE_BWD,
+)
+FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
+
+# jax.monitoring duration events recorded as spans while a tracer is
+# installed: a program was built under the span that is open on the calling
+# thread (an ``engine/dispatch`` or ``engine/eval``). jax times every build
+# as a backend compile, a load from the persistent cache included, so a
+# ``jax/cache_load`` sits inside a ``jax/compile`` of the same dispatch.
+COMPILE_SPANS = {
+    "/jax/core/compile/backend_compile_duration": "jax/compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax/cache_load",
+}
 
 # ancestors carried in a wire trace context (comm/base.py stamping): enough
 # to reconstruct the enclosing handler/broadcast chain at the receiver
@@ -95,7 +133,8 @@ class _Span:
     so every recorded span carries a causal parent link and
     :func:`wire_ctx` can snapshot the open chain for the wire."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "span_id", "_open")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "span_id", "_open",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -114,10 +153,16 @@ class _Span:
             "attrs": self._attrs,
         }
         stack.append(self._open)
+        # the same span on the profiler's clock: while jax's profiler
+        # records, it lands on this thread's line of the xplane's host
+        # plane with its attributes as stats; otherwise it costs a flag read
+        self._annotation = _annotate(self._name, self._attrs)
         return self
 
     def __exit__(self, *exc) -> bool:
         tracer = self._tracer
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         t_end = tracer._clock()
         stack = tracer._stack()
         if stack and stack[-1] is self._open:
@@ -388,6 +433,44 @@ class Tracer:
 
 _tracer: Tracer | None = None
 _job_store = None  # lazily built: jobscope is only imported when job-scoping is used
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation once a tracer was installed
+
+
+def _annotate(name: str, attrs: dict):
+    """An entered ``jax.profiler.TraceAnnotation`` for a live span, or None
+    before the first install (a bare ``Tracer()`` never imports jax)."""
+    if _TraceAnnotation is None:
+        return None
+    annotation = _TraceAnnotation(name, **attrs)
+    annotation.__enter__()
+    return annotation
+
+
+def _on_jax_duration(event: str, duration: float, **_kwargs) -> None:
+    """jax.monitoring listener: a program build becomes a span that ends now
+    on the calling thread, under whatever span is open there."""
+    name = COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    t = get()
+    if t is None:
+        return
+    now = t._clock()
+    t.add_span(name, now - duration, now)
+
+
+def _meet_jax() -> None:
+    """First install: import jax's profiler for the span mirror and register
+    the one build listener (jax keeps listeners for the process's life; with
+    no tracer installed the listener returns at once)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is not None:
+        return
+    import jax.monitoring
+    import jax.profiler
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _TraceAnnotation = jax.profiler.TraceAnnotation
 
 
 def _job_tracers():
@@ -403,6 +486,7 @@ def install(tracer: Tracer | None = None) -> Tracer:
     """Install ``tracer`` (a fresh one by default) as the process tracer and
     return it. Replaces any previously-installed tracer."""
     global _tracer
+    _meet_jax()
     _tracer = tracer if tracer is not None else Tracer()
     return _tracer
 
@@ -419,6 +503,7 @@ def install_job(job: str, tracer: Tracer | None = None) -> Tracer:
     """Install a tracer scoped to ``job``: threads bound to the job
     (jobscope.bound / jobscope.wrap_target) resolve it ahead of the process
     tracer, so each co-scheduled federation exports its own span stream."""
+    _meet_jax()
     return _job_tracers().install(
         job, tracer if tracer is not None else Tracer())
 

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from fedml_tpu.obs import trace
+
 NEG_INF = -1e30
 
 
@@ -136,6 +138,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, sm_scale, 
     o_ref[:] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
+@jax.named_scope(trace.SCOPE_FLASH_FWD)
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     b, h, t, d = q.shape
     t_k = k.shape[2]
@@ -162,6 +165,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
+        name=trace.FLASH_KERNEL_NAME,
     )(qf, kf, vf)
     return out.reshape(b, h, t, d)
 
@@ -172,6 +176,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
 def _blockwise_bwd(q, k, v, out, g, causal, sm_scale, block_k):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]

@@ -591,11 +591,6 @@ class FedSim:
         # multi-controller (jax.distributed) jobs: every process stages the
         # same host arrays but materializes only its addressable shards
         self._multihost = jax.process_count() > 1
-        # per-program-kind first-dispatch tracking: the first dispatch of a
-        # compiled program includes its XLA compilation, so marking it in
-        # the trace stream is the compile event (obs/trace.py)
-        self._dispatched: set[str] = set()
-
         # Every compiled round program is lowered through the compile
         # dispatcher (parallel/dispatch.py): pjit with explicit in/out
         # shardings when the plan shards the model, the manual shard_map
@@ -903,6 +898,7 @@ class FedSim:
             train_metrics["train_loss"], rng,
         )
 
+    @jax.named_scope(trace.SCOPE_AGGREGATE)
     def _aggregate_tail(self, global_variables, server_state, local_vars,
                         weights, num_steps, train_loss, rng):
         # The round's server side, shared verbatim by the padded, packed,
@@ -974,6 +970,7 @@ class FedSim:
         return new_global, server_state, metrics
 
     @staticmethod
+    @jax.named_scope(trace.SCOPE_GATHER)
     def _gather_batches(dataset, idx):
         """Gather [*, S, B] index maps (-1 = empty slot) into batch stacks
         with stack_cohort's exact zero-fill/mask semantics — the one
@@ -1090,6 +1087,7 @@ class FedSim:
         wbuf = jnp.zeros((c_local, T), jnp.float32)
         return stack, written, lbuf, wbuf
 
+    @jax.named_scope(trace.SCOPE_PACK_PASS)
     def _packed_pass_body(self, variables, get_batch, data, slot, gidx,
                           boundary, stack, written, lbuf, wbuf, rng):
         # One lane-scan pass over this shard's [L_local, S_lane] plan. Each
@@ -1308,18 +1306,24 @@ class FedSim:
     def _stage_block_impl(self, start_round: int, n_rounds: int, root_rng):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        per_round = [
-            self._host_cohort_indices(self._sample_round_cohort(r), r)
-            for r in range(start_round, start_round + n_rounds)
-        ]
+        with trace.span("engine/stage/cohort", round=start_round):
+            per_round = [
+                self._host_cohort_indices(self._sample_round_cohort(r), r)
+                for r in range(start_round, start_round + n_rounds)
+            ]
         block_sharding = NamedSharding(self.mesh, P(None, meshlib.CLIENT_AXIS))
-        idxs = self._put(np.stack([p[0] for p in per_round]), block_sharding)
-        weights = self._put(np.stack([p[1] for p in per_round]), block_sharding)
-        num_steps = self._put(np.stack([p[2] for p in per_round]), block_sharding)
-        rngs = jnp.stack([
-            rnglib.round_key(root_rng, r)
-            for r in range(start_round, start_round + n_rounds)
-        ])
+        with trace.span("engine/stage/put", round=start_round):
+            idxs = self._put(
+                np.stack([p[0] for p in per_round]), block_sharding)
+            weights = self._put(
+                np.stack([p[1] for p in per_round]), block_sharding)
+            num_steps = self._put(
+                np.stack([p[2] for p in per_round]), block_sharding)
+        with trace.span("engine/stage/keys", round=start_round):
+            rngs = jnp.stack([
+                rnglib.round_key(root_rng, r)
+                for r in range(start_round, start_round + n_rounds)
+            ])
         return idxs, weights, num_steps, rngs
 
     def run_block(self, start_round: int, n_rounds: int, global_variables,
@@ -1347,13 +1351,13 @@ class FedSim:
             else self._stage_block(start_round, n_rounds, root_rng)
         )
         with trace.span("engine/dispatch", program=f"block{n_rounds}",
-                        round=start_round, n_rounds=n_rounds,
-                        first=self._first_dispatch(f"block{n_rounds}")):
+                        round=start_round, n_rounds=n_rounds):
             return self._get_block_fn(n_rounds)(
                 global_variables, server_state, self._dataset, idxs, weights,
                 num_steps, rngs,
             )
 
+    @jax.named_scope(trace.SCOPE_EVAL)
     def _eval_impl(self, variables, batches):
         variables = self._compute_view(variables)
 
@@ -1454,6 +1458,23 @@ class FedSim:
         """Stage an explicit cohort's data on device: stack, apply straggler
         budgets, pad to the mesh's client axis, ship. Also used by
         HierarchicalFedAvg for per-group cohorts."""
+        with trace.span("engine/stage/cohort", round=round_idx):
+            batches, weights, num_steps = self._host_cohort_batches(
+                cohort, round_idx)
+        # sharded rounds (pjit) take the tiny [C] cohort vectors replicated
+        # — explicit in_shardings reject a mismatched committed layout
+        scalar_sharding = (
+            self._rep if self._spmd else meshlib.client_sharded(self.mesh)
+        )
+        with trace.span("engine/stage/put", round=round_idx):
+            batches = self._put(batches, self._shard)
+            weights = self._put(weights, scalar_sharding)
+            num_steps = self._put(num_steps, scalar_sharding)
+        return batches, weights, num_steps
+
+    def _host_cohort_batches(self, cohort, round_idx: int):
+        """Host side of :meth:`stage_cohort`: the cohort's batch stack,
+        weights and step budgets, padded to the mesh."""
         cfg = self.config
         shuffle = (
             np.random.RandomState(cfg.seed * 1_000_003 + round_idx)
@@ -1480,14 +1501,6 @@ class FedSim:
             }
             weights = np.concatenate([weights, np.zeros(pad, np.float32)])
             num_steps = np.concatenate([num_steps, np.zeros(pad, np.int32)])
-        # sharded rounds (pjit) take the tiny [C] cohort vectors replicated
-        # — explicit in_shardings reject a mismatched committed layout
-        scalar_sharding = (
-            self._rep if self._spmd else meshlib.client_sharded(self.mesh)
-        )
-        batches = self._put(batches, self._shard)
-        weights = self._put(weights, scalar_sharding)
-        num_steps = self._put(num_steps, scalar_sharding)
         return batches, weights, num_steps
 
     def _population_view(self, round_idx: int):
@@ -1598,14 +1611,17 @@ class FedSim:
         """Device staging for the on-device-dataset path: instead of the full
         [C, S, B, ...] batch stack, upload only a [C, S, B] int32 index map
         (-1 = empty slot); the round program gathers rows in HBM."""
-        idx, weights, num_steps = self._host_cohort_indices(cohort, round_idx)
+        with trace.span("engine/stage/cohort", round=round_idx):
+            idx, weights, num_steps = self._host_cohort_indices(
+                cohort, round_idx)
         sharded = meshlib.client_sharded(self.mesh)
         scalar_sharding = self._rep if self._spmd else sharded
-        return (
-            self._put(idx, sharded),
-            self._put(weights, scalar_sharding),
-            self._put(num_steps, scalar_sharding),
-        )
+        with trace.span("engine/stage/put", round=round_idx):
+            return (
+                self._put(idx, sharded),
+                self._put(weights, scalar_sharding),
+                self._put(num_steps, scalar_sharding),
+            )
 
     def _sample_round_cohort(self, round_idx: int) -> np.ndarray:
         cfg = self.config
@@ -1638,8 +1654,13 @@ class FedSim:
         batch staging, device_put, rng-key derivation. Pure in (config,
         round_idx, root_rng): prefetching it ahead of the dispatch loop
         (sim/prefetch.py) cannot change cohorts, keys, or metrics."""
-        rkey = rnglib.round_key(root_rng, round_idx)
-        cohort = self._sample_round_cohort(round_idx)
+        # the same three children as a block's staging; the key and the
+        # sampling come before ``engine/stage`` opens, so here they are its
+        # siblings under ``prefetch/stage``
+        with trace.span("engine/stage/keys", round=round_idx):
+            rkey = rnglib.round_key(root_rng, round_idx)
+        with trace.span("engine/stage/cohort", round=round_idx):
+            cohort = self._sample_round_cohort(round_idx)
         return self.stage_cohort_round(cohort, round_idx, rkey)
 
     def stage_cohort_round(self, cohort, round_idx: int, rkey):
@@ -1756,22 +1777,11 @@ class FedSim:
             },
         )
 
-    def _first_dispatch(self, program: str) -> bool:
-        """True exactly once per compiled-program kind, emitting the trace
-        compile marker: a program's first dispatch blocks on its XLA
-        compilation, so the span it labels IS the compile event."""
-        if program in self._dispatched:
-            return False
-        self._dispatched.add(program)
-        trace.event("engine/first_dispatch", program=program)
-        return True
-
     def run_staged_round(self, staged, global_variables, server_state):
         """Dispatch one round from a stage_round payload."""
         if isinstance(staged, PackedStaged):
-            with trace.span("engine/dispatch", program="packed",
-                            n_passes=staged.stats["n_passes"],
-                            first=self._first_dispatch("packed")):
+            with trace.span("engine/dispatch", program="packed", n_rounds=1,
+                            n_passes=staged.stats["n_passes"]):
                 return self._run_packed(staged, global_variables, server_state)
         data, weights, num_steps, rkey = staged
         if self._spmd:
@@ -1787,7 +1797,7 @@ class FedSim:
                     global_variables, self._var_shardings)
                 server_state = jax.device_put(server_state, self._rep)
             with trace.span("engine/dispatch", program="spmd_train",
-                            first=self._first_dispatch("spmd_train")):
+                            n_rounds=1):
                 if self._on_device:
                     stack, losses = self._spmd_gather_train_fn(
                         global_variables, self._dataset, data, num_steps,
@@ -1797,21 +1807,21 @@ class FedSim:
                     stack, losses = self._spmd_train_fn(
                         global_variables, data, num_steps, rkey
                     )
+            # the round's second dispatch: n_rounds=0, so that summing
+            # n_rounds over dispatches counts each round once
             with trace.span("engine/dispatch", program="spmd_agg",
-                            first=self._first_dispatch("spmd_agg")):
+                            n_rounds=0):
                 return self._spmd_agg_fn(
                     global_variables, server_state, stack, losses, weights,
                     num_steps, rkey,
                 )
         if self._on_device:
-            with trace.span("engine/dispatch", program="gather",
-                            first=self._first_dispatch("gather")):
+            with trace.span("engine/dispatch", program="gather", n_rounds=1):
                 return self._gather_round_fn(
                     global_variables, server_state, self._dataset, data,
                     weights, num_steps, rkey,
                 )
-        with trace.span("engine/dispatch", program="padded",
-                        first=self._first_dispatch("padded")):
+        with trace.span("engine/dispatch", program="padded", n_rounds=1):
             return self._round_fn(
                 global_variables, server_state, data, weights, num_steps, rkey
             )

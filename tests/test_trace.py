@@ -339,7 +339,7 @@ def test_compress_accumulate_span():
     assert "compress/decode" in names  # q8 takes the dense-decode path
 
 
-def test_engine_round_spans_and_first_dispatch_marker():
+def _blobs_sim(seed, comm_round, frequency_of_the_test, per_round, **over):
     import optax
 
     from fedml_tpu.core.trainer import ClientTrainer
@@ -348,59 +348,85 @@ def test_engine_round_spans_and_first_dispatch_marker():
     from fedml_tpu.sim.engine import FedSim, SimConfig
 
     train, test = gaussian_blobs(
-        n_clients=4, samples_per_client=16, num_classes=3, seed=1
+        n_clients=4, samples_per_client=16, num_classes=3, seed=seed
     )
     trainer = ClientTrainer(
         module=LogisticRegression(num_classes=3),
         optimizer=optax.sgd(0.1), epochs=1,
     )
-    cfg = SimConfig(client_num_in_total=4, client_num_per_round=4,
-                    batch_size=8, comm_round=2, frequency_of_the_test=2,
-                    seed=0)
-    sim = FedSim(trainer, train, test, cfg)
+    cfg = SimConfig(client_num_in_total=4, client_num_per_round=per_round,
+                    batch_size=8, comm_round=comm_round,
+                    frequency_of_the_test=frequency_of_the_test, seed=0,
+                    **over)
+    return FedSim(trainer, train, test, cfg)
+
+
+def test_engine_round_spans_and_compile_spans():
+    """The round driver's spans, and a build recorded where it happened: a
+    cold program's dispatch holds a ``jax/compile`` (or ``jax/cache_load``)
+    child, a warm one none, and a new block length compiles again under the
+    dispatch that needed it, whatever the program kind."""
+    sim = _blobs_sim(1, comm_round=4, frequency_of_the_test=2, per_round=4,
+                     block_dispatch=True)
     tracer = trace.install()
     try:
-        sim.run()
+        variables, _ = sim.run()  # blocks (0, 2), (2, 2)
+        sim.config.comm_round = 10
+        sim.config.frequency_of_the_test = 3
+        sim.run(variables=variables, start_round=4)  # (4, 2), (6, 3), (9, 1)
     finally:
         trace.uninstall()
-    events = tracer.events()
-    names = [e["name"] for e in events]
-    for expected in ("engine/stage", "engine/dispatch", "engine/sync",
-                     "engine/eval"):
-        assert expected in names, names
-    firsts = [e for e in events if e["name"] == "engine/first_dispatch"]
-    assert len(firsts) == 1  # one program kind, marked exactly once
-    dispatches = [e for e in events if e["name"] == "engine/dispatch"]
-    assert [d["args"]["first"] for d in dispatches].count(True) == 1
+    spans = [e for e in tracer.events() if e["ph"] == "X"]
+    names = {e["name"] for e in spans}
+    assert {"engine/stage", "engine/stage/cohort", "engine/stage/put",
+            "engine/stage/keys", "engine/dispatch", "engine/sync",
+            "engine/eval"} <= names, names
+    by_id = {e["args"]["span_id"]: e for e in spans}
+    builds = [e for e in spans if e["name"] in trace.COMPILE_SPANS.values()]
+    built_under = {}
+    for e in builds:
+        parent = by_id.get(e["args"].get("parent_id"))
+        if parent is not None and parent["name"] == "engine/dispatch":
+            built_under.setdefault(parent["args"]["span_id"], []).append(e)
+    dispatches = [e for e in spans if e["name"] == "engine/dispatch"]
+    assert [(d["args"]["program"], d["args"]["n_rounds"]) for d in dispatches] == [
+        ("block2", 2), ("block2", 2), ("block2", 2), ("block3", 3), ("gather", 1)]
+    cold = [d["args"]["span_id"] in built_under for d in dispatches]
+    assert cold == [True, False, False, True, True]
+    for d in dispatches:  # a build lies inside the dispatch that caused it
+        for e in built_under.get(d["args"]["span_id"], []):
+            assert e["tid"] == d["tid"] and e["dur"] <= d["dur"]
+            assert e["ts"] + e["dur"] <= d["ts"] + d["dur"] + 1.0
+    # the children lie inside their parent, on the staging thread
+    stage = next(e for e in spans if e["name"] == "engine/stage")
+    kids = [e for e in spans if e["args"].get("parent_id") == stage["args"]["span_id"]]
+    assert {e["name"] for e in kids} >= {
+        "engine/stage/cohort", "engine/stage/put", "engine/stage/keys"}
+    assert all(e["tid"] == stage["tid"] for e in kids)
+    assert sum(e["dur"] for e in kids
+               if e["name"].startswith("engine/stage/")) <= stage["dur"]
 
 
-def test_traced_run_bit_identical_to_untraced():
-    """Tracing is read-only: same records, same final variables."""
+@pytest.mark.parametrize("block", [False, True])
+def test_traced_run_bit_identical_to_untraced(block, tmp_path):
+    """Tracing is read-only: same records, same final variables, with the
+    spans mirrored into a recording profiler and the staging's child spans
+    on, on the one-round and on the block path."""
     import jax
-    import optax
 
-    from fedml_tpu.core.trainer import ClientTrainer
-    from fedml_tpu.data.synthetic import gaussian_blobs
-    from fedml_tpu.models.linear import LogisticRegression
-    from fedml_tpu.sim.engine import FedSim, SimConfig
+    def sim():
+        return _blobs_sim(2, comm_round=4, frequency_of_the_test=2,
+                          per_round=2, block_dispatch=block)
 
-    train, test = gaussian_blobs(
-        n_clients=4, samples_per_client=16, num_classes=3, seed=2
-    )
-    trainer = ClientTrainer(
-        module=LogisticRegression(num_classes=3),
-        optimizer=optax.sgd(0.1), epochs=1,
-    )
-    cfg = SimConfig(client_num_in_total=4, client_num_per_round=2,
-                    batch_size=8, comm_round=3, frequency_of_the_test=2,
-                    seed=0)
-
-    v_plain, h_plain = FedSim(trainer, train, test, cfg).run()
+    v_plain, h_plain = sim().run()
     trace.install()
+    jax.profiler.start_trace(str(tmp_path))
     try:
-        v_traced, h_traced = FedSim(trainer, train, test, cfg).run()
+        v_traced, h_traced = sim().run()
     finally:
+        jax.profiler.stop_trace()
         trace.uninstall()
+    assert len(h_plain) == len(h_traced) == 4
     for a, b in zip(jax.tree.leaves(v_plain), jax.tree.leaves(v_traced)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for rp, rt in zip(h_plain, h_traced):

@@ -214,6 +214,23 @@ def main(argv=None) -> int:
         assert report["stall_fraction"] is not None
         assert tracer.events(), "tracer recorded nothing"
 
+        # a build is recorded where it happened: the traced sim is a new
+        # FedSim, so its first round dispatch builds (compiles, or loads from
+        # the persistent cache) and the steady rounds build nothing
+        spans = [e for e in tracer.events() if e["ph"] == "X"]
+        dispatches = [e for e in spans if e["name"] == "engine/dispatch"]
+        assert len(dispatches) == ROUNDS, dispatches
+        built = [
+            [b["name"] for b in spans
+             if b["name"] in trace.COMPILE_SPANS.values()
+             and b["args"].get("parent_id") == d["args"]["span_id"]]
+            for d in dispatches
+        ]
+        assert built[0] and not any(built[1:]), (
+            f"builds under the {ROUNDS} round dispatches: {built}; expected "
+            f"one under the first (cold) dispatch only"
+        )
+
         multi = _check_multi_rank(tmp, trace_report, trace_merge)
 
         print(
