@@ -49,7 +49,9 @@ class MultiHeadSelfAttention(nn.Module):
     mp_axis: str | None = None
     # flash kernel tile sizes, tuned on a v5e at T=1024, D_head=128: a tall
     # 256-row query block with the whole 1024-key sequence in one block beat
-    # the 128x128 default by ~4% end-to-end MFU (_pick_block clamps both to T)
+    # the 128x128 default by ~4% end-to-end MFU (_pick_block clamps both to T).
+    # These tile the forward kernel; the backward kernels pick their own
+    # tiles from the shape (ops/attention.py _bwd_blocks)
     block_q: int = 256
     block_k: int = 1024
 

@@ -67,7 +67,8 @@ __all__ = [
     "span", "event", "counter", "gauge", "trace_to", "wire_ctx",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "FLASH_KERNEL_NAME", "COMPILE_SPANS",
+    "SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
+    "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
 JSONL_TRACE_NAME = "trace.jsonl"
@@ -93,6 +94,10 @@ SCOPES = (
     SCOPE_EVAL, SCOPE_PACK_PASS, SCOPE_FLASH_FWD, SCOPE_BLOCKWISE_BWD,
 )
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
+# ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
+# holds "flash_fwd", which the benchmark's forward readers match on
+FLASH_BWD_DKV_KERNEL_NAME = "flash_bwd_dkv"
+FLASH_BWD_DQ_KERNEL_NAME = "flash_bwd_dq"
 
 # jax.monitoring duration events recorded as spans while a tracer is
 # installed: a program was built under the span that is open on the calling

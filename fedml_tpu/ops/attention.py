@@ -10,11 +10,19 @@ sequence-parallel mesh axis (fedml_tpu/parallel/ring_attention.py) which
 reuses the same math.
 
 Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim).
-Forward runs the pallas kernel; backward is a custom VJP that recomputes
-attention blockwise with plain XLA ops — O(T) memory in both directions.
-On the CPU backend the kernel runs in interpreter mode so the full test
-suite exercises it on the 8-device CPU mesh; on TPU it is Mosaic-compiled;
-any other backend is refused (see :func:`_interpret_on`).
+Forward runs the pallas kernel ``flash_fwd``, which also writes each query
+row's log-sum-exp (``B*H*T`` f32, the backward's one extra residual);
+backward is a custom VJP of two more pallas kernels, the standard flash
+backward: ``flash_bwd_dkv`` (a key block a grid step, looping over the query
+blocks that see it) and ``flash_bwd_dq`` (a query block a step, looping over
+key blocks up to the causal limit). Both recompute the scores a tile at a
+time in VMEM from q, k and the log-sum-exp, with matmul operands in the
+input's dtype and f32 accumulation, so memory is O(T) in both directions and
+no ``[T, T]`` tile ever reaches HBM. The backward picks its own tiles
+(:func:`_bwd_blocks`); ``block_q`` / ``block_k`` are the forward's.
+On the CPU backend the kernels run in interpreter mode so the full test
+suite exercises them on the 8-device CPU mesh; on TPU they are
+Mosaic-compiled; any other backend is refused (see :func:`_interpret_on`).
 """
 
 from __future__ import annotations
@@ -24,6 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from fedml_tpu.obs import trace
 
@@ -88,9 +97,29 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
 # ---------------------------------------------------------------------------
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, sm_scale, block_q):
+def _eye(n):
+    return jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == jax.lax.broadcasted_iota(
+        jnp.int32, (n, n), 1
+    )
+
+
+def _row(col):
+    """``[n, 1]`` (one value a sublane) -> ``[1, n]`` (one value a lane) by a
+    masked sublane reduction: exact, ``n * n`` elements once a query block,
+    and faster on the v5e than the reshape Mosaic offers (PERF.md §6, PR 26)."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0, keepdims=True)
+
+
+def _col(row):
+    """``[1, n]`` -> ``[n, 1]``, the inverse of :func:`_row`."""
+    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1, keepdims=True)
+
+
+def _flash_fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, sm_scale, block_q
+):
     # q_ref: [block_q, D]; k_ref/v_ref: [T, D] (whole sequence for this head);
-    # grid = (B*H, T // block_q).
+    # lse_ref: [1, block_q]; grid = (B*H, T // block_q).
     iq = pl.program_id(1)
     q = q_ref[:].astype(jnp.float32) * sm_scale
     t_k, d = k_ref.shape
@@ -136,14 +165,36 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, sm_scale, 
         num_kb_eff = num_kb
     o, l, m = jax.lax.fori_loop(0, num_kb_eff, body, (o, l, m))
     o_ref[:] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    # the backward's residual: log-sum-exp of each query row's scores, one
+    # f32 a row in lanes (a fully masked row keeps about NEG_INF)
+    lse_ref[:] = _row(m + jnp.log(jnp.maximum(l, 1e-20)))
+
+
+def _head_seq(t, d):
+    """A head's whole ``[t, d]`` sequence, resident across the grid's axis 1."""
+    return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
+
+
+def _head_block(block, d):
+    return pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0))
+
+
+def _rows_spec(block):
+    """BlockSpec of a query block's ``[1, block]`` tile of a per-row f32
+    statistic (lse, delta) kept as ``[B*H, T // block, 1, block]``: T * 4
+    bytes a head in HBM, and the tile's last two dimensions are the array's
+    own, so Mosaic takes every ``block`` that :func:`_pick_block` passes."""
+    return pl.BlockSpec((None, None, 1, block), lambda i, j: (i, j, 0, 0))
 
 
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+    """``(out [B, H, T, D], lse [B, H, T] f32)``."""
     b, h, t, d = q.shape
     t_k = k.shape[2]
     block_q = _pick_block(t, block_q, q.dtype)
     block_k = _pick_block(t_k, block_k, k.dtype)
+    nq = t // block_q
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t_k, d)
     vf = v.reshape(b * h, t_k, d)
@@ -154,89 +205,216 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         sm_scale=sm_scale,
         block_q=block_q,
     )
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, t_k, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, t_k, d), lambda i, j: (i, 0, 0)),
+        grid=(b * h, nq),
+        in_specs=[_head_block(block_q, d), _head_seq(t_k, d), _head_seq(t_k, d)],
+        out_specs=[_head_block(block_q, d), _rows_spec(block_q)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, nq, 1, block_q), jnp.float32),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
         name=trace.FLASH_KERNEL_NAME,
     )(qf, kf, vf)
-    return out.reshape(b, h, t, d)
+    return out.reshape(b, h, t, d), lse.reshape(b, h, t)
 
 
 # ---------------------------------------------------------------------------
-# Blockwise backward (plain XLA, O(T·block) memory — never materializes the
-# [T, T] score matrix; standard flash-attention backward recomputation)
+# Pallas backward kernels: the standard two-kernel flash backward. Scores are
+# recomputed a tile at a time in VMEM from q, k and the forward's lse; the
+# [T, T] matrix never reaches HBM, and key blocks that the causal mask hides
+# whole are skipped as the forward skips them.
 # ---------------------------------------------------------------------------
+
+
+def _visible(q_lo, k_lo, shape, transposed):
+    """Causal mask of one score tile: key position <= query position (which
+    carries the right-aligned offset). ``transposed`` tiles are [keys, queries]."""
+    qd, kd = (1, 0) if transposed else (0, 1)
+    q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
+    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, kd)
+    return k_pos <= q_pos
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _flash_bwd_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc, *, block_q, causal, sm_scale,
+):
+    # k_ref/v_ref/dk_ref/dv_ref: [block_k, D]; q_ref/do_ref: [T_q, D] (the
+    # head's whole sequence); lse_ref/delta_ref: [T_q // block_q, 1, block_q];
+    # grid = (B*H, T_k // block_k). Tiles are transposed, [keys, queries], so
+    # the per-query statistics broadcast along sublanes and all four matmuls
+    # are plain NN / NT.
+    jk = pl.program_id(1)
+    block_k = k_ref.shape[0]
+    t_q = q_ref.shape[0]
+    num_qb = t_q // block_q
+    off = pl.num_programs(1) * block_k - t_q
+    k = k_ref[:]
+    v = v_ref[:]
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def step(masked, i):
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[rows, :]
+        do = do_ref[rows, :]
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+        p = jnp.exp(s * sm_scale - lse_ref[i])
+        if masked:
+            # select, not multiply: a fully masked row's lse is about
+            # NEG_INF and exp() of its scores is inf
+            p = jnp.where(
+                _visible(off + i * block_q, jk * block_k, s.shape, True), p, 0.0
+            )
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[i]) * sm_scale
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32
+        )
+        dk_acc[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32
+        )
+
+    def loop(lo, hi, masked):
+        jax.lax.fori_loop(lo, hi, lambda i, c: step(masked, i), None)
+
+    if causal:
+        # query blocks whose last row reaches this key block's first column,
+        # and of those the ones whose first row sees its last column (no mask)
+        first = jnp.clip((jk * block_k - off) // block_q, 0, num_qb)
+        first_whole = jnp.clip(
+            ((jk + 1) * block_k - 1 - off + block_q - 1) // block_q, first, num_qb
+        )
+        loop(first, first_whole, True)
+        loop(first_whole, num_qb, False)
+    else:
+        loop(0, num_qb, False)
+    dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+    dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
+    *, block_k, causal, sm_scale,
+):
+    # q_ref/do_ref/dq_ref: [block_q, D]; k_ref/v_ref: [T_k, D] (the head's
+    # whole sequence); lse_ref/delta_ref: [1, block_q];
+    # grid = (B*H, T_q // block_q).
+    iq = pl.program_id(1)
+    block_q = q_ref.shape[0]
+    t_k = k_ref.shape[0]
+    num_kb = t_k // block_k
+    off = t_k - pl.num_programs(1) * block_q
+    q = q_ref[:]
+    do = do_ref[:]
+    lse = _col(lse_ref[:])
+    delta = _col(delta_ref[:])
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def step(masked, j):
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[cols, :]
+        v = v_ref[cols, :]
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+        p = jnp.exp(s * sm_scale - lse)
+        if masked:
+            p = jnp.where(
+                _visible(off + iq * block_q, j * block_k, s.shape, False), p, 0.0
+            )
+        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * sm_scale
+        dq_acc[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32
+        )
+
+    def loop(lo, hi, masked):
+        jax.lax.fori_loop(lo, hi, lambda j, c: step(masked, j), None)
+
+    if causal:
+        # key blocks up to this query block's last position (as the forward's
+        # num_kb_eff), and of those the ones its first row sees whole (no mask)
+        last = jnp.clip((off + (iq + 1) * block_q - 1) // block_k + 1, 0, num_kb)
+        whole = jnp.clip((off + iq * block_q + 1) // block_k, 0, last)
+        loop(0, whole, False)
+        loop(whole, last, True)
+    else:
+        loop(0, num_kb, False)
+    dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
+    """The backward kernels' ``(block_q, block_k)``. Measured on the v5e at
+    D 128 bf16, causal: 512 x 512 is the fastest for both kernels at T 2048
+    (2.17 ms a call; 256 x 256 2.73, 1024 x 1024 2.35) and at T 1024 (0.77;
+    0.91, 0.85): tiles large enough to amortise the loop, small enough that
+    the causal diagonal wastes an eighth of the square and not a quarter, and
+    every temporary of a step fits the 16 MB of scoped VMEM wherever the
+    forward's whole-sequence K and V do (PERF.md §6, PR 26). A length whose
+    divisor under 512 Mosaic refuses keeps the forward's block, which passed."""
+
+    def pick(t, fwd_block):
+        try:
+            return _pick_block(t, 512, dtype)
+        except ValueError:
+            return _pick_block(t, fwd_block, dtype)
+
+    return pick(t_q, fwd_blocks[0]), pick(t_k, fwd_blocks[1])
 
 
 @jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
-def _blockwise_bwd(q, k, v, out, g, causal, sm_scale, block_k):
+def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret):
+    """``(dq, dk, dv)``; ``block_q`` / ``block_k`` divide ``t_q`` / ``t_k``
+    (:func:`_bwd_blocks` picks them through :func:`_pick_block`)."""
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    block_k = _pick_block(t_k, block_k, k.dtype)
-    nkb = t_k // block_k
-    qf = q.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    off = t_k - t_q
-
-    # log-sum-exp per query row, recomputed blockwise
-    q_pos = off + jnp.arange(t_q)
-
-    def lse_step(carry, j):
-        m, l = carry
-        k_blk = jax.lax.dynamic_slice_in_dim(k, j * block_k, block_k, 2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk.astype(jnp.float32)) * sm_scale
-        if causal:
-            k_pos = j * block_k + jnp.arange(block_k)
-            s = jnp.where((k_pos[None] <= q_pos[:, None])[None, None], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # masked entries must contribute 0, not exp(NEG_INF - NEG_INF) = 1
-        # (NEG_INF is finite; a fully masked row keeps m_new at NEG_INF)
-        e = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        l = l * jnp.exp(m - m_new) + jnp.sum(e, axis=-1, keepdims=True)
-        return (m_new, l), None
-
-    m0 = jnp.full((b, h, t_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, h, t_q, 1), jnp.float32)
-    (m, l), _ = jax.lax.scan(lse_step, (m0, l0), jnp.arange(nkb))
-    lse = m + jnp.log(jnp.maximum(l, 1e-20))
-
+    nq, nk = t_q // block_q, t_k // block_k
     # D_i = rowsum(dO * O)
-    delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1, keepdims=True)
-
-    def grad_step(dq, j):
-        k_blk = jax.lax.dynamic_slice_in_dim(k, j * block_k, block_k, 2).astype(jnp.float32)
-        v_blk = jax.lax.dynamic_slice_in_dim(v, j * block_k, block_k, 2).astype(jnp.float32)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk) * sm_scale
-        if causal:
-            k_pos = j * block_k + jnp.arange(block_k)
-            s = jnp.where((k_pos[None] <= q_pos[:, None])[None, None], s, NEG_INF)
-        # zero masked entries like the forward kernel does — for a fully
-        # masked row lse is ~NEG_INF too and exp(s - lse) would be O(1)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))  # [b,h,t_q,block_k]
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, v_blk)
-        ds = p * (dp - delta) * sm_scale
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk)
-        dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        return dq, (dk_blk, dv_blk)
-
-    dq0 = jnp.zeros_like(qf)
-    dq, (dk_blocks, dv_blocks) = jax.lax.scan(grad_step, dq0, jnp.arange(nkb))
-    dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(b, h, t_k, d)
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(b, h, t_k, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    args = (
+        q.reshape(b * h, t_q, d), k.reshape(b * h, t_k, d), v.reshape(b * h, t_k, d),
+        g.reshape(b * h, t_q, d),
+        lse.reshape(b * h, nq, 1, block_q), delta.reshape(b * h, nq, 1, block_q),
+    )
+    q_seq, k_seq = _head_seq(t_q, d), _head_seq(t_k, d)
+    q_blk, k_blk = _head_block(block_q, d), _head_block(block_k, d)
+    # a head's every [1, block_q] tile of lse / delta, resident like q_seq
+    rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale
+        ),
+        grid=(b * h, nk),
+        in_specs=[q_seq, k_blk, k_blk, q_seq, rows_seq, rows_seq],
+        out_specs=[k_blk, k_blk],
+        out_shape=[jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2,
+        interpret=interpret,
+        name=trace.FLASH_BWD_DKV_KERNEL_NAME,
+    )(*args)
+    dq = pl.pallas_call(
+        functools.partial(
+            _flash_bwd_dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale
+        ),
+        grid=(b * h, nq),
+        in_specs=[q_blk, k_seq, k_seq, q_blk, _rows_spec(block_q), _rows_spec(block_q)],
+        out_specs=q_blk,
+        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret=interpret,
+        name=trace.FLASH_BWD_DQ_KERNEL_NAME,
+    )(*args)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
-# Public API: pallas forward + blockwise backward
+# Public API: pallas forward + pallas backward
 # ---------------------------------------------------------------------------
 
 
@@ -252,14 +430,16 @@ def flash_attention(
 ):
     """Blockwise fused attention for ``[B, H, T, D]`` inputs.
 
-    Forward = pallas kernel (interpreter mode on the CPU); backward = blockwise
-    recomputation in plain XLA — O(T·block) memory in both directions, the
-    [T, T] score matrix is never materialized.
+    Forward = pallas kernel (interpreter mode on the CPU); backward = two
+    pallas kernels that recompute the scores blockwise from the forward's
+    log-sum-exp — O(T·block) memory in both directions, the [T, T] score
+    matrix is never materialized. ``block_q`` / ``block_k`` tile the forward;
+    the backward chooses its own tiles from the shape and dtype.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     interpret = _interpret_on(jax.default_backend())
-    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)[0]
 
 
 def flash_attention_head_parallel(
@@ -335,15 +515,24 @@ def flash_attention_head_parallel(
 
 
 def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
-    out = flash_attention(q, k, v, causal, sm_scale, block_q, block_k)
-    return out, (q, k, v, out)
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    interpret = _interpret_on(jax.default_backend())
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    return out, (q, k, v, out, lse)
 
 
 def _bwd_rule(causal, sm_scale, block_q, block_k, res, g):
-    q, k, v, out = res
+    q, k, v, out, lse = res
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _blockwise_bwd(q, k, v, out, g, causal, sm_scale, block_k)
+    blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype, (block_q, block_k))
+    trace.event(
+        "attn/bwd_path", impl="kernel", shape=tuple(q.shape), t_k=k.shape[2],
+        dtype=jnp.dtype(q.dtype).name, blocks=blocks,
+    )
+    interpret = _interpret_on(jax.default_backend())
+    return _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, interpret)
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)

@@ -83,11 +83,21 @@ def test_flash_wrap_lowers_for_tpu_under_a_sharded_plan(monkeypatch):
     def lowered_for_tpu(fn):
         with mesh:
             return jax.jit(fn, in_shardings=(rep,) * 3, out_shardings=rep) \
-                .trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+                .trace(q, q, q).lower(lowering_platforms=("tpu",)) \
+                .as_text(debug_info=True)
 
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         lowered_for_tpu(lambda q, k, v: att.flash_attention(q, k, v, True))
     for axis in (None, "model"):  # gather-for-compute plan, tensor-parallel plan
-        text = lowered_for_tpu(lambda q, k, v: att.flash_attention_head_parallel(
-            q, k, v, axis=axis, causal=True))
+        def attend(q, k, v, axis=axis):
+            return att.flash_attention_head_parallel(q, k, v, axis=axis, causal=True)
+
+        text = lowered_for_tpu(attend)
         assert "tpu_custom_call" in text
+        # the custom VJP sits inside the all-axes shard_map, so the two
+        # backward kernels lower per device too, on local heads
+        text = lowered_for_tpu(jax.grad(
+            lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+        assert text.count("tpu_custom_call") >= 3
+        for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+            assert kernel in text, (axis, kernel)

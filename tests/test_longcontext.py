@@ -171,6 +171,116 @@ def test_flash_bwd_fully_masked_rows(rng):
         np.testing.assert_allclose(a, b_, atol=1e-4)
 
 
+def _masked_reference(q, k, v, causal):
+    """attention_reference in f32 with fully masked query rows (causal,
+    t_q > t_k) forced to the kernels' zero output, and the log-sum-exp of
+    each row's scores."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    t_q, t_k = q.shape[2], k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    mask = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q) if causal \
+        else jnp.ones((t_q, t_k), bool)
+    s = jnp.where(mask, s, -jnp.inf)
+    seen = mask.any(-1)[:, None]
+    p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, 0.0), axis=-1), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v), jax.nn.logsumexp(s, axis=-1)
+
+
+# (t_q, t_k, d, dtype, blocks): several blocks a side, so that the causal
+# skipping and both loops of each kernel (masked tiles, whole tiles) engage
+_BWD_CASES = [
+    (64, 64, 8, jnp.float32, (16, 16)),
+    (64, 64, 8, jnp.float32, (32, 16)),    # block_q != block_k, both ways
+    (64, 64, 8, jnp.float32, (16, 32)),
+    (32, 64, 8, jnp.float32, (16, 16)),    # t_q < t_k: right-aligned offset
+    (32, 16, 8, jnp.float32, (8, 8)),      # t_q > t_k: fully masked rows
+    (64, 64, 16, jnp.bfloat16, (16, 16)),
+    (32, 64, 16, jnp.bfloat16, (16, 32)),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t_q,t_k,d,dtype,blocks", _BWD_CASES)
+def test_flash_bwd_kernels_match_reference(rng, causal, t_q, t_k, d, dtype, blocks):
+    """dq, dk, dv of the two backward kernels, and the forward kernel's lse
+    that feeds them, against jax's own gradient of the plain reference."""
+    from fedml_tpu.ops.attention import _flash_bwd, _flash_fwd
+
+    q, k, v, g = (jnp.asarray(rng.randn(2, 2, t, d), dtype)
+                  for t in (t_q, t_k, t_k, t_q))
+    sm_scale = d ** -0.5
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, *blocks, True)
+    got = _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, True)
+    assert lse.shape == (2, 2, t_q) and lse.dtype == jnp.float32
+    (want_out, want_lse), vjp = jax.vjp(
+        lambda q, k, v: _masked_reference(q, k, v, causal), q, k, v)
+    want = vjp((g.astype(jnp.float32), jnp.zeros_like(want_lse)))
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    seen = np.isfinite(np.asarray(want_lse))  # a fully masked row keeps ~NEG_INF
+    np.testing.assert_allclose(np.asarray(lse)[seen], np.asarray(want_lse)[seen],
+                               atol=tol)
+    assert np.all(np.asarray(lse)[~seen] < -1e29)
+    np.testing.assert_allclose(out.astype(jnp.float32), want_out, atol=tol)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype, name
+        np.testing.assert_allclose(a.astype(jnp.float32), b, atol=tol * 10,
+                                   rtol=tol, err_msg=name)
+    if causal and t_q > t_k:
+        np.testing.assert_array_equal(np.asarray(got[0][:, :, :t_q - t_k]), 0.0)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_gradients_with_the_picked_blocks(rng, causal):
+    """The public path at the kernels' own width: T 256, D 128, bf16, the
+    backward's tiles chosen by ``_bwd_blocks`` (not the forward's 64 x 64)."""
+    from fedml_tpu.ops.attention import _bwd_blocks
+
+    q, k, v, g = (jnp.asarray(rng.randn(1, 2, 256, 128), jnp.bfloat16)
+                  for _ in range(4))
+    assert _bwd_blocks(256, 256, jnp.bfloat16, (64, 64)) == (256, 256)
+    assert _bwd_blocks(2048, 1024, jnp.bfloat16, (256, 1024)) == (512, 512)
+    # 1072 = 16 x 67: no divisor up to 512 is a sublane multiple but 16
+    assert _bwd_blocks(1072, 1072, jnp.float32, (16, 16)) == (16, 16)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                       * g.astype(jnp.float32))
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(q, k, v, causal, None, 64, 64)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _masked_reference(q, k, v, causal)[0]),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        assert err <= 2e-2, (name, err)  # chip_smoke.py's bound
+
+
+def test_flash_bwd_path_event(rng):
+    """Tracing a gradient records one ``attn/bwd_path`` event a custom-VJP
+    backward, so a fallback, were one left, is seen and counted."""
+    from fedml_tpu.obs import trace
+
+    q, k, v = _qkv(rng, t=32)
+
+    def loss(q, k, v):  # two attention calls: two backwards
+        a = flash_attention(q, k, v, True, None, 8, 8)
+        return jnp.sum(flash_attention(a, k, v, False, None, 8, 8))
+
+    tracer = trace.install()
+    try:
+        jax.jit(jax.grad(loss)).lower(q, k, v)
+        jax.jit(loss).lower(q, k, v)  # a forward alone records nothing
+    finally:
+        trace.uninstall()
+    events = [e for e in tracer.events() if e["name"] == "attn/bwd_path"]
+    assert len(events) == 2
+    for e in events:
+        assert e["args"]["impl"] == "kernel"
+        assert tuple(e["args"]["shape"]) == (2, 2, 32, 8)
+        assert tuple(e["args"]["blocks"]) == (32, 32)
+
+
 @pytest.mark.slow  # compile-heavy on XLA:CPU; kept out of the fast gate
 def test_transformer_remat_matches_plain():
     """jax.checkpoint on blocks must not change values or gradients."""
