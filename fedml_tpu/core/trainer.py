@@ -35,6 +35,19 @@ from fedml_tpu.obs import trace
 Pytree = Any
 Batch = dict[str, jnp.ndarray]
 
+# A module may ``sow`` per-step statistics of its own (routing counts of an
+# expert layer) into this collection while training. They are no model state:
+# ``init`` drops them, a training step returns them beside the loss, and
+# ``make_local_train`` hands their mean over the client's steps to the round
+# program as ``stats/<path>`` metrics.
+STATS_COLLECTION = "stats"
+STATS_PREFIX = "stats/"
+
+
+def _flat_stats(tree: Pytree) -> dict[str, jnp.ndarray]:
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {"/".join(str(getattr(p, "key", p)) for p in path): leaf for path, leaf in flat}
+
 # ---------------------------------------------------------------------------
 # Task losses / metrics
 # ---------------------------------------------------------------------------
@@ -189,7 +202,7 @@ class ClientTrainer:
         variables = self.module.init(
             {"params": rng, "dropout": rng}, sample_batch["x"], train=False
         )
-        return dict(variables)
+        return {k: v for k, v in variables.items() if k != STATS_COLLECTION}
 
     # -- single gradient step on one masked batch ------------------------------
 
@@ -199,10 +212,11 @@ class ClientTrainer:
             {"params": params, **model_state},
             batch["x"],
             train=True,
-            mutable=list(model_state.keys()),
+            mutable=[*model_state.keys(), STATS_COLLECTION],
             rngs={"dropout": rng},
         )
         logits, new_model_state = out
+        stats = _flat_stats(new_model_state.pop(STATS_COLLECTION, {}))
         with jax.named_scope(trace.SCOPE_LOSS):
             loss = self.loss_and_metrics[0](logits, batch)
         if self.prox_mu > 0.0:
@@ -210,16 +224,22 @@ class ClientTrainer:
 
             diff = treelib.tree_sub(params, global_params)
             loss = loss + 0.5 * self.prox_mu * treelib.tree_dot(diff, diff)
-        return loss, new_model_state
+        return loss, (new_model_state, stats)
 
     def train_step(self, variables: Pytree, opt_state, global_params: Pytree,
                    batch: Batch, rng: jax.Array):
+        return self.train_step_stats(variables, opt_state, global_params, batch, rng)[:3]
+
+    def train_step_stats(self, variables: Pytree, opt_state, global_params: Pytree,
+                         batch: Batch, rng: jax.Array):
+        """:meth:`train_step`, and the step's ``stats`` ({} from a module
+        that sows none)."""
         params = variables["params"]
         model_state = {k: v for k, v in variables.items() if k != "params"}
         # jax marks the backward ops ``transpose(jvp(...))`` inside the scope,
         # which is what splits forward from backward in a device trace
         with jax.named_scope(trace.SCOPE_FWD_BWD):
-            (loss, new_model_state), grads = jax.value_and_grad(
+            (loss, (new_model_state, stats)), grads = jax.value_and_grad(
                 self.loss_fn, has_aux=True
             )(params, model_state, global_params, batch, rng)
         with jax.named_scope(trace.SCOPE_OPT):
@@ -237,7 +257,7 @@ class ClientTrainer:
             new_model_state = jax.tree.map(
                 lambda n, o: jnp.where(has_data, n, o), new_model_state, model_state
             )
-        return {"params": new_params, **new_model_state}, new_opt_state, loss
+        return {"params": new_params, **new_model_state}, new_opt_state, loss, stats
 
     # -- evaluation ------------------------------------------------------------
 
@@ -290,19 +310,20 @@ def make_local_train(trainer: ClientTrainer):
                     batch = dict(batch)
                     batch["mask"] = batch["mask"] * active
                 rng, step_rng = jax.random.split(rng)
-                variables, opt_state, loss = trainer.train_step(
+                variables, opt_state, loss, stats = trainer.train_step_stats(
                     variables, opt_state, global_params, batch, step_rng
                 )
                 # weight for the loss average: did this step see any data?
                 w = (jnp.sum(batch["mask"]) > 0).astype(jnp.float32)
-                return (variables, opt_state, rng), (loss, w)
+                return (variables, opt_state, rng), (loss, w, stats)
 
-            (variables, opt_state, rng), (losses, ws) = scanlib.scan(
+            (variables, opt_state, rng), (losses, ws, stats) = scanlib.scan(
                 step_body, (variables, opt_state, rng), (jnp.arange(S), data)
             )
-            return (variables, opt_state, rng), (jnp.sum(losses * ws), jnp.sum(ws))
+            stat_sums = jax.tree.map(lambda s: jnp.tensordot(ws, s, 1), stats)
+            return (variables, opt_state, rng), (jnp.sum(losses * ws), jnp.sum(ws), stat_sums)
 
-        (variables, opt_state, rng), (loss_sums, w_sums) = scanlib.scan(
+        (variables, opt_state, rng), (loss_sums, w_sums, stat_sums) = scanlib.scan(
             epoch_body, (global_variables, opt_state, rng), jnp.arange(trainer.epochs)
         )
         # mean loss over executed (unmasked) steps of the last executed epoch
@@ -315,6 +336,10 @@ def make_local_train(trainer: ClientTrainer):
         metrics = {
             "train_loss": loss_sums[last] / jnp.maximum(w_sums[last], 1.0)
         }
+        # the module's own statistics: their mean over the executed steps
+        steps = jnp.maximum(jnp.sum(w_sums), 1.0)
+        for name, sums in stat_sums.items():
+            metrics[STATS_PREFIX + name] = jnp.sum(sums, axis=0) / steps
         return variables, metrics
 
     return local_train

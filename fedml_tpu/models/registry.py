@@ -84,6 +84,13 @@ def _create(model_name: str, output_dim: int, dataset: str = "") -> Any:
         # long-context LM client (no reference equivalent — extends the zoo
         # past nlp/rnn.py; attn_impl flash/ring for single-/multi-chip)
         return TransformerLM(vocab_size=output_dim)
+    if model_name == "moe_transformer":
+        # routed experts, grouped KV heads, window and global layers mixed
+        # (models/moe_transformer.py); its widths and the share of experts
+        # held come from the caller (benchmark/families/moe_lm.py)
+        from fedml_tpu.models.moe_transformer import MoETransformerLM
+
+        return MoETransformerLM(vocab_size=output_dim)
     if model_name.startswith("vgg"):
         depth = int(model_name[3:] or 16)
         return VGG(depth=depth, num_classes=output_dim)

@@ -65,9 +65,10 @@ from typing import Any
 __all__ = [
     "Tracer", "install", "uninstall", "get", "enabled",
     "span", "event", "counter", "gauge", "trace_to", "wire_ctx",
+    "program_note", "program_notes", "last_counters",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
+    "SCOPES", "MOE_SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
     "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
@@ -93,6 +94,13 @@ SCOPES = (
     SCOPE_GATHER, SCOPE_FWD_BWD, SCOPE_LOSS, SCOPE_OPT, SCOPE_AGGREGATE,
     SCOPE_EVAL, SCOPE_PACK_PASS, SCOPE_FLASH_FWD, SCOPE_BLOCKWISE_BWD,
 )
+# Scopes of the routed-expert layer (ops/moe.py), inside SCOPE_FWD_BWD: a
+# tuple of their own, because SCOPES is held equal to the benchmark's copy
+SCOPE_MOE_ROUTE = "moe/route"
+SCOPE_MOE_DISPATCH = "moe/dispatch"
+SCOPE_MOE_EXPERTS = "moe/experts"
+SCOPE_MOE_COMBINE = "moe/combine"
+MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS, SCOPE_MOE_COMBINE)
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
 # holds "flash_fwd", which the benchmark's forward readers match on
@@ -550,13 +558,41 @@ def event(name: str, **attrs: Any) -> None:
         t.event(name, **attrs)
 
 
+# What outlives the tracer, for a reader that is handed spans only (the
+# benchmark's layer metrics): the last sample of each counter recorded while
+# a tracer was installed, and the facts noted while programs were traced
+# (jit tracing happens before any tracer is installed, and once a shape).
+_last_counters: dict[str, float] = {}
+_program_notes: dict[str, dict[tuple, dict]] = {}
+
+
 def counter(name: str, value: float, **attrs: Any) -> None:
     t = get()
     if t is not None:
         t.counter(name, value, **attrs)
+        _last_counters[name] = float(value)
 
 
 gauge = counter
+
+
+def last_counters(prefix: str = "") -> dict[str, float]:
+    """{name: last value} of the counters whose name starts with ``prefix``."""
+    return {k: v for k, v in _last_counters.items() if k.startswith(prefix)}
+
+
+def program_note(name: str, **attrs: Any) -> None:
+    """Record a trace-time fact about a program being built (which kernel,
+    which tiles): kept once per distinct ``attrs`` whether or not a tracer
+    is installed, and an instant event ``name`` when one is. ``attrs``
+    values are hashable. Runs while jax traces, never in a hot path."""
+    _program_notes.setdefault(name, {})[tuple(sorted(attrs.items()))] = attrs
+    event(name, **attrs)
+
+
+def program_notes(name: str) -> list[dict]:
+    """The distinct facts noted under ``name``, in the order first seen."""
+    return list(_program_notes.get(name, {}).values())
 
 
 def wire_ctx(origin: int | None = None) -> dict | None:

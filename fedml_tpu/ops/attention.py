@@ -9,7 +9,11 @@ the O(T²) score matrix), and the multi-chip path is ring attention over a
 sequence-parallel mesh axis (fedml_tpu/parallel/ring_attention.py) which
 reuses the same math.
 
-Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim).
+Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim). K and V
+may hold fewer heads than Q (grouped KV heads: query head ``n`` reads KV head
+``n // (H // H_kv)``), and ``window`` limits a query to the last ``window``
+keys up to and including its own position; both are static, and with equal
+head counts and ``window=None`` the kernels are the programs they were.
 Forward runs the pallas kernel ``flash_fwd``, which also writes each query
 row's log-sum-exp (``B*H*T`` f32, the backward's one extra residual);
 backward is a custom VJP of two more pallas kernels, the standard flash
@@ -75,21 +79,44 @@ def _pick_block(t: int, preferred: int, dtype) -> int:
     return b
 
 
-def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = None):
+def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = None,
+                        window: int | None = None):
     """Plain XLA attention, the numerical oracle for the kernels.
 
     Causal convention (shared with the pallas kernel): query i attends to
     keys j with j <= i + (t_k - t_q) — i.e. sequences are right-aligned, the
-    standard decode convention."""
+    standard decode convention. ``window`` (with ``causal``) counts the query
+    itself: key j is visible to query i iff i - window < j <= i, so a query
+    sees at most ``window`` keys and ``window >= t_k`` is plain causal. K and V
+    with fewer heads than Q are grouped: query head n reads KV head
+    n // (H // H_kv)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    group = _kv_group(q.shape[1], k.shape[1])
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
+    _check_window(window, causal)
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def _kv_group(h: int, h_kv: int) -> int:
+    """Query heads a KV head (1 = plain multi-head attention)."""
+    if h % h_kv:
+        raise ValueError(f"attention: {h} query heads do not divide into {h_kv} KV heads")
+    return h // h_kv
+
+
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError("attention: a window needs causal=True and window >= 1")
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +143,8 @@ def _col(row):
 
 
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, sm_scale, block_q
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, sm_scale, block_q,
+    window=None,
 ):
     # q_ref: [block_q, D]; k_ref/v_ref: [T, D] (whole sequence for this head);
     # lse_ref: [1, block_q]; grid = (B*H, T // block_q).
@@ -142,7 +170,10 @@ def _flash_fwd_kernel(
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            seen = k_pos <= q_pos
+            if window is not None:
+                seen &= k_pos > q_pos - window
+            s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         if causal:
@@ -157,26 +188,32 @@ def _flash_fwd_kernel(
     o = jnp.zeros((block_q, d), jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    if causal:
-        # only key blocks at or before this query block's last position
-        last_q_pos = (t_k - t_q) + (iq + 1) * block_q - 1
-        num_kb_eff = jnp.clip(last_q_pos // block_k + 1, 0, num_kb)
-    else:
-        num_kb_eff = num_kb
-    o, l, m = jax.lax.fori_loop(0, num_kb_eff, body, (o, l, m))
+    # only key blocks at or before this query block's last position, and
+    # under a window none that ends before its first row's window opens
+    first_kb, num_kb_eff = _fwd_kb_range(
+        iq, block_q, block_k, t_k - t_q, num_kb, causal, window
+    )
+    o, l, m = jax.lax.fori_loop(first_kb, num_kb_eff, body, (o, l, m))
     o_ref[:] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     # the backward's residual: log-sum-exp of each query row's scores, one
     # f32 a row in lanes (a fully masked row keeps about NEG_INF)
     lse_ref[:] = _row(m + jnp.log(jnp.maximum(l, 1e-20)))
 
 
-def _head_seq(t, d):
-    """A head's whole ``[t, d]`` sequence, resident across the grid's axis 1."""
-    return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
+def _head_seq(t, d, group=1):
+    """A head's whole ``[t, d]`` sequence, resident across the grid's axis 1.
+    With ``group`` query heads a KV head, grid step ``i`` (a flat batch x
+    query head) reads the KV head ``i // group``: consecutive steps of one
+    group name the same block, so it is fetched once."""
+    if group == 1:
+        return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
+    return pl.BlockSpec((None, t, d), lambda i, j: (i // group, 0, 0))
 
 
-def _head_block(block, d):
-    return pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0))
+def _head_block(block, d, group=1):
+    if group == 1:
+        return pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0))
+    return pl.BlockSpec((None, block, d), lambda i, j: (i // group, j, 0))
 
 
 def _rows_spec(block):
@@ -188,27 +225,32 @@ def _rows_spec(block):
 
 
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
     """``(out [B, H, T, D], lse [B, H, T] f32)``."""
     b, h, t, d = q.shape
-    t_k = k.shape[2]
+    h_kv, t_k = k.shape[1], k.shape[2]
+    group = _kv_group(h, h_kv)
+    _check_window(window, causal)
     block_q = _pick_block(t, block_q, q.dtype)
     block_k = _pick_block(t_k, block_k, k.dtype)
     nq = t // block_q
     qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d)
+    kf = k.reshape(b * h_kv, t_k, d)
+    vf = v.reshape(b * h_kv, t_k, d)
     kernel = functools.partial(
         _flash_fwd_kernel,
         block_k=block_k,
         causal=causal,
         sm_scale=sm_scale,
         block_q=block_q,
+        window=window,
     )
+    _note_call("fwd", q, t_k, group, causal, window, block_q, block_k)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq),
-        in_specs=[_head_block(block_q, d), _head_seq(t_k, d), _head_seq(t_k, d)],
+        in_specs=[_head_block(block_q, d), _head_seq(t_k, d, group),
+                  _head_seq(t_k, d, group)],
         out_specs=[_head_block(block_q, d), _rows_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
@@ -228,13 +270,99 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _visible(q_lo, k_lo, shape, transposed):
-    """Causal mask of one score tile: key position <= query position (which
-    carries the right-aligned offset). ``transposed`` tiles are [keys, queries]."""
+def _visible(q_lo, k_lo, shape, transposed, window=None):
+    """Mask of one score tile: key position <= query position (which carries
+    the right-aligned offset) and, under a window, > query position - window.
+    ``transposed`` tiles are [keys, queries]."""
     qd, kd = (1, 0) if transposed else (0, 1)
     q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
     k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, kd)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (k_pos > q_pos - window)
+
+
+def _clip(x, lo, hi):
+    """``clip`` for a kernel's traced block indices and for the plain ints
+    the tile counts of :func:`_note_call` are made of."""
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+# Which tiles a grid step visits. Rows of query block i are off + i * block_q
+# ... off + (i + 1) * block_q - 1 (off: the right-aligned offset t_k - t_q),
+# columns of key block j are j * block_k ... (j + 1) * block_k - 1; a pair is
+# visible iff k <= q and, under a window, k > q - window. Each function gives
+# half-open ranges of block indices; tiles outside them are hidden whole and
+# never visited, tiles in a "whole" range need no mask arithmetic.
+
+
+def _fwd_kb_range(iq, block_q, block_k, off, num_kb, causal, window):
+    """Key blocks ``[lo, hi)`` the forward visits for query block ``iq``."""
+    lo, hi = 0, num_kb
+    if causal:
+        hi = _clip((off + (iq + 1) * block_q - 1) // block_k + 1, 0, num_kb)
+    if window is not None:
+        lo = _clip((off + iq * block_q - window + 1) // block_k, 0, hi)
+    return lo, hi
+
+
+def _dq_kb_ranges(iq, block_q, block_k, off, num_kb, window):
+    """``(start, whole_start, whole_end, last)`` of the causal dq kernel's
+    key blocks: ``[start, whole_start)`` are cut by the window's edge,
+    ``[whole_start, whole_end)`` are seen whole, ``[whole_end, last)`` are
+    cut by the diagonal. Without a window ``start = whole_start = 0``."""
+    q_lo, q_hi = off + iq * block_q, off + (iq + 1) * block_q - 1
+    last = _clip(q_hi // block_k + 1, 0, num_kb)
+    whole_end = _clip((q_lo + 1) // block_k, 0, last)
+    if window is None:
+        return 0, 0, whole_end, last
+    start = _clip((q_lo - window + 1) // block_k, 0, last)
+    whole_start = _clip((q_hi - window + block_k) // block_k, start, last)
+    return start, whole_start, _clip(whole_end, whole_start, last), last
+
+
+def _dkv_qb_ranges(jk, block_q, block_k, off, num_qb, window):
+    """``(first, first_whole, end_whole, end)`` of the causal dkv kernel's
+    query blocks: ``[first, first_whole)`` are cut by the diagonal,
+    ``[first_whole, end_whole)`` see the key block whole, ``[end_whole, end)``
+    are cut by the window's edge. Without a window ``end_whole = end = num_qb``."""
+    k_lo, k_hi = jk * block_k, (jk + 1) * block_k - 1
+    first = _clip((k_lo - off) // block_q, 0, num_qb)
+    first_whole = (k_hi - off + block_q - 1) // block_q
+    if window is None:
+        return first, _clip(first_whole, first, num_qb), num_qb, num_qb
+    end = _clip((k_hi + window - 1 - off) // block_q + 1, first, num_qb)
+    first_whole = _clip(first_whole, first, end)
+    end_whole = _clip((k_lo + window - off) // block_q, first_whole, end)
+    return first, first_whole, end_whole, end
+
+
+def _note_call(kernel, q, t_k, group, causal, window, block_q, block_k):
+    """Record, while the program is traced, what one attention call will do:
+    its kind, its grouping and how many of the square's tiles the kernel
+    visits (``obs/trace.py`` :func:`program_note`; docs/OBSERVABILITY.md)."""
+    t_q = q.shape[2]
+    nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
+    if not causal:
+        visited = nq * nk
+    elif kernel == "fwd":
+        visited = sum(hi - lo for lo, hi in (
+            _fwd_kb_range(i, block_q, block_k, off, nk, True, window) for i in range(nq)))
+    elif kernel == "dq":
+        visited = sum(r[3] - r[0] for r in (
+            _dq_kb_ranges(i, block_q, block_k, off, nk, window) for i in range(nq)))
+    else:
+        visited = sum(r[3] - r[0] for r in (
+            _dkv_qb_ranges(j, block_q, block_k, off, nq, window) for j in range(nk)))
+    trace.program_note(
+        "attn/call", kernel=kernel,
+        kind="window" if window is not None else "global" if causal else "full",
+        window=window, shape=tuple(q.shape), t_k=t_k, q_heads_per_kv_head=group,
+        dtype=jnp.dtype(q.dtype).name, tile=(block_q, block_k),
+        tiles_visited=visited, tiles_total=nq * nk,
+    )
 
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -243,7 +371,7 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, block_q, causal, sm_scale,
+    dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None,
 ):
     # k_ref/v_ref/dk_ref/dv_ref: [block_k, D]; q_ref/do_ref: [T_q, D] (the
     # head's whole sequence); lse_ref/delta_ref: [T_q // block_q, 1, block_q];
@@ -270,7 +398,8 @@ def _flash_bwd_dkv_kernel(
             # select, not multiply: a fully masked row's lse is about
             # NEG_INF and exp() of its scores is inf
             p = jnp.where(
-                _visible(off + i * block_q, jk * block_k, s.shape, True), p, 0.0
+                _visible(off + i * block_q, jk * block_k, s.shape, True, window),
+                p, 0.0,
             )
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[i]) * sm_scale
@@ -286,13 +415,15 @@ def _flash_bwd_dkv_kernel(
 
     if causal:
         # query blocks whose last row reaches this key block's first column,
-        # and of those the ones whose first row sees its last column (no mask)
-        first = jnp.clip((jk * block_k - off) // block_q, 0, num_qb)
-        first_whole = jnp.clip(
-            ((jk + 1) * block_k - 1 - off + block_q - 1) // block_q, first, num_qb
+        # of those the ones whose first row sees its last column (no mask),
+        # and under a window the ones its edge cuts, then none
+        first, first_whole, end_whole, end = _dkv_qb_ranges(
+            jk, block_q, block_k, off, num_qb, window
         )
         loop(first, first_whole, True)
-        loop(first_whole, num_qb, False)
+        loop(first_whole, end_whole, False)
+        if window is not None:
+            loop(end_whole, end, True)
     else:
         loop(0, num_qb, False)
     dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
@@ -301,7 +432,7 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, block_k, causal, sm_scale,
+    *, block_k, causal, sm_scale, window=None,
 ):
     # q_ref/do_ref/dq_ref: [block_q, D]; k_ref/v_ref: [T_k, D] (the head's
     # whole sequence); lse_ref/delta_ref: [1, block_q];
@@ -325,7 +456,8 @@ def _flash_bwd_dq_kernel(
         p = jnp.exp(s * sm_scale - lse)
         if masked:
             p = jnp.where(
-                _visible(off + iq * block_q, j * block_k, s.shape, False), p, 0.0
+                _visible(off + iq * block_q, j * block_k, s.shape, False, window),
+                p, 0.0,
             )
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * sm_scale
@@ -338,11 +470,15 @@ def _flash_bwd_dq_kernel(
 
     if causal:
         # key blocks up to this query block's last position (as the forward's
-        # num_kb_eff), and of those the ones its first row sees whole (no mask)
-        last = jnp.clip((off + (iq + 1) * block_q - 1) // block_k + 1, 0, num_kb)
-        whole = jnp.clip((off + iq * block_q + 1) // block_k, 0, last)
-        loop(0, whole, False)
-        loop(whole, last, True)
+        # range), of those the ones its first row sees whole (no mask), and
+        # under a window none before it opens, its edge's tiles masked
+        start, whole_start, whole_end, last = _dq_kb_ranges(
+            iq, block_q, block_k, off, num_kb, window
+        )
+        if window is not None:
+            loop(start, whole_start, True)
+        loop(whole_start, whole_end, False)
+        loop(whole_end, last, True)
     else:
         loop(0, num_kb, False)
     dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
@@ -368,39 +504,52 @@ def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
 
 
 @jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
-def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret):
+def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
+               window=None):
     """``(dq, dk, dv)``; ``block_q`` / ``block_k`` divide ``t_q`` / ``t_k``
-    (:func:`_bwd_blocks` picks them through :func:`_pick_block`)."""
+    (:func:`_bwd_blocks` picks them through :func:`_pick_block`). Under
+    grouped KV heads ``flash_bwd_dkv`` writes each query head's part of dK
+    and dV in f32 and one XLA reduction sums a KV head's group."""
     b, h, t_q, d = q.shape
-    t_k = k.shape[2]
+    h_kv, t_k = k.shape[1], k.shape[2]
+    group = _kv_group(h, h_kv)
     nq, nk = t_q // block_q, t_k // block_k
     # D_i = rowsum(dO * O)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     args = (
-        q.reshape(b * h, t_q, d), k.reshape(b * h, t_k, d), v.reshape(b * h, t_k, d),
+        q.reshape(b * h, t_q, d), k.reshape(b * h_kv, t_k, d), v.reshape(b * h_kv, t_k, d),
         g.reshape(b * h, t_q, d),
         lse.reshape(b * h, nq, 1, block_q), delta.reshape(b * h, nq, 1, block_q),
     )
-    q_seq, k_seq = _head_seq(t_q, d), _head_seq(t_k, d)
+    q_seq, k_seq = _head_seq(t_q, d), _head_seq(t_k, d, group)
     q_blk, k_blk = _head_block(block_q, d), _head_block(block_k, d)
     # a head's every [1, block_q] tile of lse / delta, resident like q_seq
     rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
+    part = jnp.float32 if group > 1 else None  # a query head's part of dK, dV
+    _note_call("dkv", q, t_k, group, causal, window, block_q, block_k)
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale
+            _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale,
+            window=window,
         ),
         grid=(b * h, nk),
-        in_specs=[q_seq, k_blk, k_blk, q_seq, rows_seq, rows_seq],
+        in_specs=[q_seq, _head_block(block_k, d, group), _head_block(block_k, d, group),
+                  q_seq, rows_seq, rows_seq],
         out_specs=[k_blk, k_blk],
-        out_shape=[jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b * h, t_k, d), part or k.dtype),
+                   jax.ShapeDtypeStruct((b * h, t_k, d), part or v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2,
         interpret=interpret,
         name=trace.FLASH_BWD_DKV_KERNEL_NAME,
     )(*args)
+    if group > 1:
+        dk = dk.reshape(b, h_kv, group, t_k, d).sum(axis=2).astype(k.dtype)
+        dv = dv.reshape(b, h_kv, group, t_k, d).sum(axis=2).astype(v.dtype)
+    _note_call("dq", q, t_k, group, causal, window, block_q, block_k)
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale
+            _flash_bwd_dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale,
+            window=window,
         ),
         grid=(b * h, nq),
         in_specs=[q_blk, k_seq, k_seq, q_blk, _rows_spec(block_q), _rows_spec(block_q)],
@@ -418,7 +567,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q,
     k,
@@ -427,8 +576,11 @@ def flash_attention(
     sm_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
+    window: int | None = None,
 ):
-    """Blockwise fused attention for ``[B, H, T, D]`` inputs.
+    """Blockwise fused attention for ``[B, H, T, D]`` queries and
+    ``[B, H_kv, T_k, D]`` keys and values (``H_kv`` divides ``H``; see
+    :func:`attention_reference` for the grouping and for ``window``).
 
     Forward = pallas kernel (interpreter mode on the CPU); backward = two
     pallas kernels that recompute the scores blockwise from the forward's
@@ -439,7 +591,7 @@ def flash_attention(
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     interpret = _interpret_on(jax.default_backend())
-    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)[0]
+    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)[0]
 
 
 def flash_attention_head_parallel(
@@ -452,6 +604,7 @@ def flash_attention_head_parallel(
     sm_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
+    window: int | None = None,
 ):
     """:func:`flash_attention` inside a global-view (pjit) program over a
     multi-device mesh: the pallas kernel runs per device under a
@@ -487,10 +640,11 @@ def flash_attention_head_parallel(
 
     mesh = current_mesh()
     if mesh is None or mesh.size == 1:
-        return flash_attention(q, k, v, causal, sm_scale, block_q, block_k)
+        return flash_attention(q, k, v, causal, sm_scale, block_q, block_k, window)
     n_ranks = int(mesh.shape[axis]) if axis in mesh.axis_names else 1
     n_heads = q.shape[1]
-    if n_heads % n_ranks:
+    # grouped KV heads split with their query heads or not at all
+    if n_heads % n_ranks or k.shape[1] % n_ranks:
         import logging
 
         logging.getLogger(__name__).warning(
@@ -501,28 +655,28 @@ def flash_attention_head_parallel(
             "the sharded path",
             n_ranks, axis, n_heads,
         )
-        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale, window=window)
     from jax.sharding import PartitionSpec
 
     hspec = PartitionSpec(None, axis if n_ranks > 1 else None, None, None)
     return jax.shard_map(
         functools.partial(
             flash_attention, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window,
         ),
         mesh=mesh, in_specs=(hspec,) * 3, out_specs=hspec, check_vma=False,
     )(q, k, v)
 
 
-def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
+def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window):
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     interpret = _interpret_on(jax.default_backend())
-    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(causal, sm_scale, block_q, block_k, res, g):
+def _bwd_rule(causal, sm_scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -532,7 +686,7 @@ def _bwd_rule(causal, sm_scale, block_q, block_k, res, g):
         dtype=jnp.dtype(q.dtype).name, blocks=blocks,
     )
     interpret = _interpret_on(jax.default_backend())
-    return _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, interpret)
+    return _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, interpret, window)
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)

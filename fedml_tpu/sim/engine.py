@@ -30,7 +30,9 @@ from fedml_tpu.algorithms.base import (
 )
 from fedml_tpu.core import rng as rnglib
 from fedml_tpu.core import scan as scanlib
-from fedml_tpu.core.trainer import ClientTrainer, make_local_eval, make_local_train
+from fedml_tpu.core.trainer import (
+    STATS_PREFIX, ClientTrainer, make_local_eval, make_local_train,
+)
 from fedml_tpu.obs import trace
 from fedml_tpu.parallel import mesh as meshlib
 from fedml_tpu.sim import cohort as cohortlib
@@ -896,11 +898,13 @@ class FedSim:
         return self._aggregate_tail(
             global_variables, server_state, local_vars, weights, num_steps,
             train_metrics["train_loss"], rng,
+            model_stats={k: v for k, v in train_metrics.items()
+                         if k.startswith(STATS_PREFIX)},
         )
 
     @jax.named_scope(trace.SCOPE_AGGREGATE)
     def _aggregate_tail(self, global_variables, server_state, local_vars,
-                        weights, num_steps, train_loss, rng):
+                        weights, num_steps, train_loss, rng, model_stats=None):
         # The round's server side, shared verbatim by the padded, packed,
         # and sharded execution modes: all_gather the cohort stack, derive
         # tau, run the aggregation rule, and assemble round metrics. Runs
@@ -967,6 +971,14 @@ class FedSim:
             ),
             **agg_metrics,
         }
+        # the client model's own statistics (core/trainer.py STATS_PREFIX;
+        # a routed-expert model's per-layer counts): the cohort's
+        # sample-weighted mean, one scalar a layer, on the metrics the round
+        # returns anyway, so they cost no host sync of their own
+        for name, per_client in (model_stats or {}).items():
+            per_layer = jnp.tensordot(all_weights / jnp.sum(all_weights), gather(per_client), 1)
+            for i in range(per_layer.shape[0]):
+                metrics[f"{name}/layer_{i}"] = per_layer[i]
         return new_global, server_state, metrics
 
     @staticmethod
@@ -2112,6 +2124,9 @@ class FedSim:
                 if j == n - 1 and per_round_time is not None:
                     rec["round_time"] = per_round_time
                 rec.update({k: float(v[j]) for k, v in stacked_np.items()})
+                for k, v in rec.items():
+                    if k.startswith(STATS_PREFIX):  # the model's own counters
+                        trace.counter(k[len(STATS_PREFIX):], v, round=rr)
                 if j == n - 1 and eval_rec:
                     rec.update(eval_rec)
                 history.append(rec)

@@ -101,3 +101,55 @@ def test_flash_wrap_lowers_for_tpu_under_a_sharded_plan(monkeypatch):
         assert text.count("tpu_custom_call") >= 3
         for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
             assert kernel in text, (axis, kernel)
+
+
+def _lowered_for_tpu(fn, *shapes):
+    """The TPU lowering of ``fn`` (no compile, no chip): Mosaic refuses at
+    lowering what the interpreter lets through."""
+    return jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_grouped_window_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, window):
+    """``smallthinker21b_silo2``: 28 query heads on 4 KV heads of 128, T
+    8192, bf16, forward tiles 256 x 1024; the three kernels, global and
+    window."""
+    import fedml_tpu.ops.attention as att
+
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    q = jax.ShapeDtypeStruct((1, 28, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return att.flash_attention(q, k, v, True, None, 256, 1024, window).astype(
+            jnp.float32).sum()
+
+    text = _lowered_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert kernel in text, kernel
+
+
+def test_expert_layer_lowers_for_tpu_at_the_cells_shapes(monkeypatch):
+    """8192 tokens x 6 of 64 experts, 16 held, 2560 -> 768 -> 2560 in bf16:
+    megablox's grouped products, forward (gmm) and backward (gmm, tgmm)."""
+    import fedml_tpu.ops.moe as moe
+
+    monkeypatch.setattr(moe, "_interpret_on", lambda platform: False)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+
+    def loss(u, router, gate, up, down):
+        ids, weights = moe.route(u, router, 6)
+        out, _ = moe.expert_layer(u.astype(jnp.bfloat16), ids, weights, gate, up, down,
+                                  first=0, count=16, dtype=jnp.bfloat16)
+        return out.sum()
+
+    text = _lowered_for_tpu(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), f32(8192, 2560), f32(2560, 64),
+        f32(16, 2560, 768), f32(16, 2560, 768), f32(16, 768, 2560))
+    # gmm at the two forward shapes and the two transposed ones, tgmm at its
+    # two: products of one shape share one lowered function
+    assert text.count("tpu_custom_call") >= 6
+    assert moe.gmm_tiling(49152, 2560, 768) == (512, 1280, 768)
+    assert moe.gmm_tiling(49152, 768, 2560) == (512, 768, 1280)
+    assert moe.gmm_tiling(64, 64, 32) == (64, 64, 32)
