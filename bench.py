@@ -18,8 +18,8 @@ f32 rounds/sec stays in ``extra`` for continuity with BENCH_r02.
 
 MFU story (the number that actually says "fast on TPU"): a big-shape
 federated LM round — TransformerLM (D=2048, L=8, H=16, T=1024, V=32k) in
-bfloat16 with the pallas flash-attention kernel (ops/attention.py, tile
-256x1024), 2 clients x 32 local steps x batch 4 — with analytic model FLOPs
+bfloat16 with the pallas flash-attention kernel (ops/attention.py, which
+picks its tiles), 2 clients x 32 local steps x batch 4 — with analytic model FLOPs
 (matmul 2P per token + causal attention at half of 4TD, train = 3x fwd)
 against the chip's peak. Also reports pooled eval throughput on the ResNet.
 """
@@ -1690,7 +1690,7 @@ def _main(stage: list):
             "peak_bf16_tflops": peak,
             "lm_config": (
                 f"TransformerLM bf16 D{LM_D} L{LM_L} H{LM_H} T{LM_T} V{LM_V}, "
-                f"attn={LM_ATTN} (pallas 256x1024 tiles), "
+                f"attn={LM_ATTN} (pallas, tiles from the shape), "
                 f"{LM_CLIENTS} clients x {LM_STEPS} steps x batch {LM_BATCH}, "
                 f"cohort={LM_COHORT} (sequential clients free the HBM that "
                 "capped round 3 at batch 4 / MFU 0.467)"

@@ -23,8 +23,8 @@ a. The paper's headline model through the CLI: ``main_fedavg.main`` with
    two-round block program, donation, rolled scans, on-chip eval.
 b. The kernel path through the library surface: ``FedSim`` +
    ``ClientTrainer`` + ``TransformerLM`` at the width bench.py records
-   (D2048 L8 H16 T1024 V32000 bf16, flash attention, 256x1024 tiles) on one
-   device; the lowered round program must contain the Mosaic custom call;
+   (D2048 L8 H16 T1024 V32000 bf16, flash attention at the kernel's own tiles) on
+   one device; the lowered round program must contain the Mosaic custom call;
    and ``flash_attention`` against ``attention_reference``, forward and all
    three gradients of the custom VJP.
 c. With four or more devices: phase a as it is (the default mesh takes every
@@ -68,7 +68,6 @@ LM_LOSS_DROP = 2.0
 # at highest matmul precision. bf16 carries 8 bits (eps 3.9e-3). Observed
 # out 3.2e-3, dq 4.7e-3, dk 4.1e-3, dv 5.8e-3.
 KERNEL_SHAPE = (4, 16, 1024, 128)
-KERNEL_TILES = (256, 1024)  # models/transformer.py block_q, block_k
 KERNEL_TOL = {"out": 2e-2, "dq": 2e-2, "dk": 2e-2, "dv": 2e-2}
 
 # phase c: round-1 Train/Loss of each sharded arm against the one-device
@@ -303,7 +302,7 @@ def run_lm_rounds(label: str, sim, n_rounds: int = LM_ROUNDS):
     }, variables
 
 
-def check_flash_against_reference(shape=KERNEL_SHAPE, tiles=KERNEL_TILES) -> dict:
+def check_flash_against_reference(shape=KERNEL_SHAPE) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -316,7 +315,7 @@ def check_flash_against_reference(shape=KERNEL_SHAPE, tiles=KERNEL_TILES) -> dic
     )
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, True, None, *tiles)
+        return flash_attention(q, k, v, True)
 
     def reference(q, k, v):
         return attention_reference(

@@ -41,9 +41,6 @@ from fedml_tpu.ops import moe
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
 
 GLOBAL, WINDOW = "global", "window"
-# forward tiles of the flash kernel, as models/transformer.py's and for its
-# reasons (measured at T 8192 too); the backward kernels pick their own
-FLASH_FWD_BLOCKS = (256, 1024)
 
 
 class RMSNorm(nn.Module):
@@ -92,8 +89,7 @@ class GroupedAttention(nn.Module):
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         if self.attn_impl == "flash":
             a = flash_attention_head_parallel(
-                q, k, v, axis=None, causal=True, window=self.window,
-                block_q=FLASH_FWD_BLOCKS[0], block_k=FLASH_FWD_BLOCKS[1])
+                q, k, v, axis=None, causal=True, window=self.window)
         else:
             a = attention_reference(q, k, v, causal=True, window=self.window)
         a = a.transpose(0, 2, 1, 3).reshape(b, t, self.num_heads * self.head_dim)

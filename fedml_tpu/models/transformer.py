@@ -47,17 +47,6 @@ class MultiHeadSelfAttention(nn.Module):
     # sharded plan a shard_map over the whole mesh, heads split over this
     # axis, with a gathered-xla fallback when heads don't divide it).
     mp_axis: str | None = None
-    # flash forward tile sizes, tuned on a v5e at T=1024, D_head=128: a tall
-    # 256-row query block with the whole 1024-key sequence in one block beat
-    # the 128x128 default by ~4% end-to-end MFU (_pick_block clamps both to T).
-    # At T=8192 (28 query on 4 KV heads of 128, bf16, causal; one chip run,
-    # PR 28) 256 x 1024 still holds: 4.76 ms a call against 4.74 at 512 x 512
-    # and 4.86 at 512 x 1024, 5.25 at 256 x 2048, 22.7 at 128 x 128 (under a
-    # 4096 window 4.14 against 4.00 at 512 x 512, 17.4 at 128 x 128);
-    # 1024 x 1024 does not fit VMEM. The backward kernels pick their own
-    # tiles from the shape (ops/attention.py _bwd_blocks)
-    block_q: int = 256
-    block_k: int = 1024
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -81,9 +70,7 @@ class MultiHeadSelfAttention(nn.Module):
             # under a sharded plan (active mesh) the kernel runs per device
             # in a shard_map, on its local heads when mp_axis is set; plain
             # kernel otherwise — see flash_attention_head_parallel
-            o = flash_attention_head_parallel(
-                q, k, v, axis=self.mp_axis, causal=True,
-                block_q=self.block_q, block_k=self.block_k)
+            o = flash_attention_head_parallel(q, k, v, axis=self.mp_axis, causal=True)
         elif self.attn_impl == "ring":
             o = ring_attention(q, k, v, axis_name=self.sp_axis, causal=True)
         else:

@@ -20,10 +20,13 @@ backward is a custom VJP of two more pallas kernels, the standard flash
 backward: ``flash_bwd_dkv`` (a key block a grid step, looping over the query
 blocks that see it) and ``flash_bwd_dq`` (a query block a step, looping over
 key blocks up to the causal limit). Both recompute the scores a tile at a
-time in VMEM from q, k and the log-sum-exp, with matmul operands in the
-input's dtype and f32 accumulation, so memory is O(T) in both directions and
-no ``[T, T]`` tile ever reaches HBM. The backward picks its own tiles
-(:func:`_bwd_blocks`); ``block_q`` / ``block_k`` are the forward's.
+time in VMEM from q, k and the log-sum-exp. All three kernels feed the MXU
+operands in the input's dtype with f32 accumulation, skip the tiles the mask
+hides whole, and run mask arithmetic only on the tiles the diagonal or the
+window's edge cuts, so memory is O(T) in both directions and no ``[T, T]``
+tile ever reaches HBM. Each direction picks its tiles from the shape
+(:func:`_fwd_blocks`, :func:`_bwd_blocks`); a caller's ``block_q`` /
+``block_k`` override the forward's.
 On the CPU backend the kernels run in interpreter mode so the full test
 suite exercises them on the 8-device CPU mesh; on TPU they are
 Mosaic-compiled; any other backend is refused (see :func:`_interpret_on`).
@@ -124,6 +127,10 @@ def _check_window(window, causal) -> None:
 # ---------------------------------------------------------------------------
 
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
 def _eye(n):
     return jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == jax.lax.broadcasted_iota(
         jnp.int32, (n, n), 1
@@ -142,62 +149,103 @@ def _col(row):
     return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1, keepdims=True)
 
 
+def _spread(x, n):
+    """``[rows, lanes]`` with a row's value in every lane -> what broadcasts
+    against ``[rows, n]`` with no lane shuffle where ``lanes`` divides ``n``."""
+    lanes = x.shape[1]
+    if lanes in (1, n):
+        return x
+    if n % lanes:
+        return x[:, :1]
+    return jnp.tile(x, (1, n // lanes))
+
+
+def _fold(p, lanes):
+    """``[rows, n]`` -> ``[rows, lanes]``: the sum of the lane-wide column
+    groups, adds of whole registers; the lanes themselves are summed once,
+    after the last tile."""
+    if lanes == 1:
+        return jnp.sum(p, axis=-1, keepdims=True)
+    return functools.reduce(
+        jnp.add, (p[:, c:c + lanes] for c in range(0, p.shape[1], lanes)))
+
+
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, sm_scale, block_q,
-    window=None,
+    q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
+    *, block_k, causal, sm_scale, window=None,
 ):
-    # q_ref: [block_q, D]; k_ref/v_ref: [T, D] (whole sequence for this head);
-    # lse_ref: [1, block_q]; grid = (B*H, T // block_q).
+    # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [T_k, D] (the head's whole
+    # sequence); lse_ref: [1, block_q]; grid = (B*H, T_q // block_q).
+    # Scratch, all f32: o_acc [block_q, D]; m_acc, l_acc [block_q, lanes], the
+    # running max with a row's value in every lane and the running sum a lane.
+    # Cross-lane work is what bounds the kernel on the v5e (PERF.md §6, PR
+    # 29), so a step keeps one lane reduction, the max: the sum's waits for
+    # the end, and m and alpha meet the scores and o_acc lane for lane.
+    # Operands reach the MXU in the input's dtype; scores, exp, the running
+    # max and sum and the output's accumulator are f32, as in the backward.
     iq = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * sm_scale
-    t_k, d = k_ref.shape
+    block_q, d = q_ref.shape
+    lanes = m_acc.shape[1]
+    t_k = k_ref.shape[0]
     num_kb = t_k // block_k
-    t_q = pl.num_programs(1) * block_q
+    off = t_k - pl.num_programs(1) * block_q  # right-aligned, as attention_reference
+    q = q_ref[:]
+    o_acc[:] = jnp.zeros_like(o_acc)
+    l_acc[:] = jnp.zeros_like(l_acc)
+    # the running max starts above the mask's value: a row that has seen no
+    # key yet (its window opens in a later tile) gets exp(-5e29) = 0 for every
+    # hidden pair, where a start at NEG_INF would give exp(0)
+    m_acc[:] = jnp.full_like(m_acc, NEG_INF / 2)
+    if causal:
+        # a tile's mask from one value a row and one a column, so no
+        # [block_q, block_k] index array is ever made: a cut tile costs a
+        # compare and a select an element (two compares under a window)
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_idx = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
 
-    # right-aligned causal offset, matching attention_reference
-    q_pos = (t_k - t_q) + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-
-    def body(j, carry):
-        o, l, m = carry
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            seen = k_pos <= q_pos
+    def step(masked, j):
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[cols, :]
+        v = v_ref[cols, :]
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            # the tile's column of query i's own position
+            diag = q_idx + (off + iq * block_q - j * block_k)
+            seen = k_idx <= diag
             if window is not None:
-                seen &= k_pos > q_pos - window
+                seen &= k_idx > diag - window
             s = jnp.where(seen, s, NEG_INF)
+        m = m_acc[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        p = jnp.exp(s - _spread(m_new, block_k))
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o = o * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        m_acc[:] = m_new
+        l_acc[:] = l_acc[:] * alpha + _fold(p, lanes)
+        o_acc[:] = o_acc[:] * _spread(alpha, d) + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32
         )
-        return o, l, m_new
 
-    o = jnp.zeros((block_q, d), jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    # only key blocks at or before this query block's last position, and
-    # under a window none that ends before its first row's window opens
-    first_kb, num_kb_eff = _fwd_kb_range(
-        iq, block_q, block_k, t_k - t_q, num_kb, causal, window
-    )
-    o, l, m = jax.lax.fori_loop(first_kb, num_kb_eff, body, (o, l, m))
-    o_ref[:] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    def loop(lo, hi, masked):
+        jax.lax.fori_loop(lo, hi, lambda j, c: step(masked, j), None)
+
+    if causal:
+        # the key blocks the dq kernel visits for this query block: under a
+        # window the ones its edge cuts, then the ones every row sees whole
+        # (no mask arithmetic), then the ones the diagonal cuts
+        start, whole_start, whole_end, last = _fwd_kb_ranges(
+            iq, block_q, block_k, off, num_kb, window
+        )
+        if window is not None:
+            loop(start, whole_start, True)
+        loop(whole_start, whole_end, False)
+        loop(whole_end, last, True)
+    else:
+        loop(0, num_kb, False)
+    l = jnp.maximum(jnp.sum(l_acc[:], axis=-1, keepdims=True), 1e-20)
+    o_ref[:] = (o_acc[:] / l).astype(o_ref.dtype)
     # the backward's residual: log-sum-exp of each query row's scores, one
-    # f32 a row in lanes (a fully masked row keeps about NEG_INF)
-    lse_ref[:] = _row(m + jnp.log(jnp.maximum(l, 1e-20)))
+    # f32 a row in lanes (a fully masked row keeps about NEG_INF / 2)
+    lse_ref[:] = _row(m_acc[:, :1] + jnp.log(l))
 
 
 def _head_seq(t, d, group=1):
@@ -224,16 +272,43 @@ def _rows_spec(block):
     return pl.BlockSpec((None, None, 1, block), lambda i, j: (i, j, 0, 0))
 
 
+def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None):
+    """The forward kernel's ``(block_q, block_k)``: the caller's where it
+    names them (the tests' toy tiles), else 512 x 512, the backward's tile
+    too. Measured on the v5e at D 128 bf16 causal, ms a call (PERF.md §6, PR
+    29; T 2048 is (4, 16, 2048, 128), T 8192 is 28 query heads on 4 KV heads
+    of one sequence, global and under a 4096 window):
+
+        tile         T 1024   T 2048   T 8192   T 8192 w
+        256 x 256    0.873    1.368    7.621    6.018
+        256 x 512    0.642    0.894    4.243    3.513
+        256 x 1024   0.711    0.928    3.954    3.457
+        512 x 256    0.685    1.020    5.318    4.312
+        512 x 512    0.501    0.718    3.547    2.898
+        512 x 1024   0.612    0.832    3.779    3.212
+        1024 x 512   0.536    0.755    3.446    2.912
+        1024 x 1024  0.567    0.805    3.719    3.155
+
+    A step's cost is the tile's area plus a part a query row that key blocks
+    under 512 do not amortise; a wider or taller tile than 512 spends more on
+    pairs the diagonal hides than it saves. 1024 x 512 is 2.9% faster on the
+    T 8192 global layer alone and 0.5% over that model's one global and three
+    window layers: not worth a rule. Both fit Mosaic's default 16 MB of VMEM
+    beside a head's whole K and V at T 8192; 1024 x 1024 does not."""
+    return (_pick_block(t_q, block_q or 512, dtype), _pick_block(t_k, block_k or 512, dtype))
+
+
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
-    """``(out [B, H, T, D], lse [B, H, T] f32)``."""
+    """``(out [B, H, T, D], lse [B, H, T] f32)``; ``block_q`` / ``block_k``
+    of ``None`` are chosen by :func:`_fwd_blocks`."""
     b, h, t, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     group = _kv_group(h, h_kv)
     _check_window(window, causal)
-    block_q = _pick_block(t, block_q, q.dtype)
-    block_k = _pick_block(t_k, block_k, k.dtype)
+    block_q, block_k = _fwd_blocks(t, t_k, q.dtype, block_q, block_k)
     nq = t // block_q
+    lanes = 1 if block_k % 128 else 128  # of the running max and sum, see the kernel
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h_kv, t_k, d)
     vf = v.reshape(b * h_kv, t_k, d)
@@ -242,7 +317,6 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
         block_k=block_k,
         causal=causal,
         sm_scale=sm_scale,
-        block_q=block_q,
         window=window,
     )
     _note_call("fwd", q, t_k, group, causal, window, block_q, block_k)
@@ -256,6 +330,9 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, 1, block_q), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, lanes), jnp.float32),
+                        pltpu.VMEM((block_q, lanes), jnp.float32)],
         interpret=interpret,
         name=trace.FLASH_KERNEL_NAME,
     )(qf, kf, vf)
@@ -298,19 +375,10 @@ def _clip(x, lo, hi):
 # never visited, tiles in a "whole" range need no mask arithmetic.
 
 
-def _fwd_kb_range(iq, block_q, block_k, off, num_kb, causal, window):
-    """Key blocks ``[lo, hi)`` the forward visits for query block ``iq``."""
-    lo, hi = 0, num_kb
-    if causal:
-        hi = _clip((off + (iq + 1) * block_q - 1) // block_k + 1, 0, num_kb)
-    if window is not None:
-        lo = _clip((off + iq * block_q - window + 1) // block_k, 0, hi)
-    return lo, hi
-
-
-def _dq_kb_ranges(iq, block_q, block_k, off, num_kb, window):
-    """``(start, whole_start, whole_end, last)`` of the causal dq kernel's
-    key blocks: ``[start, whole_start)`` are cut by the window's edge,
+def _fwd_kb_ranges(iq, block_q, block_k, off, num_kb, window):
+    """``(start, whole_start, whole_end, last)`` of the key blocks a causal
+    query block visits, in the forward and in the dq kernel alike (each with
+    its own tiles): ``[start, whole_start)`` are cut by the window's edge,
     ``[whole_start, whole_end)`` are seen whole, ``[whole_end, last)`` are
     cut by the diagonal. Without a window ``start = whole_start = 0``."""
     q_lo, q_hi = off + iq * block_q, off + (iq + 1) * block_q - 1
@@ -346,27 +414,21 @@ def _note_call(kernel, q, t_k, group, causal, window, block_q, block_k):
     t_q = q.shape[2]
     nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
     if not causal:
-        visited = nq * nk
-    elif kernel == "fwd":
-        visited = sum(hi - lo for lo, hi in (
-            _fwd_kb_range(i, block_q, block_k, off, nk, True, window) for i in range(nq)))
-    elif kernel == "dq":
-        visited = sum(r[3] - r[0] for r in (
-            _dq_kb_ranges(i, block_q, block_k, off, nk, window) for i in range(nq)))
-    else:
-        visited = sum(r[3] - r[0] for r in (
-            _dkv_qb_ranges(j, block_q, block_k, off, nq, window) for j in range(nk)))
+        ranges = [(0, 0, nk, nk)] * nq
+    elif kernel == "dkv":
+        ranges = [_dkv_qb_ranges(j, block_q, block_k, off, nq, window) for j in range(nk)]
+    else:  # the forward and dq walk the same key blocks, each with its own tiles
+        ranges = [_fwd_kb_ranges(i, block_q, block_k, off, nk, window) for i in range(nq)]
+    visited = sum(r[3] - r[0] for r in ranges)
+    # tiles that run mask arithmetic: the two cut ranges either side of the whole one
+    masked = visited - sum(r[2] - r[1] for r in ranges)
     trace.program_note(
         "attn/call", kernel=kernel,
         kind="window" if window is not None else "global" if causal else "full",
         window=window, shape=tuple(q.shape), t_k=t_k, q_heads_per_kv_head=group,
         dtype=jnp.dtype(q.dtype).name, tile=(block_q, block_k),
-        tiles_visited=visited, tiles_total=nq * nk,
+        tiles_visited=visited, tiles_masked=masked, tiles_total=nq * nk,
     )
-
-
-_NT = (((1,), (1,)), ((), ()))  # a @ b.T
-_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def _flash_bwd_dkv_kernel(
@@ -472,7 +534,7 @@ def _flash_bwd_dq_kernel(
         # key blocks up to this query block's last position (as the forward's
         # range), of those the ones its first row sees whole (no mask), and
         # under a window none before it opens, its edge's tiles masked
-        start, whole_start, whole_end, last = _dq_kb_ranges(
+        start, whole_start, whole_end, last = _fwd_kb_ranges(
             iq, block_q, block_k, off, num_kb, window
         )
         if window is not None:
@@ -574,8 +636,8 @@ def flash_attention(
     v,
     causal: bool = False,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     window: int | None = None,
 ):
     """Blockwise fused attention for ``[B, H, T, D]`` queries and
@@ -585,8 +647,8 @@ def flash_attention(
     Forward = pallas kernel (interpreter mode on the CPU); backward = two
     pallas kernels that recompute the scores blockwise from the forward's
     log-sum-exp — O(T·block) memory in both directions, the [T, T] score
-    matrix is never materialized. ``block_q`` / ``block_k`` tile the forward;
-    the backward chooses its own tiles from the shape and dtype.
+    matrix is never materialized. Both directions choose their tiles from the
+    shape and dtype; ``block_q`` / ``block_k`` override the forward's.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -602,8 +664,8 @@ def flash_attention_head_parallel(
     axis: str | None,
     causal: bool = False,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     window: int | None = None,
 ):
     """:func:`flash_attention` inside a global-view (pjit) program over a
@@ -680,7 +742,8 @@ def _bwd_rule(causal, sm_scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype, (block_q, block_k))
+    blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype,
+                         _fwd_blocks(q.shape[2], k.shape[2], q.dtype, block_q, block_k))
     trace.event(
         "attn/bwd_path", impl="kernel", shape=tuple(q.shape), t_k=k.shape[2],
         dtype=jnp.dtype(q.dtype).name, blocks=blocks,

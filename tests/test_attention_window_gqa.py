@@ -101,25 +101,108 @@ def test_equal_heads_and_no_window_is_the_program_it_was(rng):
 
 
 def test_tile_ranges_by_hand():
-    """T 8192, window 4096, the cell's tiles: forward 256 x 1024, backward
-    512 x 512."""
-    fwd = [att._fwd_kb_range(i, 256, 1024, 0, 8, True, 4096) for i in range(32)]
-    assert fwd[0] == (0, 1) and fwd[15] == (0, 4) and fwd[16] == (0, 5)
-    assert fwd[20] == (1, 6) and fwd[31] == (3, 8)
-    assert sum(hi - lo for lo, hi in fwd) == 120
-    assert sum(hi - lo for lo, hi in (att._fwd_kb_range(i, 256, 1024, 0, 8, True, None)
-                                       for i in range(32))) == 144
-    # dq, query block 10 (rows 5120 .. 5631): keys 1025 .. 5631 are visible,
-    # blocks 2 .. 10; block 2 is cut by the window, 3 .. 9 whole, 10 by the diagonal
-    assert att._dq_kb_ranges(10, 512, 512, 0, 16, 4096) == (2, 3, 10, 11)
-    assert att._dq_kb_ranges(10, 512, 512, 0, 16, None) == (0, 0, 10, 11)
+    """T 8192, window 4096: wide 256 x 1024 tiles, then the cell's 512 x 512,
+    which the forward and the dq kernel walk alike."""
+    fwd = [att._fwd_kb_ranges(i, 256, 1024, 0, 8, 4096) for i in range(32)]
+    # (start, whole_start, whole_end, last): rows 0 .. 255 see block 0 cut by
+    # the diagonal; rows 5120 .. 5375 see keys 1025 .. 5375, block 1 cut by
+    # the window, 2 .. 4 whole, 5 by the diagonal
+    assert fwd[0] == (0, 0, 0, 1) and fwd[15] == (0, 0, 3, 4) and fwd[16] == (0, 1, 4, 5)
+    assert fwd[20] == (1, 2, 5, 6) and fwd[31] == (3, 4, 7, 8)
+    assert sum(r[3] - r[0] for r in fwd) == 120
+    assert sum(r[3] - r[0] for r in (att._fwd_kb_ranges(i, 256, 1024, 0, 8, None)
+                                     for i in range(32))) == 144
+    # the forward and dq, query block 10 (rows 5120 .. 5631): keys 1025 .. 5631 are
+    # visible, blocks 2 .. 10; block 2 is cut by the window, 3 .. 9 whole, 10 by the diagonal
+    assert att._fwd_kb_ranges(10, 512, 512, 0, 16, 4096) == (2, 3, 10, 11)
+    assert att._fwd_kb_ranges(10, 512, 512, 0, 16, None) == (0, 0, 10, 11)
     # dkv, key block 2 (columns 1024 .. 1535): queries 1024 .. 5630 see it,
     # blocks 2 .. 10; block 2 is cut by the diagonal, 3 .. 9 whole, 10 by the window
     assert att._dkv_qb_ranges(2, 512, 512, 0, 16, 4096) == (2, 3, 10, 11)
     assert att._dkv_qb_ranges(2, 512, 512, 0, 16, None) == (2, 3, 16, 16)
-    for ranges, n in ((att._dq_kb_ranges, 3), (att._dkv_qb_ranges, 3)):
+    for ranges, n in ((att._fwd_kb_ranges, 3), (att._dkv_qb_ranges, 3)):
         both = [ranges(i, 512, 512, 0, 16, 4096) for i in range(16)]
         assert sum(r[n] - r[0] for r in both) == 136 - 28  # 16 x 17 / 2 less the hidden 7 x 8 / 2
+
+
+def _tile_mask(t_q, t_k, window):
+    """The mask read straight off its definition: key j is visible to query
+    i iff j <= i + (t_k - t_q) and, under a window, j > i + (t_k - t_q) - window."""
+    q_pos = np.arange(t_q)[:, None] + (t_k - t_q)
+    k_pos = np.arange(t_k)[None, :]
+    seen = k_pos <= q_pos
+    if window is not None:
+        seen &= k_pos > q_pos - window
+    return seen
+
+
+@pytest.mark.parametrize("t_q,t_k,block_q,block_k,window,has_whole", [
+    (64, 64, 16, 16, None, True),    # whole tiles, then the diagonal's
+    (64, 64, 16, 16, 1, False),      # a window of one key: every visited tile is cut
+    (64, 64, 16, 16, 5, False),      # under a tile
+    (64, 64, 16, 16, 16, False),     # a tile (a whole one takes 16 + 16 - 1 keys)
+    (64, 64, 16, 16, 40, True),      # all three ranges in the later query blocks
+    (64, 64, 16, 16, 1000, True),    # over the sequence: the window's range is empty
+    (64, 64, 8, 32, 24, False),      # wide tiles
+    (64, 64, 32, 8, 24, False),      # tall tiles: several tiles on the diagonal
+    (32, 64, 16, 16, None, True),    # t_q != t_k: right-aligned offset
+    (32, 64, 16, 16, 20, False),
+    (64, 32, 16, 16, None, True),    # more queries than keys: the first blocks see nothing
+    (64, 32, 16, 8, 12, False),
+    (48, 96, 16, 32, 50, True),      # an offset and a window that are multiples of neither side
+])
+def test_forward_key_block_ranges_against_the_mask(t_q, t_k, block_q, block_k, window,
+                                                  has_whole):
+    """``_fwd_kb_ranges`` against a brute-force reading of the mask: every
+    visible pair lies in a visited tile, every visited tile holds one, a tile
+    of the whole range hides no pair and a tile of a cut range hides one."""
+    seen = _tile_mask(t_q, t_k, window)
+    nk = t_k // block_k
+    some_whole = some_cut = False
+    for i in range(t_q // block_q):
+        start, whole_start, whole_end, last = att._fwd_kb_ranges(
+            i, block_q, block_k, t_k - t_q, nk, window)
+        assert 0 <= start <= whole_start <= whole_end <= last <= nk
+        for j in range(nk):
+            tile = seen[i * block_q:(i + 1) * block_q, j * block_k:(j + 1) * block_k]
+            assert tile.any() == (start <= j < last), (i, j)
+            if whole_start <= j < whole_end:
+                assert tile.all(), (i, j)
+                some_whole = True
+            elif start <= j < last:
+                assert not tile.all(), (i, j)
+                some_cut = True
+    assert some_cut and some_whole == has_whole
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case,t_q,t_k,d,blocks,causal,window", [
+    ("masked_only", 16, 16, 16, (16, 16), True, None),     # one tile, on the diagonal
+    ("whole_only", 32, 64, 16, (16, 16), False, None),     # no mask: the whole loop alone
+    ("whole_and_diagonal", 64, 64, 16, (16, 16), True, None),
+    ("all_three", 64, 64, 16, (16, 16), True, 40),
+    ("window_of_one", 64, 64, 16, (16, 32), True, 1),      # rows with no key in a visited tile
+    ("offset_window", 32, 64, 16, (16, 16), True, 24),
+    # key blocks of whole lanes: the running max and sum are kept a lane
+    ("lanes_head_under", 256, 512, 16, (64, 256), True, 300),
+    ("lanes_head_equal", 128, 256, 128, (64, 128), True, None),
+    ("lanes_head_over", 64, 128, 256, (32, 128), False, None),
+])
+def test_forward_output_and_lse_match_reference(rng, dtype, tol, case, t_q, t_k, d, blocks,
+                                                causal, window):
+    """The forward's two outputs, with operands in the input's dtype: ``out``
+    against ``attention_reference`` and ``lse`` against the log-sum-exp of the
+    visible scores, 7 query heads a KV head."""
+    q, k, v, _ = _qkvg(rng, 7, 1, t_q, t_k, d=d, b=1, dtype=dtype)
+    sm_scale = d ** -0.5
+    out, lse = att._flash_fwd(q, k, v, causal, sm_scale, *blocks, True, window)
+    assert out.dtype == dtype and lse.dtype == jnp.float32 and lse.shape == (1, 7, t_q)
+    want = attention_reference(q, k, v, causal=causal, window=window).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want))) <= tol
+    s = jnp.einsum("bhqd,bkd->bhqk", q.astype(jnp.float32), k[:, 0].astype(jnp.float32)) * sm_scale
+    if causal:
+        s = jnp.where(_tile_mask(t_q, t_k, window), s, -jnp.inf)
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(s, axis=-1), atol=tol, rtol=tol)
 
 
 def test_attention_calls_are_noted_with_their_tiles(rng):
@@ -140,5 +223,19 @@ def test_attention_calls_are_noted_with_their_tiles(rng):
     # 16 x 16 tiles of a 64 square: rows of blocks see 1, 2, 3 (24 keys back
     # reach two blocks behind only in part), 3 blocks
     assert (fwd["tiles_visited"], fwd["tiles_total"], fwd["tile"]) == (9, 16, (16, 16))
+    # a window of 24 under tiles of 16 leaves no tile whole (that takes 31 keys)
+    assert fwd["tiles_masked"] == 9
+    for n in mine:  # the backward's one 64 x 64 tile is cut by the diagonal and the window
+        if n["kernel"] != "fwd":
+            assert (n["tiles_visited"], n["tiles_masked"], n["tile"]) == (1, 1, (64, 64))
     events = [e for e in tracer.events() if e["name"] == "attn/call"]
     assert len(events) == 3 and events[0]["args"]["kind"] == "window"
+    # without the window: 1 + 2 + 3 + 4 tiles, the diagonal's four run the mask;
+    # without the mask: every tile, none masked
+    jax.jit(lambda *x: flash_attention(*x, True, None, 16, 16)).lower(q, k, v)
+    jax.jit(lambda *x: flash_attention(*x, False, None, 16, 32)).lower(q, k, v)
+    glob, full = (next(n for n in trace.program_notes("attn/call")
+                       if n["shape"] == (1, 4, 64, 16) and n["kind"] == kind)
+                  for kind in ("global", "full"))
+    assert (glob["tiles_visited"], glob["tiles_masked"], glob["tiles_total"]) == (10, 4, 16)
+    assert (full["tiles_visited"], full["tiles_masked"], full["tiles_total"]) == (8, 0, 8)

@@ -109,20 +109,26 @@ def _lowered_for_tpu(fn, *shapes):
     return jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("window", [None, 4096])
-def test_grouped_window_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, window):
-    """``smallthinker21b_silo2``: 28 query heads on 4 KV heads of 128, T
-    8192, bf16, forward tiles 256 x 1024; the three kernels, global and
-    window."""
+@pytest.mark.parametrize("q_shape,kv_heads,window", [
+    pytest.param((4, 16, 2048, 128), 16, None, id="cgpt13b_silo2"),
+    pytest.param((1, 28, 8192, 128), 4, None, id="smallthinker21b_silo2-global"),
+    pytest.param((1, 28, 8192, 128), 4, 4096, id="smallthinker21b_silo2-window"),
+])
+def test_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, q_shape, kv_heads, window):
+    """The three kernels at both LM cells' shapes in bf16, with the tiles the
+    kernels choose for them (``_fwd_blocks``, ``_bwd_blocks``): equal heads at
+    T 2048; 28 query heads on 4 KV heads at T 8192, global and window."""
     import fedml_tpu.ops.attention as att
 
     monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
-    q = jax.ShapeDtypeStruct((1, 28, 8192, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    b, _, t, d = q_shape
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, kv_heads, t, d), jnp.bfloat16)
+    assert att._fwd_blocks(t, t, jnp.bfloat16) == (512, 512)
+    assert att._bwd_blocks(t, t, jnp.bfloat16, (512, 512)) == (512, 512)
 
     def loss(q, k, v):
-        return att.flash_attention(q, k, v, True, None, 256, 1024, window).astype(
-            jnp.float32).sum()
+        return att.flash_attention(q, k, v, True, window=window).astype(jnp.float32).sum()
 
     text = _lowered_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert text.count("tpu_custom_call") >= 3
