@@ -3,7 +3,7 @@
     python chip_smoke.py          # from the checkout root, on a machine with a TPU
 
 One process drives the system's main paths once, through the entry points a
-user would call, at the full width of the models the repo benches; checks
+user would call, at full model width; checks
 what comes out by the repo's own means; and prints as the LAST line of stdout
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -22,7 +22,7 @@ a. The paper's headline model through the CLI: ``main_fedavg.main`` with
    overridden, so the default TPU path runs: device-resident data, the
    two-round block program, donation, rolled scans, on-chip eval.
 b. The kernel path through the library surface: ``FedSim`` +
-   ``ClientTrainer`` + ``TransformerLM`` at the width bench.py records
+   ``ClientTrainer`` + ``TransformerLM`` at this file's own width
    (D2048 L8 H16 T1024 V32000 bf16, flash attention at the kernel's own tiles) on
    one device; the lowered round program must contain the Mosaic custom call;
    and ``flash_attention`` against ``attention_reference``, forward and all
@@ -76,8 +76,8 @@ KERNEL_TOL = {"out": 2e-2, "dq": 2e-2, "dk": 2e-2, "dv": 2e-2}
 # reductions (observed 5.0e-4).
 SHARD_LOSS_RTOL = {"transformer_fsdp": 1e-4, "transformer_tp": 5e-3}
 
-# the LM round: bench.py LM_D .. LM_V (bench.py:163), at the BENCH_r03
-# federated shape cut to 4 local steps
+# the LM round: this smoke's own width (the benchmark's LM cells are
+# benchmark/configs/), two clients of 4 local steps at batch 4
 LM_WIDTH = dict(vocab_size=32000, embed_dim=2048, num_layers=8, num_heads=16,
                 max_len=1024)
 LM_CLIENTS, LM_STEPS, LM_BATCH = 2, 4, 4
@@ -206,10 +206,9 @@ def phase_a(n_rounds: int = 4, freq: int = 2) -> dict:
 
 def build_lm_problem(width=None, clients=LM_CLIENTS, steps=LM_STEPS,
                      batch=LM_BATCH, cohort_execution="vmap"):
-    """(trainer, train_data, SimConfig) as bench._build_lm_sim builds them,
-    except the tokens: bench draws uniform-random targets, which nothing
-    can learn; here every sequence is a ramp over a small alphabet
-    (y = x + 1 mod A), so the loss must fall."""
+    """(trainer, train_data, SimConfig) of the LM round. Every sequence is
+    a ramp over a small alphabet (y = x + 1 mod A), so the loss must fall
+    (uniform-random targets would teach nothing)."""
     import numpy as np
 
     import jax.numpy as jnp
@@ -350,8 +349,8 @@ def phase_b() -> dict:
 
     one = client_mesh(jax.devices()[:1])
     out = {}
-    # the BENCH_r03 shape (vmapped cohort, batch 4), then the shape
-    # bench.py benches today (sequential cohort, batch 8)
+    # both cohort executions: vmapped at batch 4, then sequential at
+    # batch 8 (the mode the benchmark's LM cells run)
     for label, kw in (("vmap_b4", {}),
                       ("scan_b8", dict(batch=8, cohort_execution="scan"))):
         trainer, train, cfg = build_lm_problem(**kw)

@@ -80,7 +80,7 @@ class InlineCommManager(BaseCommunicationManager):
 
 @dataclasses.dataclass
 class CascadeReport:
-    """What one cascade run measured (the bench/soak acceptance surface)."""
+    """What one cascade run measured (the soak acceptance surface)."""
 
     fan_ins: tuple
     rounds: int

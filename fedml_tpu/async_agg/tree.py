@@ -1737,7 +1737,7 @@ def run_tree_fedavg(
 def run_tree_fedavg_loopback(trainer, train_data, topology, round_num,
                              batch_size, **kwargs):
     """Hierarchical FedAvg with every tier cell on an in-process loopback
-    fabric — the test/bench entry point."""
+    fabric — the test entry point."""
     return run_tree_fedavg(trainer, train_data, topology, round_num,
                            batch_size, **kwargs)
 

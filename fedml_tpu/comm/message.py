@@ -36,7 +36,7 @@ import jax
 # Counts payload serializations (frames built with at least one array
 # segment) so the encode-once contract is testable: a broadcast to N workers
 # increments this ONCE; the legacy per-rank loop increments it N times.
-# bench.py's broadcast A/B probe and tools/wire_smoke.py read these.
+# tools/wire_smoke.py reads these.
 
 _WIRE_LOCK = threading.Lock()
 _WIRE_STATS = {"payload_serializations": 0, "frames": 0}

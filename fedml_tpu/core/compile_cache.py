@@ -19,7 +19,7 @@ the TPU a model's eager init compiles dozens of programs of about half a
 second each; under a 0.5 s floor they straddled it, so every warm run
 compiled most of them again and wrote a few more (chip runs, PR 21).
 
-Every entry point (``fedml_tpu/exp/*``, ``bench.py``, ``chip_smoke.py``,
+Every entry point (``fedml_tpu/exp/*``, ``chip_smoke.py``,
 ``tools/shard_smoke.py``) calls :func:`configure_compile_cache` once before
 its first compile.
 """

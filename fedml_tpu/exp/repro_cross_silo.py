@@ -345,9 +345,9 @@ def run(args) -> dict:
 def resolve_cohort_execution(model: str, explicit: str | None) -> str:
     """Auto cohort mode: MobileNet's depthwise convolutions hit XLA's
     grouped-convolution slow path when the cohort is vmapped (the weight
-    gradient becomes a batch_group_count conv — measured minutes/round on
-    chip), so it trains clients sequentially; dense-conv models keep the
-    vmapped cohort."""
+    gradient becomes a batch_group_count conv; no cell times it), so it
+    trains clients sequentially; dense-conv models keep the vmapped
+    cohort."""
     if explicit is not None:
         return explicit
     return "scan" if model == "mobilenet" else "vmap"

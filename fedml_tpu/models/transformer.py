@@ -140,10 +140,10 @@ class TransformerLM(nn.Module):
     # (sim/engine.py); leave None for unsharded / FSDP-gather execution.
     mp_axis: str | None = None
     # LM-head matmul dtype, independent of the block compute dtype: an f32
-    # head runs the MXU at half rate but skips two [B, T, V]-sized dtype
-    # converts (logits + their gradient). Which side wins is shape-dependent;
-    # measured on a v5e at D=1024-2048, T=1024, V=32k the f32 head was ~6%
-    # faster end-to-end, hence the default
+    # head keeps the logits and their gradient out of bf16 and skips two
+    # [B, T, V]-sized dtype converts. In cgpt13b_silo2 (V 50,257) head and
+    # loss are head_loss_time_pct 20.5 of the busy time at 71% of peak
+    # (ledger PR 29; PERF.md section 5); no chip run has a bf16 head
     head_dtype: jnp.dtype = jnp.float32
     # rematerialize each block's activations in the backward pass
     # (jax.checkpoint): ~1/L of the activation memory for ~33% more FLOPs —

@@ -22,7 +22,7 @@ Design constraints:
   helpers (:func:`span` / :func:`gauge` / ...), which cost one global read
   and return a shared no-op context manager when no tracer is installed.
   Sites whose *attributes* cost anything (e.g. payload byte sums) guard on
-  :func:`get` first. bench.py's trace probe measures both sides.
+  :func:`get` first.
 - **Thread-safe.** Spans land from the driver thread, the prefetch staging
   thread, and every comm worker thread; each thread gets its own track id
   (Chrome ``tid``) so Perfetto renders the pipeline overlap visually.

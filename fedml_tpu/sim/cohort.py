@@ -185,8 +185,7 @@ def _cohort_index_map_loop(
     rng: np.random.RandomState | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Pre-vectorization reference (per-client Python loop), kept as the
-    oracle for :func:`cohort_index_map` and as the bench's staging-overhead
-    baseline (``host_stage_ms_loop``). Shuffle draws differ by construction
+    oracle for :func:`cohort_index_map`. Shuffle draws differ by construction
     (per-client ``permutation`` calls vs one block draw), so bit-exact
     comparisons use ``rng=None``."""
     sizes = np.asarray([
@@ -295,8 +294,8 @@ class PackPlan:
     dispatches (overflow cohorts spill to extra sequential passes, keeping
     every pass the same compiled program). ``total_steps`` counts executed
     (data-carrying, in-budget) steps across the cohort; ``capacity`` is
-    ``len(passes) * lanes * s_lane`` — their ratio is the packed padding
-    fraction the bench reports."""
+    ``len(passes) * lanes * s_lane`` — their ratio is the lane occupancy
+    (the ``engine/lane_occupancy`` gauge)."""
 
     passes: tuple
     lanes: int

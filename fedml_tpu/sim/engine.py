@@ -95,19 +95,18 @@ class SimConfig:
     stage_on_device: bool | None = None
     # Dispatch rounds in eval-aligned blocks (one lax.scan program per block,
     # one host->device round-trip). None = auto: on for accelerator meshes
-    # (where dispatch latency dominates small models), OFF on XLA:CPU —
-    # convolutions inside a while loop take XLA:CPU's single-threaded slow
-    # path, ~100x slower than the same round dispatched directly.
+    # (a round trip a block, not a round), OFF on XLA:CPU — convolutions
+    # inside a while loop take XLA:CPU's single-threaded slow path
+    # (core/scan.py has the CPU figures).
     block_dispatch: bool | None = None
     # How the cohort's clients execute inside the round program:
-    # "vmap" (default) trains every local client simultaneously — best MXU
-    # utilization for small models, but peak HBM scales with C_local
-    # (each live client holds params + optimizer state + activations);
-    # "scan" trains them sequentially (lax.map), holding ONE client's
-    # transient state at a time — the big-model mode (e.g. the LM bench:
-    # per-client transformer state is GBs, and its matmuls already fill the
-    # MXU without cross-client batching, so scan costs ~nothing and frees
-    # C_local-1 clients' worth of HBM for longer sequences / bigger batches).
+    # "vmap" (default) trains every local client simultaneously — small
+    # models fill the MXU only across clients, but peak HBM scales with
+    # C_local (each live client holds params + optimizer state +
+    # activations); "scan" trains them sequentially (lax.map), holding ONE
+    # client's transient state at a time — the big-model mode: a client's
+    # transformer state is GBs and its matmuls fill the MXU without
+    # cross-client batching (the LM cells run it, PERF.md section 4).
     cohort_execution: str = "vmap"
     # Packed-lane execution (docs/PERFORMANCE.md "Packed-lane cohort
     # execution"): 0 (default) = the padded [C, S_max] layout above; N > 0 =
@@ -116,7 +115,10 @@ class SimConfig:
     # resetting its carry at client boundaries — device FLOPs scale with the
     # cohort's executed steps instead of C x the straggler max, the big win
     # on power-law populations where one client holds 10-100x the median.
-    # Bit-identical to the padded path (tools/pack_smoke.py guards this);
+    # The padded path's arithmetic in another program: bit-identical to it on
+    # one device and on uniform cohorts, within float32 rounding (1 ULP seen)
+    # on a multi-device client mesh with unequal lanes, where XLA fuses the
+    # update differently (tests/test_packed_lanes.py; tools/pack_smoke.py);
     # requires broadcast-mode aggregation and the default cohort_execution.
     pack_lanes: int = 0
     # Lane length head-room over the expected per-shard cohort load. Lanes
@@ -1693,7 +1695,7 @@ class FedSim:
         """Host-only planning for one packed round: the round's [C_pad, S, B]
         cohort index map (built exactly as the padded path builds it) plus
         the lane packing of each client's executed-step stream. No device
-        work — stats consumers (bench probes) read plans without staging."""
+        work."""
         idx, weights, num_steps = self._host_cohort_indices(cohort, round_idx)
         if len(weights) != self._c_pad:
             raise ValueError(
@@ -1725,21 +1727,6 @@ class FedSim:
             predicted_steps=predicted,
         )
         return idx, weights, num_steps, plan
-
-    def pack_round_stats(self, round_idx: int) -> dict:
-        """Plan accounting for the round the engine would actually run
-        (its sampled cohort, its budgets): pass count, executed steps, lane
-        capacity, and the padded path's scanned-step count — all host-side,
-        nothing shipped to device."""
-        _, weights, _, plan = self._pack_round_plan(
-            self._sample_round_cohort(round_idx), round_idx
-        )
-        return {
-            "n_passes": len(plan.passes),
-            "total_steps": plan.total_steps,
-            "capacity": plan.capacity,
-            "padded_steps": len(weights) * self.trainer.epochs * self._steps,
-        }
 
     def _stage_packed_round(self, cohort, round_idx: int, rkey) -> PackedStaged:
         """Host staging for one packed round: plan it (:meth:`_pack_round_plan`),

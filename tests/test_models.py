@@ -274,7 +274,8 @@ def test_cv_zoo_bf16_compute():
 
 
 def test_resnet_f32_vs_bf16_accuracy_parity():
-    """bf16 compute (the bench headline numerics, bench.py) matches f32
+    """bf16 compute (the precision the benchmark's LM configurations state:
+    ``compute_dtype`` in benchmark/configs/cerebras_gpt_1p3b_cut.json) matches f32
     training accuracy on the ResNet family: same data, same recipe, both must
     learn the task and land within a few points of each other."""
     import numpy as np
