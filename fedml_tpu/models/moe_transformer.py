@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.core.trainer import STATS_COLLECTION
-from fedml_tpu.ops import moe
+from fedml_tpu.ops import moe, remat
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
 
 GLOBAL, WINDOW = "global", "window"
@@ -184,7 +184,13 @@ class MoETransformerLM(nn.Module):
     attn_impl: str = "xla"
     dtype: jnp.dtype = jnp.float32  # compute dtype of the products; params stay f32
     head_dtype: jnp.dtype = jnp.float32
-    # rematerialize each block in the backward pass (as TransformerLM.remat)
+    # rematerialize each block in the backward pass under ops/remat.py's
+    # policy (as TransformerLM.remat): a block keeps its input, the flash
+    # kernels' five residuals, the routed layer's sorted layout and its gate
+    # and up products (286.5 MB a layer in smallthinker21b_silo2, which cannot
+    # fit without remat), and computes again its norms, router, output
+    # projection, row gathers and down product: 5% of the busy time where a
+    # bare checkpoint's second forward was 12% (PERF.md section 5)
     remat: bool = False
 
     @nn.compact
@@ -194,7 +200,7 @@ class MoETransformerLM(nn.Module):
         # choice among near-equal logits should not hang on a bf16 rounding
         h = nn.Embed(self.vocab_size, self.embed_dim, name="tok_embed")(x)
         held = self.num_experts if self.experts_held is None else self.experts_held
-        block_cls = nn.remat(MoEBlock) if self.remat else MoEBlock
+        block_cls = remat.block(MoEBlock) if self.remat else MoEBlock
         stats = []
         for i, kind in enumerate(self.layer_kinds):
             h, layer_stats = block_cls(

@@ -22,6 +22,7 @@ from __future__ import annotations
 import flax.linen as nn
 import jax.numpy as jnp
 
+from fedml_tpu.ops import remat
 from fedml_tpu.ops.attention import (
     attention_reference,
     flash_attention,
@@ -145,9 +146,14 @@ class TransformerLM(nn.Module):
     # loss are head_loss_time_pct 20.5 of the busy time at 71% of peak
     # (ledger PR 29; PERF.md section 5); no chip run has a bf16 head
     head_dtype: jnp.dtype = jnp.float32
-    # rematerialize each block's activations in the backward pass
-    # (jax.checkpoint): ~1/L of the activation memory for ~33% more FLOPs —
-    # the standard TPU trade when HBM, not MXU, binds the batch size
+    # rematerialize each block in the backward pass (jax.checkpoint) under
+    # ops/remat.py's policy: a block keeps its input and the named values that
+    # are small to hold and dear to recompute (under attn_impl="flash" the
+    # kernels' residuals q, k, v, output and log-sum-exp, so neither the
+    # flash forward kernel nor the qkv product runs twice) and computes its
+    # norms, its output projection and its MLP again. The trade when HBM, not
+    # the MXU, binds the batch size; no cell runs this model with it on
+    # (docs/PERFORMANCE.md)
     remat: bool = False
 
     @nn.compact
@@ -163,7 +169,7 @@ class TransformerLM(nn.Module):
         pos_idx = pos_offset + jnp.arange(t)
         h = tok + jnp.take(pos_table, pos_idx, axis=0)[None].astype(self.dtype)
         # train selects the dropout branch: it must be static under remat
-        block_cls = nn.remat(Block, static_argnums=(2,)) if self.remat else Block
+        block_cls = remat.block(Block, static_argnums=(2,)) if self.remat else Block
         for i in range(self.num_layers):
             h = block_cls(
                 self.num_heads,

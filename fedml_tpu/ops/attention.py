@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fedml_tpu.obs import trace
+from fedml_tpu.ops import remat
 
 NEG_INF = -1e30
 
@@ -735,6 +736,11 @@ def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window):
         sm_scale = q.shape[-1] ** -0.5
     interpret = _interpret_on(jax.default_backend())
     out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)
+    # kept by a rematerialised block (ops/remat.py), whose backward then has
+    # no use for a second forward call, nor for the projections and rotary
+    # positions that made q, k and v
+    q, k, v, out, lse = (
+        remat.keep(name, x) for name, x in zip(remat.ATTN_RESIDUALS, (q, k, v, out, lse)))
     return out, (q, k, v, out, lse)
 
 

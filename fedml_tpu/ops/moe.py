@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 from fedml_tpu.obs import trace
+from fedml_tpu.ops import remat
 from fedml_tpu.ops.attention import _interpret_on
 
 HI = jax.lax.Precision.HIGHEST
@@ -62,7 +63,8 @@ def sorted_layout(ids, first: int, count: int):
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     pos = jnp.argsort(order).astype(jnp.int32).reshape(t, k)  # the inverse permutation
     sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype), axis=0, dtype=jnp.int32)
-    return held, pos, order, sizes
+    return (held, remat.keep(remat.MOE_POS, pos), remat.keep(remat.MOE_ORDER, order),
+            remat.keep(remat.MOE_SIZES, sizes))
 
 
 def _over_used_chunks(chunk_fn, n_used, outs):
@@ -187,8 +189,8 @@ def reglu_experts(rows, gate, up, down, sizes, interpret: bool):
     """``(relu(rows @ gate_e) * (rows @ up_e)) @ down_e`` for each held
     expert ``e`` over its own rows (``sizes``), in ``rows``' dtype with
     float32 accumulation. Rows past ``sum(sizes)`` are not computed."""
-    g = _gmm(rows, gate.astype(rows.dtype), sizes, interpret)
-    u = _gmm(rows, up.astype(rows.dtype), sizes, interpret)
+    g = remat.keep(remat.MOE_GATE_OUT, _gmm(rows, gate.astype(rows.dtype), sizes, interpret))
+    u = remat.keep(remat.MOE_UP_OUT, _gmm(rows, up.astype(rows.dtype), sizes, interpret))
     return _gmm(jax.nn.relu(g) * u, down.astype(rows.dtype), sizes, interpret)
 
 

@@ -1,0 +1,78 @@
+"""What a rematerialised decoder block keeps for its backward pass.
+
+A block under a bare ``jax.checkpoint`` keeps its input alone, and the
+backward pass runs the whole forward again, Mosaic kernels included. The
+blocks here are rematerialised under a policy that saves values *by name*
+(``jax.checkpoint_policies.save_only_these_names``): the code that computes
+a value small to hold and dear to recompute tags it with :func:`keep`, the
+policy saves every tagged value, and the recomputed forward loses whatever
+only fed those values (XLA and jax's own dead-code pass drop a kernel call
+whose outputs nobody reads). Everything untagged (norms, the router, the
+output projection, row gathers) is recomputed as before. A tag helps only
+where the backward pass reads the *tagged* value: a custom VJP's residuals
+tagged inside its forward rule, or a value whose consumers are ordinary
+equations. ``top_k`` and ``softmax`` keep their own untagged outputs, so a
+name on the router's choices would save nothing.
+
+Outside a rematerialised block a tag is an identity that lowers to nothing,
+so a model with ``remat`` off compiles to the program it had without tags.
+The list is fixed here and follows no option: a name costs memory, and what
+fits was sized on the one cell that needs remat (``PERF.md`` section 5).
+"""
+
+from __future__ import annotations
+
+import threading
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from fedml_tpu.obs import trace
+
+# ops/attention.py _fwd_rule: the flash kernels' residuals
+ATTN_RESIDUALS = ("attn/q", "attn/k", "attn/v", "attn/out", "attn/lse")
+MOE_ORDER, MOE_POS, MOE_SIZES = "moe/order", "moe/pos", "moe/sizes"  # ops/moe.py sorted_layout
+MOE_GATE_OUT, MOE_UP_OUT = "moe/gate_out", "moe/up_out"  # reglu_experts
+
+KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT)
+NOTE = "remat/kept"
+
+
+class _Inside(threading.local):
+    depth = 0  # rematerialised blocks being traced on this thread
+
+
+_inside = _Inside()
+
+
+def keep(name: str, x):
+    """``x`` tagged ``name`` (one of ``KEPT``) for the blocks' policy. Traced
+    inside a rematerialised block it also leaves a ``remat/kept`` program
+    note (``obs/trace.py``): the name and the bytes it holds a layer."""
+    assert name in KEPT, name
+    if _inside.depth:
+        dtype = jnp.dtype(x.dtype)
+        trace.program_note(NOTE, kept=name, shape=tuple(x.shape), dtype=dtype.name,
+                           bytes=x.size * dtype.itemsize)
+    return checkpoint_name(x, name)
+
+
+def block(cls, **remat_kwargs):
+    """The flax module ``cls`` rematerialised under the policy. While one of
+    its instances is called (which is when jax traces the block, forward and
+    backward rules alike) the tags inside note what they keep."""
+    lifted = nn.remat(cls, policy=jax.checkpoint_policies.save_only_these_names(*KEPT),
+                      **remat_kwargs)
+
+    class Kept(lifted):
+        def __call__(self, *args, **kwargs):
+            _inside.depth += 1
+            try:
+                return super().__call__(*args, **kwargs)
+            finally:
+                _inside.depth -= 1
+
+    Kept.__name__ = Kept.__qualname__ = lifted.__name__
+    return Kept
