@@ -42,6 +42,12 @@ Batch = dict[str, jnp.ndarray]
 # program as ``stats/<path>`` metrics.
 STATS_COLLECTION = "stats"
 STATS_PREFIX = "stats/"
+# A module with a multi-token-prediction head ``sow``s, while training, one
+# entry into this collection: ``{"logits": [B, T, V], "weight": scalar}``,
+# position i predicting the token after the next one. ``init``
+# drops it, and ``loss_fn`` adds ``weight * lm_loss`` of those logits against
+# the targets one step further on; metrics and eval are of the main logits.
+MTP_COLLECTION = "mtp"
 
 
 def _flat_stats(tree: Pytree) -> dict[str, jnp.ndarray]:
@@ -80,6 +86,15 @@ def lm_loss(logits: jnp.ndarray, batch: Batch) -> jnp.ndarray:
     (reference my_model_trainer_nwp.py — Shakespeare / StackOverflow NWP)."""
     ce = optax.softmax_cross_entropy_with_integer_labels(logits, batch["y"])
     return _masked_mean(ce, batch["mask"])
+
+
+def one_token_further(batch: Batch) -> Batch:
+    """``y`` and ``mask`` of a sequence batch moved one position on: position
+    i's target becomes ``y[i + 1]``, and a row's last position, which has
+    none, leaves the mask."""
+    y = jnp.roll(batch["y"], -1, axis=1)
+    mask = jnp.roll(batch["mask"], -1, axis=1).at[:, -1].set(0)
+    return {**batch, "y": y, "mask": mask}
 
 
 def lm_metrics(logits: jnp.ndarray, batch: Batch) -> dict[str, jnp.ndarray]:
@@ -202,7 +217,8 @@ class ClientTrainer:
         variables = self.module.init(
             {"params": rng, "dropout": rng}, sample_batch["x"], train=False
         )
-        return {k: v for k, v in variables.items() if k != STATS_COLLECTION}
+        return {k: v for k, v in variables.items()
+                if k not in (STATS_COLLECTION, MTP_COLLECTION)}
 
     # -- single gradient step on one masked batch ------------------------------
 
@@ -212,13 +228,18 @@ class ClientTrainer:
             {"params": params, **model_state},
             batch["x"],
             train=True,
-            mutable=[*model_state.keys(), STATS_COLLECTION],
+            mutable=[*model_state.keys(), STATS_COLLECTION, MTP_COLLECTION],
             rngs={"dropout": rng},
         )
         logits, new_model_state = out
         stats = _flat_stats(new_model_state.pop(STATS_COLLECTION, {}))
         with jax.named_scope(trace.SCOPE_LOSS):
             loss = self.loss_and_metrics[0](logits, batch)
+        mtp = new_model_state.pop(MTP_COLLECTION, None)
+        if mtp:
+            (ahead,) = mtp.values()
+            with jax.named_scope(trace.SCOPE_MTP), jax.named_scope(trace.SCOPE_LOSS):
+                loss = loss + ahead["weight"] * lm_loss(ahead["logits"], one_token_further(batch))
         if self.prox_mu > 0.0:
             from fedml_tpu.core import tree as treelib
 

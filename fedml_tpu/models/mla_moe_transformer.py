@@ -1,0 +1,258 @@
+"""Decoder-only LM with latent attention (MLA), a sigmoid router with a
+selection bias beside a shared expert, a leading dense layer and a
+multi-token-prediction module, for federated clients that hold one chip's
+share of an expert-parallel model (the JoyAI-LLM-Flash / DeepSeek-V3 block).
+
+A block, with x its input ``[T, D]`` (the residual stream, float32):
+
+    h      = rmsnorm(x)
+    c_q    = rmsnorm(h W_qa)                                  [T, q_rank]
+    q      = c_q W_qb -> H heads of [q_nope | q_rope]         [T, H, nope + rope]
+    c_kv | k_rope = h W_kva                                   [T, kv_rank] | [T, rope]
+    [k_nope | v] a head = rmsnorm(c_kv) W_kvb                 [T, H, nope + v_dim]
+    q_rope, k_rope = rope(.)   adjacent pairs (2i, 2i + 1) turned by position x
+                               theta^(-2i / rope); k_rope is one vector a
+                               position, shared by every head
+    s_ij   = (q_nope_i . k_nope_j + q_rope_i . k_rope_j) / sqrt(nope + rope), causal
+    a      = softmax(s) v;  x1 = x + concat_heads(a) W_o      (W_o: H v_dim x D)
+    u      = rmsnorm(x1)                                      (float32: the router reads it)
+    dense layer:   y = x1 + (silu(u G) * (u U)) D
+    routed layer:  sc = sigmoid(u W_r);  I = top-k of (sc + b);
+                   w = scale * sc[I] / (sum sc[I] + 1e-20)    (``ops/moe.py`` route)
+                   y = x1 + shared(u) + sum over e in I, e *held here*, of
+                       w_e * ((silu(u G_e) * (u U_e)) D_e)
+                   shared(u) = (silu(u G_s) * (u U_s)) D_s    (whole on every chip)
+
+then a final RMSNorm and an untied head give the main logits. While training
+the module also gives the logits of a depth-1 multi-token-prediction module
+(DeepSeek-V3, eq. 21-25), which shares the embedding and the head: with h_L
+the last block's output before the final norm,
+
+    g_i = [rmsnorm(h_L,i) ; rmsnorm(Emb(t_{i+1}))] M          (M: 2D x D)
+    g'  = one routed block over g;  logits_mtp = rmsnorm(g') W_head
+
+position i predicting t_{i+2}. The module runs it over all T positions with
+``t_{i+1}`` rolled round at the row's last position, which has no t_{i+1}:
+attention is causal, so that position touches no other, and the trainer gives
+it no loss (``core/trainer.py`` ``MTP_COLLECTION``). ``select_bias`` (b) is a
+parameter leaf that gets a zero gradient. ``experts_first`` / ``experts_held``
+say which of the router's outputs have their expert here, as in
+``models/moe_transformer.py``, whose ``RMSNorm``, ``Kernel`` and
+``RoutedExperts`` these blocks share.
+
+Same interface as the rest of the zoo: int tokens ``[B, T]`` in, logits
+``[B, T, V]`` float32 out, ``train`` kwarg. ``train=False`` builds no MTP
+logits.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
+from fedml_tpu.models.moe_transformer import Kernel, RMSNorm, RoutedExperts
+from fedml_tpu.obs import trace
+from fedml_tpu.ops import moe, remat
+from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
+
+
+def rope_interleaved(x, theta: float):
+    """Rotary positions over the whole last dimension of ``[B, H, T, D]``,
+    adjacent pairing (dimension 2i with 2i + 1), positions 0 ... T-1."""
+    t, d = x.shape[-2], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    rms_eps: float = 1e-6
+    attn_impl: str = "xla"  # xla | flash
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, _ = h.shape
+        n, nope, rope_d = self.num_heads, self.nope_dim, self.rope_dim
+
+        def dense(name, width, y):
+            return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(y)
+
+        def heads(y, width):  # [B, T, n * width] -> [B, n, T, width]
+            return y.reshape(b, t, n, width).transpose(0, 2, 1, 3)
+
+        with jax.named_scope(trace.SCOPE_MLA):
+            c_q = RMSNorm(self.rms_eps, self.dtype, name="q_a_norm")(dense("q_a", self.q_rank, h))
+            q = heads(dense("q_b", n * (nope + rope_d), c_q), nope + rope_d)
+            kv_a = dense("kv_a", self.kv_rank + rope_d, h)
+            c_kv = RMSNorm(self.rms_eps, self.dtype, name="kv_a_norm")(kv_a[..., :self.kv_rank])
+            k_rope = rope_interleaved(kv_a[:, None, :, self.kv_rank:], self.rope_theta)
+            kv = heads(dense("kv_b", n * (nope + self.v_dim), c_kv), nope + self.v_dim)
+            q = jnp.concatenate(
+                [q[..., :nope], rope_interleaved(q[..., nope:], self.rope_theta)], axis=-1)
+            # the kernels' key is one [H, T, nope + rope] operand: the shared
+            # rotary columns are written once a head (PERF.md section 6, PR 32)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, n, t, rope_d))], axis=-1)
+            v = kv[..., nope:]
+            if self.attn_impl == "flash":
+                a = flash_attention_head_parallel(q, k, v, axis=None, causal=True)
+            else:
+                a = attention_reference(q, k, v, causal=True)
+            a = a.transpose(0, 2, 1, 3).reshape(b, t, n * self.v_dim)
+            return dense("o", h.shape[-1], a)
+
+
+class GatedMLP(nn.Module):
+    """``(silu(u G) * (u U)) D``: the leading dense layer's feed-forward and
+    the shared expert."""
+
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        def dense(name, width, y):
+            return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(y)
+
+        return dense("down", u.shape[-1], jax.nn.silu(dense("gate", self.width, u))
+                     * dense("up", self.width, u))
+
+
+class MLABlock(nn.Module):
+    routed: bool  # False: the dense feed-forward of ``dense_dim``
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    dense_dim: int
+    num_experts: int
+    experts_per_token: int
+    expert_dim: int
+    shared_dim: int
+    route_scale: float
+    experts_first: int
+    experts_held: int
+    rope_theta: float
+    rms_eps: float = 1e-6
+    attn_impl: str = "xla"
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        h = RMSNorm(self.rms_eps, self.dtype, name="norm_attn")(x)
+        x = x + LatentAttention(
+            self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim, self.v_dim,
+            self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, name="attn")(h)
+        u = RMSNorm(self.rms_eps, jnp.float32, name="norm_ffn")(x)
+        if not self.routed:
+            return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), {}
+        u = u.reshape(b * t, d)
+        ids, weights = moe.route(
+            u, Kernel((d, self.num_experts), name="router")(), self.experts_per_token,
+            select_bias=Kernel((1, self.num_experts), name="select_bias")()[0],
+            scale=self.route_scale)
+        with jax.named_scope(trace.SCOPE_MOE_SHARED):
+            shared = GatedMLP(self.shared_dim, self.dtype, name="shared")(u)
+        m, stats = RoutedExperts(
+            d, self.expert_dim, self.experts_first, self.experts_held, self.dtype,
+            activation=jax.nn.silu, name="experts")(u, ids, weights)
+        return x + (shared.astype(jnp.float32) + m).reshape(b, t, d).astype(x.dtype), stats
+
+
+class MLAMoETransformerLM(nn.Module):
+    """Causal LM of ``dense_layers`` dense then ``routed_layers`` routed
+    :class:`MLABlock` layers, with ``mtp_depth`` (0 or 1) multi-token-
+    prediction modules of one routed block each."""
+
+    vocab_size: int = 96
+    embed_dim: int = 64
+    dense_layers: int = 1
+    routed_layers: int = 2
+    num_heads: int = 4
+    q_rank: int = 48
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    dense_dim: int = 128
+    num_experts: int = 8
+    experts_per_token: int = 2
+    expert_dim: int = 32
+    shared_dim: int = 32
+    route_scale: float = 2.5
+    experts_first: int = 0
+    experts_held: int | None = None  # None: all of them
+    mtp_depth: int = 1
+    mtp_loss_weight: float = 0.3
+    rope_theta: float = 32e6
+    rms_eps: float = 1e-6
+    attn_impl: str = "xla"
+    dtype: jnp.dtype = jnp.float32  # compute dtype of the products; params stay f32
+    head_dtype: jnp.dtype = jnp.float32
+    # rematerialize each block in the backward pass under ops/remat.py's
+    # policy, as MoETransformerLM.remat
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        if self.mtp_depth not in (0, 1):
+            raise ValueError("one multi-token-prediction module at most")
+        embed = nn.Embed(self.vocab_size, self.embed_dim, name="tok_embed")
+        head = nn.Dense(self.vocab_size, use_bias=False, name="head", dtype=self.head_dtype)
+        held = self.num_experts if self.experts_held is None else self.experts_held
+        block_cls = remat.block(MLABlock) if self.remat else MLABlock
+
+        def block(routed, name):
+            return block_cls(
+                routed, self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
+                self.v_dim, self.dense_dim, self.num_experts, self.experts_per_token,
+                self.expert_dim, self.shared_dim, self.route_scale, self.experts_first, held,
+                self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, name=name)
+
+        def logits(h, norm):
+            h = RMSNorm(self.rms_eps, self.head_dtype, name=norm)(h)
+            return head(h).astype(jnp.float32)
+
+        h = embed(x)  # the residual stream stays float32: the router reads it
+        stats = []
+        for i in range(self.dense_layers + self.routed_layers):
+            h, layer_stats = block(i >= self.dense_layers, f"block_{i}")(h)
+            stats.append(layer_stats)
+        # params of every module are made at init, whatever ``train`` says
+        if self.mtp_depth and (self.is_initializing()
+                               or (train and self.is_mutable_collection(MTP_COLLECTION))):
+            with jax.named_scope(trace.SCOPE_MTP):
+                norm = lambda name, y: RMSNorm(self.rms_eps, self.dtype, name=name)(y)  # noqa: E731
+                g = jnp.concatenate(
+                    [norm("mtp_norm_h", h), norm("mtp_norm_e", embed(jnp.roll(x, -1, axis=1)))],
+                    axis=-1)
+                g = nn.Dense(self.embed_dim, use_bias=False, name="mtp_proj", dtype=self.dtype)(g)
+                g, layer_stats = block(True, "mtp_block")(g.astype(h.dtype))
+                stats.append(layer_stats)
+                self.sow(MTP_COLLECTION, "next2",
+                         {"logits": logits(g, "mtp_norm_f"),
+                          "weight": jnp.float32(self.mtp_loss_weight)},
+                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        # one value a routed block (the MTP module's last), for the engine's counters
+        stats = [s for s in stats if s]
+        self.sow(STATS_COLLECTION, "moe",
+                 {k.split("/", 1)[1]: jnp.stack([s[k] for s in stats]) for k in stats[0]},
+                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        return logits(h, "norm_f")

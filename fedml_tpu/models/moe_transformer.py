@@ -30,7 +30,7 @@ step are sown into the ``stats`` collection (``core/trainer.py``).
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Callable, Sequence
 
 import flax.linen as nn
 import jax
@@ -116,14 +116,19 @@ class RoutedExperts(nn.Module):
     first: int
     held: int
     dtype: jnp.dtype = jnp.float32
+    # of the gate; None: expert_layer's own, ReLU. Not passed on then, because
+    # tests/benchmark_tests/test_benchmark_moe.py stands a broken expert_layer
+    # of the older signature in this one's place
+    activation: Callable | None = None
 
     @nn.compact
     def __call__(self, u, ids, weights):
         d, f, held = self.hidden, self.expert_dim, self.held
+        gate = {} if self.activation is None else {"activation": self.activation}
         return moe.expert_layer(
             u, ids, weights, Kernel((held, d, f), name="gate")(),
             Kernel((held, d, f), name="up")(), Kernel((held, f, d), name="down")(),
-            first=self.first, count=held, dtype=self.dtype)
+            first=self.first, count=held, dtype=self.dtype, **gate)
 
 
 class MoEBlock(nn.Module):
@@ -186,9 +191,10 @@ class MoETransformerLM(nn.Module):
     head_dtype: jnp.dtype = jnp.float32
     # rematerialize each block in the backward pass under ops/remat.py's
     # policy (as TransformerLM.remat): a block keeps its input, the flash
-    # kernels' five residuals, the routed layer's sorted layout and its gate
-    # and up products (286.5 MB a layer in smallthinker21b_silo2, which cannot
-    # fit without remat), and computes again its norms, router, output
+    # kernels' five residuals, the router's ids, the sorted layout made from
+    # them and the gate and up products (286.7 MB a layer in
+    # smallthinker21b_silo2, which cannot fit without remat), and computes
+    # again its norms, the router's logits and weights, output
     # projection, row gathers and down product: 5% of the busy time where a
     # bare checkpoint's second forward was 12% (PERF.md section 5)
     remat: bool = False

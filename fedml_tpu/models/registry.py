@@ -91,6 +91,14 @@ def _create(model_name: str, output_dim: int, dataset: str = "") -> Any:
         from fedml_tpu.models.moe_transformer import MoETransformerLM
 
         return MoETransformerLM(vocab_size=output_dim)
+    if model_name == "mla_moe_transformer":
+        # latent attention, a sigmoid router with a selection bias beside a
+        # shared expert, a leading dense layer and a multi-token-prediction
+        # module (models/mla_moe_transformer.py); widths and the share held
+        # come from the caller (benchmark/families/mla_moe_lm.py)
+        from fedml_tpu.models.mla_moe_transformer import MLAMoETransformerLM
+
+        return MLAMoETransformerLM(vocab_size=output_dim)
     if model_name.startswith("vgg"):
         depth = int(model_name[3:] or 16)
         return VGG(depth=depth, num_classes=output_dim)

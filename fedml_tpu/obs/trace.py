@@ -68,7 +68,7 @@ __all__ = [
     "program_note", "program_notes", "last_counters",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "MOE_SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
+    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
     "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
@@ -101,6 +101,14 @@ SCOPE_MOE_DISPATCH = "moe/dispatch"
 SCOPE_MOE_EXPERTS = "moe/experts"
 SCOPE_MOE_COMBINE = "moe/combine"
 MOE_SCOPES = (SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS, SCOPE_MOE_COMBINE)
+# Scopes of the latent-attention decoder (models/mla_moe_transformer.py),
+# inside SCOPE_FWD_BWD: the attention module whole (projections, norms,
+# rotary arithmetic and the flash kernels), the shared expert, and the
+# multi-token-prediction module with its head pass and its loss
+SCOPE_MLA = "attn/mla"
+SCOPE_MOE_SHARED = "moe/shared"
+SCOPE_MTP = "mtp"
+MLA_SCOPES = (SCOPE_MLA, SCOPE_MOE_SHARED, SCOPE_MTP)
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
 # holds "flash_fwd", which the benchmark's forward readers match on

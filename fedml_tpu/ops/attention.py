@@ -9,7 +9,9 @@ the O(T²) score matrix), and the multi-chip path is ring attention over a
 sequence-parallel mesh axis (fedml_tpu/parallel/ring_attention.py) which
 reuses the same math.
 
-Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim). K and V
+Layout convention: ``[B, H, T, D]`` (batch, heads, sequence, head_dim). The
+scores' width (q and k) and the values' (v and the output) may differ, as in
+latent attention, whose keys carry rotary columns the values lack. K and V
 may hold fewer heads than Q (grouped KV heads: query head ``n`` reads KV head
 ``n // (H // H_kv)``), and ``window`` limits a query to the last ``window``
 keys up to and including its own position; both are static, and with equal
@@ -93,7 +95,8 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
     itself: key j is visible to query i iff i - window < j <= i, so a query
     sees at most ``window`` keys and ``window >= t_k`` is plain causal. K and V
     with fewer heads than Q are grouped: query head n reads KV head
-    n // (H // H_kv)."""
+    n // (H // H_kv). q and k share the scores' width ``D_qk``; v and the
+    output have the values' width ``D_v``, which may be another."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     group = _kv_group(q.shape[1], k.shape[1])
@@ -175,9 +178,10 @@ def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
     *, block_k, causal, sm_scale, window=None,
 ):
-    # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [T_k, D] (the head's whole
-    # sequence); lse_ref: [1, block_q]; grid = (B*H, T_q // block_q).
-    # Scratch, all f32: o_acc [block_q, D]; m_acc, l_acc [block_q, lanes], the
+    # q_ref: [block_q, D_qk]; k_ref: [T_k, D_qk] and v_ref: [T_k, D_v] (the
+    # head's whole sequence); o_ref: [block_q, D_v]; lse_ref: [1, block_q];
+    # grid = (B*H, T_q // block_q).
+    # Scratch, all f32: o_acc [block_q, D_v]; m_acc, l_acc [block_q, lanes], the
     # running max with a row's value in every lane and the running sum a lane.
     # Cross-lane work is what bounds the kernel on the v5e (PERF.md §6, PR
     # 29), so a step keeps one lane reduction, the max: the sum's waits for
@@ -185,7 +189,7 @@ def _flash_fwd_kernel(
     # Operands reach the MXU in the input's dtype; scores, exp, the running
     # max and sum and the output's accumulator are f32, as in the backward.
     iq = pl.program_id(1)
-    block_q, d = q_ref.shape
+    block_q, d = o_ref.shape
     lanes = m_acc.shape[1]
     t_k = k_ref.shape[0]
     num_kb = t_k // block_k
@@ -273,38 +277,58 @@ def _rows_spec(block):
     return pl.BlockSpec((None, None, 1, block), lambda i, j: (i, j, 0, 0))
 
 
+def _mosaic_params(dtype, *whole_sequences):
+    """``compiler_params`` of a kernel that keeps ``whole_sequences`` (the
+    ``(T, D)`` of a head's resident operands of ``dtype``) in VMEM. Mosaic
+    double-buffers each, its last dimension padded to whole 128-lane
+    registers, inside 16 MB of scoped VMEM by default. Every equal-width
+    caller's pair fits that beside the tiles (two ``[8192, 128]`` bf16
+    sequences are 8 MiB) and gets no parameter, so its program is the one it
+    was; a 192-wide key pads to 256 lanes, 12 MiB with its values, and the dq
+    kernel is refused by 0.8 MB (compiled for the v5e, PR 32), so such a call
+    asks for what it holds plus the 8 MiB the tiles and a step's temporaries
+    had before."""
+    held = sum(2 * t * -(-d // 128) * 128 * jnp.dtype(dtype).itemsize
+               for t, d in whole_sequences)
+    if held <= 8 * 2 ** 20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=held + 8 * 2 ** 20)
+
+
 def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None):
     """The forward kernel's ``(block_q, block_k)``: the caller's where it
     names them (the tests' toy tiles), else 512 x 512, the backward's tile
     too. Measured on the v5e at D 128 bf16 causal, ms a call (PERF.md §6, PR
     29; T 2048 is (4, 16, 2048, 128), T 8192 is 28 query heads on 4 KV heads
-    of one sequence, global and under a 4096 window):
+    of one sequence, global and under a 4096 window; the last column, PR 32,
+    is 32 heads of T 8192 at a 192-column score on 128-column values):
 
-        tile         T 1024   T 2048   T 8192   T 8192 w
-        256 x 256    0.873    1.368    7.621    6.018
-        256 x 512    0.642    0.894    4.243    3.513
-        256 x 1024   0.711    0.928    3.954    3.457
-        512 x 256    0.685    1.020    5.318    4.312
-        512 x 512    0.501    0.718    3.547    2.898
-        512 x 1024   0.612    0.832    3.779    3.212
-        1024 x 512   0.536    0.755    3.446    2.912
-        1024 x 1024  0.567    0.805    3.719    3.155
+        tile         T 1024   T 2048   T 8192   T 8192 w   192 | 128
+        256 x 256    0.873    1.368    7.621    6.018      10.943
+        256 x 512    0.642    0.894    4.243    3.513       7.087
+        256 x 1024   0.711    0.928    3.954    3.457       6.904
+        512 x 256    0.685    1.020    5.318    4.312       8.359
+        512 x 512    0.501    0.718    3.547    2.898       6.342
+        512 x 1024   0.612    0.832    3.779    3.212       6.731
+        1024 x 512   0.536    0.755    3.446    2.912       6.337
+        1024 x 1024  0.567    0.805    3.719    3.155      refused
 
     A step's cost is the tile's area plus a part a query row that key blocks
     under 512 do not amortise; a wider or taller tile than 512 spends more on
     pairs the diagonal hides than it saves. 1024 x 512 is 2.9% faster on the
     T 8192 global layer alone and 0.5% over that model's one global and three
-    window layers: not worth a rule. Both fit Mosaic's default 16 MB of VMEM
-    beside a head's whole K and V at T 8192; 1024 x 1024 does not."""
+    window layers, and level with 512 x 512 at 192 | 128: not worth a rule.
+    Both fit Mosaic's default 16 MB of VMEM beside a head's whole K and V at
+    T 8192 (what ``_mosaic_params`` asks at 192 | 128); 1024 x 1024 does not."""
     return (_pick_block(t_q, block_q or 512, dtype), _pick_block(t_k, block_k or 512, dtype))
 
 
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
-    """``(out [B, H, T, D], lse [B, H, T] f32)``; ``block_q`` / ``block_k``
+    """``(out [B, H, T, D_v], lse [B, H, T] f32)``; ``block_q`` / ``block_k``
     of ``None`` are chosen by :func:`_fwd_blocks`."""
     b, h, t, d = q.shape
-    h_kv, t_k = k.shape[1], k.shape[2]
+    h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
     _check_window(window, causal)
     block_q, block_k = _fwd_blocks(t, t_k, q.dtype, block_q, block_k)
@@ -312,7 +336,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
     lanes = 1 if block_k % 128 else 128  # of the running max and sum, see the kernel
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h_kv, t_k, d)
-    vf = v.reshape(b * h_kv, t_k, d)
+    vf = v.reshape(b * h_kv, t_k, d_v)
     kernel = functools.partial(
         _flash_fwd_kernel,
         block_k=block_k,
@@ -320,24 +344,25 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
         sm_scale=sm_scale,
         window=window,
     )
-    _note_call("fwd", q, t_k, group, causal, window, block_q, block_k)
+    _note_call("fwd", q, t_k, d_v, group, causal, window, block_q, block_k)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq),
         in_specs=[_head_block(block_q, d), _head_seq(t_k, d, group),
-                  _head_seq(t_k, d, group)],
-        out_specs=[_head_block(block_q, d), _rows_spec(block_q)],
+                  _head_seq(t_k, d_v, group)],
+        out_specs=[_head_block(block_q, d_v), _rows_spec(block_q)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, 1, block_q), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
                         pltpu.VMEM((block_q, lanes), jnp.float32),
                         pltpu.VMEM((block_q, lanes), jnp.float32)],
         interpret=interpret,
         name=trace.FLASH_KERNEL_NAME,
+        compiler_params=_mosaic_params(k.dtype, (t_k, d), (t_k, d_v)),
     )(qf, kf, vf)
-    return out.reshape(b, h, t, d), lse.reshape(b, h, t)
+    return out.reshape(b, h, t, d_v), lse.reshape(b, h, t)
 
 
 # ---------------------------------------------------------------------------
@@ -408,10 +433,10 @@ def _dkv_qb_ranges(jk, block_q, block_k, off, num_qb, window):
     return first, first_whole, end_whole, end
 
 
-def _note_call(kernel, q, t_k, group, causal, window, block_q, block_k):
+def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
     """Record, while the program is traced, what one attention call will do:
-    its kind, its grouping and how many of the square's tiles the kernel
-    visits (``obs/trace.py`` :func:`program_note`; docs/OBSERVABILITY.md)."""
+    its kind, its two widths, its grouping and how many of the square's tiles
+    the kernel visits (``obs/trace.py`` :func:`program_note`; docs/OBSERVABILITY.md)."""
     t_q = q.shape[2]
     nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
     if not causal:
@@ -426,7 +451,8 @@ def _note_call(kernel, q, t_k, group, causal, window, block_q, block_k):
     trace.program_note(
         "attn/call", kernel=kernel,
         kind="window" if window is not None else "global" if causal else "full",
-        window=window, shape=tuple(q.shape), t_k=t_k, q_heads_per_kv_head=group,
+        window=window, shape=tuple(q.shape), t_k=t_k, d_qk=q.shape[3], d_v=d_v,
+        q_heads_per_kv_head=group,
         dtype=jnp.dtype(q.dtype).name, tile=(block_q, block_k),
         tiles_visited=visited, tiles_masked=masked, tiles_total=nq * nk,
     )
@@ -436,8 +462,9 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None,
 ):
-    # k_ref/v_ref/dk_ref/dv_ref: [block_k, D]; q_ref/do_ref: [T_q, D] (the
-    # head's whole sequence); lse_ref/delta_ref: [T_q // block_q, 1, block_q];
+    # k_ref/dk_ref: [block_k, D_qk]; v_ref/dv_ref: [block_k, D_v]; q_ref:
+    # [T_q, D_qk] and do_ref: [T_q, D_v] (the head's whole sequence);
+    # lse_ref/delta_ref: [T_q // block_q, 1, block_q];
     # grid = (B*H, T_k // block_k). Tiles are transposed, [keys, queries], so
     # the per-query statistics broadcast along sublanes and all four matmuls
     # are plain NN / NT.
@@ -497,9 +524,9 @@ def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, block_k, causal, sm_scale, window=None,
 ):
-    # q_ref/do_ref/dq_ref: [block_q, D]; k_ref/v_ref: [T_k, D] (the head's
-    # whole sequence); lse_ref/delta_ref: [1, block_q];
-    # grid = (B*H, T_q // block_q).
+    # q_ref/dq_ref: [block_q, D_qk]; do_ref: [block_q, D_v]; k_ref: [T_k, D_qk]
+    # and v_ref: [T_k, D_v] (the head's whole sequence); lse_ref/delta_ref:
+    # [1, block_q]; grid = (B*H, T_q // block_q).
     iq = pl.program_id(1)
     block_q = q_ref.shape[0]
     t_k = k_ref.shape[0]
@@ -554,8 +581,12 @@ def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
     0.91, 0.85): tiles large enough to amortise the loop, small enough that
     the causal diagonal wastes an eighth of the square and not a quarter, and
     every temporary of a step fits the 16 MB of scoped VMEM wherever the
-    forward's whole-sequence K and V do (PERF.md §6, PR 26). A length whose
-    divisor under 512 Mosaic refuses keeps the forward's block, which passed."""
+    forward's whole-sequence K and V do (PERF.md §6, PR 26). At a 192-column
+    score on 128-column values, 32 heads of T 8192 (PR 32), the two kernels
+    take 19.22 ms a call at 512 x 512; 512 x 1024 19.48, 256 x 1024 20.15,
+    512 x 256 20.41, 256 x 512 20.43, 256 x 256 22.19, and a 1024-row query
+    tile is refused (VMEM). A length whose divisor under 512 Mosaic refuses
+    keeps the forward's block, which passed."""
 
     def pick(t, fwd_block):
         try:
@@ -574,53 +605,56 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     grouped KV heads ``flash_bwd_dkv`` writes each query head's part of dK
     and dV in f32 and one XLA reduction sums a KV head's group."""
     b, h, t_q, d = q.shape
-    h_kv, t_k = k.shape[1], k.shape[2]
+    h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
     nq, nk = t_q // block_q, t_k // block_k
     # D_i = rowsum(dO * O)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     args = (
-        q.reshape(b * h, t_q, d), k.reshape(b * h_kv, t_k, d), v.reshape(b * h_kv, t_k, d),
-        g.reshape(b * h, t_q, d),
+        q.reshape(b * h, t_q, d), k.reshape(b * h_kv, t_k, d), v.reshape(b * h_kv, t_k, d_v),
+        g.reshape(b * h, t_q, d_v),
         lse.reshape(b * h, nq, 1, block_q), delta.reshape(b * h, nq, 1, block_q),
     )
-    q_seq, k_seq = _head_seq(t_q, d), _head_seq(t_k, d, group)
-    q_blk, k_blk = _head_block(block_q, d), _head_block(block_k, d)
-    # a head's every [1, block_q] tile of lse / delta, resident like q_seq
+    # a head's every [1, block_q] tile of lse / delta, resident like q
     rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
     part = jnp.float32 if group > 1 else None  # a query head's part of dK, dV
-    _note_call("dkv", q, t_k, group, causal, window, block_q, block_k)
+    _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale,
             window=window,
         ),
         grid=(b * h, nk),
-        in_specs=[q_seq, _head_block(block_k, d, group), _head_block(block_k, d, group),
-                  q_seq, rows_seq, rows_seq],
-        out_specs=[k_blk, k_blk],
+        in_specs=[_head_seq(t_q, d), _head_block(block_k, d, group),
+                  _head_block(block_k, d_v, group), _head_seq(t_q, d_v), rows_seq, rows_seq],
+        out_specs=[_head_block(block_k, d), _head_block(block_k, d_v)],
         out_shape=[jax.ShapeDtypeStruct((b * h, t_k, d), part or k.dtype),
-                   jax.ShapeDtypeStruct((b * h, t_k, d), part or v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2,
+                   jax.ShapeDtypeStruct((b * h, t_k, d_v), part or v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name=trace.FLASH_BWD_DKV_KERNEL_NAME,
+        compiler_params=_mosaic_params(q.dtype, (t_q, d), (t_q, d_v)),
     )(*args)
     if group > 1:
         dk = dk.reshape(b, h_kv, group, t_k, d).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(b, h_kv, group, t_k, d).sum(axis=2).astype(v.dtype)
-    _note_call("dq", q, t_k, group, causal, window, block_q, block_k)
+        dv = dv.reshape(b, h_kv, group, t_k, d_v).sum(axis=2).astype(v.dtype)
+    _note_call("dq", q, t_k, d_v, group, causal, window, block_q, block_k)
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale,
             window=window,
         ),
         grid=(b * h, nq),
-        in_specs=[q_blk, k_seq, k_seq, q_blk, _rows_spec(block_q), _rows_spec(block_q)],
-        out_specs=q_blk,
+        in_specs=[_head_block(block_q, d), _head_seq(t_k, d, group),
+                  _head_seq(t_k, d_v, group), _head_block(block_q, d_v),
+                  _rows_spec(block_q), _rows_spec(block_q)],
+        out_specs=_head_block(block_q, d),
         out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name=trace.FLASH_BWD_DQ_KERNEL_NAME,
+        compiler_params=_mosaic_params(k.dtype, (t_k, d), (t_k, d_v)),
     )(*args)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -641,9 +675,10 @@ def flash_attention(
     block_k: int | None = None,
     window: int | None = None,
 ):
-    """Blockwise fused attention for ``[B, H, T, D]`` queries and
-    ``[B, H_kv, T_k, D]`` keys and values (``H_kv`` divides ``H``; see
-    :func:`attention_reference` for the grouping and for ``window``).
+    """Blockwise fused attention for ``[B, H, T, D_qk]`` queries, ``[B, H_kv,
+    T_k, D_qk]`` keys and ``[B, H_kv, T_k, D_v]`` values (``H_kv`` divides
+    ``H``; see :func:`attention_reference` for the grouping, for ``window``
+    and for the two widths); the output is ``[B, H, T, D_v]``.
 
     Forward = pallas kernel (interpreter mode on the CPU); backward = two
     pallas kernels that recompute the scores blockwise from the forward's

@@ -17,6 +17,11 @@ moves into sorted order run over whole chunks of used rows only, so the work
 follows the number of assignments held through whole tiles and chunks and
 through nothing else.
 
+The router scores as the configuration states (:func:`route`): a softmax
+over the chosen logits, or sigmoid scores chosen under a selection bias,
+normalised over the chosen and scaled. The experts' gate activation is the
+configuration's too (ReLU unless told).
+
 Scopes (``obs/trace.py`` ``MOE_SCOPES``): ``moe/route``, ``moe/dispatch``,
 ``moe/experts``, ``moe/combine``.
 """
@@ -37,13 +42,31 @@ GMM_TILES = (512, 1280, 1280)  # caps of the grouped products' tiles: see gmm_ti
 
 
 @jax.named_scope(trace.SCOPE_MOE_ROUTE)
-def route(x, router_kernel, top_k: int):
-    """``(expert ids [T, k] int32, weights [T, k] f32)``: the router's
-    logits over all its outputs, the ``top_k`` largest and a softmax over
-    those alone, all in float32 whatever ``x`` is."""
+def route(x, router_kernel, top_k: int, *, select_bias=None, scale: float = 1.0):
+    """``(expert ids [T, k] int32, weights [T, k] f32)`` from the router's
+    logits over all its outputs, all in float32 whatever ``x`` is.
+
+    Without ``select_bias``: the ``top_k`` largest logits and a softmax over
+    those alone. With one (``[outputs]``; the "noaux_tc" router of one
+    group): the scores are ``sigmoid(logits)``, the ``top_k`` largest of
+    ``scores + select_bias`` are chosen, and the weights are the chosen
+    *scores* over their sum, times ``scale``. The bias chooses and never
+    weighs, and no gradient reaches it: upstream moves it by a load-balancing
+    rule outside the gradient."""
     logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32), precision=HI)
-    top, ids = jax.lax.top_k(logits, top_k)
-    return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if select_bias is None:
+        top, ids = jax.lax.top_k(logits, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + select_bias.astype(jnp.float32)), top_k)
+    # a rematerialised block chooses once (ops/remat.py): what follows reads
+    # the kept ids, as the kept sorted layout was made from them
+    ids = remat.keep(remat.MOE_IDS, ids.astype(jnp.int32))
+    if select_bias is None:
+        return ids, jax.nn.softmax(top, axis=-1)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
 
 def sorted_layout(ids, first: int, count: int):
@@ -185,22 +208,24 @@ def _gmm(lhs, rhs, sizes, interpret):
 
 
 @jax.named_scope(trace.SCOPE_MOE_EXPERTS)
-def reglu_experts(rows, gate, up, down, sizes, interpret: bool):
-    """``(relu(rows @ gate_e) * (rows @ up_e)) @ down_e`` for each held
+def glu_experts(rows, gate, up, down, sizes, interpret: bool, activation=jax.nn.relu):
+    """``(activation(rows @ gate_e) * (rows @ up_e)) @ down_e`` for each held
     expert ``e`` over its own rows (``sizes``), in ``rows``' dtype with
     float32 accumulation. Rows past ``sum(sizes)`` are not computed."""
     g = remat.keep(remat.MOE_GATE_OUT, _gmm(rows, gate.astype(rows.dtype), sizes, interpret))
     u = remat.keep(remat.MOE_UP_OUT, _gmm(rows, up.astype(rows.dtype), sizes, interpret))
-    return _gmm(jax.nn.relu(g) * u, down.astype(rows.dtype), sizes, interpret)
+    return _gmm(activation(g) * u, down.astype(rows.dtype), sizes, interpret)
 
 
-def expert_layer(u, ids, weights, gate, up, down, *, first: int, count: int, dtype):
+def expert_layer(u, ids, weights, gate, up, down, *, first: int, count: int, dtype,
+                 activation=jax.nn.relu):
     """This chip's part of the routed-expert layer.
 
     ``u`` [T, D] is the layer's normalised input, ``ids`` / ``weights``
     [T, k] the router's choices over all experts (:func:`route`), ``gate`` /
     ``up`` [count, D, F] and ``down`` [count, F, D] the held experts
-    ``first ... first + count - 1``. Returns the partial sum [T, D] float32
+    ``first ... first + count - 1``, ``activation`` the gate's (ReLU: ReGLU;
+    ``jax.nn.silu``: SwiGLU). Returns the partial sum [T, D] float32
     over the held experts and the layer's routing statistics: assignments
     held and the most loaded held expert's rows over the mean."""
     interpret = _interpret_on(jax.default_backend())
@@ -209,7 +234,7 @@ def expert_layer(u, ids, weights, gate, up, down, *, first: int, count: int, dty
         n_held = jnp.sum(sizes)
         layout = (held, pos, order, n_held)
         rows = dispatch(u.astype(dtype), layout)
-    out_rows = reglu_experts(rows, gate, up, down, sizes, interpret)
+    out_rows = glu_experts(rows, gate, up, down, sizes, interpret, activation)
     with jax.named_scope(trace.SCOPE_MOE_COMBINE):
         out = combine(out_rows, weights, layout)
     mean = jnp.maximum(n_held.astype(jnp.float32) / count, 1e-9)

@@ -11,8 +11,17 @@ whose outputs nobody reads). Everything untagged (norms, the router, the
 output projection, row gathers) is recomputed as before. A tag helps only
 where the backward pass reads the *tagged* value: a custom VJP's residuals
 tagged inside its forward rule, or a value whose consumers are ordinary
-equations. ``top_k`` and ``softmax`` keep their own untagged outputs, so a
-name on the router's choices would save nothing.
+equations.
+
+One name is kept for agreement and not for time: the router's choice
+(``moe/ids``). The kept sorted layout was made from the first forward's
+choice, and a router may read a stream the block recomputes (one placed
+after attention does), which can differ from the first in its last bits and
+turn a near tie the other way. Whatever then read the second choice beside
+the kept layout (which assignments are held, the chosen scores) would take
+one expert's row for another's, or a row nobody wrote. So ``route`` tags the
+ids under every scoring and a block chooses once; ``top_k`` and ``softmax``
+keep their own untagged outputs, so the name saves no time.
 
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
@@ -34,9 +43,11 @@ from fedml_tpu.obs import trace
 # ops/attention.py _fwd_rule: the flash kernels' residuals
 ATTN_RESIDUALS = ("attn/q", "attn/k", "attn/v", "attn/out", "attn/lse")
 MOE_ORDER, MOE_POS, MOE_SIZES = "moe/order", "moe/pos", "moe/sizes"  # ops/moe.py sorted_layout
-MOE_GATE_OUT, MOE_UP_OUT = "moe/gate_out", "moe/up_out"  # reglu_experts
+MOE_GATE_OUT, MOE_UP_OUT = "moe/gate_out", "moe/up_out"  # glu_experts
+# ops/moe.py route: the choice the kept layout was made from (see above)
+MOE_IDS = "moe/ids"
 
-KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT)
+KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS)
 NOTE = "remat/kept"
 
 

@@ -113,27 +113,35 @@ def _lowered_for_tpu(fn, *shapes):
     pytest.param((4, 16, 2048, 128), 16, None, id="cgpt13b_silo2"),
     pytest.param((1, 28, 8192, 128), 4, None, id="smallthinker21b_silo2-global"),
     pytest.param((1, 28, 8192, 128), 4, 4096, id="smallthinker21b_silo2-window"),
+    pytest.param((1, 32, 8192, 192), 32, None, id="joyai_flash_silo2-192-on-128"),
 ])
 def test_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, q_shape, kv_heads, window):
-    """The three kernels at both LM cells' shapes in bf16, with the tiles the
+    """The three kernels at the LM cells' shapes in bf16, with the tiles the
     kernels choose for them (``_fwd_blocks``, ``_bwd_blocks``): equal heads at
-    T 2048; 28 query heads on 4 KV heads at T 8192, global and window."""
+    T 2048; 28 query heads on 4 KV heads at T 8192, global and window; 32
+    heads of 192 score columns on 128 value columns at T 8192, whose resident
+    sequences ask Mosaic for more than its default VMEM (``_mosaic_params``)."""
     import fedml_tpu.ops.attention as att
 
     monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
     b, _, t, d = q_shape
+    d_v = 128
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, kv_heads, t, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, kv_heads, t, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, kv_heads, t, d_v), jnp.bfloat16)
     assert att._fwd_blocks(t, t, jnp.bfloat16) == (512, 512)
     assert att._bwd_blocks(t, t, jnp.bfloat16, (512, 512)) == (512, 512)
+    params = att._mosaic_params(jnp.bfloat16, (t, d), (t, d_v))
+    assert (params is None) == (d == d_v)
 
     def loss(q, k, v):
         return att.flash_attention(q, k, v, True, window=window).astype(jnp.float32).sum()
 
-    text = _lowered_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    text = _lowered_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
     assert text.count("tpu_custom_call") >= 3
     for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
         assert kernel in text, kernel
+    assert ("scoped_memory_configs" in text) == (d != d_v)  # where the VMEM limit is written
 
 
 def test_expert_layer_lowers_for_tpu_at_the_cells_shapes(monkeypatch):

@@ -149,7 +149,8 @@ def test_kept_note_names_and_bytes(monkeypatch):
     assert {k: n["bytes"] for k, n in notes.items()} == {
         "attn/q": q, "attn/k": kv, "attn/v": kv, "attn/out": q, "attn/lse": 2 * 4 * T * 4,
         remat.MOE_ORDER: rows * 4, remat.MOE_POS: rows * 4, remat.MOE_SIZES: E * 4,
-        remat.MOE_GATE_OUT: rows * F * 4, remat.MOE_UP_OUT: rows * F * 4}
+        remat.MOE_GATE_OUT: rows * F * 4, remat.MOE_UP_OUT: rows * F * 4,
+        remat.MOE_IDS: rows * 4}
     assert set(notes) == set(remat.KEPT)
     assert notes["attn/out"]["shape"] == (2, 4, T, 16)
     assert notes[remat.MOE_GATE_OUT]["dtype"] == "float32"

@@ -32,13 +32,19 @@ def _no_leaked_tracer():
 
 def test_span_nesting_across_threads():
     t = Tracer()
+    # the workers meet inside their outer spans: all three are alive at once,
+    # so the OS cannot hand a finished worker's ident to the next one started
+    # (which merged two tracks into one on a loaded machine)
+    meet = threading.Barrier(3)
 
-    def work(tag):
+    def work(tag, meet=None):
         with t.span("outer", tag=tag):
+            if meet is not None:
+                meet.wait(timeout=30)
             with t.span("inner", tag=tag):
                 time.sleep(0.002)
 
-    threads = [threading.Thread(target=work, args=(i,), name=f"w{i}")
+    threads = [threading.Thread(target=work, args=(i, meet), name=f"w{i}")
                for i in range(3)]
     for th in threads:
         th.start()
