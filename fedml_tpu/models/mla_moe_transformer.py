@@ -172,7 +172,7 @@ class MLABlock(nn.Module):
             shared = GatedMLP(self.shared_dim, self.dtype, name="shared")(u)
         m, stats = RoutedExperts(
             d, self.expert_dim, self.experts_first, self.experts_held, self.dtype,
-            activation=jax.nn.silu, name="experts")(u, ids, weights)
+            activation=jax.nn.silu, outputs=self.num_experts, name="experts")(u, ids, weights)
         return x + (shared.astype(jnp.float32) + m).reshape(b, t, d).astype(x.dtype), stats
 
 

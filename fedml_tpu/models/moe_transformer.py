@@ -120,15 +120,20 @@ class RoutedExperts(nn.Module):
     # tests/benchmark_tests/test_benchmark_moe.py stands a broken expert_layer
     # of the older signature in this one's place
     activation: Callable | None = None
+    # the router's outputs, of which ``held`` have their expert here (None:
+    # all of them). It sizes the layer's buffers and goes around the call, not
+    # into it, for the same stand-in's sake
+    outputs: int | None = None
 
     @nn.compact
     def __call__(self, u, ids, weights):
         d, f, held = self.hidden, self.expert_dim, self.held
         gate = {} if self.activation is None else {"activation": self.activation}
-        return moe.expert_layer(
-            u, ids, weights, Kernel((held, d, f), name="gate")(),
-            Kernel((held, d, f), name="up")(), Kernel((held, f, d), name="down")(),
-            first=self.first, count=held, dtype=self.dtype, **gate)
+        with moe.router_width(self.outputs):
+            return moe.expert_layer(
+                u, ids, weights, Kernel((held, d, f), name="gate")(),
+                Kernel((held, d, f), name="up")(), Kernel((held, f, d), name="down")(),
+                first=self.first, count=held, dtype=self.dtype, **gate)
 
 
 class MoEBlock(nn.Module):
@@ -163,7 +168,7 @@ class MoEBlock(nn.Module):
         u = RMSNorm(self.rms_eps, self.dtype, name="norm_moe")(x)
         m, stats = RoutedExperts(
             d, self.expert_dim, self.experts_first, self.experts_held, self.dtype,
-            name="experts")(u.reshape(b * t, d), ids, weights)
+            outputs=self.num_experts, name="experts")(u.reshape(b * t, d), ids, weights)
         return x + m.reshape(b, t, d).astype(x.dtype), stats
 
 
@@ -192,11 +197,11 @@ class MoETransformerLM(nn.Module):
     # rematerialize each block in the backward pass under ops/remat.py's
     # policy (as TransformerLM.remat): a block keeps its input, the flash
     # kernels' five residuals, the router's ids, the sorted layout made from
-    # them and the gate and up products (286.7 MB a layer in
-    # smallthinker21b_silo2, which cannot fit without remat), and computes
-    # again its norms, the router's logits and weights, output
-    # projection, row gathers and down product: 5% of the busy time where a
-    # bare checkpoint's second forward was 12% (PERF.md section 5)
+    # them and the gate and up products over the routed layer's buffer rows
+    # (192.3 MB a layer in smallthinker21b_silo2, which cannot fit without
+    # remat), and computes again its norms, the router's logits and weights,
+    # output projection, row gathers and down product: 5% of the busy time
+    # where a bare checkpoint's second forward was 12% (PERF.md section 5)
     remat: bool = False
 
     @nn.compact

@@ -23,6 +23,14 @@ one expert's row for another's, or a row nobody wrote. So ``route`` tags the
 ids under every scoring and a block chooses once; ``top_k`` and ``softmax``
 keep their own untagged outputs, so the name saves no time.
 
+The routed layer's kept values have the rows of its buffers, not of the
+assignments: ``moe/gate_out`` and ``moe/up_out`` are ``[C, F]`` with ``C``
+the layer's capacity (``ops/moe.py`` ``capacity``: one and a half times the
+share of the router's outputs held), tagged inside the layer's own forward
+rule; ``moe/order``, ``moe/pos`` and ``moe/ids`` stay ``T x k`` int32. The
+rare step that passes the capacity keeps nothing more: its overflow is
+recomputed from the layout, tile by tile, in the backward pass.
+
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
 The list is fixed here and follows no option: a name costs memory, and what

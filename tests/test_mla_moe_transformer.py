@@ -341,16 +341,25 @@ def test_a_rematerialised_block_routes_once():
     assert remat.MOE_IDS in kept
 
 
+@pytest.mark.parametrize("overflow", [False, True], ids=["within_capacity", "overflow"])
 @pytest.mark.parametrize("sigmoid", [False, True], ids=["softmax", "sigmoid"])
-def test_a_recomputed_stream_that_differs_in_its_last_bits_moves_no_row(sigmoid):
+def test_a_recomputed_stream_that_differs_in_its_last_bits_moves_no_row(sigmoid, overflow,
+                                                                        monkeypatch):
     """A router that reads what a rematerialised block recomputes, under
     either scoring. The stream comes through a host callback that nudges one
     element of token 0 from its second evaluation on (x (1 + 2^-18): the
     recompute), which turns that token's tie between a held and an absent
     expert. With the ids kept by name the gradients are those of the layer
     without remat; with ``moe/ids`` off the list the second choice meets the
-    first's layout and they are not."""
+    first's layout and they are not. With ``overflow`` the layer's buffers
+    hold 6 rows (a router said to be 8 wide, row tiles of 2) and the held
+    assignments pass them, so the overflow loop's backward reads the kept
+    layout beside the recomputed stream too."""
     from fedml_tpu.ops import remat
+
+    if overflow:
+        monkeypatch.setattr(moe, "GMM_TILES", (2, 1280, 1280))
+    width = 8 if overflow else 2
 
     held = 2
     rng = np.random.RandomState(0)
@@ -381,9 +390,10 @@ def test_a_recomputed_stream_that_differs_in_its_last_bits_moves_no_row(sigmoid)
             u = stream(x)
             bias = dict(select_bias=jnp.zeros(4), scale=2.5) if sigmoid else {}
             ids, weights = moe.route(u, router, 1, **bias)
-            out, _ = moe.expert_layer(u, ids, weights, gate, up, down, first=0, count=held,
-                                      dtype=jnp.float32)
-            return jnp.sum(out * jnp.arange(1.0, 9.0))
+            with moe.router_width(width):
+                out, stats = moe.expert_layer(u, ids, weights, gate, up, down, first=0,
+                                              count=held, dtype=jnp.float32)
+            return jnp.sum(out * jnp.arange(1.0, 9.0)), stats["moe/overflow_tiles"]
 
         return f
 
@@ -392,7 +402,8 @@ def test_a_recomputed_stream_that_differs_in_its_last_bits_moves_no_row(sigmoid)
         f = layer(calls)
         if names is not None:
             f = jax.checkpoint(f, policy=jax.checkpoint_policies.save_only_these_names(*names))
-        out = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(x, router, *stacks)
+        out, tiles = jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(x, router, *stacks)
+        assert (float(tiles) > 0) == overflow
         return [np.asarray(g) for g in out], len(calls)
 
     want, calls = grads(None)

@@ -185,7 +185,8 @@ def test_training_step_returns_the_routing_statistics():
     assert "stats" not in trainer.init(jax.random.key(0), sample)
     new, _, loss, stats = jax.jit(trainer.train_step_stats)(
         {"params": params}, trainer.optimizer.init(params), params, batch, jax.random.key(0))
-    assert set(stats) == {"moe/assignments_held", "moe/load_max_over_mean"}
+    assert set(stats) == {"moe/assignments_held", "moe/load_max_over_mean",
+                          "moe/rows_touched", "moe/overflow_tiles"}
     held = np.asarray(stats["moe/assignments_held"])
     assert held.shape == (4,) and np.all(held > 0) and np.all(held <= 2 * T * K)
     assert np.all(np.asarray(stats["moe/load_max_over_mean"]) >= 1.0)
@@ -220,7 +221,7 @@ def test_fedsim_round_carries_the_counts_to_counters():
     assert all(k in history[-1] for k in keys)
     assert 0 < history[-1]["stats/moe/assignments_held/layer_0"] <= T * K
     counters = [e for e in tracer.events() if e["ph"] == "C" and e["name"].startswith("moe/")]
-    assert len(counters) == 2 * 8
+    assert len(counters) == 4 * 8  # four statistics, four layers, two rounds
     last = trace.last_counters("moe/assignments_held/")
     assert last["moe/assignments_held/layer_3"] == history[-1][keys[3]]
 
