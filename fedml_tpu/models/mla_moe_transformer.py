@@ -40,12 +40,34 @@ say which of the router's outputs have their expert here, as in
 ``models/moe_transformer.py``, whose ``RMSNorm``, ``Kernel`` and
 ``RoutedExperts`` these blocks share.
 
+A layer's mixer is latent attention as above or, where ``mixers`` says
+"kda", Kimi Delta Attention (the Kimi-Linear block: three such layers to one
+latent-attention layer, which there has no query latent, ``q_rank`` None, and
+leaves its position columns unrotated, ``rope_theta`` None). With H heads of
+d = 128 key and value columns:
+
+    q, k, v = silu(conv4(h W_q)), silu(conv4(h W_k)), silu(conv4(h W_v))   [T, H d] each
+              conv4: causal depthwise convolution along T, 4 taps a channel,
+              zeros before the first token, no bias (``ops/kda.py`` short_conv)
+    q_t, k_t a head:  q_t = l2norm(q_t) * d^-0.5,  k_t = l2norm(k_t)       (eps 1e-6)
+    g_t    = -exp(A_log[head]) * softplus((h W_fa) W_fb + dt_bias)         [T, H, d] <= 0, float32
+    beta_t = sigmoid(h W_b)                                                [T, H]
+    S_0 = 0;  S~ = Diag(exp g_t) S_{t-1};  S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T
+    o_t = S_t^T q_t
+    x1 = x + concat_heads(rmsnorm_d(o_t) * sigmoid((h W_ga) W_gb + b_g)) W_o
+
+``attn_impl`` "flash" runs the recurrence in chunks (``ops/kda.py`` kda),
+"xla" token by token (kda_reference). ``A_log`` is one scalar a head and
+``dt_bias`` one a channel, each a ``[1, n]`` leaf named ``kernel``.
+
 Same interface as the rest of the zoo: int tokens ``[B, T]`` in, logits
 ``[B, T, V]`` float32 out, ``train`` kwarg. ``train=False`` builds no MTP
 logits.
 """
 
 from __future__ import annotations
+
+from typing import Sequence
 
 import flax.linen as nn
 import jax
@@ -54,8 +76,10 @@ import jax.numpy as jnp
 from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
 from fedml_tpu.models.moe_transformer import Kernel, RMSNorm, RoutedExperts
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import moe, remat
+from fedml_tpu.ops import kda, moe, remat
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
+
+MLA, KDA = "mla", "kda"
 
 
 def rope_interleaved(x, theta: float):
@@ -73,12 +97,12 @@ def rope_interleaved(x, theta: float):
 
 class LatentAttention(nn.Module):
     num_heads: int
-    q_rank: int
+    q_rank: int | None  # None: no query latent, one product from the stream to the heads
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    rope_theta: float
+    rope_theta: float | None  # None: the ``rope_dim`` columns stay as they are, unrotated
     rms_eps: float = 1e-6
     attn_impl: str = "xla"  # xla | flash
     dtype: jnp.dtype = jnp.float32
@@ -94,15 +118,22 @@ class LatentAttention(nn.Module):
         def heads(y, width):  # [B, T, n * width] -> [B, n, T, width]
             return y.reshape(b, t, n, width).transpose(0, 2, 1, 3)
 
+        def turned(y):
+            return y if self.rope_theta is None else rope_interleaved(y, self.rope_theta)
+
         with jax.named_scope(trace.SCOPE_MLA):
-            c_q = RMSNorm(self.rms_eps, self.dtype, name="q_a_norm")(dense("q_a", self.q_rank, h))
-            q = heads(dense("q_b", n * (nope + rope_d), c_q), nope + rope_d)
+            if self.q_rank is None:
+                q = heads(dense("q", n * (nope + rope_d), h), nope + rope_d)
+            else:
+                c_q = RMSNorm(self.rms_eps, self.dtype, name="q_a_norm")(
+                    dense("q_a", self.q_rank, h))
+                q = heads(dense("q_b", n * (nope + rope_d), c_q), nope + rope_d)
             kv_a = dense("kv_a", self.kv_rank + rope_d, h)
             c_kv = RMSNorm(self.rms_eps, self.dtype, name="kv_a_norm")(kv_a[..., :self.kv_rank])
-            k_rope = rope_interleaved(kv_a[:, None, :, self.kv_rank:], self.rope_theta)
+            k_rope = turned(kv_a[:, None, :, self.kv_rank:])
             kv = heads(dense("kv_b", n * (nope + self.v_dim), c_kv), nope + self.v_dim)
-            q = jnp.concatenate(
-                [q[..., :nope], rope_interleaved(q[..., nope:], self.rope_theta)], axis=-1)
+            if self.rope_theta is not None:
+                q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
             # the kernels' key is one [H, T, nope + rope] operand: the shared
             # rotary columns are written once a head (PERF.md section 6, PR 32)
             k = jnp.concatenate(
@@ -114,6 +145,53 @@ class LatentAttention(nn.Module):
                 a = attention_reference(q, k, v, causal=True)
             a = a.transpose(0, 2, 1, 3).reshape(b, t, n * self.v_dim)
             return dense("o", h.shape[-1], a)
+
+
+class DeltaAttention(nn.Module):
+    """Kimi Delta Attention (the module docstring's equations): ``num_heads``
+    heads of ``head_dim`` key and value columns."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    rms_eps: float = 1e-6
+    attn_impl: str = "xla"  # xla: token by token | flash: in chunks
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, _ = h.shape
+        n, d = self.num_heads, self.head_dim  # d is also the two low-rank gates' rank
+
+        def dense(name, width, y):
+            return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(y)
+
+        def heads(y):  # [B, T, n * d] -> [B, n, T, d]
+            return y.reshape(b, t, n, d).transpose(0, 2, 1, 3)
+
+        def conv(name, kept):
+            # the projection is what a rematerialised block keeps: the taps'
+            # gradient reads it, and the convolution costs little to run again
+            y = remat.keep(kept, dense(name, n * d, h))
+            return heads(jax.nn.silu(kda.short_conv(y, Kernel((self.conv_size, n * d),
+                                                              name=name + "_conv")())))
+
+        with jax.named_scope(trace.SCOPE_KDA):
+            q, k, v = conv("q", remat.KDA_Q), conv("k", remat.KDA_K), conv("v", remat.KDA_V)
+            q = (kda.l2norm(q) * d ** -0.5).astype(self.dtype)
+            k = kda.l2norm(k).astype(self.dtype)
+            decay = dense("f_b", n * d, dense("f_a", d, h)).astype(jnp.float32)
+            decay = jax.nn.softplus(decay + Kernel((1, n * d), name="dt_bias")()[0])
+            g = -jnp.exp(Kernel((1, n), name="A_log")()[0])[:, None, None] * heads(decay)
+            beta = jax.nn.sigmoid(dense("b", n, h).astype(jnp.float32)).transpose(0, 2, 1)
+            if self.attn_impl == "flash":
+                o = kda.kda(q, k, v, g, beta)
+            else:
+                o = kda.kda_reference(q, k, v, g, beta)
+            o = RMSNorm(self.rms_eps, self.dtype, name="o_norm")(o)
+            gate = nn.Dense(n * d, name="g_b", dtype=self.dtype)(dense("g_a", d, h))
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, n * d) * jax.nn.sigmoid(gate)
+            return dense("o", h.shape[-1], o), jax.lax.stop_gradient(kda.decay_floor(g))
 
 
 class GatedMLP(nn.Module):
@@ -148,21 +226,35 @@ class MLABlock(nn.Module):
     route_scale: float
     experts_first: int
     experts_held: int
-    rope_theta: float
+    rope_theta: float | None
     rms_eps: float = 1e-6
     attn_impl: str = "xla"
     dtype: jnp.dtype = jnp.float32
+    mixer: str = MLA  # MLA | KDA
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    conv_size: int = 4
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
         h = RMSNorm(self.rms_eps, self.dtype, name="norm_attn")(x)
-        x = x + LatentAttention(
-            self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim, self.v_dim,
-            self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, name="attn")(h)
+        mixer_stats = {}
+        if self.mixer == KDA:
+            mixed, floor = DeltaAttention(
+                self.kda_heads, self.kda_head_dim, self.conv_size, self.rms_eps,
+                self.attn_impl, self.dtype, name="attn")(h)
+            mixer_stats = {"kda/decay_floor": floor}
+        else:
+            mixed = LatentAttention(
+                self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
+                self.v_dim, self.rope_theta, self.rms_eps, self.attn_impl, self.dtype,
+                name="attn")(h)
+        x = x + mixed
         u = RMSNorm(self.rms_eps, jnp.float32, name="norm_ffn")(x)
         if not self.routed:
-            return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), {}
+            return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), (
+                mixer_stats)
         u = u.reshape(b * t, d)
         ids, weights = moe.route(
             u, Kernel((d, self.num_experts), name="router")(), self.experts_per_token,
@@ -173,20 +265,22 @@ class MLABlock(nn.Module):
         m, stats = RoutedExperts(
             d, self.expert_dim, self.experts_first, self.experts_held, self.dtype,
             activation=jax.nn.silu, outputs=self.num_experts, name="experts")(u, ids, weights)
-        return x + (shared.astype(jnp.float32) + m).reshape(b, t, d).astype(x.dtype), stats
+        return x + (shared.astype(jnp.float32) + m).reshape(b, t, d).astype(x.dtype), {
+            **stats, **mixer_stats}
 
 
 class MLAMoETransformerLM(nn.Module):
     """Causal LM of ``dense_layers`` dense then ``routed_layers`` routed
     :class:`MLABlock` layers, with ``mtp_depth`` (0 or 1) multi-token-
-    prediction modules of one routed block each."""
+    prediction modules of one routed block each. ``mixers`` gives each
+    layer's mixer in order ("mla" | "kda"; None: latent attention in all)."""
 
     vocab_size: int = 96
     embed_dim: int = 64
     dense_layers: int = 1
     routed_layers: int = 2
     num_heads: int = 4
-    q_rank: int = 48
+    q_rank: int | None = 48
     kv_rank: int = 32
     nope_dim: int = 16
     rope_dim: int = 8
@@ -201,7 +295,11 @@ class MLAMoETransformerLM(nn.Module):
     experts_held: int | None = None  # None: all of them
     mtp_depth: int = 1
     mtp_loss_weight: float = 0.3
-    rope_theta: float = 32e6
+    rope_theta: float | None = 32e6
+    mixers: Sequence[str] | None = None
+    kda_heads: int = 4
+    kda_head_dim: int = 16
+    conv_size: int = 4
     rms_eps: float = 1e-6
     attn_impl: str = "xla"
     dtype: jnp.dtype = jnp.float32  # compute dtype of the products; params stay f32
@@ -219,12 +317,18 @@ class MLAMoETransformerLM(nn.Module):
         held = self.num_experts if self.experts_held is None else self.experts_held
         block_cls = remat.block(MLABlock) if self.remat else MLABlock
 
-        def block(routed, name):
+        layers = self.dense_layers + self.routed_layers
+        mixers = (MLA,) * layers if self.mixers is None else tuple(self.mixers)
+        if len(mixers) != layers or set(mixers) - {MLA, KDA}:
+            raise ValueError(f"mixers must name {layers} layers' mixers, each mla or kda")
+
+        def block(routed, name, mixer=MLA):
             return block_cls(
                 routed, self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
                 self.v_dim, self.dense_dim, self.num_experts, self.experts_per_token,
                 self.expert_dim, self.shared_dim, self.route_scale, self.experts_first, held,
-                self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, name=name)
+                self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, mixer,
+                self.kda_heads, self.kda_head_dim, self.conv_size, name=name)
 
         def logits(h, norm):
             h = RMSNorm(self.rms_eps, self.head_dtype, name=norm)(h)
@@ -232,8 +336,8 @@ class MLAMoETransformerLM(nn.Module):
 
         h = embed(x)  # the residual stream stays float32: the router reads it
         stats = []
-        for i in range(self.dense_layers + self.routed_layers):
-            h, layer_stats = block(i >= self.dense_layers, f"block_{i}")(h)
+        for i, mixer in enumerate(mixers):
+            h, layer_stats = block(i >= self.dense_layers, f"block_{i}", mixer)(h)
             stats.append(layer_stats)
         # params of every module are made at init, whatever ``train`` says
         if self.mtp_depth and (self.is_initializing()
@@ -250,9 +354,13 @@ class MLAMoETransformerLM(nn.Module):
                          {"logits": logits(g, "mtp_norm_f"),
                           "weight": jnp.float32(self.mtp_loss_weight)},
                          reduce_fn=lambda _, new: new, init_fn=lambda: None)
-        # one value a routed block (the MTP module's last), for the engine's counters
-        stats = [s for s in stats if s]
-        self.sow(STATS_COLLECTION, "moe",
-                 {k.split("/", 1)[1]: jnp.stack([s[k] for s in stats]) for k in stats[0]},
-                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        # for the engine's counters: one value a routed block (the MTP module's
+        # last) under "moe", one a delta-attention block under "kda"
+        for group in ("moe", "kda"):
+            found = [{k: v for k, v in s.items() if k.startswith(group + "/")} for s in stats]
+            found = [s for s in found if s]
+            if found:
+                self.sow(STATS_COLLECTION, group,
+                         {k.split("/", 1)[1]: jnp.stack([s[k] for s in found]) for k in found[0]},
+                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
         return logits(h, "norm_f")

@@ -68,8 +68,8 @@ __all__ = [
     "program_note", "program_notes", "last_counters",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "FLASH_KERNEL_NAME", "FLASH_BWD_DKV_KERNEL_NAME",
-    "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
+    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "FLASH_KERNEL_NAME",
+    "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
 JSONL_TRACE_NAME = "trace.jsonl"
@@ -109,6 +109,12 @@ SCOPE_MLA = "attn/mla"
 SCOPE_MOE_SHARED = "moe/shared"
 SCOPE_MTP = "mtp"
 MLA_SCOPES = (SCOPE_MLA, SCOPE_MOE_SHARED, SCOPE_MTP)
+# Scopes of the delta-rule linear-attention mixer, inside SCOPE_FWD_BWD: the
+# module whole (projections, convolutions, gates, norms and the scan), and
+# the chunked recurrence alone (ops/kda.py), forward and backward
+SCOPE_KDA = "attn/kda"
+SCOPE_KDA_SCAN = "attn/kda/scan"
+KDA_SCOPES = (SCOPE_KDA, SCOPE_KDA_SCAN)
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
 # holds "flash_fwd", which the benchmark's forward readers match on

@@ -31,10 +31,19 @@ rule; ``moe/order``, ``moe/pos`` and ``moe/ids`` stay ``T x k`` int32. The
 rare step that passes the capacity keeps nothing more: its overflow is
 recomputed from the layout, tile by tile, in the backward pass.
 
+A delta-attention block (``models/mla_moe_transformer.py`` ``DeltaAttention``)
+keeps five: the q, k and v projections *before* their convolutions
+(``kda/q``, ``kda/k``, ``kda/v``: the taps' gradient reads the projection, and
+the convolution, SiLU and l2norm cost little to run again), and, tagged inside
+the scan's forward rule (``ops/kda.py``), its output ``kda/out`` and the state
+that entered each group of chunks ``kda/states``, so that the second forward
+runs no scan and the backward starts each group from a kept state. The
+log-decays are never kept: they are remade from a ``[T, rank]`` product.
+
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
 The list is fixed here and follows no option: a name costs memory, and what
-fits was sized on the one cell that needs remat (``PERF.md`` section 5).
+fits was sized on the cells that need remat (``PERF.md`` sections 5 and 6).
 """
 
 from __future__ import annotations
@@ -55,7 +64,15 @@ MOE_GATE_OUT, MOE_UP_OUT = "moe/gate_out", "moe/up_out"  # glu_experts
 # ops/moe.py route: the choice the kept layout was made from (see above)
 MOE_IDS = "moe/ids"
 
-KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS)
+# models/mla_moe_transformer.py DeltaAttention: the three projections before
+# their convolutions; ops/kda.py _fwd_rule: the scan's output and the state
+# that entered each group of chunks
+KDA_Q, KDA_K, KDA_V = "kda/q", "kda/k", "kda/v"
+KDA_OUT, KDA_STATES = "kda/out", "kda/states"
+KDA_KEPT = (KDA_Q, KDA_K, KDA_V, KDA_OUT, KDA_STATES)
+
+KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS,
+        *KDA_KEPT)
 NOTE = "remat/kept"
 
 

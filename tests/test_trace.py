@@ -567,3 +567,91 @@ def test_sysstats_cpu_counter_primed():
         # real utilization measurement (a float; 0.0 only if the host was
         # truly idle over the window, not the unprimed constant)
         assert isinstance(sample["cpu_utilization"], float)
+
+
+# -- the delta-attention mixer's scopes, note, counter and kept names --------
+
+
+def _delta_lm(**over):
+    from fedml_tpu.models.mla_moe_transformer import KDA, MLAMoETransformerLM
+
+    return MLAMoETransformerLM(**{**dict(
+        vocab_size=61, embed_dim=32, dense_layers=2, routed_layers=0, q_rank=None,
+        rope_theta=None, dense_dim=64, mtp_depth=0, mixers=(KDA, KDA), kda_heads=2,
+        kda_head_dim=16, attn_impl="flash"), **over})
+
+
+def test_delta_attention_scopes_note_and_kept_names_in_the_lowered_training_step():
+    """``attn/kda`` round the mixer and ``attn/kda/scan`` round the recurrence
+    alone, inside ``fed/fwd_bwd``, forward and backward; a ``kda/call`` note a
+    call; a rematerialised block's ``remat/kept`` notes for the three
+    projections, the scan's output and its states."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.ops import kda, remat
+
+    assert trace.KDA_SCOPES == (trace.SCOPE_KDA, trace.SCOPE_KDA_SCAN) == (
+        "attn/kda", "attn/kda/scan")
+    assert not set(trace.KDA_SCOPES) & (
+        set(trace.SCOPES) | set(trace.MOE_SCOPES) | set(trace.MLA_SCOPES))
+    model = _delta_lm(remat=True)
+    x = jnp.zeros((2, 24), jnp.int32)
+    params = model.init(jax.random.key(0), x)["params"]
+    trainer = ClientTrainer(module=model, task="nwp", optimizer=optax.sgd(0.01))
+    batch = {"x": x, "y": x, "mask": jnp.ones(x.shape, jnp.float32)}
+    lines = jax.jit(trainer.train_step_stats).lower(
+        {"params": params}, trainer.optimizer.init(params), params, batch,
+        jax.random.key(0)).as_text(debug_info=True).splitlines()
+    for scope in trace.KDA_SCOPES:
+        assert any(f"/{scope}/" in ln and "fed/fwd_bwd" in ln and "transpose(" not in ln
+                   for ln in lines), scope
+        assert any(f"/{scope}/" in ln and "fed/fwd_bwd" in ln and "transpose(" in ln
+                   for ln in lines), scope
+    # the projections lie under the mixer's scope and not under the scan's
+    assert any("attn/kda/q/dot_general" in ln and "attn/kda/scan" not in ln for ln in lines)
+    assert {"impl": "xla", "chunk": kda.CHUNK, "chunks": 1, "heads": 2, "d_k": 16, "d_v": 16,
+            "t": 24} in trace.program_notes("kda/call")
+    kept = {n["kept"]: n for n in trace.program_notes(remat.NOTE) if n["kept"].startswith("kda/")}
+    assert set(kept) == set(remat.KDA_KEPT)
+    assert kept[remat.KDA_Q]["shape"] == (2, 24, 32) and kept[remat.KDA_Q]["bytes"] == 2 * 24 * 32 * 4
+    # one group of one (padded) chunk: the state that entered it, float32 a head
+    assert kept[remat.KDA_STATES]["shape"] == (1, 2, 2, 16, 16)
+    assert kept[remat.KDA_OUT]["shape"] == (1, 2, 2, 1, kda.CHUNK, 16)
+
+
+def test_decay_floor_counters_reach_the_tracer_through_the_round():
+    """``kda/decay_floor/layer_<i>``: one a delta-attention block, on the
+    engine's stats path, kept past the tracer's life for the benchmark's
+    reader."""
+    import jax.numpy as jnp
+    import optax
+
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.sim.cohort import FederatedArrays
+    from fedml_tpu.sim.engine import FedSim, SimConfig
+
+    rows = np.random.RandomState(0).randint(0, 61, (4, 25)).astype(np.int32)
+    train = FederatedArrays(
+        {"x": rows[:, :-1], "y": rows[:, 1:], "mask": np.ones((4, 24), np.float32)},
+        {0: np.arange(2), 1: np.arange(2, 4)})
+    sim = FedSim(
+        ClientTrainer(module=_delta_lm(), task="nwp", epochs=1, optimizer=optax.sgd(0.01)),
+        train, None, SimConfig(client_num_in_total=2, client_num_per_round=2, batch_size=1,
+                               comm_round=1, epochs=1, frequency_of_the_test=100,
+                               shuffle_each_round=False, cohort_execution="scan",
+                               block_dispatch=False))
+    tracer = trace.install()
+    try:
+        _, history = sim.run()
+    finally:
+        trace.uninstall()
+    floors = trace.last_counters("kda/decay_floor/")
+    assert set(floors) == {"kda/decay_floor/layer_0", "kda/decay_floor/layer_1"}
+    assert all(v < 0 for v in floors.values())
+    assert history[-1]["stats/kda/decay_floor/layer_1"] == pytest.approx(
+        floors["kda/decay_floor/layer_1"])
+    assert any(e["name"] == "kda/decay_floor/layer_0" for e in tracer.events())
+    assert jnp.isfinite(history[-1]["Train/Loss"])
