@@ -69,7 +69,8 @@ __all__ = [
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
     "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "FLASH_KERNEL_NAME",
-    "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "COMPILE_SPANS",
+    "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
+    "KDA_BWD_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
 JSONL_TRACE_NAME = "trace.jsonl"
@@ -120,6 +121,9 @@ FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # holds "flash_fwd", which the benchmark's forward readers match on
 FLASH_BWD_DKV_KERNEL_NAME = "flash_bwd_dkv"
 FLASH_BWD_DQ_KERNEL_NAME = "flash_bwd_dq"
+# ``name=`` of the delta-rule scan's two Mosaic kernels (ops/kda.py)
+KDA_FWD_KERNEL_NAME = "kda_fwd"
+KDA_BWD_KERNEL_NAME = "kda_bwd"
 
 # jax.monitoring duration events recorded as spans while a tracer is
 # installed: a program was built under the span that is open on the calling

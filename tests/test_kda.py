@@ -1,11 +1,12 @@
-"""The delta rule's chunked scan (``fedml_tpu/ops/kda.py``) against the
-recurrence run token by token: output and the five operands' gradients at
-three chunk sizes, at a length that is no multiple of the chunk, under decays
-whose cumulated logs pass -200 (where ``exp(-gamma)`` alone overflows), on
-sixty-four equal keys (where powers of the triangular system overflow), and
-in bfloat16; the short convolution against a direct sum; the loops a block
-adds; and the scan's lowering for the TPU at the cell's shape. On the CPU;
-nothing here describes a TPU topology, so the file is safe under xdist.
+"""The delta rule's chunked scan (``fedml_tpu/ops/kda.py``: two Mosaic kernels,
+interpreted here) against the recurrence run token by token: output and the
+five operands' gradients at three chunk sizes, at a length that is no
+multiple of the chunk, under decays whose cumulated logs pass -200 (where
+``exp(-gamma)`` alone overflows), on sixty-four equal keys (where powers of the
+triangular system overflow), and in bfloat16; the short convolution against a
+direct sum; the kernels and loops a block adds, and the scope they sit under;
+and the scan's lowering for the TPU at the cell's shape. On the CPU; nothing
+here describes a TPU topology, so the file is safe under xdist.
 """
 
 import collections
@@ -19,6 +20,8 @@ from fedml_tpu.models.mla_moe_transformer import KDA, MLABlock
 from fedml_tpu.obs import trace
 from fedml_tpu.ops import kda, remat
 from tests.test_remat_policy import _equations
+
+KERNELS = (trace.KDA_FWD_KERNEL_NAME, trace.KDA_BWD_KERNEL_NAME)
 
 B, H, D_K, D_V = 2, 3, 32, 24
 OPERANDS = ("q", "k", "v", "g", "beta")
@@ -77,16 +80,24 @@ def test_equal_keys_and_beta_near_one(chunk):
     g, beta = jnp.full((1, 2, t, D_K), -1e-4), jnp.full((1, 2, t), 0.999)
     args = (k * D_K ** -0.5, k, v, g, beta)
     close(kda.kda(*args, chunk=chunk), kda.kda_reference(*args), 1e-5)
+    # the inverse's cotangent, -T^T dT T^T, on the same system
+    weight = jax.random.normal(jax.random.key(4), v.shape)
+    grads = jax.grad(lambda *a: jnp.sum(kda.kda(*a, chunk=chunk) * weight), argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(kda.kda_reference(*a) * weight), argnums=range(5))(*args)
+    for name, got, want in zip(OPERANDS, grads, wants):
+        if name == "g":
+            # sums of order one that cancel to 4e-4 on equal keys: float32 leaves 1e-5 of them
+            assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+        else:
+            close(got, want, 1e-5)
 
 
-def test_unit_lower_inverse_is_the_inverse():
-    # entries as beta * (k_i . k_j) has them: a well-conditioned system
-    low = 0.1 * jnp.tril(jax.random.normal(jax.random.key(0), (3, 64, 64)), -1)
-    inverse = kda._unit_lower_inverse(low)
-    np.testing.assert_allclose(
-        jnp.matmul(inverse, jnp.eye(64) + low, precision=kda.HI),
-        np.broadcast_to(np.eye(64), (3, 64, 64)), atol=1e-5)
-    assert float(jnp.max(jnp.abs(jnp.triu(inverse, 1)))) == 0.0
+@pytest.mark.parametrize("chunk", [8, 24, 96])
+def test_a_chunk_the_kernels_cannot_halve_is_refused(chunk):
+    """On every backend, as Mosaic would: a chunk is a power of two of at
+    least 16 rows (bfloat16's sublane tile), halved down to the sub-blocks."""
+    with pytest.raises(ValueError, match="power of two"):
+        kda.kda(*operands(40, 0.1), chunk=chunk)
 
 
 def test_bfloat16_operands_stay_finite_and_near():
@@ -98,8 +109,13 @@ def test_bfloat16_operands_stay_finite_and_near():
     out = kda.kda(*args)
     assert out.dtype == jnp.bfloat16
     close(out.astype(jnp.float32), kda.kda_reference(*args), 0.03)
-    grads = jax.grad(lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)), argnums=range(5))(*args)
-    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in grads)
+    weight = jax.random.normal(jax.random.key(9), out.shape)
+    grads = jax.grad(lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32) * weight),
+                     argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(kda.kda_reference(*a) * weight), argnums=range(5))(*args)
+    for name, got, want in zip(OPERANDS, grads, wants):
+        assert got.dtype == want.dtype, name
+        close(got.astype(jnp.float32), want.astype(jnp.float32), 0.05)
 
 
 def test_no_initial_state_and_causal():
@@ -141,16 +157,15 @@ def test_decay_floor_is_the_least_chunk_sum():
 def test_every_call_leaves_a_note():
     args = operands(40, 0.1, seed=5)
     kda.kda(*args, chunk=16)
-    assert {"impl": "xla", "chunk": 16, "chunks": 3, "heads": H, "d_k": D_K, "d_v": D_V,
+    assert {"impl": "kda_fwd", "chunk": 16, "chunks": 3, "heads": H, "d_k": D_K, "d_v": D_V,
             "t": 40} in trace.program_notes("kda/call")
 
 
 # -- what a block adds to the program -----------------------------------------------
-# a delta-attention block holds no Mosaic kernel of the mixer's. Its value holds
-# two loops: the scan over groups and, in its body, the walk over a group's
-# chunks. Its gradient holds five: those two, the reverse scan over groups, and
-# in that one's body the walk again and its transpose. A rematerialised block
-# keeps the scan's output and states by name and runs no forward scan twice
+# a delta-attention block's value holds one Mosaic kernel, kda_fwd, and its
+# gradient a second, kda_bwd; neither holds a loop (a group's chunks lie side by
+# side in a grid step, and the grid walks the groups). A rematerialised block
+# keeps the scan's output and states by name and runs no forward kernel twice
 
 def _block(remat_on):
     cls = remat.block(MLABlock) if remat_on else MLABlock
@@ -160,7 +175,7 @@ def _block(remat_on):
 
 def _loops(jaxpr):
     return collections.Counter(
-        p for p, _, _ in _equations(jaxpr) if p in ("scan", "while", "pallas_call"))
+        kernel or p for p, kernel, _ in _equations(jaxpr) if p in ("scan", "while", "pallas_call"))
 
 
 @pytest.mark.parametrize("remat_on", [False, True], ids=["plain", "remat"])
@@ -169,18 +184,37 @@ def test_loops_and_kernels_a_delta_attention_block_adds(remat_on):
     x = jax.random.normal(jax.random.key(0), (1, 48, 64))
     params = block.init(jax.random.key(1), x)
     value = lambda params: jnp.sum(block.apply(params, x)[0])  # noqa: E731
-    assert dict(_loops(jax.make_jaxpr(value)(params).jaxpr)) == {"scan": 2}
-    assert dict(_loops(jax.make_jaxpr(jax.value_and_grad(value))(params).jaxpr)) == {"scan": 5}
+    assert dict(_loops(jax.make_jaxpr(value)(params).jaxpr)) == {"kda_fwd": 1}
+    assert dict(_loops(jax.make_jaxpr(jax.value_and_grad(value))(params).jaxpr)) == {
+        "kda_fwd": 1, "kda_bwd": 1}
     kept = {n["kept"] for n in trace.program_notes(remat.NOTE)}
     if remat_on:
         assert set(remat.KDA_KEPT) <= kept
 
 
-def test_the_scan_lowers_for_the_tpu_at_the_cells_shape():
+@pytest.mark.parametrize("remat_on", [False, True], ids=["plain", "remat"])
+def test_both_kernels_sit_under_the_scans_scope(remat_on):
+    """Two ledger metrics read the scan by ``attn/kda/scan`` in the ops' names
+    (``benchmark/layer_metrics/kda_scan_*``): the forward kernel and the
+    backward kernel both bear it in the locations of the text lowered from a
+    block's gradient, which is where the chip's trace takes ``op_name`` from."""
+    block = _block(remat_on)
+    x = jax.random.normal(jax.random.key(0), (1, 48, 64))
+    params = block.init(jax.random.key(1), x)
+    grad = jax.grad(lambda params: jnp.sum(block.apply(params, x)[0]))
+    text = jax.jit(grad).lower(params).as_text(debug_info=True)
+    for kernel in KERNELS:
+        named = [line for line in text.splitlines()
+                 if line.lstrip().startswith("#loc") and kernel in line]
+        assert named and all(trace.SCOPE_KDA_SCAN in line for line in named), kernel
+
+
+def test_the_scan_lowers_for_the_tpu_at_the_cells_shape(monkeypatch):
     """[1, 32, 8192, 128] in bfloat16, value and gradients, lowered for the TPU
-    from here (no compile, no chip): plain XLA, five loops (the scan over
-    groups and the walk over a group's chunks forward; the reverse scan with
-    the walk and its transpose inside), no custom call."""
+    from here (no compile, no chip; Mosaic as on the chip, not interpreted):
+    two custom calls, kda_fwd and kda_bwd, and no loop of XLA's (the walk
+    over chunks is inside them)."""
+    monkeypatch.setattr(kda, "_interpret_on", lambda platform: False)
     q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
     g = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32)
     beta = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32)
@@ -190,7 +224,9 @@ def test_the_scan_lowers_for_the_tpu_at_the_cells_shape():
 
     text = jax.jit(jax.grad(loss, argnums=range(5))).trace(q, q, q, g, beta).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert text.count("stablehlo.while") == 5
-    assert "tpu_custom_call" not in text
-    assert {"impl": "xla", "chunk": kda.CHUNK, "chunks": 8192 // kda.CHUNK, "heads": 32,
+    assert text.count("stablehlo.while") == 0
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    for kernel in KERNELS:
+        assert text.count(f'kernel_name = "{kernel}"') == 1
+    assert {"impl": "kda_fwd", "chunk": kda.CHUNK, "chunks": 8192 // kda.CHUNK, "heads": 32,
             "d_k": 128, "d_v": 128, "t": 8192} in trace.program_notes("kda/call")

@@ -612,14 +612,15 @@ def test_delta_attention_scopes_note_and_kept_names_in_the_lowered_training_step
                    for ln in lines), scope
     # the projections lie under the mixer's scope and not under the scan's
     assert any("attn/kda/q/dot_general" in ln and "attn/kda/scan" not in ln for ln in lines)
-    assert {"impl": "xla", "chunk": kda.CHUNK, "chunks": 1, "heads": 2, "d_k": 16, "d_v": 16,
-            "t": 24} in trace.program_notes("kda/call")
+    assert {"impl": trace.KDA_FWD_KERNEL_NAME, "chunk": kda.CHUNK, "chunks": 1, "heads": 2,
+            "d_k": 16, "d_v": 16, "t": 24} in trace.program_notes("kda/call")
     kept = {n["kept"]: n for n in trace.program_notes(remat.NOTE) if n["kept"].startswith("kda/")}
     assert set(kept) == set(remat.KDA_KEPT)
     assert kept[remat.KDA_Q]["shape"] == (2, 24, 32) and kept[remat.KDA_Q]["bytes"] == 2 * 24 * 32 * 4
     # one group of one (padded) chunk: the state that entered it, float32 a head
-    assert kept[remat.KDA_STATES]["shape"] == (1, 2, 2, 16, 16)
-    assert kept[remat.KDA_OUT]["shape"] == (1, 2, 2, 1, kda.CHUNK, 16)
+    # (transposed: [d_v, d_k]), and the padded rows of each of the 2 x 2 heads
+    assert kept[remat.KDA_STATES]["shape"] == (4, 1, 16, 16)
+    assert kept[remat.KDA_OUT]["shape"] == (4, kda.CHUNK, 16)
 
 
 def test_decay_floor_counters_reach_the_tracer_through_the_round():
