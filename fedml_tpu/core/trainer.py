@@ -338,15 +338,18 @@ def make_local_train(trainer: ClientTrainer):
                 w = (jnp.sum(batch["mask"]) > 0).astype(jnp.float32)
                 return (variables, opt_state, rng), (loss, w, stats)
 
-            (variables, opt_state, rng), (losses, ws, stats) = scanlib.scan(
-                step_body, (variables, opt_state, rng), (jnp.arange(S), data)
-            )
+            with trace.loop(trace.SCOPE_LOOP_STEPS, carry):
+                (variables, opt_state, rng), (losses, ws, stats) = scanlib.scan(
+                    step_body, carry, (jnp.arange(S), data)
+                )
             stat_sums = jax.tree.map(lambda s: jnp.tensordot(ws, s, 1), stats)
             return (variables, opt_state, rng), (jnp.sum(losses * ws), jnp.sum(ws), stat_sums)
 
-        (variables, opt_state, rng), (loss_sums, w_sums, stat_sums) = scanlib.scan(
-            epoch_body, (global_variables, opt_state, rng), jnp.arange(trainer.epochs)
-        )
+        carry = (global_variables, opt_state, rng)
+        with trace.loop(trace.SCOPE_LOOP_EPOCHS, carry):
+            (variables, opt_state, rng), (loss_sums, w_sums, stat_sums) = scanlib.scan(
+                epoch_body, carry, jnp.arange(trainer.epochs)
+            )
         # mean loss over executed (unmasked) steps of the last executed epoch
         if num_steps is None:
             last = trainer.epochs - 1

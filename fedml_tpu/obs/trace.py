@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import math
 import threading
 import time
 from pathlib import Path
@@ -65,10 +66,11 @@ from typing import Any
 __all__ = [
     "Tracer", "install", "uninstall", "get", "enabled",
     "span", "event", "counter", "gauge", "trace_to", "wire_ctx",
-    "program_note", "program_notes", "last_counters",
+    "program_note", "program_notes", "last_counters", "loop",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "FLASH_KERNEL_NAME",
+    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "LOOP_SCOPES", "LOOP_CARRY_NOTE",
+    "FLASH_KERNEL_NAME",
     "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
     "KDA_BWD_KERNEL_NAME", "COMPILE_SPANS",
 ]
@@ -116,6 +118,18 @@ MLA_SCOPES = (SCOPE_MLA, SCOPE_MOE_SHARED, SCOPE_MTP)
 SCOPE_KDA = "attn/kda"
 SCOPE_KDA_SCAN = "attn/kda/scan"
 KDA_SCOPES = (SCOPE_KDA, SCOPE_KDA_SCAN)
+# The loops of a round's path (sim/engine.py, core/trainer.py), opened by
+# :func:`loop` around the call that makes the loop and nothing wider. Never
+# under ``fed/``: the ops inside keep their phase (a reader classes an op by
+# the outermost ``fed/*`` of its op_name), and what a phase does not cover,
+# the copies and slices that carry the loop's state, is named by its loop.
+# A tuple of their own, as the others are: SCOPES is held to the benchmark's
+SCOPE_LOOP_ROUNDS = "loop/rounds"
+SCOPE_LOOP_COHORT = "loop/cohort"
+SCOPE_LOOP_EPOCHS = "loop/epochs"
+SCOPE_LOOP_STEPS = "loop/steps"
+LOOP_SCOPES = (SCOPE_LOOP_ROUNDS, SCOPE_LOOP_COHORT, SCOPE_LOOP_EPOCHS, SCOPE_LOOP_STEPS)
+LOOP_CARRY_NOTE = "loop/carry"  # the program note each loop leaves where it is made
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
 # holds "flash_fwd", which the benchmark's forward readers match on
@@ -303,9 +317,9 @@ class Tracer:
     def add_span(self, name: str, t_start: float, t_end: float,
                  **attrs: Any) -> None:
         """Record an already-timed span (``time.perf_counter`` endpoints) —
-        the manual-timing API for callers like RoundTimer that measured the
-        interval themselves. Parented under the calling thread's innermost
-        open span, like a context-manager span would be."""
+        the manual-timing API for callers that measured the interval
+        themselves (the jax build listener). Parented under the calling
+        thread's innermost open span, like a context-manager span would be."""
         stack = self._stack()
         rec = {
             "name": name, "ph": "X", "ts": self._us(t_start),
@@ -611,6 +625,23 @@ def program_note(name: str, **attrs: Any) -> None:
 def program_notes(name: str) -> list[dict]:
     """The distinct facts noted under ``name``, in the order first seen."""
     return list(_program_notes.get(name, {}).values())
+
+
+def loop(name: str, carry: Any, side_by_side: int = 1):
+    """The ``jax.named_scope`` of one of :data:`LOOP_SCOPES`, for the call
+    that makes the loop, and the loop's :data:`LOOP_CARRY_NOTE`: ``bytes``
+    and ``leaves`` of what one trip carries for one client, from the shapes
+    and dtypes of ``carry``'s leaves (under ``vmap`` a tracer's shape is one
+    client's), and ``side_by_side``, the clients a trip carries at once.
+    Only the engine knows that width, so the trainer's loops say 1 and the
+    ``loop/cohort`` note has it. Runs while jax traces, once a shape."""
+    import jax
+
+    leaves = jax.tree.leaves(carry)
+    program_note(LOOP_CARRY_NOTE, loop=name, leaves=len(leaves),
+                 bytes=sum(math.prod(x.shape) * x.dtype.itemsize for x in leaves),
+                 side_by_side=int(side_by_side))
+    return jax.named_scope(name)
 
 
 def wire_ctx(origin: int | None = None) -> dict | None:

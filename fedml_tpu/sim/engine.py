@@ -880,29 +880,45 @@ class FedSim:
         # per-client mode: each client starts from its own model (stacked
         # leading axis); broadcast mode: everyone starts from the global
         var_axis = 0 if self._per_client else None
-        if self.config.cohort_execution == "scan":
-            # sequential clients: one client's optimizer state + activations
-            # live at a time (outputs still stack incrementally to [C, ...])
-            if self._per_client:
-                local_vars, train_metrics = jax.lax.map(
-                    lambda args: self._local_train(*args),
-                    (global_variables, batches, keys, num_steps),
-                )
+        with self._cohort_loop(global_variables, c_local):
+            if self.config.cohort_execution == "scan":
+                # sequential clients: one client's optimizer state + activations
+                # live at a time (outputs still stack incrementally to [C, ...])
+                if self._per_client:
+                    local_vars, train_metrics = jax.lax.map(
+                        lambda args: self._local_train(*args),
+                        (global_variables, batches, keys, num_steps),
+                    )
+                else:
+                    local_vars, train_metrics = jax.lax.map(
+                        lambda args: self._local_train(global_variables, *args),
+                        (batches, keys, num_steps),
+                    )
             else:
-                local_vars, train_metrics = jax.lax.map(
-                    lambda args: self._local_train(global_variables, *args),
-                    (batches, keys, num_steps),
-                )
-        else:
-            local_vars, train_metrics = jax.vmap(
-                self._local_train, in_axes=(var_axis, 0, 0, 0)
-            )(global_variables, batches, keys, num_steps)
+                local_vars, train_metrics = jax.vmap(
+                    self._local_train, in_axes=(var_axis, 0, 0, 0)
+                )(global_variables, batches, keys, num_steps)
         return self._aggregate_tail(
             global_variables, server_state, local_vars, weights, num_steps,
             train_metrics["train_loss"], rng,
             model_stats={k: v for k, v in train_metrics.items()
                          if k.startswith(STATS_PREFIX)},
         )
+
+    def _cohort_loop(self, global_variables, clients_a_device: int):
+        """The name of the cohort's execution, around the ``lax.map`` or the
+        ``vmap`` that makes it (under ``vmap`` there is no loop, but the
+        broadcast of the global variables to a client axis and the stacking
+        of the clients' results are the same work under another lowering),
+        and its note: what a client starts from, and how many clients a
+        device trains side by side."""
+        one_client = global_variables
+        if self._per_client:  # a stacked leading axis: one client's slice
+            one_client = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), global_variables)
+        vmapped = self.config.cohort_execution != "scan"
+        return trace.loop(trace.SCOPE_LOOP_COHORT, one_client,
+                          side_by_side=clients_a_device if vmapped else 1)
 
     @jax.named_scope(trace.SCOPE_AGGREGATE)
     def _aggregate_tail(self, global_variables, server_state, local_vars,
@@ -1043,16 +1059,17 @@ class FedSim:
         global_variables = self._compute_view(global_variables)
         C = num_steps.shape[0]
         keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(C))
-        if self.config.cohort_execution == "scan":
-            local_vars, train_metrics = jax.lax.map(
-                lambda args: self._local_train(global_variables, *args),
-                (batches, keys, num_steps),
-            )
-        else:
-            local_vars, train_metrics = jax.vmap(
-                self._local_train, in_axes=(None, 0, 0, 0),
-                spmd_axis_name=meshlib.CLIENT_AXIS,
-            )(global_variables, batches, keys, num_steps)
+        with self._cohort_loop(global_variables, C // self._n_client_shards):
+            if self.config.cohort_execution == "scan":
+                local_vars, train_metrics = jax.lax.map(
+                    lambda args: self._local_train(global_variables, *args),
+                    (batches, keys, num_steps),
+                )
+            else:
+                local_vars, train_metrics = jax.vmap(
+                    self._local_train, in_axes=(None, 0, 0, 0),
+                    spmd_axis_name=meshlib.CLIENT_AXIS,
+                )(global_variables, batches, keys, num_steps)
         return local_vars, train_metrics["train_loss"]
 
     def _spmd_gather_train_impl(self, global_variables, dataset, idx,
@@ -1279,10 +1296,11 @@ class FedSim:
             v, s, m = self._gather_round_impl(v, s, dataset, idx, w, ns, key)
             return (v, s), m
 
-        (v, s), ms = jax.lax.scan(
-            step, (global_variables, server_state),
-            (idxs, weights, num_steps, rngs),
-        )
+        carry = (global_variables, server_state)
+        with trace.loop(trace.SCOPE_LOOP_ROUNDS, carry):
+            (v, s), ms = jax.lax.scan(
+                step, carry, (idxs, weights, num_steps, rngs)
+            )
         return v, s, ms
 
     def _get_block_fn(self, n_rounds: int):

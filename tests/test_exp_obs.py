@@ -9,7 +9,7 @@ import pytest
 
 from fedml_tpu.exp.main_fedavg import add_args, run
 from fedml_tpu.obs.checkpoint import RoundCheckpointer
-from fedml_tpu.obs.metrics import MetricsLogger, RoundTimer
+from fedml_tpu.obs.metrics import MetricsLogger
 from fedml_tpu.obs.sysstats import SysStats
 
 import argparse
@@ -74,13 +74,6 @@ def test_resume_continues_training(tmp_path):
     assert h2[-1]["round"] == 4
     # resumed history contains the pre-resume rounds
     assert [r["round"] for r in h2][:3] == [0, 1, 2]
-
-
-def test_round_timer():
-    t = RoundTimer()
-    t.tick("comm")
-    t.tock("comm")
-    assert "comm" in t.summary()
 
 
 def test_sysstats_sample():

@@ -1,9 +1,15 @@
 """The device side of tracing: every phase scope of ``obs/trace.py``'s
-``SCOPES`` is in the lowered text of the program that should carry it, and
-the host spans mirror into jax's profiler, each thread on its own line."""
+``SCOPES`` and every loop's name of its ``LOOP_SCOPES`` is in the lowered text
+of the program that should carry it, the loops' names change no reader's
+answer for an op a scope already claims, each loop leaves the note of its
+carry, and the host spans mirror into jax's profiler, each thread on its own
+line."""
 
 import glob
+import json
+import os
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +117,145 @@ def test_scopes_are_in_the_lowered_programs(program, make, scopes):
 def test_every_scope_is_held_to_some_program():
     assert len(set(trace.SCOPES)) == len(trace.SCOPES)
     assert {s for _, _, scopes in PROGRAMS for s in scopes} == set(trace.SCOPES)
+
+
+# -- the loops of a round's path -----------------------------------------------
+
+ALL_LOOPS = trace.LOOP_SCOPES
+IN_A_ROUND = trace.LOOP_SCOPES[1:]  # a gather round is one trip of no rounds' loop
+LOOP_PROGRAMS = {
+    "gather_round/vmap": ("gather_round", _blobs_sim, IN_A_ROUND),
+    "block/vmap": ("block", _blobs_sim, ALL_LOOPS),
+    "gather_round/scan_lm": ("gather_round", _lm_sim, IN_A_ROUND),
+    "block/scan": ("block", lambda: _blobs_sim(cohort_execution="scan"), ALL_LOOPS),
+}
+
+
+@pytest.fixture(scope="module")
+def loop_texts():
+    """{(program, rolled): lowered text}, made once: straight-lined as
+    ``core/scan.py`` lowers the trainer's loops on the CPU, and rolled as on
+    the chip (the module is handed a jax that names another backend)."""
+    from fedml_tpu.core import scan as scanlib
+
+    texts = {}
+    for key, (program, make, _) in LOOP_PROGRAMS.items():
+        texts[key, False] = _lowered(make(), program)
+    chip = types.SimpleNamespace(default_backend=lambda: "tpu", lax=jax.lax, tree=jax.tree)
+    real, scanlib.jax = scanlib.jax, chip
+    try:
+        for key in ("block/vmap", "gather_round/scan_lm"):
+            program, make, _ = LOOP_PROGRAMS[key]
+            texts[key, True] = _lowered(make(), program)
+    finally:
+        scanlib.jax = real
+    return texts
+
+
+@pytest.mark.parametrize("key,loop", [
+    (key, loop) for key, (_, _, loops) in LOOP_PROGRAMS.items() for loop in loops])
+def test_loop_names_are_in_the_lowered_programs(loop_texts, key, loop):
+    text = loop_texts[key, False]
+    assert re.search(rf'["/(]{loop}[/)]', text), loop  # "vmap(loop/epochs)/" under a vmap
+    # never under a phase: a reader gives an op to the outermost fed/*. (A
+    # backward op repeats its forward's names inside "transpose(...)", the
+    # loops' with them: "fed/fwd_bwd/transpose(loop/epochs)/loop/steps/fed/...")
+    for name in re.findall(r'loc\("([^"]*loop/[^"]*)"', text):
+        assert not re.search(r"fed/[a-z_]+.*loop/", name.split("transpose(")[0]), name
+    if loop == trace.SCOPE_LOOP_STEPS:  # straight-lined, the steps are ops of the loop's name
+        assert re.search(rf"{loop}\)?/(vmap\()?{trace.SCOPE_FWD_BWD}", text)
+
+
+@pytest.mark.parametrize("key", ["block/vmap", "gather_round/scan_lm"])
+def test_rolled_loops_are_whiles_under_their_names(loop_texts, key):
+    text = loop_texts[key, True]
+    for loop in LOOP_PROGRAMS[key][2]:
+        if loop == trace.SCOPE_LOOP_COHORT and "vmap" in key:
+            assert re.search(rf"{loop}/vmap\(", text)  # no loop there: the vmap bears the name
+        else:
+            assert re.search(rf"{loop}\)?/while/body/", text), loop
+    # the body is a function of its own, whose names start anew in this text
+    assert re.search(rf'"(vmap\()?{trace.SCOPE_FWD_BWD}', text)
+
+
+def _fixture_op_names():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "fixtures", "scope_fixture.json")) as f:
+        rows = json.load(f)["scope_rows"]
+    return sorted({r[1] for per_program in rows.values() for r in per_program})
+
+
+LOOPS_AROUND = ("loop/rounds/while/body/closed_call/loop/cohort/while/body/closed_call/"
+                "loop/epochs/while/body/loop/steps/while/body/closed_call/")
+LOOPS_AROUND_A_VMAP = ("loop/rounds/while/body/closed_call/loop/cohort/vmap(loop/epochs)/while/"
+                       "body/closed_call/vmap(loop/steps)/while/body/closed_call/")
+
+
+def _inside_the_loops(op_name: str) -> str:
+    """``op_name`` as the chip writes it once the loops are named: the loops'
+    path before the op's first scope, after the ``jit(...)`` elements."""
+    head = re.match(r"(?:jit\([^)]*\)/)*", op_name).end()
+    around = LOOPS_AROUND_A_VMAP if op_name[head:].startswith("vmap(") else LOOPS_AROUND
+    return op_name[:head] + around + op_name[head:]
+
+
+@pytest.mark.parametrize("op_name", _fixture_op_names() + [
+    "jit(f)/fed/fwd_bwd/jvp(M)/blocks_1/moe/dispatch/gather",
+    "jit(f)/fed/fwd_bwd/transpose(jvp(M))/blocks_1/moe/experts/gmm/pallas_call",
+    "jit(f)/fed/fwd_bwd/jvp(M)/blocks_0/attn/mla/attn/flash_fwd/flash_fwd/pallas_call",
+    "jit(f)/fed/fwd_bwd/jvp(mtp)/fed/loss/reduce_max",
+    "jit(f)/fed/fwd_bwd/transpose(jvp(M))/blocks_2/attn/kda/attn/kda/scan/kda_bwd/pallas_call",
+])
+def test_the_loops_names_change_no_readers_answer(op_name):
+    from benchmark import loop_reduce, mla_reduce, moe_reduce, scope_reduce
+
+    named = _inside_the_loops(op_name)
+    assert named != op_name and len(loop_reduce.LOOP.findall(named)) == 4
+    assert scope_reduce.classify(named) == scope_reduce.classify(op_name)
+    assert scope_reduce.sub_shares(named) == scope_reduce.sub_shares(op_name)
+    patterns = [moe_reduce.MOE_SCOPE % "route|dispatch|experts|combine",
+                moe_reduce.MOE_SCOPE % "dispatch|combine"]
+    patterns += [mla_reduce.SCOPE % re.escape(scope) for scope in
+                 trace.MLA_SCOPES + trace.KDA_SCOPES]
+    for pattern in patterns:
+        assert bool(re.search(pattern, named)) == bool(re.search(pattern, op_name)), pattern
+    # and the loop's own reader leaves what a phase claims alone
+    assert (loop_reduce.loop_of(named) is None) == (scope_reduce.classify(op_name) != "unattributed")
+
+
+def _tree_bytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("execution,width", [("vmap", 4), ("scan", 1)])
+def test_each_loop_leaves_the_note_of_its_carry_once_a_shape(monkeypatch, execution, width):
+    """One chip holds the cohort of four: side by side under ``vmap``, in turn
+    under ``lax.map``; the bytes are one client's either way."""
+    from fedml_tpu.parallel import mesh as meshlib
+
+    monkeypatch.setattr(trace, "_program_notes", {})
+    train, test = gaussian_blobs(n_clients=8, samples_per_client=16, num_classes=3, seed=1)
+    optimizer = optax.sgd(0.1, momentum=0.9)
+    trainer = ClientTrainer(module=LogisticRegression(num_classes=3), optimizer=optimizer,
+                            epochs=2)
+    cfg = SimConfig(client_num_in_total=8, client_num_per_round=4, batch_size=8, comm_round=6,
+                    frequency_of_the_test=3, seed=0, epochs=2, cohort_execution=execution)
+    sim = FedSim(trainer, train, test, cfg, mesh=meshlib.client_mesh(jax.devices()[:1]))
+    _lowered(sim, "block")
+    variables = sim.init_round_variables()
+    model = _tree_bytes(variables)
+    state = _tree_bytes(sim.aggregator.init_state(variables))
+    step = model + _tree_bytes(optimizer.init(variables["params"])) + 8  # and the key
+    leaves = len(jax.tree.leaves(variables))
+    notes = trace.program_notes(trace.LOOP_CARRY_NOTE)
+    assert {n["loop"]: (n["bytes"], n["side_by_side"]) for n in notes} == {
+        trace.SCOPE_LOOP_ROUNDS: (model + state, 1),
+        trace.SCOPE_LOOP_COHORT: (model, width),
+        trace.SCOPE_LOOP_EPOCHS: (step, 1),  # the trainer cannot see a vmap around it
+        trace.SCOPE_LOOP_STEPS: (step, 1)}
+    assert len(notes) == 4 and all(n["leaves"] >= leaves for n in notes)
+    _lowered(sim, "gather_round")  # the same shapes again: no second note
+    assert trace.program_notes(trace.LOOP_CARRY_NOTE) == notes
 
 
 def test_flash_kernel_is_named_in_the_tpu_lowering(monkeypatch):

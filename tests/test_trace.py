@@ -1,5 +1,5 @@
 """Span tracer, instrumented-layer emission, and the obs satellite fixes
-(RoundTimer / MetricsLogger / CommBytesAccountant / SysStats)."""
+(MetricsLogger / CommBytesAccountant / SysStats)."""
 
 import json
 import threading
@@ -14,7 +14,6 @@ from fedml_tpu.obs.metrics import (
     COMM_RATIO,
     CommBytesAccountant,
     MetricsLogger,
-    RoundTimer,
 )
 from fedml_tpu.obs.trace import Tracer
 
@@ -480,43 +479,6 @@ def test_trace_smoke_tool_runs():
 
 
 # -- satellite fixes ---------------------------------------------------------
-
-
-def test_round_timer_unmatched_tock_raises_clearly():
-    t = RoundTimer()
-    t.tick("comm")
-    t.tick("agg")
-    with pytest.raises(ValueError, match=r"tock\('nope'\).*'agg'.*'comm'"):
-        t.tock("nope")
-    assert t.tock("comm") >= 0.0  # open tags survive the failed tock
-    with pytest.raises(ValueError, match="none"):
-        RoundTimer().tock("x")
-
-
-def test_round_timer_delegates_spans_to_tracer():
-    tracer = Tracer()
-    t = RoundTimer(tracer=tracer)
-    t.tick("round")
-    time.sleep(0.002)
-    dt = t.tock("round")
-    spans = tracer.events()
-    assert [e["name"] for e in spans] == ["round"]
-    assert spans[0]["dur"] == pytest.approx(dt * 1e6, rel=0.05)
-
-    # default: the process tracer picked up at tock time
-    proc = trace.install()
-    try:
-        t2 = RoundTimer()
-        t2.tick("x")
-        t2.tock("x")
-    finally:
-        trace.uninstall()
-    assert [e["name"] for e in proc.events()] == ["x"]
-    # and without any tracer, tick/tock still works (summary only)
-    t3 = RoundTimer()
-    t3.tick("y")
-    t3.tock("y")
-    assert "y" in t3.summary()
 
 
 def test_metrics_logger_context_manager_and_close_semantics(tmp_path):

@@ -104,8 +104,8 @@ def _self_times(spans: list[dict]) -> dict[int, float]:
             while stack and stack[-1][0] <= s["ts"] + 1e-9:
                 pop(stack.pop())
             # count s toward the enclosing span's children only when fully
-            # contained: manually-timed spans (Tracer.add_span, e.g.
-            # RoundTimer tags) can overlap without nesting, and subtracting
+            # contained: manually-timed spans (Tracer.add_span, the build
+            # listener's) can overlap without nesting, and subtracting
             # a merely-overlapping span would corrupt the parent's self time
             if stack and stack[-1][0] >= s["ts"] + dur - 1e-9:
                 stack[-1][2].append(dur)
