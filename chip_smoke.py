@@ -254,6 +254,8 @@ def round_program_text(sim, staged, variables, server_state) -> str:
         prog = sim._gather_round_fn
         args = (variables, server_state, sim._dataset, data, weights,
                 num_steps, rkey)
+        if sim._mean_in_carry:  # and a model's worth of buffers to sum into
+            args += (variables,)
     # as dispatch.Lowered.__call__ does: only global-view programs trace
     # under the mesh context
     with prog.mesh if prog.mode == "pjit" else contextlib.nullcontext():

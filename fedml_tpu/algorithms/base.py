@@ -5,7 +5,10 @@ holding mutable server state and an ``aggregate()`` method looping over
 client state_dicts key by key (e.g. fedml_api/distributed/fedavg/
 FedAVGAggregator.py:59-88). Here an aggregator is a pair of pure functions
 over *stacked* client pytrees (leading client axis) — aggregation is one
-weighted reduction XLA lowers to a psum over the mesh's client axis.
+weighted reduction XLA lowers to a psum over the mesh's client axis. A rule
+that needs nothing of the clients' models but their sample-weighted mean
+says so (``Aggregator.aggregate_mean``, built by :func:`mean_aggregator`),
+and an engine that runs its clients in turn then never builds the stack.
 """
 
 from __future__ import annotations
@@ -58,6 +61,20 @@ class Aggregator:
     per-client stack (e.g. a mixing-matrix multiply). The reference analogue
     is each DecentralizedWorker holding its own model across rounds
     (decentralized_framework/decentralized_worker.py:4).
+
+    ``aggregate_mean(global, mean, weights, state, rng, extras=None)`` is
+    ``aggregate`` with the clients' sample-weighted mean
+    (``tree_weighted_mean(stacked_locals, weights)``, one model) in the
+    stack's place. A rule gives it iff its result depends on the clients'
+    models through that mean alone (FedAvg, FedOpt; build such a rule with
+    :func:`mean_aggregator`, so it has one definition). It is the rule's
+    whole declaration: the sim engine, where the cohort runs in sequence
+    (``SimConfig.cohort_execution="scan"``), then folds each client's
+    result into a running float32 sum as it finishes and calls this, and
+    the [C, ...] stack is never built. Every rule that looks at clients
+    one by one (robust statistics, FedNova's per-client normalisers,
+    compression residuals, per-client models) leaves it None and is handed
+    the stack as ever.
     """
 
     init_state: Callable[[Pytree], Any]
@@ -74,6 +91,21 @@ class Aggregator:
     # only consume the trained stack leave this off and receive the local
     # shard's slice instead)
     needs_prev_stack: bool = False
+    # the rule over the clients' weighted mean, where that is all it needs
+    # of their models (class docstring); None: the rule needs the stack
+    aggregate_mean: Callable[..., tuple[Pytree, Any, dict]] | None = None
+
+
+def mean_aggregator(init_state, aggregate_mean, name: str) -> Aggregator:
+    """The rule ``aggregate_mean`` (``Aggregator`` docstring) as an
+    aggregator: its ``aggregate`` takes the stack's weighted mean and hands
+    it on, so the rule is written once."""
+
+    def aggregate(global_variables, stacked, weights, state, rng, extras=None):
+        mean = treelib.tree_weighted_mean(stacked, weights)
+        return aggregate_mean(global_variables, mean, weights, state, rng, extras)
+
+    return Aggregator(init_state, aggregate, name=name, aggregate_mean=aggregate_mean)
 
 
 def fedavg_aggregator() -> Aggregator:
@@ -82,8 +114,7 @@ def fedavg_aggregator() -> Aggregator:
     def init_state(global_variables):
         return ()
 
-    def aggregate(global_variables, stacked, weights, state, rng, extras=None):
-        new_global = treelib.tree_weighted_mean(stacked, weights)
-        return new_global, state, {}
+    def aggregate_mean(global_variables, mean, weights, state, rng, extras=None):
+        return mean, state, {}
 
-    return Aggregator(init_state, aggregate, name="fedavg")
+    return mean_aggregator(init_state, aggregate_mean, name="fedavg")

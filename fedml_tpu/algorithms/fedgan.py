@@ -140,5 +140,4 @@ def make_gan_local_train(trainer: GANTrainer):
 def fedgan_aggregator() -> Aggregator:
     """The nested two-network weighted average (FedGANAggregator.aggregate:
     58-88) — identical math to fedavg over the pair pytree."""
-    inner = fedavg_aggregator()
-    return Aggregator(inner.init_state, inner.aggregate, name="fedgan")
+    return dataclasses.replace(fedavg_aggregator(), name="fedgan")

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from fedml_tpu.algorithms.base import Aggregator
+from fedml_tpu.algorithms.base import Aggregator, mean_aggregator
 from fedml_tpu.core import tree as treelib
 
 
@@ -46,8 +46,7 @@ def fedopt_aggregator(opt: optax.GradientTransformation) -> Aggregator:
     def init_state(global_variables):
         return opt.init(global_variables["params"])
 
-    def aggregate(global_variables, stacked, weights, opt_state, rng, extras=None):
-        avg = treelib.tree_weighted_mean(stacked, weights)
+    def aggregate_mean(global_variables, avg, weights, opt_state, rng, extras=None):
         # pseudo-gradient: old - avg (FedOptAggregator.set_model_global_grads:109-120)
         pseudo_grad = treelib.tree_sub(global_variables["params"], avg["params"])
         updates, opt_state = opt.update(pseudo_grad, opt_state, global_variables["params"])
@@ -55,4 +54,4 @@ def fedopt_aggregator(opt: optax.GradientTransformation) -> Aggregator:
         new_global = {**avg, "params": new_params}
         return new_global, opt_state, {}
 
-    return Aggregator(init_state, aggregate, name="fedopt")
+    return mean_aggregator(init_state, aggregate_mean, name="fedopt")

@@ -28,8 +28,7 @@ def fedprox_trainer(trainer: ClientTrainer, mu: float) -> ClientTrainer:
 
 def fedprox_aggregator() -> Aggregator:
     """Server side is plain weighted averaging (FedProx paper)."""
-    inner = fedavg_aggregator()
-    return Aggregator(inner.init_state, inner.aggregate, name="fedprox")
+    return dataclasses.replace(fedavg_aggregator(), name="fedprox")
 
 
 def straggler_epochs(

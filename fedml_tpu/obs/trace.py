@@ -70,6 +70,7 @@ __all__ = [
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
     "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "LOOP_SCOPES", "LOOP_CARRY_NOTE",
+    "COHORT_AGGREGATE_NOTE",
     "FLASH_KERNEL_NAME",
     "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
     "KDA_BWD_KERNEL_NAME", "COMPILE_SPANS",
@@ -130,6 +131,11 @@ SCOPE_LOOP_EPOCHS = "loop/epochs"
 SCOPE_LOOP_STEPS = "loop/steps"
 LOOP_SCOPES = (SCOPE_LOOP_ROUNDS, SCOPE_LOOP_COHORT, SCOPE_LOOP_EPOCHS, SCOPE_LOOP_STEPS)
 LOOP_CARRY_NOTE = "loop/carry"  # the program note each loop leaves where it is made
+# ... and the cohort's second note (sim/engine.py ``_cohort_loop``): ``form``
+# "carry" where the clients' weighted mean is summed in the loop's carry,
+# "stack" where their models are stacked for the rule; ``clients`` a device
+# and the ``bytes`` of that stack, built or not
+COHORT_AGGREGATE_NOTE = "cohort/aggregate"
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
 # ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
 # holds "flash_fwd", which the benchmark's forward readers match on

@@ -103,10 +103,15 @@ class SimConfig:
     # "vmap" (default) trains every local client simultaneously — small
     # models fill the MXU only across clients, but peak HBM scales with
     # C_local (each live client holds params + optimizer state +
-    # activations); "scan" trains them sequentially (lax.map), holding ONE
-    # client's transient state at a time — the big-model mode: a client's
-    # transformer state is GBs and its matmuls fill the MXU without
-    # cross-client batching (the LM cells run it, PERF.md section 4).
+    # activations); "scan" trains them sequentially (a loop over the
+    # device's clients), holding ONE client's transient state at a time —
+    # the big-model mode: a client's transformer state is GBs and its
+    # matmuls fill the MXU without cross-client batching (the LM cells run
+    # it, PERF.md section 4). Where the aggregation rule needs the clients'
+    # weighted mean alone (Aggregator.aggregate_mean: FedAvg, FedOpt) that
+    # loop folds each client's result into a running float32 sum and no
+    # [C, ...] stack of models is built; any other rule gets the stack, a
+    # client at a time (lax.map).
     cohort_execution: str = "vmap"
     # Packed-lane execution (docs/PERFORMANCE.md "Packed-lane cohort
     # execution"): 0 (default) = the padded [C, S_max] layout above; N > 0 =
@@ -611,10 +616,34 @@ class FedSim:
         # per-client mode: the model state is itself a stacked [C, ...] pytree
         # sharded over the clients axis, in and out of the round program
         var_spec = cohort_spec if self._per_client else P()
+        # clients in sequence under a rule that is a function of their
+        # weighted mean: the round program (not the sharded plan's two, nor
+        # the packed lanes') sums the mean in the cohort loop's carry and
+        # never builds the stack of their models (_cohort_mean)
+        self._mean_in_carry = (
+            config.cohort_execution == "scan" and not self._per_client
+            and not self._spmd
+            and getattr(self.aggregator, "aggregate_mean", None) is not None
+        )
         # shard_map round programs donate the model argument on every
         # backend; the pjit programs below donate only where the backend
         # implements it (XLA:CPU does not)
         self._donate = (0,)
+        # ... but a round that sums the mean in its cohort loop's carry
+        # cannot write the sum over its model: clients start from the model
+        # until the last one has. Donated, XLA would copy the finished sum
+        # back into it (a model read and written a round, under no scope);
+        # not donated, the runtime would hold a third model, the output of
+        # the round it enqueues while this one runs. So such a round takes
+        # one argument more, ``spare``: a model's worth of dead buffers (the
+        # model of the round before, kept by _call_round) that it donates
+        # and sums into, and two models pass each other from round to round.
+        self._spare = None
+        spare_spec = (var_spec,) if self._mean_in_carry else ()
+
+        def round_donate(n_args):  # the spare comes last
+            return (n_args,) if self._mean_in_carry else self._donate
+
         if self._spmd:
             # Two-program sharded round: a pjit TRAIN program emits the
             # cohort's update stack at a program boundary, then a pjit
@@ -664,9 +693,9 @@ class FedSim:
             self._round_fn = displib.lower(
                 self._round_impl, mesh=self.mesh,
                 in_specs=(var_spec, P(), cohort_spec, cohort_spec,
-                          cohort_spec, P()),
+                          cohort_spec, P()) + spare_spec,
                 out_specs=(var_spec, P(), P()),
-                donate_argnums=self._donate,
+                donate_argnums=round_donate(6),
             )
         self._eval_fn = jit_(self._eval_impl) if self._can_eval else None
 
@@ -701,9 +730,9 @@ class FedSim:
                 self._gather_round_fn = displib.lower(
                     self._gather_round_impl, mesh=self.mesh,
                     in_specs=(var_spec, P(), P(), cohort_spec, cohort_spec,
-                              cohort_spec, P()),
+                              cohort_spec, P()) + spare_spec,
                     out_specs=(var_spec, P(), P()),
-                    donate_argnums=self._donate,
+                    donate_argnums=round_donate(7),
                 )
 
         if self._pack:
@@ -866,7 +895,7 @@ class FedSim:
     # -- jitted programs -----------------------------------------------------
 
     def _round_impl(self, global_variables, server_state, batches, weights,
-                    num_steps, rng):
+                    num_steps, rng, spare=None):
         # Runs per client-shard: ``batches``/``weights``/``num_steps`` carry
         # this device's local cohort slice [C_local, ...]. Per-client rng keys
         # are derived from the *global* client slot so results are
@@ -880,52 +909,110 @@ class FedSim:
         # per-client mode: each client starts from its own model (stacked
         # leading axis); broadcast mode: everyone starts from the global
         var_axis = 0 if self._per_client else None
-        with self._cohort_loop(global_variables, c_local):
-            if self.config.cohort_execution == "scan":
-                # sequential clients: one client's optimizer state + activations
-                # live at a time (outputs still stack incrementally to [C, ...])
-                if self._per_client:
-                    local_vars, train_metrics = jax.lax.map(
-                        lambda args: self._local_train(*args),
-                        (global_variables, batches, keys, num_steps),
-                    )
+        local_vars = mean_sum = None
+        if self._mean_in_carry:
+            mean_sum, train_metrics = self._cohort_mean(
+                global_variables, batches, weights, num_steps, keys, spare)
+        else:
+            with self._cohort_loop(global_variables, c_local):
+                if self.config.cohort_execution == "scan":
+                    # sequential clients: one client's optimizer state +
+                    # activations live at a time; the rule needs every
+                    # client's model, so the results stack to [C, ...]
+                    if self._per_client:
+                        local_vars, train_metrics = jax.lax.map(
+                            lambda args: self._local_train(*args),
+                            (global_variables, batches, keys, num_steps),
+                        )
+                    else:
+                        local_vars, train_metrics = jax.lax.map(
+                            lambda args: self._local_train(global_variables, *args),
+                            (batches, keys, num_steps),
+                        )
                 else:
-                    local_vars, train_metrics = jax.lax.map(
-                        lambda args: self._local_train(global_variables, *args),
-                        (batches, keys, num_steps),
-                    )
-            else:
-                local_vars, train_metrics = jax.vmap(
-                    self._local_train, in_axes=(var_axis, 0, 0, 0)
-                )(global_variables, batches, keys, num_steps)
+                    local_vars, train_metrics = jax.vmap(
+                        self._local_train, in_axes=(var_axis, 0, 0, 0)
+                    )(global_variables, batches, keys, num_steps)
         return self._aggregate_tail(
             global_variables, server_state, local_vars, weights, num_steps,
             train_metrics["train_loss"], rng,
             model_stats={k: v for k, v in train_metrics.items()
                          if k.startswith(STATS_PREFIX)},
+            mean_sum=mean_sum,
         )
 
-    def _cohort_loop(self, global_variables, clients_a_device: int):
-        """The name of the cohort's execution, around the ``lax.map`` or the
-        ``vmap`` that makes it (under ``vmap`` there is no loop, but the
-        broadcast of the global variables to a client axis and the stacking
-        of the clients' results are the same work under another lowering),
-        and its note: what a client starts from, and how many clients a
-        device trains side by side."""
+    def _cohort_mean(self, global_variables, batches, weights, num_steps, keys,
+                     spare=None):
+        """The device's clients in sequence, each one's trained model folded
+        into a running sum as it finishes: ``tree_weighted_mean``'s
+        arithmetic (weights normalised in float32 over the whole cohort, a
+        float32 sum, one cast at the end, in ``_aggregate_tail``) with the
+        sum in the loop's carry, so there is no ``[C, ...]`` stack to move a
+        result into and none to pass over afterwards. The sum starts in
+        ``spare``'s buffers (a dead model's, donated: ``__init__``), whatever
+        they hold: the first trip adds to zero and not to what it finds. A
+        block of rounds has no spare and starts from zeros. Returns this
+        shard's partial sum (float32 leaves) and the clients' ``[C_local]``
+        metrics."""
+        from fedml_tpu.parallel.mesh import CLIENT_AXIS
+
+        c_local = weights.shape[0]
+        w = weights.astype(jnp.float32)
+        w = w / jnp.maximum(jax.lax.psum(jnp.sum(w), CLIENT_AXIS), 1e-12)
+
+        def one_client(acc, client):
+            first, w_i, *args = client
+            vars_i, metrics_i = self._local_train(global_variables, *args)
+            with jax.named_scope(trace.SCOPE_AGGREGATE):
+                acc = jax.tree.map(
+                    lambda a, v: jnp.where(first, 0.0, a) + v.astype(jnp.float32) * w_i,
+                    acc, vars_i)
+            return acc, metrics_i
+
+        if spare is None:
+            spare = jax.tree.map(jnp.zeros_like, global_variables)
+        acc = jax.tree.map(lambda x: x.astype(jnp.float32), spare)
+        with self._cohort_loop(global_variables, c_local, acc=acc):
+            return jax.lax.scan(
+                one_client, acc,
+                (jnp.arange(c_local) == 0, w, batches, keys, num_steps))
+
+    def _cohort_loop(self, global_variables, clients_a_device: int, acc=None):
+        """The name of the cohort's execution, around the ``lax.scan``, the
+        ``lax.map`` or the ``vmap`` that makes it (under ``vmap`` there is no
+        loop, but the broadcast of the global variables to a client axis and
+        the stacking of the clients' results are the same work under another
+        lowering), and its two notes. ``loop/carry``: what a trip carries,
+        the running sum ``acc`` where the mean is folded in the loop
+        (``_cohort_mean``), else what a client starts from, and how many
+        clients a device trains side by side. ``cohort/aggregate``: in which
+        ``form`` the clients' models reach the rule, summed in the ``carry``
+        or as a ``stack``, the device's ``clients``, and the ``bytes`` of
+        their stack, built or not."""
         one_client = global_variables
         if self._per_client:  # a stacked leading axis: one client's slice
             one_client = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), global_variables)
+        trace.program_note(
+            trace.COHORT_AGGREGATE_NOTE, form="stack" if acc is None else "carry",
+            clients=clients_a_device,
+            bytes=clients_a_device * sum(
+                x.size * x.dtype.itemsize for x in jax.tree.leaves(one_client)))
         vmapped = self.config.cohort_execution != "scan"
-        return trace.loop(trace.SCOPE_LOOP_COHORT, one_client,
+        return trace.loop(trace.SCOPE_LOOP_COHORT, one_client if acc is None else acc,
                           side_by_side=clients_a_device if vmapped else 1)
 
     @jax.named_scope(trace.SCOPE_AGGREGATE)
     def _aggregate_tail(self, global_variables, server_state, local_vars,
-                        weights, num_steps, train_loss, rng, model_stats=None):
+                        weights, num_steps, train_loss, rng, model_stats=None,
+                        mean_sum=None):
         # The round's server side, shared verbatim by the padded, packed,
         # and sharded execution modes: all_gather the cohort stack, derive
-        # tau, run the aggregation rule, and assemble round metrics. Runs
+        # tau, run the aggregation rule, and assemble round metrics. Where
+        # the cohort loop summed the clients' weighted mean in its carry
+        # (_cohort_mean) there is no stack: ``local_vars`` is None,
+        # ``mean_sum`` is this shard's float32 partial sum, one model of it
+        # crosses the client axis, and the rule is handed the mean. Runs
         # per client-shard inside shard_map — except under a shard plan
         # (self._spmd), where it is its own global-view pjit program whose
         # inputs already arrive as full replicated stacks, so the gather is
@@ -944,7 +1031,7 @@ class FedSim:
             gather = partial(
                 jax.lax.all_gather, axis_name=CLIENT_AXIS, axis=0, tiled=True
             )
-        stacked = jax.tree.map(gather, local_vars)
+        stacked = jax.tree.map(gather, local_vars)  # None where there is none
         all_weights = gather(weights)
         all_losses = gather(train_loss)
         # true per-client SGD steps τ_i = e_i · ceil(n_i / B) — heterogeneous
@@ -956,7 +1043,15 @@ class FedSim:
             jnp.maximum(all_weights, 1.0) / self.config.batch_size
         )
         extras = {"tau": tau, "max_tau": self.trainer.epochs * self._steps}
-        if self._per_client:
+        if mean_sum is not None:
+            if self._n_client_shards > 1:
+                mean_sum = jax.lax.psum(mean_sum, CLIENT_AXIS)
+            mean = jax.tree.map(
+                lambda a, x: a.astype(x.dtype), mean_sum, global_variables)
+            new_global, server_state, agg_metrics = self.aggregator.aggregate_mean(
+                global_variables, mean, all_weights, server_state, rng, extras
+            )
+        elif self._per_client:
             # shard info lets the rule compute only its block of output rows
             extras["shard_start"] = shard_idx * c_local
             extras["shard_size"] = c_local
@@ -1025,12 +1120,13 @@ class FedSim:
         return batches
 
     def _gather_round_impl(self, global_variables, server_state, dataset, idx,
-                           weights, num_steps, rng):
+                           weights, num_steps, rng, spare=None):
         # Build this shard's batch stack on device: ``idx`` [C_local, S, B]
         # indexes dataset rows, -1 marks an empty padding slot.
         batches = self._gather_batches(dataset, idx)
         return self._round_impl(
-            global_variables, server_state, batches, weights, num_steps, rng
+            global_variables, server_state, batches, weights, num_steps, rng,
+            spare,
         )
 
     # -- sharded client models (SimConfig.shard_rules) -----------------------
@@ -1834,14 +1930,32 @@ class FedSim:
                 )
         if self._on_device:
             with trace.span("engine/dispatch", program="gather", n_rounds=1):
-                return self._gather_round_fn(
-                    global_variables, server_state, self._dataset, data,
-                    weights, num_steps, rkey,
+                return self._call_round(
+                    self._gather_round_fn, global_variables, server_state,
+                    self._dataset, data, weights, num_steps, rkey,
                 )
         with trace.span("engine/dispatch", program="padded", n_rounds=1):
-            return self._round_fn(
-                global_variables, server_state, data, weights, num_steps, rkey
+            return self._call_round(
+                self._round_fn, global_variables, server_state, data, weights,
+                num_steps, rkey,
             )
+
+    def _call_round(self, program, global_variables, *args):
+        """``program(global_variables, *args)`` for a round program, which
+        consumes its model argument. The stack's round donates it. The
+        running mean's round (``__init__``: ``spare``) is handed the model
+        of the round before to sum into, and its own model is kept for the
+        round after: the caller's arrays are gone one call later. The first
+        round, or one given the same arrays again, sums into new zeros."""
+        if not self._mean_in_carry:
+            return program(global_variables, *args)
+        spare, self._spare = self._spare, global_variables
+        given = {id(x) for x in jax.tree.leaves(global_variables)}
+        if spare is None or any(
+                id(x) in given or not isinstance(x, jax.Array) or x.is_deleted()
+                for x in jax.tree.leaves(spare)):
+            spare = jax.tree.map(jnp.zeros_like, global_variables)
+        return program(global_variables, *args, spare)
 
     def _run_packed(self, staged: PackedStaged, global_variables, server_state):
         """One packed round: zero buffers, P lane-scan passes chaining the

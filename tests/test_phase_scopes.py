@@ -72,6 +72,8 @@ def _lowered(sim, program: str) -> str:
     if program == "gather_round":
         fn, args = sim._gather_round_fn.fn, (
             variables, state, sim._dataset, *sim.stage_round(0, root))
+        if sim._mean_in_carry:  # and a dead model's buffers to sum into
+            args += (variables,)
     elif program == "block":
         fn, args = sim._get_block_fn(3).fn, (
             variables, state, sim._dataset, *sim._stage_block(0, 3, root))
@@ -178,6 +180,39 @@ def test_rolled_loops_are_whiles_under_their_names(loop_texts, key):
     assert re.search(rf'"(vmap\()?{trace.SCOPE_FWD_BWD}', text)
 
 
+def _ops_in_the_cohorts_body(text):
+    """(op, name) of the ops in the function that is the cohort loop's body
+    (the callee of the call named ``.../loop/cohort/while/body/closed_call``):
+    names start anew there, and the chip writes each after that call's."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    (callee,) = {m.group(1) for m in re.finditer(r"func\.call @(\w+)\(.*loc\((#loc\d+)\)", text)
+                 if locs.get(m.group(2), "").endswith(
+                     f"{trace.SCOPE_LOOP_COHORT}/while/body/closed_call")}
+    body = text.split(f"func.func private @{callee}(")[1].split("func.func")[0]
+    return [(op, locs[ref]) for op, ref in
+            re.findall(r"= stablehlo\.(\w+) .*loc\((#loc\d+)\)", body) if ref in locs]
+
+
+@pytest.mark.parametrize("key,rolled", [
+    ("gather_round/scan_lm", False), ("gather_round/scan_lm", True), ("block/scan", False)])
+def test_the_running_means_multiply_add_is_aggregation_inside_the_cohort_loop(
+        loop_texts, key, rolled):
+    """Where the scan cohort sums the clients' mean in its carry, a leaf's
+    multiply and add are ``fed/aggregate`` ops of the loop's body, one of each
+    a leaf: a phase claims an op before a loop does, so none of them counts
+    for ``loop/cohort``."""
+    sim = LOOP_PROGRAMS[key][1]()
+    assert sim._mean_in_carry
+    leaves = len(jax.tree.leaves(sim.init_round_variables()))
+    ops = _ops_in_the_cohorts_body(loop_texts[key, rolled])
+    for op, name in (("multiply", "mul"), ("add", "add")):
+        assert ops.count((op, f"{trace.SCOPE_AGGREGATE}/{name}")) == leaves, op
+    # a bare name there is the loop's alone, and a loop's name after the
+    # phase's would hand the op to the phase's reader under the wrong loop
+    assert ("multiply", "mul") not in ops
+    assert not any(trace.SCOPE_AGGREGATE in name and "loop/" in name for _, name in ops)
+
+
 def _fixture_op_names():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "fixtures", "scope_fixture.json")) as f:
@@ -230,7 +265,8 @@ def _tree_bytes(tree):
 @pytest.mark.parametrize("execution,width", [("vmap", 4), ("scan", 1)])
 def test_each_loop_leaves_the_note_of_its_carry_once_a_shape(monkeypatch, execution, width):
     """One chip holds the cohort of four: side by side under ``vmap``, in turn
-    under ``lax.map``; the bytes are one client's either way."""
+    under ``scan``, where the trip carries the running float32 sum of their
+    models (FedAvg's mean is summed in the loop): one model's bytes either way."""
     from fedml_tpu.parallel import mesh as meshlib
 
     monkeypatch.setattr(trace, "_program_notes", {})
