@@ -60,6 +60,20 @@ d = 128 key and value columns:
 "xla" token by token (kda_reference). ``A_log`` is one scalar a head and
 ``dt_bias`` one a channel, each a ``[1, n]`` leaf named ``kernel``.
 
+Two more mixers make the LFM2 block (three short-convolution layers to one
+attention layer, no shared expert: ``shared_dim`` 0 builds none; a head tied
+to the embedding: ``tie_head``). "conv", the gated short-convolution operator
+(:class:`ShortConv`; the chain is ``ops/shortconv.py``'s):
+
+    [B | C | z] = h W_in                           W_in: D x 3D, no bias
+    c_t = sum_j w_j * (B * z)_{t - (K - 1) + j}    K = ``conv_size`` taps a channel, causal
+    x1  = x + (C * c) W_out                        W_out: D x D
+
+and "gqa", ``models/moe_transformer.py``'s :class:`GroupedAttention` with
+``num_heads`` query heads on ``kv_heads`` KV heads of ``head_dim`` columns,
+an RMSNorm of q and of k a head (one scale of ``head_dim`` each) and then
+rotate-half rotary positions at ``rope_theta``, every earlier key visible.
+
 Same interface as the rest of the zoo: int tokens ``[B, T]`` in, logits
 ``[B, T, V]`` float32 out, ``train`` kwarg. ``train=False`` builds no MTP
 logits.
@@ -74,12 +88,12 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
-from fedml_tpu.models.moe_transformer import Kernel, RMSNorm, RoutedExperts
+from fedml_tpu.models.moe_transformer import GroupedAttention, Kernel, RMSNorm, RoutedExperts
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import kda, moe, remat
+from fedml_tpu.ops import kda, moe, remat, shortconv
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
 
-MLA, KDA = "mla", "kda"
+MLA, KDA, CONV, GQA = "mla", "kda", "conv", "gqa"
 
 
 def rope_interleaved(x, theta: float):
@@ -194,6 +208,28 @@ class DeltaAttention(nn.Module):
             return dense("o", h.shape[-1], o), jax.lax.stop_gradient(kda.decay_floor(g))
 
 
+class ShortConv(nn.Module):
+    """The gated short-convolution operator (the module docstring's
+    equations): ``taps`` taps a channel, as many channels as the stream."""
+
+    taps: int = 3
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        d = h.shape[-1]
+
+        def dense(name, width, y):
+            return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(y)
+
+        with jax.named_scope(trace.SCOPE_SHORTCONV):
+            # what a rematerialised block keeps: every step of the chain and of
+            # its backward reads a chunk of it, and the chain is cheap to run again
+            bcz = remat.keep(remat.SHORTCONV_IN, dense("in", 3 * d, h))
+            y = shortconv.gated_short_conv(bcz, Kernel((self.taps, d), name="taps")())
+            return dense("out", d, y)
+
+
 class GatedMLP(nn.Module):
     """``(silu(u G) * (u U)) D``: the leading dense layer's feed-forward and
     the shared expert."""
@@ -230,10 +266,12 @@ class MLABlock(nn.Module):
     rms_eps: float = 1e-6
     attn_impl: str = "xla"
     dtype: jnp.dtype = jnp.float32
-    mixer: str = MLA  # MLA | KDA
+    mixer: str = MLA  # MLA | KDA | CONV | GQA
     kda_heads: int = 0
     kda_head_dim: int = 0
-    conv_size: int = 4
+    conv_size: int = 4  # taps of the KDA and CONV mixers' convolutions
+    kv_heads: int = 0  # of the GQA mixer, whose query heads are ``num_heads``
+    head_dim: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -245,6 +283,14 @@ class MLABlock(nn.Module):
                 self.kda_heads, self.kda_head_dim, self.conv_size, self.rms_eps,
                 self.attn_impl, self.dtype, name="attn")(h)
             mixer_stats = {"kda/decay_floor": floor}
+        elif self.mixer == CONV:
+            mixed = ShortConv(self.conv_size, self.dtype, name="conv")(h)
+        elif self.mixer == GQA:
+            with jax.named_scope(trace.SCOPE_GQA):
+                mixed = GroupedAttention(
+                    self.num_heads, self.kv_heads, self.head_dim, rope_theta=self.rope_theta,
+                    attn_impl=self.attn_impl, dtype=self.dtype, qk_norm_eps=self.rms_eps,
+                    name="attn")(h)
         else:
             mixed = LatentAttention(
                 self.num_heads, self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
@@ -260,20 +306,25 @@ class MLABlock(nn.Module):
             u, Kernel((d, self.num_experts), name="router")(), self.experts_per_token,
             select_bias=Kernel((1, self.num_experts), name="select_bias")()[0],
             scale=self.route_scale)
-        with jax.named_scope(trace.SCOPE_MOE_SHARED):
-            shared = GatedMLP(self.shared_dim, self.dtype, name="shared")(u)
+        if self.shared_dim:
+            with jax.named_scope(trace.SCOPE_MOE_SHARED):
+                shared = GatedMLP(self.shared_dim, self.dtype, name="shared")(u)
         m, stats = RoutedExperts(
             d, self.expert_dim, self.experts_first, self.experts_held, self.dtype,
             activation=jax.nn.silu, outputs=self.num_experts, name="experts")(u, ids, weights)
-        return x + (shared.astype(jnp.float32) + m).reshape(b, t, d).astype(x.dtype), {
-            **stats, **mixer_stats}
+        if self.shared_dim:
+            m = shared.astype(jnp.float32) + m
+        return x + m.reshape(b, t, d).astype(x.dtype), {**stats, **mixer_stats}
 
 
 class MLAMoETransformerLM(nn.Module):
     """Causal LM of ``dense_layers`` dense then ``routed_layers`` routed
     :class:`MLABlock` layers, with ``mtp_depth`` (0 or 1) multi-token-
     prediction modules of one routed block each. ``mixers`` gives each
-    layer's mixer in order ("mla" | "kda"; None: latent attention in all)."""
+    layer's mixer in order ("mla" | "kda" | "conv" | "gqa"; None: latent
+    attention in all). ``shared_dim`` 0: no shared expert. ``tie_head``: the
+    logits are the final norm's output times the embedding's transpose (in
+    float32, as the embedding is), and the tree has no ``head``."""
 
     vocab_size: int = 96
     embed_dim: int = 64
@@ -307,20 +358,25 @@ class MLAMoETransformerLM(nn.Module):
     # rematerialize each block in the backward pass under ops/remat.py's
     # policy, as MoETransformerLM.remat
     remat: bool = False
+    kv_heads: int = 2  # of the "gqa" mixer, whose query heads are ``num_heads``
+    head_dim: int = 16
+    tie_head: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         if self.mtp_depth not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         embed = nn.Embed(self.vocab_size, self.embed_dim, name="tok_embed")
-        head = nn.Dense(self.vocab_size, use_bias=False, name="head", dtype=self.head_dtype)
+        head = None if self.tie_head else nn.Dense(
+            self.vocab_size, use_bias=False, name="head", dtype=self.head_dtype)
         held = self.num_experts if self.experts_held is None else self.experts_held
         block_cls = remat.block(MLABlock) if self.remat else MLABlock
 
         layers = self.dense_layers + self.routed_layers
         mixers = (MLA,) * layers if self.mixers is None else tuple(self.mixers)
-        if len(mixers) != layers or set(mixers) - {MLA, KDA}:
-            raise ValueError(f"mixers must name {layers} layers' mixers, each mla or kda")
+        if len(mixers) != layers or set(mixers) - {MLA, KDA, CONV, GQA}:
+            raise ValueError(
+                f"mixers must name {layers} layers' mixers, each mla, kda, conv or gqa")
 
         def block(routed, name, mixer=MLA):
             return block_cls(
@@ -328,10 +384,14 @@ class MLAMoETransformerLM(nn.Module):
                 self.v_dim, self.dense_dim, self.num_experts, self.experts_per_token,
                 self.expert_dim, self.shared_dim, self.route_scale, self.experts_first, held,
                 self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, mixer,
-                self.kda_heads, self.kda_head_dim, self.conv_size, name=name)
+                self.kda_heads, self.kda_head_dim, self.conv_size, self.kv_heads, self.head_dim,
+                name=name)
 
         def logits(h, norm):
             h = RMSNorm(self.rms_eps, self.head_dtype, name=norm)(h)
+            if self.tie_head:
+                with jax.named_scope(trace.SCOPE_HEAD):
+                    return embed.attend(h).astype(jnp.float32)
             return head(h).astype(jnp.float32)
 
         h = embed(x)  # the residual stream stays float32: the router reads it

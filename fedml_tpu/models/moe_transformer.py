@@ -67,6 +67,15 @@ def rope(x, theta: float):
 
 
 class GroupedAttention(nn.Module):
+    """Causal attention of ``num_heads`` query heads on ``num_kv_heads`` key
+    and value heads of ``head_dim`` columns (query head n reads KV head
+    n // (num_heads // num_kv_heads)), no bias: ``softmax(q k^T /
+    sqrt(head_dim)) v`` a head, then the output projection. With
+    ``qk_norm_eps`` the queries and keys are RMS-normalised a head before the
+    rotation, each kind under one learned scale of ``head_dim`` that the
+    heads share (``q_norm``, ``k_norm``); without it the module has no such
+    leaves and computes what it did."""
+
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -74,6 +83,7 @@ class GroupedAttention(nn.Module):
     rope_theta: float | None = None  # None: no positional encoding
     attn_impl: str = "xla"  # xla | flash
     dtype: jnp.dtype = jnp.float32
+    qk_norm_eps: float | None = None  # None: q and k go on as projected
 
     @nn.compact
     def __call__(self, h):
@@ -85,6 +95,9 @@ class GroupedAttention(nn.Module):
 
         q, k, v = heads("q", self.num_heads), heads("k", self.num_kv_heads), heads(
             "v", self.num_kv_heads)
+        if self.qk_norm_eps is not None:
+            q = RMSNorm(self.qk_norm_eps, self.dtype, name="q_norm")(q)
+            k = RMSNorm(self.qk_norm_eps, self.dtype, name="k_norm")(k)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         if self.attn_impl == "flash":

@@ -94,8 +94,11 @@ def _create(model_name: str, output_dim: int, dataset: str = "") -> Any:
     if model_name == "mla_moe_transformer":
         # latent attention, a sigmoid router with a selection bias beside a
         # shared expert, a leading dense layer and a multi-token-prediction
-        # module (models/mla_moe_transformer.py); widths and the share held
-        # come from the caller (benchmark/families/mla_moe_lm.py)
+        # module, with a mixer a layer: latent attention, delta attention, a
+        # gated short convolution or grouped-query attention with normalised
+        # heads, and a head that may be tied (models/mla_moe_transformer.py);
+        # widths, mixers and the share held come from the caller
+        # (benchmark/families/mla_moe_lm.py, kda_moe_lm.py, conv_moe_lm.py)
         from fedml_tpu.models.mla_moe_transformer import MLAMoETransformerLM
 
         return MLAMoETransformerLM(vocab_size=output_dim)

@@ -69,7 +69,8 @@ __all__ = [
     "program_note", "program_notes", "last_counters", "loop",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "LOOP_SCOPES", "LOOP_CARRY_NOTE",
+    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "SHORTCONV_SCOPES", "LOOP_SCOPES",
+    "LOOP_CARRY_NOTE",
     "COHORT_AGGREGATE_NOTE",
     "FLASH_KERNEL_NAME",
     "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
@@ -119,6 +120,19 @@ MLA_SCOPES = (SCOPE_MLA, SCOPE_MOE_SHARED, SCOPE_MTP)
 SCOPE_KDA = "attn/kda"
 SCOPE_KDA_SCAN = "attn/kda/scan"
 KDA_SCOPES = (SCOPE_KDA, SCOPE_KDA_SCAN)
+# Scopes of the decoder whose layers mix by a gated short convolution or by
+# grouped-query attention (the "conv" and "gqa" mixers of
+# models/mla_moe_transformer.py), inside SCOPE_FWD_BWD: the convolution
+# operator whole (both projections, the gates and the taps); inside it the
+# elementwise chain alone (ops/shortconv.py: B * z, the taps, C * c, forward
+# and backward); the attention layer whole (projections, the heads' norms,
+# the rotation and the flash kernels); and the tied head's product, under the
+# name an untied head's flax module has
+SCOPE_SHORTCONV = "mix/shortconv"
+SCOPE_SHORTCONV_GATE = "mix/shortconv/gate"
+SCOPE_GQA = "attn/gqa"
+SCOPE_HEAD = "head"
+SHORTCONV_SCOPES = (SCOPE_SHORTCONV, SCOPE_SHORTCONV_GATE, SCOPE_GQA, SCOPE_HEAD)
 # The loops of a round's path (sim/engine.py, core/trainer.py), opened by
 # :func:`loop` around the call that makes the loop and nothing wider. Never
 # under ``fed/``: the ops inside keep their phase (a reader classes an op by

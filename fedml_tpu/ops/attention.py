@@ -154,8 +154,8 @@ def _col(row):
 
 
 def _spread(x, n):
-    """``[rows, lanes]`` with a row's value in every lane -> what broadcasts
-    against ``[rows, n]`` with no lane shuffle where ``lanes`` divides ``n``."""
+    """``[rows, lanes]``, a row's value in every lane -> what broadcasts on ``[rows, n]``: whole
+    registers where ``lanes`` divides ``n``, else (``n`` 64 of 128 lanes) lane 0, ``[rows, 1]``."""
     lanes = x.shape[1]
     if lanes in (1, n):
         return x

@@ -40,6 +40,13 @@ that entered each group of chunks ``kda/states``, so that the second forward
 runs no scan and the backward starts each group from a kept state. The
 log-decays are never kept: they are remade from a ``[T, rank]`` product.
 
+A short-convolution block (``ShortConv`` there) keeps one: its input
+projection's output ``shortconv/in`` (``[tokens, 3 D]``: every step of the
+elementwise chain and of its backward reads a chunk of it, and the chain
+itself, ``B * z``, the taps and ``C * c``, costs less to run again than its
+values cost to hold). A grouped-query attention block keeps the flash
+kernels' five, as every attention block does.
+
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
 The list is fixed here and follows no option: a name costs memory, and what
@@ -71,8 +78,11 @@ KDA_Q, KDA_K, KDA_V = "kda/q", "kda/k", "kda/v"
 KDA_OUT, KDA_STATES = "kda/out", "kda/states"
 KDA_KEPT = (KDA_Q, KDA_K, KDA_V, KDA_OUT, KDA_STATES)
 
+# models/mla_moe_transformer.py ShortConv: the input projection's [B | C | z]
+SHORTCONV_IN = "shortconv/in"
+
 KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS,
-        *KDA_KEPT)
+        *KDA_KEPT, SHORTCONV_IN)
 NOTE = "remat/kept"
 
 

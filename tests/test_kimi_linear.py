@@ -248,7 +248,7 @@ def test_the_mixers_list_is_held_to_the_depth():
     tokens = jnp.zeros((1, 8), jnp.int32)
     with pytest.raises(ValueError, match="mixers must name 3"):
         _model(mixers=(KDA, MLA)).init(jax.random.key(0), tokens)
-    with pytest.raises(ValueError, match="mla or kda"):
+    with pytest.raises(ValueError, match="mla, kda, conv or gqa"):
         _model(mixers=(KDA, "ssm", MLA)).init(jax.random.key(0), tokens)
     # no mixers given: latent attention in every layer, and no "kda" statistics
     params = _model(mixers=None).init(jax.random.key(0), tokens)
