@@ -73,7 +73,7 @@ __all__ = [
     "LOOP_CARRY_NOTE",
     "COHORT_AGGREGATE_NOTE",
     "FLASH_KERNEL_NAME",
-    "FLASH_BWD_DKV_KERNEL_NAME", "FLASH_BWD_DQ_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
+    "FLASH_BWD_DKV_KERNEL_NAME", "KDA_FWD_KERNEL_NAME",
     "KDA_BWD_KERNEL_NAME", "COMPILE_SPANS",
 ]
 
@@ -151,10 +151,9 @@ LOOP_CARRY_NOTE = "loop/carry"  # the program note each loop leaves where it is 
 # and the ``bytes`` of that stack, built or not
 COHORT_AGGREGATE_NOTE = "cohort/aggregate"
 FLASH_KERNEL_NAME = "flash_fwd"  # ``name=`` of the Mosaic forward kernel
-# ... and of the two backward kernels, under SCOPE_BLOCKWISE_BWD; neither
-# holds "flash_fwd", which the benchmark's forward readers match on
+# ... and of the one backward kernel (dQ, dK and dV), under SCOPE_BLOCKWISE_BWD;
+# it does not hold "flash_fwd", which the benchmark's forward readers match on
 FLASH_BWD_DKV_KERNEL_NAME = "flash_bwd_dkv"
-FLASH_BWD_DQ_KERNEL_NAME = "flash_bwd_dq"
 # ``name=`` of the delta-rule scan's two Mosaic kernels (ops/kda.py)
 KDA_FWD_KERNEL_NAME = "kda_fwd"
 KDA_BWD_KERNEL_NAME = "kda_bwd"

@@ -18,15 +18,15 @@ keys up to and including its own position; both are static, and with equal
 head counts and ``window=None`` the kernels are the programs they were.
 Forward runs the pallas kernel ``flash_fwd``, which also writes each query
 row's log-sum-exp (``B*H*T`` f32, the backward's one extra residual);
-backward is a custom VJP of two more pallas kernels, the standard flash
-backward: ``flash_bwd_dkv`` (a key block a grid step, looping over the query
-blocks that see it) and ``flash_bwd_dq`` (a query block a step, looping over
-key blocks up to the causal limit). Both recompute the scores a tile at a
-time in VMEM from q, k and the log-sum-exp. All three kernels feed the MXU
-operands in the input's dtype with f32 accumulation, skip the tiles the mask
-hides whole, and run mask arithmetic only on the tiles the diagonal or the
-window's edge cuts, so memory is O(T) in both directions and no ``[T, T]``
-tile ever reaches HBM. Each direction picks its tiles from the shape
+backward is a custom VJP of one more pallas kernel, ``flash_bwd_dkv`` (a key
+block a grid step, looping over the query blocks that see it): it recomputes
+each score tile once in VMEM from q, k and the log-sum-exp and feeds all
+three gradients from it, dK and dV of its key block and the head's dQ, which
+it sums in VMEM across the key blocks. Both kernels feed the MXU operands in
+the input's dtype with f32 accumulation, skip the tiles the mask hides
+whole, and run mask arithmetic only on the tiles the diagonal or the window's
+edge cuts, so memory is O(T) in both directions and no ``[T, T]`` tile ever
+reaches HBM. Each direction picks its tiles from the shape
 (:func:`_fwd_blocks`, :func:`_bwd_blocks`); a caller's ``block_q`` /
 ``block_k`` override the forward's.
 On the CPU backend the kernels run in interpreter mode so the full test
@@ -133,6 +133,7 @@ def _check_window(window, causal) -> None:
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _eye(n):
@@ -146,11 +147,6 @@ def _row(col):
     masked sublane reduction: exact, ``n * n`` elements once a query block,
     and faster on the v5e than the reshape Mosaic offers (PERF.md §6, PR 26)."""
     return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0, keepdims=True)
-
-
-def _col(row):
-    """``[1, n]`` -> ``[n, 1]``, the inverse of :func:`_row`."""
-    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1, keepdims=True)
 
 
 def _spread(x, n):
@@ -234,9 +230,8 @@ def _flash_fwd_kernel(
         jax.lax.fori_loop(lo, hi, lambda j, c: step(masked, j), None)
 
     if causal:
-        # the key blocks the dq kernel visits for this query block: under a
-        # window the ones its edge cuts, then the ones every row sees whole
-        # (no mask arithmetic), then the ones the diagonal cuts
+        # under a window the key blocks its edge cuts, then the ones every
+        # row sees whole (no mask arithmetic), then the ones the diagonal cuts
         start, whole_start, whole_end, last = _fwd_kb_ranges(
             iq, block_q, block_k, off, num_kb, window
         )
@@ -253,14 +248,16 @@ def _flash_fwd_kernel(
     lse_ref[:] = _row(m_acc[:, :1] + jnp.log(l))
 
 
-def _head_seq(t, d, group=1):
+def _head_seq(t, d, group=1, buffers=None):
     """A head's whole ``[t, d]`` sequence, resident across the grid's axis 1.
     With ``group`` query heads a KV head, grid step ``i`` (a flat batch x
     query head) reads the KV head ``i // group``: consecutive steps of one
-    group name the same block, so it is fetched once."""
+    group name the same block, so it is fetched once. ``buffers=1`` keeps one
+    copy in VMEM and not the pipeline's two (the block changes once a head)."""
+    mode = {} if buffers is None else {"pipeline_mode": pl.Buffered(buffers)}
     if group == 1:
-        return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
-    return pl.BlockSpec((None, t, d), lambda i, j: (i // group, 0, 0))
+        return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0), **mode)
+    return pl.BlockSpec((None, t, d), lambda i, j: (i // group, 0, 0), **mode)
 
 
 def _head_block(block, d, group=1):
@@ -277,20 +274,27 @@ def _rows_spec(block):
     return pl.BlockSpec((None, None, 1, block), lambda i, j: (i, j, 0, 0))
 
 
-def _mosaic_params(dtype, *whole_sequences):
+def _mosaic_params(dtype, *whole_sequences, scratch=(), buffers=2):
     """``compiler_params`` of a kernel that keeps ``whole_sequences`` (the
-    ``(T, D)`` of a head's resident operands of ``dtype``) in VMEM. Mosaic
-    double-buffers each, its last dimension padded to whole 128-lane
-    registers, inside 16 MB of scoped VMEM by default. Every equal-width
-    caller's pair fits that beside the tiles (two ``[8192, 128]`` bf16
-    sequences are 8 MiB) and gets no parameter, so its program is the one it
-    was; a 192-wide key pads to 256 lanes, 12 MiB with its values, and the dq
-    kernel is refused by 0.8 MB (compiled for the v5e, PR 32), so such a call
-    asks for what it holds plus the 8 MiB the tiles and a step's temporaries
-    had before."""
-    held = sum(2 * t * -(-d // 128) * 128 * jnp.dtype(dtype).itemsize
+    ``(T, D)`` of a head's resident operands and outputs of ``dtype``, each in
+    ``buffers`` copies: the pipeline's two, or the one of ``_head_seq(...,
+    buffers=1)``) and ``scratch`` (the ``(T, D)`` of its resident f32
+    accumulators) in VMEM, last dimensions padded to whole 128-lane registers.
+    Mosaic's default is 16 MB of scoped VMEM. What holds 10 MiB or less fits
+    that beside the tiles (the forward's K and V at ``[8192, 128]`` bf16: 8;
+    the backward's q, dO, dQ and accumulator there: 10) and gets no parameter.
+    **A request is not free**: XLA takes the largest limit any custom call of
+    a program asks out of what its own fusions may keep in VMEM (in
+    ``lfm2moe_silo2`` a 24 MiB request made seven matmul fusions of other
+    layers 40-60% slower, 3.4% of the round, on the parent's kernels as on
+    these; PERF.md §6, PR 43), so a kernel holds as little as it can and asks
+    only when it must: the 192-wide key pads to 256 lanes (forward 12 MiB,
+    refused at the default by 0.8 MB, PR 32; backward 18), and asks for what
+    it holds plus the 8 MiB the tiles and a step's temporaries had before."""
+    held = sum(buffers * t * -(-d // 128) * 128 * jnp.dtype(dtype).itemsize
                for t, d in whole_sequences)
-    if held <= 8 * 2 ** 20:
+    held += sum(t * -(-d // 128) * 128 * 4 for t, d in scratch)
+    if held <= 10 * 2 ** 20:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=held + 8 * 2 ** 20)
 
@@ -366,20 +370,19 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels: the standard two-kernel flash backward. Scores are
-# recomputed a tile at a time in VMEM from q, k and the forward's lse; the
-# [T, T] matrix never reaches HBM, and key blocks that the causal mask hides
-# whole are skipped as the forward skips them.
+# Pallas backward kernel: one pass over the visible tiles. A score tile is
+# recomputed once in VMEM from q, k and the forward's lse and feeds dQ, dK and
+# dV (five products and one exp a tile); the [T, T] matrix never reaches HBM,
+# and tiles that the causal mask hides whole are skipped as the forward skips them.
 # ---------------------------------------------------------------------------
 
 
-def _visible(q_lo, k_lo, shape, transposed, window=None):
-    """Mask of one score tile: key position <= query position (which carries
-    the right-aligned offset) and, under a window, > query position - window.
-    ``transposed`` tiles are [keys, queries]."""
-    qd, kd = (1, 0) if transposed else (0, 1)
-    q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
-    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, kd)
+def _visible(q_lo, k_lo, shape, window=None):
+    """Mask of one transposed score tile, [keys, queries]: key position <=
+    query position (which carries the right-aligned offset) and, under a
+    window, > query position - window."""
+    q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     if window is None:
         return k_pos <= q_pos
     return (k_pos <= q_pos) & (k_pos > q_pos - window)
@@ -403,10 +406,10 @@ def _clip(x, lo, hi):
 
 def _fwd_kb_ranges(iq, block_q, block_k, off, num_kb, window):
     """``(start, whole_start, whole_end, last)`` of the key blocks a causal
-    query block visits, in the forward and in the dq kernel alike (each with
-    its own tiles): ``[start, whole_start)`` are cut by the window's edge,
-    ``[whole_start, whole_end)`` are seen whole, ``[whole_end, last)`` are
-    cut by the diagonal. Without a window ``start = whole_start = 0``."""
+    query block visits in the forward: ``[start, whole_start)`` are cut by
+    the window's edge, ``[whole_start, whole_end)`` are seen whole,
+    ``[whole_end, last)`` are cut by the diagonal. Without a window ``start =
+    whole_start = 0``."""
     q_lo, q_hi = off + iq * block_q, off + (iq + 1) * block_q - 1
     last = _clip(q_hi // block_k + 1, 0, num_kb)
     whole_end = _clip((q_lo + 1) // block_k, 0, last)
@@ -435,21 +438,23 @@ def _dkv_qb_ranges(jk, block_q, block_k, off, num_qb, window):
 
 def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
     """Record, while the program is traced, what one attention call will do:
-    its kind, its two widths, its grouping and how many of the square's tiles
-    the kernel visits (``obs/trace.py`` :func:`program_note`; docs/OBSERVABILITY.md)."""
+    its kind, its two widths, its grouping, how many of the square's tiles
+    the kernel visits and what it ``writes`` (``obs/trace.py``
+    :func:`program_note`; docs/OBSERVABILITY.md)."""
     t_q = q.shape[2]
     nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
     if not causal:
         ranges = [(0, 0, nk, nk)] * nq
     elif kernel == "dkv":
         ranges = [_dkv_qb_ranges(j, block_q, block_k, off, nq, window) for j in range(nk)]
-    else:  # the forward and dq walk the same key blocks, each with its own tiles
+    else:
         ranges = [_fwd_kb_ranges(i, block_q, block_k, off, nk, window) for i in range(nq)]
     visited = sum(r[3] - r[0] for r in ranges)
     # tiles that run mask arithmetic: the two cut ranges either side of the whole one
     masked = visited - sum(r[2] - r[1] for r in ranges)
     trace.program_note(
         "attn/call", kernel=kernel,
+        writes=("dq", "dk", "dv") if kernel == "dkv" else ("out", "lse"),
         kind="window" if window is not None else "global" if causal else "full",
         window=window, shape=tuple(q.shape), t_k=t_k, d_qk=q.shape[3], d_v=d_v,
         q_heads_per_kv_head=group,
@@ -459,15 +464,19 @@ def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None,
 ):
-    # k_ref/dk_ref: [block_k, D_qk]; v_ref/dv_ref: [block_k, D_v]; q_ref:
+    # k_ref/dk_ref: [block_k, D_qk]; v_ref/dv_ref: [block_k, D_v]; q_ref/dq_ref:
     # [T_q, D_qk] and do_ref: [T_q, D_v] (the head's whole sequence);
     # lse_ref/delta_ref: [T_q // block_q, 1, block_q];
     # grid = (B*H, T_k // block_k). Tiles are transposed, [keys, queries], so
-    # the per-query statistics broadcast along sublanes and all four matmuls
-    # are plain NN / NT.
+    # the per-query statistics broadcast along sublanes and four of the five
+    # matmuls are plain NN / NT; dQ's contracts the tile's keys, dimension 0 of
+    # both operands. dq_acc [T_q, D_qk] f32 lives across the head's key blocks,
+    # which come in ascending order: zeroed at the first, written at the last.
+    # q_ref, do_ref and dq_ref change once a head and keep one buffer each, so
+    # that at [8192, 128] the kernel asks Mosaic for no VMEM (_mosaic_params).
     jk = pl.program_id(1)
     block_k = k_ref.shape[0]
     t_q = q_ref.shape[0]
@@ -477,6 +486,10 @@ def _flash_bwd_dkv_kernel(
     v = v_ref[:]
     dk_acc[:] = jnp.zeros_like(dk_acc)
     dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(jk == 0)
+    def _():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def step(masked, i):
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
@@ -488,17 +501,16 @@ def _flash_bwd_dkv_kernel(
             # select, not multiply: a fully masked row's lse is about
             # NEG_INF and exp() of its scores is inf
             p = jnp.where(
-                _visible(off + i * block_q, jk * block_k, s.shape, True, window),
+                _visible(off + i * block_q, jk * block_k, s.shape, window),
                 p, 0.0,
             )
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[i]) * sm_scale
+        ds = (p * (dp - delta_ref[i]) * sm_scale).astype(q.dtype)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32
         )
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32
-        )
+        dk_acc[:] += jax.lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
 
     def loop(lo, hi, masked):
         jax.lax.fori_loop(lo, hi, lambda i, c: step(masked, i), None)
@@ -519,74 +531,34 @@ def _flash_bwd_dkv_kernel(
     dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, block_k, causal, sm_scale, window=None,
-):
-    # q_ref/dq_ref: [block_q, D_qk]; do_ref: [block_q, D_v]; k_ref: [T_k, D_qk]
-    # and v_ref: [T_k, D_v] (the head's whole sequence); lse_ref/delta_ref:
-    # [1, block_q]; grid = (B*H, T_q // block_q).
-    iq = pl.program_id(1)
-    block_q = q_ref.shape[0]
-    t_k = k_ref.shape[0]
-    num_kb = t_k // block_k
-    off = t_k - pl.num_programs(1) * block_q
-    q = q_ref[:]
-    do = do_ref[:]
-    lse = _col(lse_ref[:])
-    delta = _col(delta_ref[:])
-    dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    def step(masked, j):
-        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
-        k = k_ref[cols, :]
-        v = v_ref[cols, :]
-        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
-        p = jnp.exp(s * sm_scale - lse)
-        if masked:
-            p = jnp.where(
-                _visible(off + iq * block_q, j * block_k, s.shape, False, window),
-                p, 0.0,
-            )
-        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32
-        )
-
-    def loop(lo, hi, masked):
-        jax.lax.fori_loop(lo, hi, lambda j, c: step(masked, j), None)
-
-    if causal:
-        # key blocks up to this query block's last position (as the forward's
-        # range), of those the ones its first row sees whole (no mask), and
-        # under a window none before it opens, its edge's tiles masked
-        start, whole_start, whole_end, last = _fwd_kb_ranges(
-            iq, block_q, block_k, off, num_kb, window
-        )
-        if window is not None:
-            loop(start, whole_start, True)
-        loop(whole_start, whole_end, False)
-        loop(whole_end, last, True)
-    else:
-        loop(0, num_kb, False)
-    dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+    @pl.when(jk == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
-    """The backward kernels' ``(block_q, block_k)``. Measured on the v5e at
-    D 128 bf16, causal: 512 x 512 is the fastest for both kernels at T 2048
-    (2.17 ms a call; 256 x 256 2.73, 1024 x 1024 2.35) and at T 1024 (0.77;
-    0.91, 0.85): tiles large enough to amortise the loop, small enough that
-    the causal diagonal wastes an eighth of the square and not a quarter, and
-    every temporary of a step fits the 16 MB of scoped VMEM wherever the
-    forward's whole-sequence K and V do (PERF.md §6, PR 26). At a 192-column
-    score on 128-column values, 32 heads of T 8192 (PR 32), the two kernels
-    take 19.22 ms a call at 512 x 512; 512 x 1024 19.48, 256 x 1024 20.15,
-    512 x 256 20.41, 256 x 512 20.43, 256 x 256 22.19, and a 1024-row query
-    tile is refused (VMEM). A length whose divisor under 512 Mosaic refuses
-    keeps the forward's block, which passed."""
+    """The backward kernel's ``(block_q, block_k)``. Measured on the v5e, bf16
+    causal, ms a backward (the kernel, the ``rowsum(dO * O)`` fusion and a KV
+    group's sum; PERF.md §6, PR 43; the columns are :func:`_fwd_blocks`' and
+    32 query heads on 8 of width 64 at batch 2; PR 26's pair of kernels took
+    20.10, 11.26, 9.49, 26.64 and 2.214 at 512 x 512; measured with q, dO and
+    dQ double-buffered: with the one buffer each they have now 512 x 512
+    reads 15.06, 8.04, 6.77, 19.33 and 1.643):
+
+        tile         192 | 128   T 8192   T 8192 w   D 64     T 2048
+        256 x 256    16.35       11.30    9.03       26.53    2.009
+        256 x 512    15.44        8.57    7.08       20.40    1.659
+        512 x 256    15.56        8.67    7.18       20.56    1.684
+        512 x 512    14.84        8.09    6.79       19.26    1.571
+        512 x 1024   15.04        8.13    7.25       refused  1.754
+        1024 x 512   15.11        8.15    7.28       19.41    1.766
+        1024 x 1024  refused      refused refused    refused  1.728
+
+    Tiles large enough to amortise the loop, small enough that the causal
+    diagonal wastes an eighth of the square and not a quarter; "refused" is
+    the VMEM :func:`_mosaic_params` asks for, which counts 512 x 512's
+    temporaries. A length whose divisor under 512 Mosaic refuses keeps the
+    forward's block, which passed."""
 
     def pick(t, fwd_block):
         try:
@@ -600,10 +572,11 @@ def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
 @jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
 def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
                window=None):
-    """``(dq, dk, dv)``; ``block_q`` / ``block_k`` divide ``t_q`` / ``t_k``
-    (:func:`_bwd_blocks` picks them through :func:`_pick_block`). Under
-    grouped KV heads ``flash_bwd_dkv`` writes each query head's part of dK
-    and dV in f32 and one XLA reduction sums a KV head's group."""
+    """``(dq, dk, dv)`` from one kernel; ``block_q`` / ``block_k`` divide
+    ``t_q`` / ``t_k`` (:func:`_bwd_blocks` picks them through
+    :func:`_pick_block`). Under grouped KV heads ``flash_bwd_dkv`` writes each
+    query head's part of dK and dV in f32 and one XLA reduction sums a KV
+    head's group."""
     b, h, t_q, d = q.shape
     h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
@@ -619,43 +592,31 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
     part = jnp.float32 if group > 1 else None  # a query head's part of dK, dV
     _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k)
-    dk, dv = pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale,
             window=window,
         ),
         grid=(b * h, nk),
-        in_specs=[_head_seq(t_q, d), _head_block(block_k, d, group),
-                  _head_block(block_k, d_v, group), _head_seq(t_q, d_v), rows_seq, rows_seq],
-        out_specs=[_head_block(block_k, d), _head_block(block_k, d_v)],
-        out_shape=[jax.ShapeDtypeStruct((b * h, t_k, d), part or k.dtype),
+        in_specs=[_head_seq(t_q, d, buffers=1), _head_block(block_k, d, group),
+                  _head_block(block_k, d_v, group), _head_seq(t_q, d_v, buffers=1),
+                  rows_seq, rows_seq],
+        out_specs=[_head_seq(t_q, d, buffers=1), _head_block(block_k, d),
+                   _head_block(block_k, d_v)],
+        out_shape=[jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, t_k, d), part or k.dtype),
                    jax.ShapeDtypeStruct((b * h, t_k, d_v), part or v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((t_q, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name=trace.FLASH_BWD_DKV_KERNEL_NAME,
-        compiler_params=_mosaic_params(q.dtype, (t_q, d), (t_q, d_v)),
+        compiler_params=_mosaic_params(
+            q.dtype, (t_q, d), (t_q, d_v), (t_q, d), scratch=[(t_q, d)], buffers=1),
     )(*args)
     if group > 1:
         dk = dk.reshape(b, h_kv, group, t_k, d).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(b, h_kv, group, t_k, d_v).sum(axis=2).astype(v.dtype)
-    _note_call("dq", q, t_k, d_v, group, causal, window, block_q, block_k)
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale,
-            window=window,
-        ),
-        grid=(b * h, nq),
-        in_specs=[_head_block(block_q, d), _head_seq(t_k, d, group),
-                  _head_seq(t_k, d_v, group), _head_block(block_q, d_v),
-                  _rows_spec(block_q), _rows_spec(block_q)],
-        out_specs=_head_block(block_q, d),
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name=trace.FLASH_BWD_DQ_KERNEL_NAME,
-        compiler_params=_mosaic_params(k.dtype, (t_k, d), (t_k, d_v)),
-    )(*args)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -680,8 +641,8 @@ def flash_attention(
     ``H``; see :func:`attention_reference` for the grouping, for ``window``
     and for the two widths); the output is ``[B, H, T, D_v]``.
 
-    Forward = pallas kernel (interpreter mode on the CPU); backward = two
-    pallas kernels that recompute the scores blockwise from the forward's
+    Forward = pallas kernel (interpreter mode on the CPU); backward = one
+    pallas kernel that recomputes the scores blockwise from the forward's
     log-sum-exp — O(T·block) memory in both directions, the [T, T] score
     matrix is never materialized. Both directions choose their tiles from the
     shape and dtype; ``block_q`` / ``block_k`` override the forward's.
@@ -786,7 +747,7 @@ def _bwd_rule(causal, sm_scale, block_q, block_k, window, res, g):
     blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype,
                          _fwd_blocks(q.shape[2], k.shape[2], q.dtype, block_q, block_k))
     trace.event(
-        "attn/bwd_path", impl="kernel", shape=tuple(q.shape), t_k=k.shape[2],
+        "attn/bwd_path", impl="fused", shape=tuple(q.shape), t_k=k.shape[2],
         dtype=jnp.dtype(q.dtype).name, blocks=blocks,
     )
     interpret = _interpret_on(jax.default_backend())

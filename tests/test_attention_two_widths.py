@@ -1,4 +1,4 @@
-"""The three flash kernels with a score width that differs from the value
+"""The two flash kernels with a score width that differs from the value
 width, and with rotary key columns shared by every head (latent attention),
 against ``attention_reference``: forward and the three gradients, on the CPU
 interpreter. At equal widths they are the kernels they were."""
@@ -124,7 +124,7 @@ def test_attn_call_notes_carry_both_widths():
     q, k, v = _qkv((1, 2, 96, 40), 8, seed=5)
     jax.grad(lambda q: flash_attention(q, k, v, True, None, 32, 32).sum())(q)
     notes = [n for n in trace.program_notes("attn/call") if n["shape"] == (1, 2, 96, 40)]
-    assert {n["kernel"] for n in notes} == {"fwd", "dkv", "dq"}
+    assert {n["kernel"] for n in notes} == {"fwd", "dkv"}  # dkv writes dq too
     assert all((n["d_qk"], n["d_v"]) == (40, 8) for n in notes)
     fwd = next(n for n in notes if n["kernel"] == "fwd")  # the backward picks its own tiles
     assert (fwd["tile"], fwd["tiles_visited"], fwd["tiles_total"]) == ((32, 32), 6, 9)

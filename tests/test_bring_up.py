@@ -94,13 +94,14 @@ def test_flash_wrap_lowers_for_tpu_under_a_sharded_plan(monkeypatch):
 
         text = lowered_for_tpu(attend)
         assert "tpu_custom_call" in text
-        # the custom VJP sits inside the all-axes shard_map, so the two
-        # backward kernels lower per device too, on local heads
+        # the custom VJP sits inside the all-axes shard_map, so the one
+        # backward kernel lowers per device too, on local heads
         text = lowered_for_tpu(jax.grad(
             lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
-        assert text.count("tpu_custom_call") >= 3
-        for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert text.count("tpu_custom_call") == 2
+        for kernel in ("flash_fwd", "flash_bwd_dkv"):
             assert kernel in text, (axis, kernel)
+        assert "flash_bwd_dq" not in text
 
 
 def _lowered_for_tpu(fn, *shapes):
@@ -109,39 +110,82 @@ def _lowered_for_tpu(fn, *shapes):
     return jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("q_shape,kv_heads,window", [
-    pytest.param((4, 16, 2048, 128), 16, None, id="cgpt13b_silo2"),
-    pytest.param((1, 28, 8192, 128), 4, None, id="smallthinker21b_silo2-global"),
-    pytest.param((1, 28, 8192, 128), 4, 4096, id="smallthinker21b_silo2-window"),
-    pytest.param((1, 32, 8192, 192), 32, None, id="joyai_flash_silo2-192-on-128"),
-])
-def test_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, q_shape, kv_heads, window):
-    """The three kernels at the LM cells' shapes in bf16, with the tiles the
+# the LM cells' attention calls, bf16 causal: (q shape, KV heads, d_v, window) and the
+# MiB of VMEM the forward's K and V (double-buffered) and the backward's q, dO, dQ (one
+# buffer each) and f32 dQ accumulator hold, lanes padded to 128; a call that holds more
+# than 10 MiB asks Mosaic for that plus 8 (``_mosaic_params``), and only the 192-wide do
+CELL_ATTENTION = [
+    pytest.param((4, 16, 2048, 128), 16, 128, None, 2, 2.5, id="cgpt13b_silo2"),
+    pytest.param((1, 28, 8192, 128), 4, 128, None, 8, 10, id="smallthinker21b_silo2-global"),
+    pytest.param((1, 28, 8192, 128), 4, 128, 4096, 8, 10, id="smallthinker21b_silo2-window"),
+    pytest.param((1, 32, 8192, 192), 32, 128, None, 12, 18, id="joyai_flash_silo2-192-on-128"),
+    pytest.param((1, 32, 8192, 192), 32, 128, None, 12, 18, id="kimilinear_silo2-192-on-128"),
+    pytest.param((2, 32, 8192, 64), 8, 64, None, 8, 10, id="lfm2moe_silo2-64-wide"),
+]
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,d_v,window,fwd_mib,bwd_mib", CELL_ATTENTION)
+def test_mosaic_params_count_what_the_kernels_hold(q_shape, kv_heads, d_v, window, fwd_mib,
+                                                   bwd_mib):
+    import fedml_tpu.ops.attention as att
+
+    _, _, t, d = q_shape
+    mib = 2 ** 20
+    pad = lambda w: -(-w // 128) * 128  # noqa: E731
+    assert 2 * 2 * t * (pad(d) + pad(d_v)) == fwd_mib * mib
+    assert 2 * t * (2 * pad(d) + pad(d_v)) + 4 * t * pad(d) == bwd_mib * mib
+    for held, params in (
+            (fwd_mib, att._mosaic_params(jnp.bfloat16, (t, d), (t, d_v))),
+            (bwd_mib, att._mosaic_params(jnp.bfloat16, (t, d), (t, d_v), (t, d),
+                                         scratch=[(t, d)], buffers=1))):
+        if held <= 10:
+            assert params is None  # Mosaic's default 16 MB: no request, which is not free
+        else:
+            assert params.vmem_limit_bytes == (held + 8) * mib < 128 * mib  # the v5e's VMEM
+
+
+def test_mosaic_params_count_f32_sequences_at_twice_the_bytes():
+    """The backward at T 4096 of 128 columns holds 8 MiB in f32, still no
+    request; at T 8192 it holds 16 and asks for 24."""
+    import fedml_tpu.ops.attention as att
+
+    def backward(t):
+        return att._mosaic_params(jnp.float32, (t, 128), (t, 128), (t, 128), scratch=[(t, 128)],
+                                  buffers=1)
+
+    assert backward(4096) is None
+    assert backward(8192).vmem_limit_bytes == (16 + 8) * 2 ** 20
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,d_v,window,fwd_mib,bwd_mib", CELL_ATTENTION)
+def test_flash_kernels_lower_for_tpu_at_the_cells_shapes(monkeypatch, q_shape, kv_heads, d_v,
+                                                         window, fwd_mib, bwd_mib):
+    """The two kernels at the LM cells' shapes in bf16, with the tiles the
     kernels choose for them (``_fwd_blocks``, ``_bwd_blocks``): equal heads at
     T 2048; 28 query heads on 4 KV heads at T 8192, global and window; 32
-    heads of 192 score columns on 128 value columns at T 8192, whose resident
-    sequences ask Mosaic for more than its default VMEM (``_mosaic_params``)."""
+    heads of 192 score columns on 128 value columns at T 8192; 32 heads of 64
+    on 8 at batch 2. Two custom calls an attention, forward and backward; the
+    one that holds more than 10 MiB writes its VMEM limit."""
     import fedml_tpu.ops.attention as att
 
     monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
     b, _, t, d = q_shape
-    d_v = 128
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, kv_heads, t, d), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, kv_heads, t, d_v), jnp.bfloat16)
     assert att._fwd_blocks(t, t, jnp.bfloat16) == (512, 512)
     assert att._bwd_blocks(t, t, jnp.bfloat16, (512, 512)) == (512, 512)
-    params = att._mosaic_params(jnp.bfloat16, (t, d), (t, d_v))
-    assert (params is None) == (d == d_v)
 
     def loss(q, k, v):
         return att.flash_attention(q, k, v, True, window=window).astype(jnp.float32).sum()
 
     text = _lowered_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
-    assert text.count("tpu_custom_call") >= 3
-    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
         assert kernel in text, kernel
-    assert ("scoped_memory_configs" in text) == (d != d_v)  # where the VMEM limit is written
+    assert "flash_bwd_dq" not in text
+    # where a VMEM limit is written: once a kernel that asks
+    assert text.count("scoped_memory_configs") == (fwd_mib > 10) + (bwd_mib > 10)
 
 
 def test_expert_layer_lowers_for_tpu_at_the_cells_shapes(monkeypatch):

@@ -1,0 +1,77 @@
+"""The flash kernels compiled for a described TPU v5e (no chip): Mosaic and
+the TPU compiler refuse here what the interpreter lets through (VMEM a kernel
+may not have, a slice off the tiling). The topology is described inside a
+fixture, so only the worker that runs this file loads the TPU's library; keep
+every such compile in this one file (the ``on-chip-measurement`` guide, 2)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import fedml_tpu.ops.attention as att
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache and cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (q shape, KV heads, t_k, d_v, dtype, causal, window): the five LM cells'
+# calls, chip_smoke.py's check, and the kinds of shape the CPU tests run
+# through the kernels' own tiles: a block that is the whole axis and no
+# multiple of 128 (T 64, 96), one that is a part of it and no multiple of 128
+# (T 640 in blocks of 320), t_q != t_k both ways, f32, no mask; with head counts
+# no other test uses, since the ``attn/call`` notes outlive a test
+SHAPES = [
+    pytest.param((1, 32, 8192, 192), 32, 8192, 128, jnp.bfloat16, True, None, id="joyai-kimi"),
+    pytest.param((1, 28, 8192, 128), 4, 8192, 128, jnp.bfloat16, True, None, id="st-global"),
+    pytest.param((1, 28, 8192, 128), 4, 8192, 128, jnp.bfloat16, True, 4096, id="st-window"),
+    pytest.param((2, 32, 8192, 64), 8, 8192, 64, jnp.bfloat16, True, None, id="lfm2"),
+    pytest.param((4, 16, 2048, 128), 16, 2048, 128, jnp.bfloat16, True, None, id="cgpt"),
+    pytest.param((4, 16, 1024, 128), 16, 1024, 128, jnp.bfloat16, True, None, id="chip_smoke"),
+    pytest.param((1, 2, 640, 128), 2, 640, 128, jnp.bfloat16, True, None, id="blocks-of-320"),
+    pytest.param((1, 3, 96, 40), 3, 96, 8, jnp.float32, True, None, id="T96-40-on-8"),
+    pytest.param((1, 6, 64, 24), 3, 64, 16, jnp.float32, True, 24, id="T64-grouped-window"),
+    pytest.param((1, 3, 64, 24), 3, 64, 16, jnp.bfloat16, True, None, id="T64-bf16"),
+    pytest.param((1, 3, 128, 192), 3, 128, 128, jnp.float32, True, None, id="T128-192-on-128"),
+    pytest.param((1, 2, 256, 128), 2, 512, 128, jnp.bfloat16, True, None, id="t_q-under-t_k"),
+    pytest.param((1, 2, 512, 128), 2, 256, 128, jnp.bfloat16, True, None, id="t_q-over-t_k"),
+    pytest.param((1, 2, 1024, 128), 2, 1024, 128, jnp.float32, False, None, id="full-f32"),
+]
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,t_k,d_v,dtype,causal,window", SHAPES)
+def test_flash_forward_and_backward_compile_for_the_v5e(
+        monkeypatch, one_chip, q_shape, kv_heads, t_k, d_v, dtype, causal, window):
+    """The gradient of ``flash_attention`` (the forward kernel and the one
+    backward kernel, with the VMEM ``_mosaic_params`` asks for them) compiles
+    for the chip at the tiles the kernels pick."""
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    b, _, _, d = q_shape
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        return att.flash_attention(q, k, v, causal, window=window).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip(*q_shape), on_chip(b, kv_heads, t_k, d), on_chip(b, kv_heads, t_k, d_v)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text and "flash_bwd_dq" not in text

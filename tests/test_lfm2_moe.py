@@ -192,7 +192,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(layer):
                                atol=3e-5)
 
 
-# -- 64-wide heads in the three flash kernels -------------------------------------------
+# -- 64-wide heads in the two flash kernels ---------------------------------------------
 
 
 def _heads64(seed=0, t=256, dtype=jnp.float32):
@@ -231,7 +231,7 @@ def test_the_kernels_take_their_128_lane_path_at_64_wide_heads():
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)],
                          ids=["f32", "bf16"])
 def test_flash_at_width_64_grouped_four_to_one_equals_the_oracle(dtype, atol):
-    """Forward and the three gradients of the three kernels (interpreted) at
+    """Forward and the three gradients of the two kernels (interpreted) at
     head width 64, four query heads on one KV head, on normalised and rotated
     q and k, against ``attention_reference``."""
     q, k, v, do = _heads64(dtype=dtype)
@@ -254,7 +254,7 @@ def test_flash_at_width_64_grouped_four_to_one_equals_the_oracle(dtype, atol):
                                    atol=atol * max(scale, 1.0))
     notes = [n for n in trace.program_notes("attn/call")
              if n["shape"] == (1, 4, 256, 64) and n["dtype"] == jnp.dtype(dtype).name]
-    assert {n["kernel"] for n in notes} == {"fwd", "dkv", "dq"}
+    assert {n["kernel"] for n in notes} == {"fwd", "dkv"}  # dkv writes dq too
     assert all(n["d_qk"] == 64 and n["d_v"] == 64 and n["q_heads_per_kv_head"] == 4
                for n in notes)
 
@@ -336,17 +336,17 @@ def test_the_tied_heads_product_bears_the_heads_name(lowered_step):
 
 
 def test_notes_and_kernel_counts(lowered_step):
-    """One ``shortconv/call`` and the three ``attn/call`` notes at this
+    """One ``shortconv/call`` and the two ``attn/call`` notes at this
     model's shapes; and PR 34's rule: a block holds no more ``pallas_call``s
     than its parent's kind. An attention block under remat: flash forward
-    once (its residuals are kept) and the two backward kernels; a routed
+    once (its residuals are kept) and the one backward kernel; a routed
     feed-forward's nine and its recompute's one; the operator none."""
     conv = {"impl": "xla", "tokens": 2 * T, "channels": D, "taps": 3, "dtype": "float32"}
     assert conv in trace.program_notes("shortconv/call")
     calls = [n for n in trace.program_notes("attn/call") if n["shape"] == (2, 4, T, 16)]
-    assert {n["kernel"] for n in calls} == {"fwd", "dkv", "dq"}
+    assert {n["kernel"] for n in calls} == {"fwd", "dkv"}  # dkv writes dq too
     assert all(n["q_heads_per_kv_head"] == 2 and n["kind"] == "global" for n in calls)
-    assert _count_pallas(lowered_step[1].jaxpr) == 3 + 3 * 10
+    assert _count_pallas(lowered_step[1].jaxpr) == 2 + 3 * 10
     dense_only = _model(remat=True, dense_layers=4, routed_layers=0,
                         mixers=(CONV, CONV, CONV, CONV))
     params, x, y = _seeded(dense_only)

@@ -187,7 +187,7 @@ def _masked_reference(q, k, v, causal):
 
 
 # (t_q, t_k, d, dtype, blocks): several blocks a side, so that the causal
-# skipping and both loops of each kernel (masked tiles, whole tiles) engage
+# skipping and both loops of a kernel (masked tiles, whole tiles) engage
 _BWD_CASES = [
     (64, 64, 8, jnp.float32, (16, 16)),
     (64, 64, 8, jnp.float32, (32, 16)),    # block_q != block_k, both ways
@@ -202,8 +202,8 @@ _BWD_CASES = [
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("t_q,t_k,d,dtype,blocks", _BWD_CASES)
 def test_flash_bwd_kernels_match_reference(rng, causal, t_q, t_k, d, dtype, blocks):
-    """dq, dk, dv of the two backward kernels, and the forward kernel's lse
-    that feeds them, against jax's own gradient of the plain reference."""
+    """dq, dk, dv of the one backward kernel, and the forward kernel's lse
+    that feeds it, against jax's own gradient of the plain reference."""
     from fedml_tpu.ops.attention import _flash_bwd, _flash_fwd
 
     q, k, v, g = (jnp.asarray(rng.randn(2, 2, t, d), dtype)
@@ -227,6 +227,78 @@ def test_flash_bwd_kernels_match_reference(rng, causal, t_q, t_k, d, dtype, bloc
                                    rtol=tol, err_msg=name)
     if causal and t_q > t_k:
         np.testing.assert_array_equal(np.asarray(got[0][:, :, :t_q - t_k]), 0.0)
+
+
+# dQ is summed in VMEM across a head's key blocks (zeroed at the first, written
+# at the last): tiles of 8-32 so that every dQ row meets three or more of them.
+# (shape of q, KV heads, t_k, d_v, dtype, causal, window, (block_q, block_k))
+_DQ_CASES = {
+    "causal": ((2, 2, 64, 8), 2, 64, 8, jnp.float32, True, None, (16, 16)),
+    "full": ((2, 2, 64, 8), 2, 64, 8, jnp.float32, False, None, (32, 8)),
+    # key blocks 0 .. 3 lie wholly before the last query block's window
+    "window": ((1, 2, 96, 8), 2, 96, 8, jnp.float32, True, 24, (16, 16)),
+    "window-bf16": ((1, 2, 96, 16), 2, 96, 16, jnp.bfloat16, True, 40, (32, 16)),
+    "grouped": ((2, 4, 64, 8), 2, 64, 8, jnp.float32, True, None, (16, 16)),
+    "grouped-window": ((1, 6, 64, 8), 2, 64, 8, jnp.float32, True, 20, (8, 16)),
+    "192-on-128": ((1, 2, 64, 192), 2, 64, 128, jnp.float32, True, None, (16, 16)),
+    "192-on-128-bf16": ((1, 2, 64, 192), 2, 64, 128, jnp.bfloat16, True, None, (16, 16)),
+    "64-wide-bf16": ((2, 4, 64, 64), 2, 64, 64, jnp.bfloat16, True, None, (16, 16)),
+    "t_q<t_k": ((2, 2, 32, 8), 2, 64, 8, jnp.float32, True, None, (16, 16)),
+    "t_q<t_k-bf16": ((1, 2, 32, 16), 1, 96, 16, jnp.bfloat16, True, None, (16, 32)),
+    # right-aligned: the first 24 query rows see no key, their dQ is zero
+    "masked-rows": ((2, 2, 48, 8), 2, 24, 8, jnp.float32, True, None, (8, 8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DQ_CASES))
+def test_flash_bwd_sums_dq_across_key_blocks(rng, case):
+    """The one backward kernel's dQ (and dK, dV beside it) against jax's
+    gradient of ``attention_reference``, where a query row's terms come from
+    three or more grid steps and, in the second head on, after another head
+    has used the accumulator."""
+    from fedml_tpu.ops.attention import _flash_bwd, _flash_fwd
+
+    q_shape, kv_heads, t_k, d_v, dtype, causal, window, blocks = _DQ_CASES[case]
+    b, h, t_q, d = q_shape
+    assert t_k // blocks[1] >= 3 and b * h >= 2
+    q, g = (jnp.asarray(rng.randn(b, h, t_q, w), dtype) for w in (d, d_v))
+    k, v = (jnp.asarray(rng.randn(b, kv_heads, t_k, w), dtype) for w in (d, d_v))
+    sm_scale = d ** -0.5
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, *blocks, True, window)
+    got = _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, True, window)
+    if t_q > t_k:  # the kernels' zero output for rows that see nothing
+        def reference(q, k, v):
+            return _masked_reference(q, k, v, causal)[0]
+    else:
+        def reference(q, k, v):
+            return attention_reference(q, k, v, causal=causal, window=window)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    want = jax.vjp(reference, *f32)[1](g.astype(jnp.float32))
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    for name, a, w, x in zip(("dq", "dk", "dv"), got, want, (q, k, v)):
+        assert a.dtype == dtype and a.shape == x.shape, name
+        np.testing.assert_allclose(a.astype(jnp.float32), w, atol=tol * 10, rtol=tol,
+                                   err_msg=name)
+    if t_q > t_k:
+        np.testing.assert_array_equal(np.asarray(got[0][:, :, :t_q - t_k]), 0.0)
+
+
+def test_flash_bwd_sums_dq_under_a_cohort_vmap(rng, monkeypatch):
+    """The vmapped cohort adds a grid axis in front: the key-block axis the
+    accumulator's first and last steps are told by moves with it."""
+    import fedml_tpu.ops.attention as att
+
+    monkeypatch.setattr(att, "_bwd_blocks", lambda *a: (16, 16))
+    q = jnp.asarray(rng.randn(3, 1, 4, 64, 8), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(3, 1, 2, 64, 8), jnp.float32) for _ in range(2))
+
+    def grads(fn):
+        return jax.vmap(jax.grad(lambda *x: jnp.sum(fn(*x) ** 2), argnums=(0, 1, 2)))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16, 24))
+    want = grads(lambda q, k, v: attention_reference(q, k, v, causal=True, window=24))
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4, err_msg=name)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -276,7 +348,7 @@ def test_flash_bwd_path_event(rng):
     events = [e for e in tracer.events() if e["name"] == "attn/bwd_path"]
     assert len(events) == 2
     for e in events:
-        assert e["args"]["impl"] == "kernel"
+        assert e["args"]["impl"] == "fused"
         assert tuple(e["args"]["shape"]) == (2, 2, 32, 8)
         assert tuple(e["args"]["blocks"]) == (32, 32)
 

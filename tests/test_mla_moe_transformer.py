@@ -306,10 +306,12 @@ def test_scopes_kernels_and_notes_in_the_lowered_training_step():
     assert any("jvp(mtp)/fed/loss/" in ln and "transpose(" not in ln for ln in lines)
     assert any("transpose(jvp(mtp))/fed/loss/" in ln for ln in lines)
     assert any("/mtp/mtp_block/" in ln and "attn/mla" in ln for ln in lines)
-    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
         assert any(kernel in ln and "attn/mla" in ln for ln in lines), kernel
+    assert "flash_bwd_dq" not in text
     notes = [n for n in trace.program_notes("attn/call") if n["shape"] == (2, 4, T, 24)]
-    assert {n["kernel"] for n in notes} == {"fwd", "dkv", "dq"}
+    assert {n["kernel"]: n["writes"] for n in notes} == {
+        "fwd": ("out", "lse"), "dkv": ("dq", "dk", "dv")}
     assert all((n["d_qk"], n["d_v"], n["kind"]) == (24, 16, "global") for n in notes)
     kept = {n["kept"]: n for n in trace.program_notes("remat/kept")
             if n["shape"][-2:] in ((T, 24), (T, 16)) and n["shape"][0] == 2}

@@ -168,8 +168,8 @@ def test_the_two_counters(case, tiles):
 # gradient at the parent of the PR that brought the capacity (PR 34; counted
 # there by these equations' walk): 3 gmm forward and 3 gmm + 3 tgmm backward, and
 # under ops/remat.py's policy the down product once more. The flash kernels
-# beside them: one forward, two backward. A value alone: 3 and 1.
-PARENT_KERNELS = {False: {"grouped": 9, "flash": 3}, True: {"grouped": 10, "flash": 3}}
+# beside them: one forward, one backward (two before PR 43). A value alone: 3 and 1.
+PARENT_KERNELS = {False: {"grouped": 9, "flash": 2}, True: {"grouped": 10, "flash": 2}}
 PARENT_KERNELS_VALUE = {"grouped": 3, "flash": 1}
 
 

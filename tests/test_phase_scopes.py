@@ -310,8 +310,9 @@ def test_flash_kernel_is_named_in_the_tpu_lowering(monkeypatch):
 
 @pytest.mark.parametrize("t_q,t_k", [(1024, 1024), (128, 256)])
 def test_flash_backward_kernels_are_named_in_the_tpu_lowering(monkeypatch, t_q, t_k):
-    """``jax.grad`` of flash attention lowers for the TPU to the two Mosaic
-    backward kernels, by name, under the scope ``attn_bwd_time_pct`` reads;
+    """``jax.grad`` of flash attention lowers for the TPU to two Mosaic custom
+    calls an attention, the forward and the one backward kernel, by name,
+    under the scope ``attn_bwd_time_pct`` reads; ``flash_bwd_dq`` is gone, and
     no XLA loop is left under that scope (the plain-XLA backward was two
     ``while`` scans there)."""
     import fedml_tpu.ops.attention as att
@@ -325,12 +326,13 @@ def test_flash_backward_kernels_are_named_in_the_tpu_lowering(monkeypatch, t_q, 
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
         .trace(q, k, k).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert text.count("tpu_custom_call") == 3
-    for kernel in (trace.FLASH_BWD_DKV_KERNEL_NAME, trace.FLASH_BWD_DQ_KERNEL_NAME):
-        assert trace.FLASH_KERNEL_NAME not in kernel  # the forward's readers match on it
-        # "attn/blockwise_bwd/<kernel>" in a model, "transpose(jvp(attn/
-        # blockwise_bwd))/<kernel>" when the gradient is of the op itself
-        assert re.search(rf"{trace.SCOPE_BLOCKWISE_BWD}\)*/{kernel}/", text), kernel
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_bwd_dq" not in text and not hasattr(trace, "FLASH_BWD_DQ_KERNEL_NAME")
+    kernel = trace.FLASH_BWD_DKV_KERNEL_NAME
+    assert trace.FLASH_KERNEL_NAME not in kernel  # the forward's readers match on it
+    # "attn/blockwise_bwd/<kernel>" in a model, "transpose(jvp(attn/
+    # blockwise_bwd))/<kernel>" when the gradient is of the op itself
+    assert re.search(rf"{trace.SCOPE_BLOCKWISE_BWD}\)*/{kernel}/", text), kernel
     assert not re.search(rf"{trace.SCOPE_BLOCKWISE_BWD}\)*/while", text)
     assert re.search(rf"{trace.SCOPE_FLASH_FWD}\)*/{trace.FLASH_KERNEL_NAME}/", text)
 

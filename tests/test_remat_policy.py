@@ -78,7 +78,7 @@ def _kernel_calls(loss, params):
 @pytest.mark.parametrize("family", sorted(MODELS))
 def test_backward_runs_no_second_flash_forward(family, policy, monkeypatch):
     """``num_layers`` forward kernel calls in the gradient's jaxpr, as with
-    remat off, and ``num_layers`` of each backward kernel; under a bare
+    remat off, and ``num_layers`` of the one backward kernel; under a bare
     ``nn.remat`` (the control: what the models did before) twice the forward
     calls. The routed layer's two sorts are not run twice either, and of the
     three grouped products only the last one is."""
@@ -89,7 +89,7 @@ def test_backward_runs_no_second_flash_forward(family, policy, monkeypatch):
     got = _kernel_calls(*_loss_of(build(remat=True)))
     assert plain["flash_fwd"] == layers
     assert got["flash_fwd"] == (2 * layers if policy == "bare" else layers)
-    assert got["flash_bwd_dkv"] == got["flash_bwd_dq"] == layers
+    assert got["flash_bwd_dkv"] == layers and "flash_bwd_dq" not in got
     if family == "moe":
         again = 1 if policy == "bare" else 0
         assert got["sort"] == plain["sort"] * (1 + again) == 2 * layers * (1 + again)
@@ -108,7 +108,7 @@ def test_policy_reaches_the_kernel_under_a_sharded_plan():
     with mesh:
         got = _kernel_calls(*_loss_of(_dense_lm(remat=True)))
     assert got["shard_map"] > 0
-    assert got["flash_fwd"] == got["flash_bwd_dkv"] == got["flash_bwd_dq"] == 2
+    assert got["flash_fwd"] == got["flash_bwd_dkv"] == 2 and "flash_bwd_dq" not in got
 
 
 @pytest.mark.parametrize("kinds", [("global",), ("window",), KINDS])
