@@ -343,6 +343,49 @@ def check_flash_against_reference(shape=KERNEL_SHAPE) -> dict:
     return {n: float(f"{e:.3g}") for n, e in errs.items()}
 
 
+def check_staircase_against_reference(shape=(1, 8, 4096, 128), stair=(1024, 64)) -> dict:
+    """``flash_attention_lse`` under the staircase mask with ``t_q != t_k``
+    (``ops/eva.py``'s remote call): output, log-sum-exp and the three
+    gradients through both against ``attention_reference``."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.attention import attention_reference, flash_attention_lse
+
+    f32 = jnp.float32
+    b, h, t, d = shape
+    t_k = t // stair[0] * stair[1]
+    kq, kk, kv, kg, kl = jax.random.split(jax.random.key(1), 5)
+    q, g = (jax.random.normal(key, shape, jnp.bfloat16) for key in (kq, kg))
+    k, v = (jax.random.normal(key, (b, h, t_k, d), jnp.bfloat16) for key in (kk, kv))
+    g_lse = jax.random.normal(kl, (b, h, t), f32)
+
+    def both(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            seen = lse > -1e20  # the first step's rows see no key
+            return (jnp.sum(out.astype(f32) * g.astype(f32))
+                    + jnp.sum(jnp.where(seen, lse * g_lse, 0.0))), (out, jnp.where(seen, lse, 0.0))
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    flash = both(lambda q, k, v: flash_attention_lse(q, k, v, stair=stair))
+    reference = both(lambda q, k, v: attention_reference(
+        q.astype(f32), k.astype(f32), v.astype(f32), stair=stair, with_lse=True))
+    (_, got_aux), got = flash(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, want_aux), want = reference(q, k, v)
+    errs = {}
+    for name, a, b_ in zip(("out", "lse", "dq", "dk", "dv"), (*got_aux, *got), (*want_aux, *want)):
+        a, b_ = a.astype(f32), b_.astype(f32)
+        assert bool(jnp.all(jnp.isfinite(a))), f"staircase {name} not finite"
+        errs[name] = float(jnp.max(jnp.abs(a - b_)) / jnp.max(jnp.abs(b_)))
+    say(f"  staircase {stair} with lse at {shape} on {t_k} keys bf16, max|diff|/max|ref|: "
+        + ", ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    for name, e in errs.items():
+        assert e <= 2e-2, (name, e)
+    return {n: float(f"{e:.3g}") for n, e in errs.items()}
+
+
 def phase_b() -> dict:
     import jax
 
@@ -360,6 +403,7 @@ def phase_b() -> dict:
             f"lm {label}", FedSim(trainer, train, None, cfg, mesh=one))[0]
         free_device_memory()
     out["kernel_rel_err"] = check_flash_against_reference()
+    out["staircase_rel_err"] = check_staircase_against_reference()
     free_device_memory()
     return out
 

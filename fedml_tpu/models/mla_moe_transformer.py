@@ -74,6 +74,22 @@ and "gqa", ``models/moe_transformer.py``'s :class:`GroupedAttention` with
 an RMSNorm of q and of k a head (one scale of ``head_dim`` each) and then
 rotate-half rotary positions at ``rope_theta``, every earlier key visible.
 
+A fifth mixer, "eva", makes the EvaByte block (every layer dense:
+``routed_layers`` 0; ``norm_unit_offset``: every RMSNorm is ``x / rms(x) * (1
++ g)`` with ``g`` from zero; ``num_pred_heads`` next-byte heads on one stream):
+EVA attention (:class:`EvaAttention`; the mathematics is ``ops/eva.py``'s), H
+heads of ``head_dim`` columns with rotate-half positions on q and k:
+
+    q, k, v = h W_q, h W_k, h W_v;  q, k = rope(q), rope(k)
+    k~, v~  = chunk summaries of k, v by the head's learned phi and mu
+    o_i     = one softmax over the keys j <= i of query i's own ``eva_window``
+              and the summaries of every ``eva_chunk`` of the windows before it
+    x1      = x + concat_heads(o) W_o
+
+With ``num_pred_heads`` P > 1 the head has P x V columns and the logits are
+``[B, T, P, V]``: head p at position t predicts token t + 1 + p, and the
+trainer's ``lm_loss`` takes ``y`` and ``mask`` of ``[B, T, P]`` as they are.
+
 Same interface as the rest of the zoo: int tokens ``[B, T]`` in, logits
 ``[B, T, V]`` float32 out, ``train`` kwarg. ``train=False`` builds no MTP
 logits.
@@ -81,6 +97,7 @@ logits.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Sequence
 
 import flax.linen as nn
@@ -88,12 +105,13 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
-from fedml_tpu.models.moe_transformer import GroupedAttention, Kernel, RMSNorm, RoutedExperts
+from fedml_tpu.models.moe_transformer import (
+    GroupedAttention, Kernel, RMSNorm, RoutedExperts, rope as rope_half)
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import kda, moe, remat, shortconv
+from fedml_tpu.ops import eva, kda, moe, remat, shortconv
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
 
-MLA, KDA, CONV, GQA = "mla", "kda", "conv", "gqa"
+MLA, KDA, CONV, GQA, EVA = "mla", "kda", "conv", "gqa", "eva"
 
 
 def rope_interleaved(x, theta: float):
@@ -230,6 +248,38 @@ class ShortConv(nn.Module):
             return dense("out", d, y)
 
 
+class EvaAttention(nn.Module):
+    """EVA attention (the module docstring's equations): ``num_heads`` heads
+    of ``head_dim`` columns, ``adaptive_phi`` and ``adaptive_mu_k`` one vector
+    a head each, ``[num_heads, head_dim]`` leaves named ``kernel``."""
+
+    num_heads: int
+    head_dim: int
+    window: int
+    chunk: int
+    rope_theta: float
+    attn_impl: str = "xla"  # xla: the whole score matrix | flash: two kernel calls merged
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, _ = h.shape
+        n, d = self.num_heads, self.head_dim
+
+        def heads(name):  # [B, T, D] -> [B, n, T, d]
+            y = nn.Dense(n * d, use_bias=False, name=name, dtype=self.dtype)(h)
+            return y.reshape(b, t, n, d).transpose(0, 2, 1, 3)
+
+        with jax.named_scope(trace.SCOPE_EVA):
+            q, k = rope_half(heads("q"), self.rope_theta), rope_half(heads("k"), self.rope_theta)
+            v = heads("v")
+            phi, mu = Kernel((n, d), name="adaptive_phi")(), Kernel((n, d), name="adaptive_mu_k")()
+            o, mass = eva.eva_attention(q, k, v, phi, mu, window=self.window, chunk=self.chunk,
+                                        impl=self.attn_impl)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, n * d)
+            return nn.Dense(h.shape[-1], use_bias=False, name="o", dtype=self.dtype)(o), mass
+
+
 class GatedMLP(nn.Module):
     """``(silu(u G) * (u U)) D``: the leading dense layer's feed-forward and
     the shared expert."""
@@ -271,14 +321,22 @@ class MLABlock(nn.Module):
     kda_head_dim: int = 0
     conv_size: int = 4  # taps of the KDA and CONV mixers' convolutions
     kv_heads: int = 0  # of the GQA mixer, whose query heads are ``num_heads``
-    head_dim: int = 0
+    head_dim: int = 0  # of the GQA and EVA mixers
+    eva_window: int = 0
+    eva_chunk: int = 0
+    norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
-        h = RMSNorm(self.rms_eps, self.dtype, name="norm_attn")(x)
+        h = RMSNorm(self.rms_eps, self.dtype, self.norm_unit_offset, name="norm_attn")(x)
         mixer_stats = {}
-        if self.mixer == KDA:
+        if self.mixer == EVA:
+            mixed, mass = EvaAttention(
+                self.num_heads, self.head_dim, self.eva_window, self.eva_chunk, self.rope_theta,
+                self.attn_impl, self.dtype, name="attn")(h)
+            mixer_stats = {"eva/remote_mass": mass}
+        elif self.mixer == KDA:
             mixed, floor = DeltaAttention(
                 self.kda_heads, self.kda_head_dim, self.conv_size, self.rms_eps,
                 self.attn_impl, self.dtype, name="attn")(h)
@@ -297,10 +355,14 @@ class MLABlock(nn.Module):
                 self.v_dim, self.rope_theta, self.rms_eps, self.attn_impl, self.dtype,
                 name="attn")(h)
         x = x + mixed
-        u = RMSNorm(self.rms_eps, jnp.float32, name="norm_ffn")(x)
+        u = RMSNorm(self.rms_eps, jnp.float32, self.norm_unit_offset, name="norm_ffn")(x)
         if not self.routed:
-            return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), (
-                mixer_stats)
+            # the feed-forward bears a scope of its own on the EVA model's path only
+            scope = (jax.named_scope(trace.SCOPE_MLP_DENSE) if self.mixer == EVA
+                     else contextlib.nullcontext())
+            with scope:
+                return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), (
+                    mixer_stats)
         u = u.reshape(b * t, d)
         ids, weights = moe.route(
             u, Kernel((d, self.num_experts), name="router")(), self.experts_per_token,
@@ -321,7 +383,7 @@ class MLAMoETransformerLM(nn.Module):
     """Causal LM of ``dense_layers`` dense then ``routed_layers`` routed
     :class:`MLABlock` layers, with ``mtp_depth`` (0 or 1) multi-token-
     prediction modules of one routed block each. ``mixers`` gives each
-    layer's mixer in order ("mla" | "kda" | "conv" | "gqa"; None: latent
+    layer's mixer in order ("mla" | "kda" | "conv" | "gqa" | "eva"; None: latent
     attention in all). ``shared_dim`` 0: no shared expert. ``tie_head``: the
     logits are the final norm's output times the embedding's transpose (in
     float32, as the embedding is), and the tree has no ``head``."""
@@ -359,24 +421,31 @@ class MLAMoETransformerLM(nn.Module):
     # policy, as MoETransformerLM.remat
     remat: bool = False
     kv_heads: int = 2  # of the "gqa" mixer, whose query heads are ``num_heads``
-    head_dim: int = 16
+    head_dim: int = 16  # of the "gqa" and "eva" mixers
     tie_head: bool = False
+    eva_window: int = 32  # of the "eva" mixer: positions a window, positions a chunk
+    eva_chunk: int = 4
+    norm_unit_offset: bool = False  # every RMSNorm as x / rms(x) * (1 + g)
+    num_pred_heads: int = 1  # P > 1: the head has P x V columns, logits [B, T, P, V]
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         if self.mtp_depth not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         embed = nn.Embed(self.vocab_size, self.embed_dim, name="tok_embed")
+        if self.tie_head and self.num_pred_heads != 1:
+            raise ValueError("a tied head predicts one token a position")
         head = None if self.tie_head else nn.Dense(
-            self.vocab_size, use_bias=False, name="head", dtype=self.head_dtype)
+            self.num_pred_heads * self.vocab_size, use_bias=False, name="head",
+            dtype=self.head_dtype)
         held = self.num_experts if self.experts_held is None else self.experts_held
         block_cls = remat.block(MLABlock) if self.remat else MLABlock
 
         layers = self.dense_layers + self.routed_layers
         mixers = (MLA,) * layers if self.mixers is None else tuple(self.mixers)
-        if len(mixers) != layers or set(mixers) - {MLA, KDA, CONV, GQA}:
+        if len(mixers) != layers or set(mixers) - {MLA, KDA, CONV, GQA, EVA}:
             raise ValueError(
-                f"mixers must name {layers} layers' mixers, each mla, kda, conv or gqa")
+                f"mixers must name {layers} layers' mixers, each mla, kda, conv or gqa, or eva")
 
         def block(routed, name, mixer=MLA):
             return block_cls(
@@ -385,14 +454,17 @@ class MLAMoETransformerLM(nn.Module):
                 self.expert_dim, self.shared_dim, self.route_scale, self.experts_first, held,
                 self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, mixer,
                 self.kda_heads, self.kda_head_dim, self.conv_size, self.kv_heads, self.head_dim,
-                name=name)
+                self.eva_window, self.eva_chunk, self.norm_unit_offset, name=name)
 
         def logits(h, norm):
-            h = RMSNorm(self.rms_eps, self.head_dtype, name=norm)(h)
+            h = RMSNorm(self.rms_eps, self.head_dtype, self.norm_unit_offset, name=norm)(h)
             if self.tie_head:
                 with jax.named_scope(trace.SCOPE_HEAD):
                     return embed.attend(h).astype(jnp.float32)
-            return head(h).astype(jnp.float32)
+            out = head(h).astype(jnp.float32)
+            if self.num_pred_heads == 1:
+                return out
+            return out.reshape(*out.shape[:-1], self.num_pred_heads, self.vocab_size)
 
         h = embed(x)  # the residual stream stays float32: the router reads it
         stats = []
@@ -415,8 +487,9 @@ class MLAMoETransformerLM(nn.Module):
                           "weight": jnp.float32(self.mtp_loss_weight)},
                          reduce_fn=lambda _, new: new, init_fn=lambda: None)
         # for the engine's counters: one value a routed block (the MTP module's
-        # last) under "moe", one a delta-attention block under "kda"
-        for group in ("moe", "kda"):
+        # last) under "moe", one a delta-attention block under "kda", one an
+        # EVA block under "eva"
+        for group in ("moe", "kda", "eva"):
             found = [{k: v for k, v in s.items() if k.startswith(group + "/")} for s in stats]
             found = [s for s in found if s]
             if found:

@@ -46,13 +46,17 @@ GLOBAL, WINDOW = "global", "window"
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32  # of the output; the statistics are float32
+    # x / rms(x) * (1 + g): the learned ``scale`` starts at zero and is an
+    # offset from one (EvaByte's ``norm_add_unit_offset``)
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        init = nn.initializers.zeros if self.unit_offset else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],))
         x = x.astype(jnp.float32)
         y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(self.dtype)
+        return (y * (1.0 + scale if self.unit_offset else scale)).astype(self.dtype)
 
 
 def rope(x, theta: float):

@@ -69,7 +69,8 @@ __all__ = [
     "program_note", "program_notes", "last_counters", "loop",
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
-    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "SHORTCONV_SCOPES", "LOOP_SCOPES",
+    "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "SHORTCONV_SCOPES", "EVA_SCOPES",
+    "LOOP_SCOPES",
     "LOOP_CARRY_NOTE",
     "COHORT_AGGREGATE_NOTE",
     "FLASH_KERNEL_NAME",
@@ -133,6 +134,17 @@ SCOPE_SHORTCONV_GATE = "mix/shortconv/gate"
 SCOPE_GQA = "attn/gqa"
 SCOPE_HEAD = "head"
 SHORTCONV_SCOPES = (SCOPE_SHORTCONV, SCOPE_SHORTCONV_GATE, SCOPE_GQA, SCOPE_HEAD)
+# Scopes of the decoder whose mixer is EVA attention (the "eva" mixer of
+# models/mla_moe_transformer.py; ops/eva.py), inside SCOPE_FWD_BWD: the mixer
+# whole (four projections, the rotation, the summaries, the two flash calls
+# and their merge); inside it the chunk summaries alone and the merge of the
+# two calls by their log-sum-exps, forward and backward; and, on this model's
+# path only, the dense feed-forward's three products and its gate
+SCOPE_EVA = "attn/eva"
+SCOPE_EVA_SUMMARY = "attn/eva/summary"
+SCOPE_EVA_MERGE = "attn/eva/merge"
+SCOPE_MLP_DENSE = "mlp/dense"
+EVA_SCOPES = (SCOPE_EVA, SCOPE_EVA_SUMMARY, SCOPE_EVA_MERGE, SCOPE_MLP_DENSE)
 # The loops of a round's path (sim/engine.py, core/trainer.py), opened by
 # :func:`loop` around the call that makes the loop and nothing wider. Never
 # under ``fed/``: the ops inside keep their phase (a reader classes an op by

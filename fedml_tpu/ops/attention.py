@@ -16,6 +16,13 @@ may hold fewer heads than Q (grouped KV heads: query head ``n`` reads KV head
 ``n // (H // H_kv)``), and ``window`` limits a query to the last ``window``
 keys up to and including its own position; both are static, and with equal
 head counts and ``window=None`` the kernels are the programs they were.
+A third mask, ``stair=(s_q, s_k)``, shows key ``j`` to query ``i`` iff ``j <
+s_k * (i // s_q)``: whole ``s_q x s_k`` steps of a staircase, for keys that
+summarise the windows before the query's own (``ops/eva.py``), where ``t_q``
+and ``t_k`` differ; a row that sees no key gives zeros and a log-sum-exp of
+about ``NEG_INF / 2``. :func:`flash_attention_lse` hands the log-sum-exp out
+as a second, differentiable output, so that two calls over two key sets can
+share one softmax.
 Forward runs the pallas kernel ``flash_fwd``, which also writes each query
 row's log-sum-exp (``B*H*T`` f32, the backward's one extra residual);
 backward is a custom VJP of one more pallas kernel, ``flash_bwd_dkv`` (a key
@@ -86,7 +93,8 @@ def _pick_block(t: int, preferred: int, dtype) -> int:
 
 
 def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = None,
-                        window: int | None = None):
+                        window: int | None = None, stair: tuple | None = None,
+                        with_lse: bool = False):
     """Plain XLA attention, the numerical oracle for the kernels.
 
     Causal convention (shared with the pallas kernel): query i attends to
@@ -96,7 +104,11 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
     sees at most ``window`` keys and ``window >= t_k`` is plain causal. K and V
     with fewer heads than Q are grouped: query head n reads KV head
     n // (H // H_kv). q and k share the scores' width ``D_qk``; v and the
-    output have the values' width ``D_v``, which may be another."""
+    output have the values' width ``D_v``, which may be another.
+    ``stair=(s_q, s_k)`` (without ``causal``): key j is visible to query i iff
+    j < s_k * (i // s_q), both counted from 0; a row that sees no key gives
+    zeros. ``with_lse``: ``(out, lse [B, H, T_q] float32)``, the log-sum-exp
+    of each row's visible scores (about ``NEG_INF`` where it sees none)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     group = _kv_group(q.shape[1], k.shape[1])
@@ -110,8 +122,16 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask, s, NEG_INF)
+    _check_stair(stair, causal)
+    if stair:
+        steps = jnp.arange(s.shape[-2])[:, None] // stair[0]
+        mask = jnp.arange(s.shape[-1])[None] < stair[1] * steps
+        s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+    if stair:
+        p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+    return (out, jax.nn.logsumexp(s, axis=-1)) if with_lse else out
 
 
 def _kv_group(h: int, h_kv: int) -> int:
@@ -124,6 +144,11 @@ def _kv_group(h: int, h_kv: int) -> int:
 def _check_window(window, causal) -> None:
     if window is not None and (not causal or window < 1):
         raise ValueError("attention: a window needs causal=True and window >= 1")
+
+
+def _check_stair(stair, causal) -> None:
+    if stair is not None and (causal or len(stair) != 2 or min(stair) < 1):
+        raise ValueError("attention: stair=(s_q, s_k) of whole steps >= 1 excludes causal")
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +197,7 @@ def _fold(p, lanes):
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
-    *, block_k, causal, sm_scale, window=None,
+    *, block_k, causal, sm_scale, window=None, stair=None,
 ):
     # q_ref: [block_q, D_qk]; k_ref: [T_k, D_qk] and v_ref: [T_k, D_v] (the
     # head's whole sequence); o_ref: [block_q, D_v]; lse_ref: [1, block_q];
@@ -197,19 +222,24 @@ def _flash_fwd_kernel(
     # key yet (its window opens in a later tile) gets exp(-5e29) = 0 for every
     # hidden pair, where a start at NEG_INF would give exp(0)
     m_acc[:] = jnp.full_like(m_acc, NEG_INF / 2)
-    if causal:
+    if causal or stair:
         # a tile's mask from one value a row and one a column, so no
         # [block_q, block_k] index array is ever made: a cut tile costs a
         # compare and a select an element (two compares under a window)
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
         k_idx = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    if stair:
+        # the keys each row of this query block sees: s_k a whole step below it
+        seen_to = stair[1] * _step_of(q_idx + iq * block_q, stair[0])
 
     def step(masked, j):
         cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
         k = k_ref[cols, :]
         v = v_ref[cols, :]
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
-        if masked:
+        if masked and stair:
+            s = jnp.where(k_idx < seen_to - j * block_k, s, NEG_INF)
+        elif masked:
             # the tile's column of query i's own position
             diag = q_idx + (off + iq * block_q - j * block_k)
             seen = k_idx <= diag
@@ -239,6 +269,12 @@ def _flash_fwd_kernel(
             loop(start, whole_start, True)
         loop(whole_start, whole_end, False)
         loop(whole_end, last, True)
+    elif stair:
+        # the key blocks every row sees whole, then the ones a step's edge cuts
+        _, _, whole_end, last = _stair_kb_ranges(iq, block_q, block_k, num_kb, stair)
+        loop(0, whole_end, False)
+        if _stair_cuts(block_q, block_k, stair):
+            loop(whole_end, last, True)
     else:
         loop(0, num_kb, False)
     l = jnp.maximum(jnp.sum(l_acc[:], axis=-1, keepdims=True), 1e-20)
@@ -299,7 +335,7 @@ def _mosaic_params(dtype, *whole_sequences, scratch=(), buffers=2):
     return pltpu.CompilerParams(vmem_limit_bytes=held + 8 * 2 ** 20)
 
 
-def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None):
+def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None, stair=None):
     """The forward kernel's ``(block_q, block_k)``: the caller's where it
     names them (the tests' toy tiles), else 512 x 512, the backward's tile
     too. Measured on the v5e at D 128 bf16 causal, ms a call (PERF.md §6, PR
@@ -323,19 +359,28 @@ def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None):
     T 8192 global layer alone and 0.5% over that model's one global and three
     window layers, and level with 512 x 512 at 192 | 128: not worth a rule.
     Both fit Mosaic's default 16 MB of VMEM beside a head's whole K and V at
-    T 8192 (what ``_mosaic_params`` asks at 192 | 128); 1024 x 1024 does not."""
-    return (_pick_block(t_q, block_q or 512, dtype), _pick_block(t_k, block_k or 512, dtype))
+    T 8192 (what ``_mosaic_params`` asks at 192 | 128); 1024 x 1024 does not.
+    Under a staircase a block of its own choosing is no longer than a step
+    where the step is a whole number of sublane tiles, so that no tile is cut
+    (steps of 2048 x 128: 512 x 128, 6 of the 16 step-by-step blocks visited)."""
+    want_q, want_k = block_q or 512, block_k or 512
+    if stair:
+        sublane = 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+        want_q = want_q if block_q or stair[0] % sublane else min(want_q, stair[0])
+        want_k = want_k if block_k or stair[1] % sublane else min(want_k, stair[1])
+    return _pick_block(t_q, want_q, dtype), _pick_block(t_k, want_k, dtype)
 
 
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, stair=None):
     """``(out [B, H, T, D_v], lse [B, H, T] f32)``; ``block_q`` / ``block_k``
     of ``None`` are chosen by :func:`_fwd_blocks`."""
     b, h, t, d = q.shape
     h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
     _check_window(window, causal)
-    block_q, block_k = _fwd_blocks(t, t_k, q.dtype, block_q, block_k)
+    _check_stair(stair, causal)
+    block_q, block_k = _fwd_blocks(t, t_k, q.dtype, block_q, block_k, stair)
     nq = t // block_q
     lanes = 1 if block_k % 128 else 128  # of the running max and sum, see the kernel
     qf = q.reshape(b * h, t, d)
@@ -347,8 +392,9 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
         causal=causal,
         sm_scale=sm_scale,
         window=window,
+        **({"stair": stair} if stair else {}),
     )
-    _note_call("fwd", q, t_k, d_v, group, causal, window, block_q, block_k)
+    _note_call("fwd", q, t_k, d_v, group, causal, window, block_q, block_k, stair)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq),
@@ -377,10 +423,15 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
 # ---------------------------------------------------------------------------
 
 
-def _visible(q_lo, k_lo, shape, window=None):
+def _visible(q_lo, k_lo, shape, window=None, stair=None):
     """Mask of one transposed score tile, [keys, queries]: key position <=
     query position (which carries the right-aligned offset) and, under a
-    window, > query position - window."""
+    window, > query position - window; under a staircase, key position <
+    s_k * (query position // s_q), from one value a column and one a row."""
+    if stair:
+        q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+        return k_pos < stair[1] * _step_of(q_pos, stair[0])
     q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     if window is None:
@@ -394,6 +445,17 @@ def _clip(x, lo, hi):
     if all(isinstance(a, int) for a in (x, lo, hi)):
         return max(lo, min(x, hi))
     return jnp.clip(x, lo, hi)
+
+
+def _step_of(pos, s_q):
+    """``pos // s_q`` for positions >= 0, ints or a kernel's int32 values: a
+    shift where ``s_q`` is a power of two (every published window), else
+    ``lax.div``, which truncates as it floors there."""
+    if isinstance(pos, int):
+        return pos // s_q
+    if s_q & (s_q - 1) == 0:
+        return jax.lax.shift_right_logical(pos, jnp.int32(s_q.bit_length() - 1))
+    return jax.lax.div(pos, jnp.int32(s_q))
 
 
 # Which tiles a grid step visits. Rows of query block i are off + i * block_q
@@ -436,14 +498,49 @@ def _dkv_qb_ranges(jk, block_q, block_k, off, num_qb, window):
     return first, first_whole, end_whole, end
 
 
-def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
+# Under ``stair=(s_q, s_k)`` key j is visible to query i iff j < s_k * (i //
+# s_q): no offset, both counted from 0. A tile is cut only where a step's
+# edge crosses it, which none does when the blocks divide the steps.
+
+
+def _stair_cuts(block_q, block_k, stair) -> bool:
+    """Whether any visited tile can need mask arithmetic."""
+    return bool(stair[0] % block_q or stair[1] % block_k)
+
+
+def _stair_kb_ranges(iq, block_q, block_k, num_kb, stair):
+    """:func:`_fwd_kb_ranges` under a staircase: ``[0, whole_end)`` are seen
+    whole by every row of query block ``iq``, ``[whole_end, last)`` by some."""
+    s_q, s_k = stair
+    to_all = s_k * _step_of(iq * block_q, s_q)
+    to_some = s_k * _step_of((iq + 1) * block_q - 1, s_q)
+    whole_end = _clip(to_all // block_k, 0, num_kb)
+    return 0, 0, whole_end, _clip((to_some + block_k - 1) // block_k, whole_end, num_kb)
+
+
+def _stair_qb_ranges(jk, block_q, block_k, num_qb, stair):
+    """:func:`_dkv_qb_ranges` under a staircase: query blocks ``[first,
+    first_whole)`` see key block ``jk`` in part, ``[first_whole, num_qb)`` whole."""
+    s_q, s_k = stair
+    q_some = s_q * (_step_of(jk * block_k, s_k) + 1)  # the first query that sees its first key
+    q_all = s_q * (_step_of((jk + 1) * block_k - 1, s_k) + 1)  # ... and its last
+    first = _clip(q_some // block_q, 0, num_qb)
+    first_whole = _clip((q_all + block_q - 1) // block_q, first, num_qb)
+    return first, first_whole, num_qb, num_qb
+
+
+def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k, stair=None):
     """Record, while the program is traced, what one attention call will do:
     its kind, its two widths, its grouping, how many of the square's tiles
     the kernel visits and what it ``writes`` (``obs/trace.py``
     :func:`program_note`; docs/OBSERVABILITY.md)."""
     t_q = q.shape[2]
     nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
-    if not causal:
+    if stair and kernel == "dkv":
+        ranges = [_stair_qb_ranges(j, block_q, block_k, nq, stair) for j in range(nk)]
+    elif stair:
+        ranges = [_stair_kb_ranges(i, block_q, block_k, nk, stair) for i in range(nq)]
+    elif not causal:
         ranges = [(0, 0, nk, nk)] * nq
     elif kernel == "dkv":
         ranges = [_dkv_qb_ranges(j, block_q, block_k, off, nq, window) for j in range(nk)]
@@ -455,8 +552,9 @@ def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
     trace.program_note(
         "attn/call", kernel=kernel,
         writes=("dq", "dk", "dv") if kernel == "dkv" else ("out", "lse"),
-        kind="window" if window is not None else "global" if causal else "full",
-        window=window, shape=tuple(q.shape), t_k=t_k, d_qk=q.shape[3], d_v=d_v,
+        kind=("stair" if stair else "window" if window is not None
+              else "global" if causal else "full"),
+        window=window, stair=stair, shape=tuple(q.shape), t_k=t_k, d_qk=q.shape[3], d_v=d_v,
         q_heads_per_kv_head=group,
         dtype=jnp.dtype(q.dtype).name, tile=(block_q, block_k),
         tiles_visited=visited, tiles_masked=masked, tiles_total=nq * nk,
@@ -465,7 +563,7 @@ def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k):
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None,
+    dq_acc, dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None, stair=None,
 ):
     # k_ref/dk_ref: [block_k, D_qk]; v_ref/dv_ref: [block_k, D_v]; q_ref/dq_ref:
     # [T_q, D_qk] and do_ref: [T_q, D_v] (the head's whole sequence);
@@ -500,10 +598,8 @@ def _flash_bwd_dkv_kernel(
         if masked:
             # select, not multiply: a fully masked row's lse is about
             # NEG_INF and exp() of its scores is inf
-            p = jnp.where(
-                _visible(off + i * block_q, jk * block_k, s.shape, window),
-                p, 0.0,
-            )
+            q_lo = i * block_q if stair else off + i * block_q
+            p = jnp.where(_visible(q_lo, jk * block_k, s.shape, window, stair), p, 0.0)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
         ds = (p * (dp - delta_ref[i]) * sm_scale).astype(q.dtype)
         dv_acc[:] += jax.lax.dot_general(
@@ -526,6 +622,12 @@ def _flash_bwd_dkv_kernel(
         loop(first_whole, end_whole, False)
         if window is not None:
             loop(end_whole, end, True)
+    elif stair:
+        # query blocks a step's edge cuts, then the ones that see the key block whole
+        first, first_whole, _, _ = _stair_qb_ranges(jk, block_q, block_k, num_qb, stair)
+        if _stair_cuts(block_q, block_k, stair):
+            loop(first, first_whole, True)
+        loop(first_whole, num_qb, False)
     else:
         loop(0, num_qb, False)
     dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
@@ -536,7 +638,7 @@ def _flash_bwd_dkv_kernel(
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
+def _bwd_blocks(t_q, t_k, dtype, fwd_blocks, stair=None):
     """The backward kernel's ``(block_q, block_k)``. Measured on the v5e, bf16
     causal, ms a backward (the kernel, the ``rowsum(dO * O)`` fusion and a KV
     group's sum; PERF.md §6, PR 43; the columns are :func:`_fwd_blocks`' and
@@ -566,12 +668,14 @@ def _bwd_blocks(t_q, t_k, dtype, fwd_blocks):
         except ValueError:
             return _pick_block(t, fwd_block, dtype)
 
+    if stair:  # the forward's, which cut no tile where the steps allow it
+        return fwd_blocks
     return pick(t_q, fwd_blocks[0]), pick(t_k, fwd_blocks[1])
 
 
 @jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
 def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
-               window=None):
+               window=None, stair=None, g_lse=None):
     """``(dq, dk, dv)`` from one kernel; ``block_q`` / ``block_k`` divide
     ``t_q`` / ``t_k`` (:func:`_bwd_blocks` picks them through
     :func:`_pick_block`). Under grouped KV heads ``flash_bwd_dkv`` writes each
@@ -581,8 +685,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
     nq, nk = t_q // block_q, t_k // block_k
-    # D_i = rowsum(dO * O)
+    # D_i = rowsum(dO * O); the log-sum-exp's own cotangent, where it was
+    # handed out, is a term of it: d lse_i / d s_ij = p_ij
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
     args = (
         q.reshape(b * h, t_q, d), k.reshape(b * h_kv, t_k, d), v.reshape(b * h_kv, t_k, d_v),
         g.reshape(b * h, t_q, d_v),
@@ -591,11 +698,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     # a head's every [1, block_q] tile of lse / delta, resident like q
     rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
     part = jnp.float32 if group > 1 else None  # a query head's part of dK, dV
-    _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k)
+    _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k, stair)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale,
-            window=window,
+            window=window, **({"stair": stair} if stair else {}),
         ),
         grid=(b * h, nk),
         in_specs=[_head_seq(t_q, d, buffers=1), _head_block(block_k, d, group),
@@ -651,6 +758,55 @@ def flash_attention(
         sm_scale = q.shape[-1] ** -0.5
     interpret = _interpret_on(jax.default_backend())
     return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def flash_attention_lse(q, k, v, causal=False, sm_scale=None, block_q=None, block_k=None,
+                        window=None, stair=None, keep=None):
+    """:func:`flash_attention` with each row's log-sum-exp as a second,
+    differentiable output: ``(out [B, H, T, D_v], lse [B, H, T] float32)``,
+    from the same two kernels (the forward writes the log-sum-exp anyway; its
+    cotangent enters the backward as a term of the row's ``delta``). Two
+    calls over two key sets then share one softmax: ``ops/eva.py`` merges
+    them. ``stair=(s_q, s_k)`` is the staircase mask of
+    :func:`attention_reference`. ``keep``: the five names a rematerialised
+    block keeps this call's q, k, v, out and lse under (``None``:
+    ``remat.ATTN_RESIDUALS``)."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    interpret = _interpret_on(jax.default_backend())
+    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, stair)
+
+
+def _lse_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window, stair, keep):
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    interpret = _interpret_on(jax.default_backend())
+    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, stair)
+    # kept by a rematerialised block (ops/remat.py), whose backward then has
+    # no use for a second forward call, nor for the projections and rotary
+    # positions that made q, k and v
+    q, k, v, out, lse = (remat.keep(name, x) for name, x in zip(
+        keep or remat.ATTN_RESIDUALS, (q, k, v, out, lse)))
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _lse_bwd_rule(causal, sm_scale, block_q, block_k, window, stair, keep, res, g):
+    q, k, v, out, lse = res
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype, _fwd_blocks(
+        q.shape[2], k.shape[2], q.dtype, block_q, block_k, stair), stair)
+    trace.event(
+        "attn/bwd_path", impl="fused", shape=tuple(q.shape), t_k=k.shape[2],
+        dtype=jnp.dtype(q.dtype).name, blocks=blocks,
+    )
+    interpret = _interpret_on(jax.default_backend())
+    return _flash_bwd(q, k, v, out, lse, g[0], causal, sm_scale, *blocks, interpret, window,
+                      stair, g_lse=g[1])
+
+
+flash_attention_lse.defvjp(_lse_fwd_rule, _lse_bwd_rule)
 
 
 def flash_attention_head_parallel(
@@ -728,30 +884,12 @@ def flash_attention_head_parallel(
 
 
 def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window):
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    interpret = _interpret_on(jax.default_backend())
-    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)
-    # kept by a rematerialised block (ops/remat.py), whose backward then has
-    # no use for a second forward call, nor for the projections and rotary
-    # positions that made q, k and v
-    q, k, v, out, lse = (
-        remat.keep(name, x) for name, x in zip(remat.ATTN_RESIDUALS, (q, k, v, out, lse)))
-    return out, (q, k, v, out, lse)
+    (out, _), res = _lse_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window, None, None)
+    return out, res
 
 
 def _bwd_rule(causal, sm_scale, block_q, block_k, window, res, g):
-    q, k, v, out, lse = res
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    blocks = _bwd_blocks(q.shape[2], k.shape[2], q.dtype,
-                         _fwd_blocks(q.shape[2], k.shape[2], q.dtype, block_q, block_k))
-    trace.event(
-        "attn/bwd_path", impl="fused", shape=tuple(q.shape), t_k=k.shape[2],
-        dtype=jnp.dtype(q.dtype).name, blocks=blocks,
-    )
-    interpret = _interpret_on(jax.default_backend())
-    return _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, *blocks, interpret, window)
+    return _lse_bwd_rule(causal, sm_scale, block_q, block_k, window, None, None, res, (g, None))
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)

@@ -47,6 +47,14 @@ itself, ``B * z``, the taps and ``C * c``, costs less to run again than its
 values cost to hold). A grouped-query attention block keeps the flash
 kernels' five, as every attention block does.
 
+An EVA block (``EvaAttention`` there; ``ops/eva.py``) keeps the residuals of
+its two flash calls under names of its own (``ops/attention.py``
+``flash_attention_lse``'s ``keep``): q, k, v and the local call's output and
+log-sum-exp, the chunk summaries and the remote call's output and
+log-sum-exp; q is one array under two shapes and is kept once. The summaries
+and the merge cost little and are remade only as far as the backward reads
+them.
+
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
 The list is fixed here and follows no option: a name costs memory, and what
@@ -81,8 +89,13 @@ KDA_KEPT = (KDA_Q, KDA_K, KDA_V, KDA_OUT, KDA_STATES)
 # models/mla_moe_transformer.py ShortConv: the input projection's [B | C | z]
 SHORTCONV_IN = "shortconv/in"
 
+# ops/eva.py: the local and the remote flash call's residuals, in
+# ATTN_RESIDUALS' order (q, k, v, out, lse); q is one array under two shapes
+EVA_LOCAL = ("eva/q", "eva/k", "eva/v", "eva/local_out", "eva/local_lse")
+EVA_REMOTE = ("eva/q", "eva/k_sum", "eva/v_sum", "eva/remote_out", "eva/remote_lse")
+
 KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS,
-        *KDA_KEPT, SHORTCONV_IN)
+        *KDA_KEPT, SHORTCONV_IN, *dict.fromkeys(EVA_LOCAL + EVA_REMOTE))
 NOTE = "remat/kept"
 
 

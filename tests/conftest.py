@@ -47,11 +47,15 @@ def rng():
 # file the benchmark has is not a later PR's to edit. That holds for the table
 # too, so a PR that appends a cell after such a test was written names the test
 # here; the next ``benchmark`` PR moves the entry into the table.
-# test_benchmark_loop_reduce.py (PR 40) holds the manifest to its five cells.
+# test_benchmark_loop_reduce.py (PR 40) holds the manifest to its five cells;
+# test_benchmark_lfm2.py (PR 42) reads its entries as the lists' last.
 READS_TAILS_SINCE = {
     ("test_benchmark_loop_reduce", "test_manifest_lists_the_five_for_the_cells_they_read"): {
         "configs": "kimi_linear_48b_a3b_cut", "workloads": "kimilinear_silo2",
         "per_layer": "loop_steps_carry_passes"},
+    ("test_benchmark_lfm2", "test_manifest_entries_and_the_configuration_file"): {
+        "configs": "lfm2_24b_a2b_cut", "workloads": "lfm2moe_silo2",
+        "per_layer": "loop_steps_time_pct_lfm2"},
 }
 
 
