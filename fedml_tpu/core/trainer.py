@@ -31,6 +31,7 @@ import optax
 
 from fedml_tpu.core import scan as scanlib
 from fedml_tpu.obs import trace
+from fedml_tpu.ops.head_loss import HEAD_COLLECTION, HeadOperands, head_loss
 
 Pytree = Any
 Batch = dict[str, jnp.ndarray]
@@ -43,7 +44,8 @@ Batch = dict[str, jnp.ndarray]
 STATS_COLLECTION = "stats"
 STATS_PREFIX = "stats/"
 # A module with a multi-token-prediction head ``sow``s, while training, one
-# entry into this collection: ``{"logits": [B, T, V], "weight": scalar}``,
+# entry into this collection: ``{"logits": [B, T, V], "weight": scalar}``
+# (``logits`` what its head gave: the array or the ``HeadOperands``),
 # position i predicting the token after the next one. ``init``
 # drops it, and ``loss_fn`` adds ``weight * lm_loss`` of those logits against
 # the targets one step further on; metrics and eval are of the main logits.
@@ -59,10 +61,12 @@ def _flat_stats(tree: Pytree) -> dict[str, jnp.ndarray]:
 # ---------------------------------------------------------------------------
 
 
+def _per_unmasked(total: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def _masked_mean(values: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    total = jnp.sum(values * mask)
-    count = jnp.maximum(jnp.sum(mask), 1.0)
-    return total / count
+    return _per_unmasked(jnp.sum(values * mask), mask)
 
 
 def classification_loss(logits: jnp.ndarray, batch: Batch) -> jnp.ndarray:
@@ -83,7 +87,12 @@ def classification_metrics(logits: jnp.ndarray, batch: Batch) -> dict[str, jnp.n
 
 def lm_loss(logits: jnp.ndarray, batch: Batch) -> jnp.ndarray:
     """Next-token loss for [B, T, V] logits with per-token mask [B, T]
-    (reference my_model_trainer_nwp.py — Shakespeare / StackOverflow NWP)."""
+    (reference my_model_trainer_nwp.py — Shakespeare / StackOverflow NWP).
+    ``logits`` may be the :class:`HeadOperands` they would be made from (a
+    decoder's, while training): the same loss by ``ops/head_loss.py``, which
+    never holds the ``[rows, V]`` array where that is large."""
+    if isinstance(logits, HeadOperands):
+        return _per_unmasked(head_loss(logits, batch["y"], batch["mask"]), batch["mask"])
     ce = optax.softmax_cross_entropy_with_integer_labels(logits, batch["y"])
     return _masked_mean(ce, batch["mask"])
 
@@ -224,11 +233,14 @@ class ClientTrainer:
 
     def loss_fn(self, params: Pytree, model_state: Pytree, global_params: Pytree,
                 batch: Batch, rng: jax.Array):
+        mutable = [*model_state.keys(), STATS_COLLECTION, MTP_COLLECTION]
+        if self.loss_and_metrics[0] is lm_loss:
+            mutable.append(HEAD_COLLECTION)  # lm_loss takes a decoder's operands
         out = self.module.apply(
             {"params": params, **model_state},
             batch["x"],
             train=True,
-            mutable=[*model_state.keys(), STATS_COLLECTION, MTP_COLLECTION],
+            mutable=mutable,
             rngs={"dropout": rng},
         )
         logits, new_model_state = out

@@ -110,6 +110,7 @@ from fedml_tpu.models.moe_transformer import (
 from fedml_tpu.obs import trace
 from fedml_tpu.ops import eva, kda, moe, remat, shortconv
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
+from fedml_tpu.ops.head_loss import decoder_head
 
 MLA, KDA, CONV, GQA, EVA = "mla", "kda", "conv", "gqa", "eva"
 
@@ -458,13 +459,8 @@ class MLAMoETransformerLM(nn.Module):
 
         def logits(h, norm):
             h = RMSNorm(self.rms_eps, self.head_dtype, self.norm_unit_offset, name=norm)(h)
-            if self.tie_head:
-                with jax.named_scope(trace.SCOPE_HEAD):
-                    return embed.attend(h).astype(jnp.float32)
-            out = head(h).astype(jnp.float32)
-            if self.num_pred_heads == 1:
-                return out
-            return out.reshape(*out.shape[:-1], self.num_pred_heads, self.vocab_size)
+            return decoder_head(self, h, train, dense=head, embed=embed,
+                                heads=self.num_pred_heads)
 
         h = embed(x)  # the residual stream stays float32: the router reads it
         stats = []

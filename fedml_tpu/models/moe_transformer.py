@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from fedml_tpu.core.trainer import STATS_COLLECTION
 from fedml_tpu.ops import moe, remat
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
+from fedml_tpu.ops.head_loss import decoder_head
 
 GLOBAL, WINDOW = "global", "window"
 
@@ -223,7 +224,7 @@ class MoETransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        del train  # no dropout: the same program trains and evaluates
+        # no dropout: ``train`` only lets the head hand the trainer its operands
         # the residual stream stays float32: the router reads it, and a
         # choice among near-equal logits should not hang on a bf16 rounding
         h = nn.Embed(self.vocab_size, self.embed_dim, name="tok_embed")(x)
@@ -242,5 +243,5 @@ class MoETransformerLM(nn.Module):
                  {k.split("/", 1)[1]: jnp.stack([s[k] for s in stats]) for k in stats[0]},
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
         h = RMSNorm(self.rms_eps, self.head_dtype, name="norm_f")(h)
-        return nn.Dense(self.vocab_size, use_bias=False, name="head",
-                        dtype=self.head_dtype)(h).astype(jnp.float32)
+        return decoder_head(self, h, train, dense=nn.Dense(
+            self.vocab_size, use_bias=False, name="head", dtype=self.head_dtype))

@@ -28,6 +28,7 @@ from fedml_tpu.ops.attention import (
     flash_attention,
     flash_attention_head_parallel,
 )
+from fedml_tpu.ops.head_loss import decoder_head
 from fedml_tpu.parallel.ring_attention import ring_attention
 
 
@@ -141,10 +142,13 @@ class TransformerLM(nn.Module):
     # (sim/engine.py); leave None for unsharded / FSDP-gather execution.
     mp_axis: str | None = None
     # LM-head matmul dtype, independent of the block compute dtype: an f32
-    # head keeps the logits and their gradient out of bf16 and skips two
-    # [B, T, V]-sized dtype converts. In cgpt13b_silo2 (V 50,257) head and
-    # loss are head_loss_time_pct 20.5 of the busy time at 71% of peak
-    # (ledger PR 29; PERF.md section 5); no chip run has a bf16 head
+    # head keeps the logits and their gradient out of bf16. While a trainer
+    # trains, head and loss run in chunks of rows where the float32 logits
+    # are large (ops/head_loss.py: no [B, T, V] array, float32 or bf16, is
+    # held), in this dtype and at jax's default precision as the plain head
+    # does. In cgpt13b_silo2 (V 50,257) head and loss are head_loss_time_pct
+    # 21.07 of the busy time (ledger, PR 44), the three products 16.5 of it
+    # at the MXU's peak (PERF.md sections 5, 6); no chip run has a bf16 head
     head_dtype: jnp.dtype = jnp.float32
     # rematerialize each block in the backward pass (jax.checkpoint) under
     # ops/remat.py's policy: a block keeps its input and the named values that
@@ -183,5 +187,5 @@ class TransformerLM(nn.Module):
         h = nn.LayerNorm(dtype=self.dtype, name="ln_f")(h)
         # the loss always receives f32 logits (softmax headroom); with a
         # bf16 head they are bf16-quantized before the upcast
-        return nn.Dense(self.vocab_size, name="head",
-                        dtype=self.head_dtype)(h).astype(jnp.float32)
+        return decoder_head(self, h, train, dense=nn.Dense(
+            self.vocab_size, name="head", dtype=self.head_dtype))

@@ -43,7 +43,7 @@ def _blobs_sim(**over):
     return FedSim(trainer, train, test, cfg)
 
 
-def _lm_sim(**over):
+def _lm_sim(module=None, **over):
     from fedml_tpu.models.transformer import TransformerLM
     from fedml_tpu.sim.cohort import FederatedArrays
 
@@ -52,8 +52,8 @@ def _lm_sim(**over):
     train = FederatedArrays(
         {"x": x, "y": np.roll(x, -1, 1), "mask": np.ones(x.shape, np.float32)},
         {c: np.arange(4 * c, 4 * c + 4) for c in range(2)})
-    module = TransformerLM(vocab_size=31, embed_dim=16, num_layers=1,
-                           num_heads=2, max_len=16, attn_impl="flash")
+    module = module or TransformerLM(vocab_size=31, embed_dim=16, num_layers=1,
+                                     num_heads=2, max_len=16, attn_impl="flash")
     trainer = ClientTrainer(module=module, task="nwp", epochs=1,
                             optimizer=optax.sgd(0.01, momentum=0.9))
     cfg = SimConfig(client_num_in_total=2, client_num_per_round=2,
@@ -292,6 +292,59 @@ def test_each_loop_leaves_the_note_of_its_carry_once_a_shape(monkeypatch, execut
     assert len(notes) == 4 and all(n["leaves"] >= leaves for n in notes)
     _lowered(sim, "gather_round")  # the same shapes again: no second note
     assert trace.program_notes(trace.LOOP_CARRY_NOTE) == notes
+
+
+# -- head and loss in chunks of rows (ops/head_loss.py) --------------------------
+
+def _mla_sim(**model):
+    from fedml_tpu.models.mla_moe_transformer import MLAMoETransformerLM
+
+    return _lm_sim(MLAMoETransformerLM(vocab_size=31, routed_layers=1, **model))
+
+
+CHUNKED_HEADS = {  # sim, the scopes that a second pass bears besides
+    "bias": (_lm_sim, ()),
+    "mtp": (lambda: _mla_sim(mtp_depth=1), (trace.SCOPE_MTP,)),
+    "tied": (lambda: _mla_sim(mtp_depth=0, tie_head=True), ()),
+}
+
+
+@pytest.mark.parametrize("case", CHUNKED_HEADS)
+def test_the_chunked_heads_ops_bear_the_names_its_readers_look_for(monkeypatch, case):
+    """Compiled, every op of the operator's loop (the three products a pass
+    under ``head``, the elementwise loss, the loop itself) bears ``fed/loss``
+    inside ``fed/fwd_bwd``, so ``head_loss_time_pct`` and its twins count it
+    (``benchmark/scope_reduce.py`` ``sub_shares``); the MTP pass bears ``mtp``
+    too, as ``mtp_time_pct`` wants; all of it is forward but the scaling by
+    the cotangent."""
+    from benchmark import scope_reduce
+    from fedml_tpu.ops import head_loss
+
+    for constant, value in (("WHOLE_BYTES", 0), ("CHUNK_ROWS", 16), ("TILE_ROWS", 8)):
+        monkeypatch.setattr(head_loss, constant, value)
+    make, scopes = CHUNKED_HEADS[case]
+    sim = make()
+    variables = sim.init_round_variables()
+    args = (variables, sim.aggregator.init_state(variables), sim._dataset,
+            *sim.stage_round(0, rnglib.root_key(sim.config.seed)), variables)
+    text = sim._gather_round_fn.fn.lower(*args).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    # "jvp(fed/loss)/while/body/...", and the MTP pass "jvp(mtp)/fed/loss/while/body/..."
+    in_the_loop = {n for n in names if re.search(rf"{trace.SCOPE_LOSS}\)?/while/body/", n)}
+    heads = {n for n in in_the_loop if n.endswith(f"/{trace.SCOPE_HEAD}/dot_general")}
+    assert heads and any(n.endswith("/exp") for n in in_the_loop)
+    for n in in_the_loop:
+        assert scope_reduce.classify(n) == "train_fwd", n
+        assert scope_reduce.sub_shares(n) == ["head_loss"], n
+    if scopes:  # the second pass: some of the loop's ops sit under every scope
+        inner = {n for n in in_the_loop if all(re.search(
+            rf"[/(]{s}[/)]", n) for s in scopes)}
+        assert inner and {n for n in inner if n in heads} and inner != in_the_loop
+    # no product of head and loss outside the loop, and no head's product outside the loss
+    assert not [n for n in names if n.endswith("/dot_general") and n not in in_the_loop
+                and "head_loss" in scope_reduce.sub_shares(n)]
+    note = trace.program_notes(head_loss.NOTE)[-1]
+    assert note["form"] == "chunked" and note["chunk_rows"] == 16
 
 
 def test_flash_kernel_is_named_in_the_tpu_lowering(monkeypatch):
