@@ -180,6 +180,17 @@ class LatentAttention(nn.Module):
             return dense("o", h.shape[-1], a)
 
 
+class Scale(nn.Module):
+    """A norm's learned scale alone, a leaf named ``scale`` as ``RMSNorm``'s
+    is, for a norm that an operator of ``ops/`` applies."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.width,))
+
+
 class DeltaAttention(nn.Module):
     """Kimi Delta Attention (the module docstring's equations): ``num_heads``
     heads of ``head_dim`` key and value columns."""
@@ -193,37 +204,30 @@ class DeltaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        b, t, _ = h.shape
         n, d = self.num_heads, self.head_dim  # d is also the two low-rank gates' rank
 
         def dense(name, width, y):
             return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(y)
 
-        def heads(y):  # [B, T, n * d] -> [B, n, T, d]
-            return y.reshape(b, t, n, d).transpose(0, 2, 1, 3)
-
-        def conv(name, kept):
-            # the projection is what a rematerialised block keeps: the taps'
-            # gradient reads it, and the convolution costs little to run again
+        def stream(name, kept, norm, scale=1.0):
+            # the projection is what a rematerialised block keeps: the chain's
+            # backward reads it, and the chain costs little to run again
             y = remat.keep(kept, dense(name, n * d, h))
-            return heads(jax.nn.silu(kda.short_conv(y, Kernel((self.conv_size, n * d),
-                                                              name=name + "_conv")())))
+            taps = Kernel((self.conv_size, n * d), name=name + "_conv")()
+            return kda.conv_act(y, taps, heads=n, norm=norm, scale=scale)
 
         with jax.named_scope(trace.SCOPE_KDA):
-            q, k, v = conv("q", remat.KDA_Q), conv("k", remat.KDA_K), conv("v", remat.KDA_V)
-            q = (kda.l2norm(q) * d ** -0.5).astype(self.dtype)
-            k = kda.l2norm(k).astype(self.dtype)
-            decay = dense("f_b", n * d, dense("f_a", d, h)).astype(jnp.float32)
-            decay = jax.nn.softplus(decay + Kernel((1, n * d), name="dt_bias")()[0])
-            g = -jnp.exp(Kernel((1, n), name="A_log")()[0])[:, None, None] * heads(decay)
+            q = stream("q", remat.KDA_Q, True, d ** -0.5)
+            k, v = stream("k", remat.KDA_K, True), stream("v", remat.KDA_V, False)
+            dt_bias, a_log = Kernel((1, n * d), name="dt_bias")(), Kernel((1, n), name="A_log")()
+            g = kda.decay(dense("f_b", n * d, dense("f_a", d, h)), dt_bias[0], a_log[0])
             beta = jax.nn.sigmoid(dense("b", n, h).astype(jnp.float32)).transpose(0, 2, 1)
             if self.attn_impl == "flash":
                 o = kda.kda(q, k, v, g, beta)
             else:
                 o = kda.kda_reference(q, k, v, g, beta)
-            o = RMSNorm(self.rms_eps, self.dtype, name="o_norm")(o)
             gate = nn.Dense(n * d, name="g_b", dtype=self.dtype)(dense("g_a", d, h))
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, n * d) * jax.nn.sigmoid(gate)
+            o = kda.gated_norm(o, gate, Scale(d, name="o_norm")(), eps=self.rms_eps)
             return dense("o", h.shape[-1], o), jax.lax.stop_gradient(kda.decay_floor(g))
 
 

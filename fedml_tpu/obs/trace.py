@@ -117,10 +117,16 @@ SCOPE_MTP = "mtp"
 MLA_SCOPES = (SCOPE_MLA, SCOPE_MOE_SHARED, SCOPE_MTP)
 # Scopes of the delta-rule linear-attention mixer, inside SCOPE_FWD_BWD: the
 # module whole (projections, convolutions, gates, norms and the scan), and
-# the chunked recurrence alone (ops/kda.py), forward and backward
+# the chunked recurrence alone (ops/kda.py), forward and backward; and the
+# mixer's three pointwise chains, one operator each there (conv_act: a
+# stream's taps, SiLU and l2norm; decay: the log-decays; gated_norm: the
+# output's norm and gate), forward and backward
 SCOPE_KDA = "attn/kda"
 SCOPE_KDA_SCAN = "attn/kda/scan"
-KDA_SCOPES = (SCOPE_KDA, SCOPE_KDA_SCAN)
+SCOPE_KDA_CONV = "attn/kda/conv"
+SCOPE_KDA_DECAY = "attn/kda/decay"
+SCOPE_KDA_GATE = "attn/kda/gate"
+KDA_SCOPES = (SCOPE_KDA, SCOPE_KDA_SCAN, SCOPE_KDA_CONV, SCOPE_KDA_DECAY, SCOPE_KDA_GATE)
 # Scopes of the decoder whose layers mix by a gated short convolution or by
 # grouped-query attention (the "conv" and "gqa" mixers of
 # models/mla_moe_transformer.py), inside SCOPE_FWD_BWD: the convolution

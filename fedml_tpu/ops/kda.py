@@ -1,6 +1,6 @@
 """Kimi Delta Attention's recurrence (a gated delta rule with one decay a key
-channel) in chunked form, forward and backward, and the short causal
-convolution that feeds it.
+channel) in chunked form, forward and backward, the short causal convolution
+that feeds it, and the mixer's three pointwise chains as one operator each.
 
 A head keeps a state ``S`` ``[d_k, d_v]`` that every token first decays, a
 key channel at its own rate, then rewrites by a delta rule, then reads
@@ -64,6 +64,52 @@ every backend.
 Scope ``attn/kda/scan`` (``obs/trace.py``) is round both kernels' calls, so
 their custom calls' ``op_name`` holds it; every call leaves a ``kda/call``
 program note.
+
+**The chains between the projections and the scan** are three operators,
+each a ``jax.custom_vjp`` over two small Mosaic kernels (``CHAIN_KERNELS``;
+interpreted on the CPU), a forward and a backward written by hand:
+
+    conv_act(y, w)             y [B, T, n d] kept projection, w [K, n d] taps
+                               -> [B, n, T, d]: short_conv, SiLU, and for q and
+                               k the l2norm over a head (q's times d ** -0.5)
+    decay(a, dt_bias, A_log)   a [B, T, n d] -> g [B, n, T, d] float32:
+                               -exp(A_log) a head times softplus(a + dt_bias)
+    gated_norm(o, gate, w)     o [B, n, T, d], gate [B, T, n d] -> [B, T, n d]:
+                               RMSNorm over a head under w, times sigmoid(gate)
+
+A grid step holds ``BLOCK`` tokens of one head (a head's ``d`` columns are a
+block of lanes, so the move between ``[B, T, n d]`` and ``[B, n, T, d]`` is
+the BlockSpecs' index maps and costs no pass), reads each operand once,
+holds float32 in VMEM only and writes each result once; inside, one loop
+walks the block in pieces of ``PIECE`` rows, short enough that a piece's
+float32 values stay in registers (a whole block's spill: twice the bundles,
+compiled for the v5e) and one piece is all the kernel's program holds (eight
+unrolled doubled the time jax takes to trace and lower a layer). The taps
+read the ``HALO`` rows before a block through a second BlockSpec on the same
+array (zeros before the first token), and the convolution's backward the ``HALO``
+rows after it (of the input and of the cotangent: it remakes the chain there
+too, so the input's gradient reads the convolution's cotangent shifted
+forward in time with zeros after the last token and no block waits for
+another). A backward remakes what it needs from the operator's own inputs:
+SiLU, the norms' sums a row, softplus. Nothing is saved, so a rematerialised
+block keeps no new name (its second forward runs the forward kernels again);
+the parameters' gradients (the ``K`` taps', ``dt_bias``'s, ``A_log``'s, the
+norm weight's) are sums over tokens held in an output block across a head's
+sequential grid steps. The forwards call :func:`short_conv` and
+:func:`l2norm` as this module holds them when the operator is traced (the
+benchmark's controls stand other functions in their place). Float32 runs
+from the operands to each result: the composition's bfloat16 stops after the
+convolution, after SiLU, after the norm and after the sigmoid are gone, none
+is added; the logistic is one ``tanh`` (:func:`_sigmoid`). Mosaic takes a
+head's columns as lanes: on the TPU ``d`` is a multiple of 128 or there is
+one head. Scopes ``attn/kda/conv``, ``attn/kda/decay`` and ``attn/kda/gate``
+(``CHAIN_SCOPES``) are round the kernels' calls, forward
+and backward, and every operator leaves a ``kda/chain`` program note (``op``,
+``rows``, ``columns``, the ``bytes`` one forward has to move). ``*_reference``
+are the plain XLA compositions the operators replace, which the tests hold
+them to: compiled for the v5e inside the round program those moved 16.5 GB a
+layer-step (two float32 transposes a stream for the move to heads alone)
+where the operators move 3 (``PERF.md`` sections 5 and 6, PR 46).
 """
 
 from __future__ import annotations
@@ -89,21 +135,492 @@ ELIMINATED = 16  # rows of a diagonal block of the triangular system inverted on
 GROUP = 256
 
 
+L2_EPS = 1e-6  # of l2norm, and of conv_act's backward, which remakes the norm by hand
+CHAIN_NOTE = "kda/chain"  # the program note each of the three operators below leaves
+
+
 def short_conv(x, w):
     """Causal depthwise convolution along the sequence: ``x`` ``[B, T, C]``,
     ``w`` ``[K, C]`` (tap ``K - 1`` meets the token itself), zeros before the
     first token, no bias: ``y_t = sum_j w_j * x_{t - (K - 1) + j}``.
-    Accumulated in float32, returned in ``x``'s dtype."""
+    Accumulated in float32, returned in ``x``'s dtype. The zeros are put
+    before the sequence in ``x``'s dtype and a tap's slice is widened as it is
+    read (``ops/shortconv.py`` ``_taps``' form, the same values bit for bit):
+    no float32 copy of the padded input exists."""
     taps, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
-    y = sum(padded[:, j:j + t] * w[j].astype(jnp.float32) for j in range(taps))
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(padded[:, j:j + t].astype(jnp.float32) * w[j].astype(jnp.float32)
+            for j in range(taps))
     return y.astype(x.dtype)
 
 
-def l2norm(x, eps: float = 1e-6):
+def l2norm(x, eps: float = L2_EPS):
     """``x * rsqrt(sum x^2 + eps)`` over the last axis, in float32."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+# ---------------------------------------------------------------------------
+# The mixer's three pointwise chains (the module docstring's last part): for
+# each the plain composition, the two kernels, and the operator over them.
+# ---------------------------------------------------------------------------
+
+BLOCK = 2048  # tokens a grid step (a step costs 0.35 us beside its work: PERF.md section 6, PR 46)
+HALO = 16  # rows read beside a block for the taps: bfloat16's sublane tile, at least K - 1
+PIECE = 128  # rows a kernel works at a time: a piece's float32 values stay in registers
+# a chain's scope; its kernels are ``kda_<chain>_fwd`` and ``kda_<chain>_bwd``
+CHAIN_SCOPES = {"conv": trace.SCOPE_KDA_CONV, "decay": trace.SCOPE_KDA_DECAY,
+                "gate": trace.SCOPE_KDA_GATE}
+CHAIN_KERNELS = tuple(f"kda_{chain}_{side}" for chain in CHAIN_SCOPES for side in ("fwd", "bwd"))
+
+
+def _heads(x, n):
+    """``[B, T, n d] -> [B, n, T, d]``: the layout the scan kernels take."""
+    b, t, c = x.shape
+    return x.reshape(b, t, n, c // n).transpose(0, 2, 1, 3)
+
+
+def _flat(x):
+    """``[B, n, T, d] -> [B, T, n d]``: the layout the projections take."""
+    b, n, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, n * d)
+
+
+def _softplus(x):
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+def _sigmoid(x):
+    """The logistic function by one ``tanh`` (a transcendental unit's op) in
+    place of an ``exp`` and a division refined on the VPU: within float32's
+    rounding of ``jax.nn.sigmoid`` (7e-8 absolute)."""
+    return 0.5 * jnp.tanh(0.5 * x) + 0.5
+
+
+def _whole_blocks(x, axis, t):
+    """``x`` with its token axis padded by zeros to ``t``: whole blocks."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, t - x.shape[axis])
+    return jnp.pad(x, pad) if pad[axis][1] else x
+
+
+def _row(x):
+    """A parameter ``[width]`` as the float32 row ``[1, width]`` a kernel reads."""
+    return x.astype(jnp.float32).reshape(1, -1)
+
+
+class _Blocks:
+    """The BlockSpecs of a chain's kernels over the grid (head, batch,
+    block): ``bt`` tokens a block of ``t`` (padded to whole blocks), a head's
+    ``d`` columns."""
+
+    def __init__(self, tokens, d):
+        self.d = d
+        unit = HALO if tokens <= PIECE else PIECE  # a block is one short piece or whole pieces
+        self.bt = min(BLOCK, -(-tokens // unit) * unit)
+        self.t = -(-tokens // self.bt) * self.bt
+        self.per, self.last = self.bt // HALO, self.t // HALO - 1
+
+    def grid(self, heads, batch):
+        return heads, batch, self.t // self.bt
+
+    def _halo(self, after):  # the HALO rows before a block (clamped at 0) or after it (at the end)
+        if after:
+            return lambda j: jnp.minimum((j + 1) * self.per, self.last)
+        return lambda j: jnp.maximum(j * self.per - 1, 0)
+
+    def flat(self, halo=None):
+        """Of ``[B, T, n d]``: a block's rows, or the HALO rows ``halo`` it."""
+        if halo is None:
+            return pl.BlockSpec((None, self.bt, self.d), lambda h, b, j: (b, j, h))
+        at = self._halo(halo == "after")
+        return pl.BlockSpec((None, HALO, self.d), lambda h, b, j: (b, at(j), h))
+
+    def head(self, halo=None):
+        """Of ``[B, n, T, d]``."""
+        if halo is None:
+            return pl.BlockSpec((None, None, self.bt, self.d), lambda h, b, j: (b, h, j, 0))
+        at = self._halo(halo == "after")
+        return pl.BlockSpec((None, None, HALO, self.d), lambda h, b, j: (b, h, at(j), 0))
+
+    def columns(self, rows):
+        """Of ``[rows, n d]``: a head's columns of a parameter or of a sum."""
+        return pl.BlockSpec((rows, self.d), lambda h, b, j: (0, h))
+
+    def shared(self):
+        """Of ``[1, d]``: a row every head reads whole."""
+        return pl.BlockSpec((1, self.d), lambda h, b, j: (0, 0))
+
+
+def _chain_call(body, chain, backward, blocks, heads, batch, operands, **specs):
+    """The forward or the ``backward`` kernel of ``chain`` on ``operands`` under
+    the chain's scope. A backward sums parameters' gradients over tokens in an
+    output block: its batch and block axes are sequential."""
+    along = "arbitrary" if backward else "parallel"
+    with jax.named_scope(CHAIN_SCOPES[chain]):
+        return pl.pallas_call(
+            body, name=f"kda_{chain}_{'bwd' if backward else 'fwd'}",
+            grid=blocks.grid(heads, batch),
+            interpret=_interpret_on(jax.default_backend()),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", along, along)),
+            **specs)(*operands)
+
+
+def _first_step():
+    return (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+
+
+def _pieces(rows, body, carry=None):
+    """``body(piece, r0, size, carry) -> carry`` over a block's ``rows`` in
+    pieces of ``PIECE`` (one loop, so a kernel's program holds one piece)."""
+    size = min(PIECE, rows)
+
+    def step(piece, carry):
+        return body(piece, pl.multiple_of(piece * size, size), size, carry)
+
+    return jax.lax.fori_loop(0, rows // size, step, carry)
+
+
+def _halo_before(before_ref):
+    """The HALO rows the second BlockSpec read before a block: zeros before
+    the first token."""
+    before = before_ref[...]
+    return jnp.where(pl.program_id(2) == 0, jnp.zeros_like(before), before)
+
+
+def _halo_after(after_ref, zeros_after_the_last):
+    """The HALO rows the BlockSpec after a block read (clamped at the
+    sequence's end: zeros there where the caller's arithmetic needs them)."""
+    after = after_ref[...]
+    if zeros_after_the_last:
+        after = jnp.where(pl.program_id(2) == pl.num_programs(2) - 1, jnp.zeros_like(after), after)
+    return after
+
+
+def _rows_before(ref, before, piece, r0):
+    """The HALO rows before row ``r0`` of a block: the block's own, or for its
+    first piece ``before``."""
+    own = ref[pl.ds(pl.multiple_of(jnp.maximum(r0 - HALO, 0), HALO), HALO)]
+    return jnp.where(piece == 0, before, own)
+
+
+def _rows_after(ref, after, r1):
+    """The HALO rows from row ``r1`` of a block on: the block's own, or past
+    its last piece ``after``."""
+    own = ref[pl.ds(pl.multiple_of(jnp.minimum(r1, ref.shape[0] - HALO), HALO), HALO)]
+    return jnp.where(r1 >= ref.shape[0], after, own)
+
+
+def _chain_note(op, x, moved):
+    """``kda/chain``: the operator, its rows and columns, and the bytes one
+    forward call has to move (each operand read once, each result written
+    once)."""
+    trace.program_note(CHAIN_NOTE, op=op, rows=x.shape[0] * x.shape[1], columns=x.shape[2],
+                       bytes=moved)
+
+
+def conv_act_reference(y, w, *, heads, norm, scale=1.0):
+    """The composition :func:`conv_act` replaces, as the module wrote it:
+    convolution, SiLU, the move to heads, and for q and k the l2norm over a
+    head times ``scale``."""
+    a = _heads(jax.nn.silu(short_conv(y, w)), heads)
+    if norm:
+        a = l2norm(a) * scale
+    return a.astype(y.dtype)
+
+
+def _conv_fwd_kernel(y_ref, before_ref, w_ref, out_ref, *, norm, scale):
+    w, before = w_ref[...], _halo_before(before_ref)
+
+    def piece(i, r0, rows, _):
+        x = jnp.concatenate([_rows_before(y_ref, before, i, r0), y_ref[pl.ds(r0, rows)]], axis=0)
+        c = short_conv(x.astype(jnp.float32)[None], w)[0, HALO:]
+        a = c * _sigmoid(c)
+        if norm:
+            a = l2norm(a) * scale
+        out_ref[pl.ds(r0, rows)] = a.astype(out_ref.dtype)
+
+    _pieces(y_ref.shape[0], piece)
+
+
+def _conv_bwd_kernel(y_ref, before_ref, after_ref, w_ref, d_ref, d_after_ref, d_y_ref, d_w_ref,
+                     *, norm, scale):
+    """A piece's rows and the HALO after them: the chain is remade in float32
+    up to the SiLU (and the norm's two sums a row), the input's gradient reads
+    the convolution's cotangent shifted forward in time (zeros after the last
+    token) and the taps' gradients are ``K`` sums of it against the shifted
+    input."""
+    f32, taps = jnp.float32, w_ref.shape[0]
+    w, before = w_ref[...].astype(f32), _halo_before(before_ref)
+    after, d_after = _halo_after(after_ref, False), _halo_after(d_after_ref, True)
+
+    def piece(i, r0, rows, d_w):
+        x = jnp.concatenate([_rows_before(y_ref, before, i, r0), y_ref[pl.ds(r0, rows)],
+                             _rows_after(y_ref, after, r0 + rows)], axis=0).astype(f32)
+        c = short_conv(x[None], w)[0, HALO:]
+        s = _sigmoid(c)
+        d_a = jnp.concatenate([d_ref[pl.ds(r0, rows)],
+                               _rows_after(d_ref, d_after, r0 + rows)], axis=0).astype(f32)
+        if norm:
+            a = c * s
+            r = jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS)
+            d_a = scale * r * (d_a - a * (r * r * jnp.sum(d_a * a, axis=-1, keepdims=True)))
+        d_c = d_a * (s * (1.0 + c * (1.0 - s)))
+        d_y = sum(d_c[taps - 1 - j:taps - 1 - j + rows] * w[j:j + 1] for j in range(taps))
+        d_y_ref[pl.ds(r0, rows)] = d_y.astype(d_y_ref.dtype)
+        first = HALO - (taps - 1)  # of the rows the taps met
+        met = [x[first + j:first + j + rows] for j in range(taps)]
+        return tuple(d_w[j] + jnp.sum(d_c[:rows] * met[j], axis=0, keepdims=True)
+                     for j in range(taps))
+
+    d_w = _pieces(y_ref.shape[0], piece, (jnp.zeros((1, w.shape[1]), f32),) * taps)
+
+    @pl.when(_first_step())
+    def _():
+        d_w_ref[...] = jnp.zeros_like(d_w_ref)
+
+    for j in range(taps):
+        d_w_ref[j:j + 1, :] += d_w[j]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _conv_act(y, w, heads, norm, scale):
+    b, t, c = y.shape
+    blocks = _Blocks(t, c // heads)
+    y = _whole_blocks(y, 1, blocks.t)
+    out = _chain_call(
+        functools.partial(_conv_fwd_kernel, norm=norm, scale=scale), "conv", False, blocks,
+        heads, b, (y, y, w),
+        in_specs=[blocks.flat(), blocks.flat("before"), blocks.columns(w.shape[0])],
+        out_specs=blocks.head(),
+        out_shape=jax.ShapeDtypeStruct((b, heads, blocks.t, blocks.d), y.dtype))
+    return out[:, :, :t]
+
+
+def _conv_act_fwd(y, w, heads, norm, scale):
+    return _conv_act(y, w, heads, norm, scale), (y, w)
+
+
+def _conv_act_bwd(heads, norm, scale, res, d_out):
+    y, w = res
+    b, t, c = y.shape
+    blocks = _Blocks(t, c // heads)
+    y, d_out = _whole_blocks(y, 1, blocks.t), _whole_blocks(d_out.astype(y.dtype), 2, blocks.t)
+    d_y, d_w = _chain_call(
+        functools.partial(_conv_bwd_kernel, norm=norm, scale=scale), "conv", True, blocks,
+        heads, b, (y, y, y, w, d_out, d_out),
+        in_specs=[blocks.flat(), blocks.flat("before"), blocks.flat("after"),
+                  blocks.columns(w.shape[0]), blocks.head(), blocks.head("after")],
+        out_specs=[blocks.flat(), blocks.columns(w.shape[0])],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32)])
+    return d_y[:, :t], d_w.astype(w.dtype)
+
+
+_conv_act.defvjp(_conv_act_fwd, _conv_act_bwd)
+
+
+def conv_act(y, w, *, heads, norm, scale=1.0):
+    """A kept projection ``y`` ``[B, T, n d]`` under its taps ``w`` ``[K, n
+    d]`` (``K - 1 <= HALO``) -> ``[B, n, T, d]`` in ``y``'s dtype:
+    :func:`short_conv`, SiLU, and where ``norm`` the :func:`l2norm` over a head
+    times ``scale``, float32 from the convolution's output on."""
+    if w.shape[0] - 1 > HALO:
+        raise ValueError(
+            f"conv_act: {w.shape[0]} taps reach past the {HALO} rows read before a block")
+    _chain_note("conv_act", y, 2 * y.size * jnp.dtype(y.dtype).itemsize)
+    return _conv_act(y, w, heads, norm, scale)
+
+
+def decay_reference(a, dt_bias, a_log):
+    """The composition :func:`decay` replaces: ``-exp(A_log)`` a head times
+    ``softplus(a + dt_bias)``, float32, head-major."""
+    rate = jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+    return -jnp.exp(a_log)[:, None, None] * _heads(rate, a_log.shape[0])
+
+
+def _decay_fwd_kernel(a_ref, bias_ref, rate_ref, g_ref):
+    bias, rate = bias_ref[...], rate_ref[...]
+
+    def piece(i, r0, rows, _):
+        g_ref[pl.ds(r0, rows)] = rate * _softplus(a_ref[pl.ds(r0, rows)].astype(jnp.float32) + bias)
+
+    _pieces(a_ref.shape[0], piece)
+
+
+def _decay_bwd_kernel(a_ref, bias_ref, rate_ref, d_g_ref, d_a_ref, d_bias_ref, d_log_ref):
+    """``softplus`` is remade, its slope is the sigmoid, and both parameters'
+    gradients are column sums of the same pass (``A_log``'s is the sum of
+    ``d_g * g`` over its head's columns)."""
+    bias, rate = bias_ref[...], rate_ref[...]
+
+    def piece(i, r0, rows, sums):
+        x = a_ref[pl.ds(r0, rows)].astype(jnp.float32) + bias
+        through = rate * d_g_ref[pl.ds(r0, rows)]
+        d_x = through * _sigmoid(x)
+        d_a_ref[pl.ds(r0, rows)] = d_x.astype(d_a_ref.dtype)
+        return (sums[0] + jnp.sum(d_x, axis=0, keepdims=True),
+                sums[1] + jnp.sum(through * _softplus(x), axis=0, keepdims=True))
+
+    d_bias, d_log = _pieces(a_ref.shape[0], piece, (jnp.zeros_like(bias),) * 2)
+
+    @pl.when(_first_step())
+    def _():
+        d_bias_ref[...] = jnp.zeros_like(d_bias_ref)
+        d_log_ref[...] = jnp.zeros_like(d_log_ref)
+
+    d_bias_ref[...] += d_bias
+    d_log_ref[...] += d_log
+
+
+def _decay_operands(a, dt_bias, a_log):
+    """The two parameters a row of ``[1, n d]`` each: the bias, and a head's
+    rate ``-exp(A_log)`` on each of its columns."""
+    rate = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), a.shape[2] // a_log.shape[0])
+    return _row(dt_bias), _row(rate)
+
+
+@jax.custom_vjp
+def _decay(a, dt_bias, a_log):
+    b, t, c = a.shape
+    n = a_log.shape[0]
+    blocks = _Blocks(t, c // n)
+    g = _chain_call(
+        _decay_fwd_kernel, "decay", False, blocks, n, b,
+        (_whole_blocks(a, 1, blocks.t), *_decay_operands(a, dt_bias, a_log)),
+        in_specs=[blocks.flat(), blocks.columns(1), blocks.columns(1)], out_specs=blocks.head(),
+        out_shape=jax.ShapeDtypeStruct((b, n, blocks.t, blocks.d), jnp.float32))
+    return g[:, :, :t]
+
+
+def _decay_fwd(a, dt_bias, a_log):
+    return _decay(a, dt_bias, a_log), (a, dt_bias, a_log)
+
+
+def _decay_bwd(res, d_g):
+    a, dt_bias, a_log = res
+    b, t, c = a.shape
+    n = a_log.shape[0]
+    blocks = _Blocks(t, c // n)
+    row = jax.ShapeDtypeStruct((1, c), jnp.float32)
+    d_a, d_bias, d_log = _chain_call(
+        _decay_bwd_kernel, "decay", True, blocks, n, b,
+        (_whole_blocks(a, 1, blocks.t), *_decay_operands(a, dt_bias, a_log),
+         _whole_blocks(d_g.astype(jnp.float32), 2, blocks.t)),
+        in_specs=[blocks.flat(), blocks.columns(1), blocks.columns(1), blocks.head()],
+        out_specs=[blocks.flat(), blocks.columns(1), blocks.columns(1)],
+        out_shape=[jax.ShapeDtypeStruct((b, blocks.t, c), a.dtype), row, row])
+    return (d_a[:, :t], d_bias.reshape(dt_bias.shape).astype(dt_bias.dtype),
+            jnp.sum(d_log.reshape(n, -1), axis=1).astype(a_log.dtype))
+
+
+_decay.defvjp(_decay_fwd, _decay_bwd)
+
+
+def decay(a, dt_bias, a_log):
+    """The low-rank gate's output ``a`` ``[B, T, n d]``, ``dt_bias`` ``[n d]``
+    and ``A_log`` ``[n]`` -> the log-decays ``g`` ``[B, n, T, d]`` float32
+    (<= 0): ``-exp(A_log) * softplus(a + dt_bias)``."""
+    _chain_note("decay", a, a.size * (jnp.dtype(a.dtype).itemsize + 4))
+    return _decay(a, dt_bias, a_log)
+
+
+def gated_norm_reference(o, gate, weight, *, eps):
+    """The composition :func:`gated_norm` replaces: RMSNorm over a head
+    (float32 statistics, the output in ``gate``'s dtype), the move back to
+    ``[B, T, n d]``, times the sigmoid of ``gate``."""
+    o = o.astype(jnp.float32)
+    normed = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps) * weight
+    return _flat(normed.astype(gate.dtype)) * jax.nn.sigmoid(gate)
+
+
+def _normed(o, eps):
+    """``(o / rms(o), 1 / rms(o))`` of a piece's rows, float32."""
+    o = o.astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * r, r
+
+
+def _gate_fwd_kernel(o_ref, gate_ref, weight_ref, out_ref, *, eps):
+    weight = weight_ref[...]
+
+    def piece(i, r0, rows, _):
+        normed, _ = _normed(o_ref[pl.ds(r0, rows)], eps)
+        out = normed * weight * _sigmoid(gate_ref[pl.ds(r0, rows)].astype(jnp.float32))
+        out_ref[pl.ds(r0, rows)] = out.astype(out_ref.dtype)
+
+    _pieces(o_ref.shape[0], piece)
+
+
+def _gate_bwd_kernel(o_ref, gate_ref, weight_ref, d_ref, d_o_ref, d_gate_ref, d_weight_ref, *,
+                     eps):
+    """Both gradients, each in its operand's layout, and the norm weight's
+    sum over this head's rows (the heads' sums are added outside)."""
+    weight = weight_ref[...]
+
+    def piece(i, r0, rows, d_weight):
+        normed, r = _normed(o_ref[pl.ds(r0, rows)], eps)
+        s = _sigmoid(gate_ref[pl.ds(r0, rows)].astype(jnp.float32))
+        d_normed = d_ref[pl.ds(r0, rows)].astype(jnp.float32) * s
+        through = d_normed * normed
+        d_gate_ref[pl.ds(r0, rows)] = (through * weight * (1.0 - s)).astype(d_gate_ref.dtype)
+        d_o = r * (d_normed * weight
+                   - normed * jnp.mean(through * weight, axis=-1, keepdims=True))
+        d_o_ref[pl.ds(r0, rows)] = d_o.astype(d_o_ref.dtype)
+        return d_weight + jnp.sum(through, axis=0, keepdims=True)
+
+    d_weight = _pieces(o_ref.shape[0], piece, jnp.zeros_like(weight))
+
+    @pl.when(_first_step())
+    def _():
+        d_weight_ref[...] = jnp.zeros_like(d_weight_ref)
+
+    d_weight_ref[...] += d_weight
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gated_norm(o, gate, weight, eps):
+    b, n, t, d = o.shape
+    blocks = _Blocks(t, d)
+    out = _chain_call(
+        functools.partial(_gate_fwd_kernel, eps=eps), "gate", False, blocks, n, b,
+        (_whole_blocks(o, 2, blocks.t), _whole_blocks(gate, 1, blocks.t), _row(weight)),
+        in_specs=[blocks.head(), blocks.flat(), blocks.shared()], out_specs=blocks.flat(),
+        out_shape=jax.ShapeDtypeStruct((b, blocks.t, n * d), gate.dtype))
+    return out[:, :t]
+
+
+def _gated_norm_fwd(o, gate, weight, eps):
+    return _gated_norm(o, gate, weight, eps), (o, gate, weight)
+
+
+def _gated_norm_bwd(eps, res, d_out):
+    o, gate, weight = res
+    b, n, t, d = o.shape
+    blocks = _Blocks(t, d)
+    padded = [_whole_blocks(x, axis, blocks.t) for x, axis in ((o, 2), (gate, 1), (d_out, 1))]
+    d_o, d_gate, d_weight = _chain_call(
+        functools.partial(_gate_bwd_kernel, eps=eps), "gate", True, blocks, n, b,
+        (padded[0], padded[1], _row(weight), padded[2].astype(gate.dtype)),
+        in_specs=[blocks.head(), blocks.flat(), blocks.shared(), blocks.flat()],
+        out_specs=[blocks.head(), blocks.flat(), blocks.columns(1)],
+        out_shape=[jax.ShapeDtypeStruct(padded[0].shape, o.dtype),
+                   jax.ShapeDtypeStruct(padded[1].shape, gate.dtype),
+                   jax.ShapeDtypeStruct((1, n * d), jnp.float32)])
+    return (d_o[:, :, :t], d_gate[:, :t],
+            jnp.sum(d_weight.reshape(n, d), axis=0).astype(weight.dtype))
+
+
+_gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+def gated_norm(o, gate, weight, *, eps):
+    """The scan's output ``o`` ``[B, n, T, d]`` and the output gate's
+    pre-activation ``gate`` ``[B, T, n d]`` -> ``[B, T, n d]`` in ``gate``'s
+    dtype: RMSNorm over a head under ``weight`` ``[d]``, times
+    ``sigmoid(gate)``, float32 up to the result."""
+    _chain_note("gated_norm", gate, 3 * gate.size * jnp.dtype(gate.dtype).itemsize)
+    return _gated_norm(o, gate, weight, eps)
 
 
 def kda_reference(q, k, v, g, beta):

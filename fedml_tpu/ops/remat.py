@@ -33,12 +33,16 @@ recomputed from the layout, tile by tile, in the backward pass.
 
 A delta-attention block (``models/mla_moe_transformer.py`` ``DeltaAttention``)
 keeps five: the q, k and v projections *before* their convolutions
-(``kda/q``, ``kda/k``, ``kda/v``: the taps' gradient reads the projection, and
-the convolution, SiLU and l2norm cost little to run again), and, tagged inside
+(``kda/q``, ``kda/k``, ``kda/v``: they are ``ops/kda.py`` ``conv_act``'s input,
+which is all its backward reads, and the operator's forward, one pass over
+the projection, costs little to run again), and, tagged inside
 the scan's forward rule (``ops/kda.py``), its output ``kda/out`` and the state
 that entered each group of chunks ``kda/states``, so that the second forward
 runs no scan and the backward starts each group from a kept state. The
-log-decays are never kept: they are remade from a ``[T, rank]`` product.
+log-decays are never kept: they are remade from a ``[T, rank]`` product and
+one pass of ``decay``. The mixer's three operators (``conv_act``, ``decay``,
+``gated_norm``) save nothing of their own: each backward remakes its chain
+from the operator's inputs, so nothing more is kept than before them.
 
 A short-convolution block (``ShortConv`` there) keeps one: its input
 projection's output ``shortconv/in`` (``[tokens, 3 D]``: every step of the

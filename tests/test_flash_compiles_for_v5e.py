@@ -75,3 +75,44 @@ def test_flash_forward_and_backward_compile_for_the_v5e(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "flash_fwd" in text and "flash_bwd_dkv" in text and "flash_bwd_dq" not in text
+
+
+# the delta-attention mixer's three chains (ops/kda.py): the cell's shape
+# ([1, 8192, 32 x 128] bfloat16: whole blocks of 2,048 tokens), a length that is
+# a part of one block, one that is no multiple of a block, float32, one head
+CHAINS = [
+    pytest.param(op, *shape, id=f"{op}-{name}")
+    for op in ("conv_act_norm", "conv_act_plain", "decay", "gated_norm")
+    for name, shape in (("kimi", (1, 8192, 32, 128, jnp.bfloat16)),
+                        ("part-of-a-block", (2, 200, 2, 128, jnp.bfloat16)),
+                        ("blocks-and-a-part", (1, 4500, 1, 256, jnp.float32)))]
+
+
+@pytest.mark.parametrize("op,b,t,n,d,dtype", CHAINS)
+def test_the_mixers_chains_compile_for_the_v5e(monkeypatch, one_chip, op, b, t, n, d, dtype):
+    """Value and gradients of each operator: its forward and its backward
+    kernel compile for the chip (the in-kernel ``short_conv`` pads and slices
+    off the sublane tiling, which only Mosaic can refuse), and at whole blocks
+    nothing of XLA's moves an array beside them."""
+    import fedml_tpu.ops.kda as kda
+
+    monkeypatch.setattr(kda, "_interpret_on", lambda platform: False)
+
+    def on_chip(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    flat, heads = on_chip((b, t, n * d), dtype), on_chip((b, n, t, d), dtype)
+    if op == "decay":
+        fn, args = kda.decay, (flat, on_chip((n * d,)), on_chip((n,)))
+    elif op == "gated_norm":
+        fn, args = (lambda *a: kda.gated_norm(*a, eps=1e-5)), (heads, flat, on_chip((d,)))
+    else:
+        norm = op == "conv_act_norm"
+        fn = lambda y, w: kda.conv_act(y, w, heads=n, norm=norm, scale=d ** -0.5)  # noqa: E731
+        args = (flat, on_chip((4, n * d)))
+    text = jax.jit(jax.value_and_grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                                      argnums=range(len(args)))).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert sum(kernel in text for kernel in kda.CHAIN_KERNELS) == 2
+    if t % kda.BLOCK == 0:
+        assert " copy(" not in text and " transpose(" not in text and " pad(" not in text

@@ -4,9 +4,12 @@ five operands' gradients at three chunk sizes, at a length that is no
 multiple of the chunk, under decays whose cumulated logs pass -200 (where
 ``exp(-gamma)`` alone overflows), on sixty-four equal keys (where powers of the
 triangular system overflow), and in bfloat16; the short convolution against a
-direct sum; the kernels and loops a block adds, and the scope they sit under;
-and the scan's lowering for the TPU at the cell's shape. On the CPU; nothing
-here describes a TPU topology, so the file is safe under xdist.
+direct sum; the mixer's three pointwise chains (``conv_act``, ``decay``,
+``gated_norm``: a hand-written backward each) against the plain compositions
+they replace, values and every operand's gradient; the kernels and loops a
+block adds, and the scope they sit under; and the scan's lowering for the TPU
+at the cell's shape. On the CPU; nothing here describes a TPU topology, so
+the file is safe under xdist.
 """
 
 import collections
@@ -148,6 +151,124 @@ def test_short_conv_against_a_direct_sum(taps):
     assert kda.short_conv(x.astype(jnp.bfloat16), w).dtype == jnp.bfloat16
 
 
+# -- the three chains: each operator against the composition it replaces ---------------
+# (batch, tokens, heads, head width): T 1, T no multiple of 8, one head and several;
+# a block of several pieces, and three blocks (the last part padding) whose taps
+# and whose convolution's backward read across pieces and across blocks
+CHAIN_SHAPES = {"one_token": (2, 1, 3, 8), "odd_length": (2, 13, 3, 8), "one_head": (1, 21, 1, 16),
+                "two_tiles": (1, 40, 4, 16), "pieces": (2, 300, 2, 8), "blocks": (1, 4500, 2, 8)}
+DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+def _draw(seed, shape, dtype=jnp.float32, scale=1.0):
+    return (scale * jax.random.normal(jax.random.key(seed), shape)).astype(dtype)
+
+
+def _chain(op, shape, dtype, taps=4):
+    """``(operator, reference, operands)`` of one chain at a shape."""
+    b, t, n, d = shape
+    if op == "gated_norm":
+        args = (_draw(1, (b, n, t, d), dtype), _draw(2, (b, t, n * d), dtype),
+                1.0 + 0.3 * _draw(3, (d,)))
+        return (lambda *a: kda.gated_norm(*a, eps=1e-5),
+                lambda *a: kda.gated_norm_reference(*a, eps=1e-5), args)
+    if op == "decay":
+        return kda.decay, kda.decay_reference, (
+            _draw(1, (b, t, n * d), dtype), _draw(2, (n * d,), scale=2.0), _draw(3, (n,)))
+    norm = op == "conv_act_norm"
+    kw = dict(heads=n, norm=norm, scale=d ** -0.5 if norm else 1.0)
+    args = (_draw(1, (b, t, n * d), dtype), _draw(2, (taps, n * d), scale=taps ** -0.5))
+    return (lambda *a: kda.conv_act(*a, **kw), lambda *a: kda.conv_act_reference(*a, **kw), args)
+
+
+CHAINS = ("conv_act_norm", "conv_act_plain", "decay", "gated_norm")
+
+
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+@pytest.mark.parametrize("shape", sorted(CHAIN_SHAPES))
+@pytest.mark.parametrize("op", CHAINS)
+def test_a_chain_equals_the_composition_it_replaces(op, shape, dtype):
+    """Values, and every operand's gradient pulled back from one random
+    cotangent: the kernels (interpreted here) against the plain composition
+    and jax's own backward of it. Float32 to rounding; bfloat16 to a few of
+    its roundings (the operators stop in bfloat16 where the composition does,
+    or later: they hold float32 from the convolution's output on)."""
+    fn, ref, args = _chain(op, CHAIN_SHAPES[shape], DTYPES[dtype])
+    got, want = fn(*args), ref(*args)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    close(got.astype(jnp.float32), want.astype(jnp.float32), 2e-6 if dtype == "f32" else 0.02)
+    weight = _draw(9, got.shape)
+    pulled = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * weight), argnums=range(len(args)))(*args)
+    for i, (g, r) in enumerate(zip(pulled(fn), pulled(ref))):
+        assert g.dtype == r.dtype == args[i].dtype and g.shape == args[i].shape, i
+        scale = max(float(jnp.max(jnp.abs(r.astype(jnp.float32)))), 1e-3)
+        tol = 2e-5 if dtype == "f32" and args[i].dtype == jnp.float32 else 0.04
+        np.testing.assert_allclose(g.astype(jnp.float32), r.astype(jnp.float32), atol=tol * scale,
+                                   err_msg=f"{op} operand {i}")
+
+
+@pytest.mark.parametrize("taps", [2, 4])
+@pytest.mark.parametrize("op", ["conv_act_norm", "conv_act_plain"])
+def test_conv_act_at_two_and_four_taps_and_causal(op, taps):
+    """The taps' and the input's gradients at both tap counts, against the
+    composition's; and a later token changes no earlier output, nor does a
+    later cotangent reach an earlier token's input gradient the wrong way (the
+    input's gradient at ``t`` reads cotangents ``t ... t + K - 1`` only)."""
+    fn, ref, (y, w) = _chain(op, (2, 11, 2, 8), jnp.float32, taps)
+    weight = _draw(9, fn(y, w).shape)
+    pulled = lambda f, w8=weight: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * w8), argnums=(0, 1))(y, w)
+    for g, r in zip(pulled(fn), pulled(ref)):
+        np.testing.assert_allclose(g, r, atol=2e-5 * float(jnp.max(jnp.abs(r))))
+    later = fn(y.at[:, 6:].add(1.0), w)
+    np.testing.assert_array_equal(later[:, :, :6], fn(y, w)[:, :, :6])
+    assert float(jnp.abs(later[:, :, 6:] - fn(y, w)[:, :, 6:]).max()) > 1e-3
+    d_y, _ = pulled(fn)
+    d_y_cut, _ = pulled(fn, weight.at[:, :, :4].set(0.0))  # cotangents of tokens 0 .. 3 dropped
+    np.testing.assert_allclose(d_y_cut[:, 4:], d_y[:, 4:], atol=1e-6)
+    assert float(jnp.abs(d_y_cut[:, :4] - d_y[:, :4]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("op", CHAINS)
+def test_a_chains_gradient_program_is_two_kernels_and_no_padded_float32_array(op, monkeypatch):
+    """Lowered for the TPU at whole blocks (``[1, 2048, 4 x 128]`` bfloat16; no
+    compile, no chip; Mosaic as on the chip, not interpreted): the forward
+    kernel and the backward kernel under the chain's scope and nothing of XLA's
+    around them that moves an array: no pad (no ``[B, T + K - 1, C]`` array in
+    any dtype: the rows before a block are read by a second BlockSpec), no
+    transpose (the move between the layouts is the BlockSpecs' index maps),
+    no slice back."""
+    monkeypatch.setattr(kda, "_interpret_on", lambda platform: False)
+    fn, _, args = _chain(op, (1, 2048, 4, 128), jnp.bfloat16)
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+    text = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                                      argnums=range(len(args)))).trace(*shapes).lower(
+                                          lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    kernels = [k for k in kda.CHAIN_KERNELS if f'kernel_name = "{k}"' in text]
+    assert len(kernels) == 2 and kernels[0][:-3] == kernels[1][:-3]  # one chain's fwd and bwd
+    scope = kda.CHAIN_SCOPES[kernels[0].split("_")[1]]
+    for kernel in kernels:
+        named = [line for line in text.splitlines()
+                 if line.lstrip().startswith("#loc") and f"{kernel}/" in line]
+        assert named and all(scope in line for line in named), kernel
+    body = text[:text.index("\n#loc", 200)]
+    assert "stablehlo.pad" not in body and "stablehlo.transpose" not in body
+    assert "x2051x" not in body and "stablehlo.slice" not in body
+
+
+def test_every_chain_leaves_a_note_with_its_stated_bytes():
+    for op in CHAINS:
+        fn, _, args = _chain(op, (2, 17, 3, 8), jnp.bfloat16)  # a shape no other test notes
+        fn(*args)
+    notes = {n["op"]: n for n in trace.program_notes(kda.CHAIN_NOTE)
+             if (n["rows"], n["columns"]) == (34, 24)}
+    # bfloat16 [34, 24]: one in and one out; one in and float32 out; two in and one out
+    assert {op: n["bytes"] for op, n in notes.items()} == {
+        "conv_act": 2 * 34 * 24 * 2, "decay": 34 * 24 * (2 + 4), "gated_norm": 3 * 34 * 24 * 2}
+
+
 def test_decay_floor_is_the_least_chunk_sum():
     g = -jnp.arange(1.0, 11.0)[None, :, None] * jnp.ones((2, 10, 3))  # tokens 1..10
     assert float(kda.decay_floor(g, 4)) == -(5 + 6 + 7 + 8)  # chunks 1-4, 5-8, 9-10 (padded)
@@ -162,10 +283,22 @@ def test_every_call_leaves_a_note():
 
 
 # -- what a block adds to the program -----------------------------------------------
-# a delta-attention block's value holds one Mosaic kernel, kda_fwd, and its
-# gradient a second, kda_bwd; neither holds a loop (a group's chunks lie side by
-# side in a grid step, and the grid walks the groups). A rematerialised block
-# keeps the scan's output and states by name and runs no forward kernel twice
+# a delta-attention block's value holds the scan's kernel, kda_fwd, and the five
+# chain kernels round it (three streams, the decay, the gated norm); its gradient
+# the scan's second, kda_bwd, and the five backward kernels. The scan's kernels
+# hold no loop (a group's chunks lie side by side in a grid step, and the grid
+# walks the groups); a chain's kernel holds one, over its block's pieces, and XLA
+# sees none. A rematerialised block keeps the scan's output and states by name and
+# runs no scan twice; the chains, which keep nothing, run again
+
+FORWARD = {"kda_fwd": 1, "kda_conv_fwd": 3, "kda_decay_fwd": 1, "kda_gate_fwd": 1}
+BACKWARD = {"kda_bwd": 1, "kda_conv_bwd": 3, "kda_decay_bwd": 1, "kda_gate_bwd": 1}
+
+
+def _with_their_loops(kernels):
+    """... and the one loop inside each chain kernel (jax writes it ``scan``)."""
+    return {**kernels, "scan": sum(v for k, v in kernels.items() if k in kda.CHAIN_KERNELS)}
+
 
 def _block(remat_on):
     cls = remat.block(MLABlock) if remat_on else MLABlock
@@ -184,9 +317,10 @@ def test_loops_and_kernels_a_delta_attention_block_adds(remat_on):
     x = jax.random.normal(jax.random.key(0), (1, 48, 64))
     params = block.init(jax.random.key(1), x)
     value = lambda params: jnp.sum(block.apply(params, x)[0])  # noqa: E731
-    assert dict(_loops(jax.make_jaxpr(value)(params).jaxpr)) == {"kda_fwd": 1}
-    assert dict(_loops(jax.make_jaxpr(jax.value_and_grad(value))(params).jaxpr)) == {
-        "kda_fwd": 1, "kda_bwd": 1}
+    assert dict(_loops(jax.make_jaxpr(value)(params).jaxpr)) == _with_their_loops(FORWARD)
+    again = {k: 2 * v for k, v in FORWARD.items() if k != "kda_fwd"} if remat_on else {}
+    assert dict(_loops(jax.make_jaxpr(jax.value_and_grad(value))(params).jaxpr)) == (
+        _with_their_loops({**FORWARD, **again, **BACKWARD}))
     kept = {n["kept"] for n in trace.program_notes(remat.NOTE)}
     if remat_on:
         assert set(remat.KDA_KEPT) <= kept

@@ -187,6 +187,27 @@ def test_delta_attention_is_its_equations():
     assert float(floor_c) == float(floor) < 0
 
 
+@pytest.mark.parametrize("attn_impl", ["xla", "flash"])
+@pytest.mark.parametrize("name,stand_in", [
+    ("short_conv", lambda x, w: x), ("l2norm", lambda x, eps=1e-6: x.astype(jnp.float32))],
+    ids=["short_conv", "l2norm"])
+def test_a_patched_definition_is_the_one_the_mixer_runs(monkeypatch, name, stand_in, attn_impl):
+    """``ops/kda.py``'s ``short_conv`` and ``l2norm`` are looked up in the module
+    when the operators are traced: the benchmark's controls
+    (``tests/benchmark_tests/test_benchmark_kda.py``) break the model by
+    standing another function in their place, so each stand-in must change
+    the module's output, on both paths."""
+    params, _, _ = _seeded(_model())
+    p = params["block_1"]["attn"]
+    h = jax.random.normal(jax.random.key(4), (1, T, D))
+    module = DeltaAttention(4, 16, attn_impl=attn_impl)
+    sound, _ = module.apply({"params": p}, h)
+    monkeypatch.setattr(kda_ops, name, stand_in)
+    broken, _ = module.apply({"params": p}, h)
+    assert bool(jnp.all(jnp.isfinite(broken)))
+    assert float(jnp.max(jnp.abs(broken - sound))) > 0.05 * float(jnp.max(jnp.abs(sound)))
+
+
 class _LatentAttentionAsItWas(LatentAttention):
     """``LatentAttention.__call__`` as the parent commit had it (PR 34): a
     query latent and rotation always."""

@@ -544,10 +544,13 @@ def _delta_lm(**over):
 
 
 def test_delta_attention_scopes_note_and_kept_names_in_the_lowered_training_step():
-    """``attn/kda`` round the mixer and ``attn/kda/scan`` round the recurrence
-    alone, inside ``fed/fwd_bwd``, forward and backward; a ``kda/call`` note a
-    call; a rematerialised block's ``remat/kept`` notes for the three
-    projections, the scan's output and its states."""
+    """``attn/kda`` round the mixer, ``attn/kda/scan`` round the recurrence
+    alone and ``attn/kda/conv``, ``attn/kda/decay``, ``attn/kda/gate`` round the
+    three pointwise chains, inside ``fed/fwd_bwd``, forward and backward; a
+    ``kda/call`` note a call and a ``kda/chain`` note an operator; a
+    rematerialised block's ``remat/kept`` notes for the three projections, the
+    scan's output and its states: the same five names as before the
+    operators, which keep nothing."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -555,8 +558,10 @@ def test_delta_attention_scopes_note_and_kept_names_in_the_lowered_training_step
     from fedml_tpu.core.trainer import ClientTrainer
     from fedml_tpu.ops import kda, remat
 
-    assert trace.KDA_SCOPES == (trace.SCOPE_KDA, trace.SCOPE_KDA_SCAN) == (
-        "attn/kda", "attn/kda/scan")
+    assert trace.KDA_SCOPES == (
+        trace.SCOPE_KDA, trace.SCOPE_KDA_SCAN, trace.SCOPE_KDA_CONV, trace.SCOPE_KDA_DECAY,
+        trace.SCOPE_KDA_GATE) == (
+            "attn/kda", "attn/kda/scan", "attn/kda/conv", "attn/kda/decay", "attn/kda/gate")
     assert not set(trace.KDA_SCOPES) & (
         set(trace.SCOPES) | set(trace.MOE_SCOPES) | set(trace.MLA_SCOPES))
     model = _delta_lm(remat=True)
@@ -576,8 +581,13 @@ def test_delta_attention_scopes_note_and_kept_names_in_the_lowered_training_step
     assert any("attn/kda/q/dot_general" in ln and "attn/kda/scan" not in ln for ln in lines)
     assert {"impl": trace.KDA_FWD_KERNEL_NAME, "chunk": kda.CHUNK, "chunks": 1, "heads": 2,
             "d_k": 16, "d_v": 16, "t": 24} in trace.program_notes("kda/call")
+    # [2, 24, 2 x 16] float32: one in and one out; one in and float32 out; two in and one out
+    chains = {n["op"]: n for n in trace.program_notes(kda.CHAIN_NOTE)
+              if (n["rows"], n["columns"]) == (48, 32)}
+    assert {op: n["bytes"] for op, n in chains.items()} == {
+        "conv_act": 2 * 48 * 32 * 4, "decay": 48 * 32 * 8, "gated_norm": 3 * 48 * 32 * 4}
     kept = {n["kept"]: n for n in trace.program_notes(remat.NOTE) if n["kept"].startswith("kda/")}
-    assert set(kept) == set(remat.KDA_KEPT)
+    assert set(kept) == set(remat.KDA_KEPT) and len(kept) == 5
     assert kept[remat.KDA_Q]["shape"] == (2, 24, 32) and kept[remat.KDA_Q]["bytes"] == 2 * 24 * 32 * 4
     # one group of one (padded) chunk: the state that entered it, float32 a head
     # (transposed: [d_v, d_k]), and the padded rows of each of the 2 x 2 heads
