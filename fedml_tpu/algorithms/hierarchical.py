@@ -70,8 +70,9 @@ class HierarchicalFedAvg:
         for g_round in range(hier.global_comm_round):
             group_models, group_weights = [], []
             for gid, client_ids in self.groups.items():
-                # sim._round_fn donates its params argument; give each group a
-                # private copy so the global model survives all groups.
+                # the round program consumes its model argument (FedSim.
+                # _call_round); give each group a private copy so the global
+                # model survives all groups.
                 gvars = jax.tree.map(jnp.copy, variables)
                 for _ in range(hier.group_comm_round):
                     # shared staging + dispatch: straggler budgets, padding,

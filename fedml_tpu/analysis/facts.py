@@ -38,7 +38,7 @@ _KEY_RE = re.compile(r"^MSG_ARG_KEY_\w+$")
 # schema version of the serialized facts: bump on ANY change to the
 # dataclasses below or to extraction semantics — the cache discards
 # mismatched entries wholesale
-FACTS_SCHEMA_VERSION = 2
+FACTS_SCHEMA_VERSION = 3
 
 # call names that register their callable arguments as THREAD ENTRIES:
 # the callable runs later on another thread, with no locks held
@@ -363,6 +363,7 @@ class _Extractor(ast.NodeVisitor):
         self.facts = FileFacts(path=source_file.path)
         self.class_stack: list[int] = []
         self.func_stack: list[int] = []
+        self._func_nodes: list[ast.AST] = []  # the defs around the visitor
         self.held: tuple[str, ...] = ()
         # id(lambda node) -> via, for lambdas handed to lowering calls
         self._lambda_via: dict[int, str] = {}
@@ -474,10 +475,12 @@ class _Extractor(ast.NodeVisitor):
     def _visit_function(self, node, name: str, kind: str) -> None:
         ff = self._enter_function(node, name, kind)
         self.func_stack.append(ff.index)
+        self._func_nodes.append(node)
         # the body runs later: enclosing with-blocks do NOT protect it
         saved_held, self.held = self.held, ()
         self.generic_visit(node)
         self.held = saved_held
+        self._func_nodes.pop()
         self.func_stack.pop()
 
     def visit_FunctionDef(self, node: ast.FunctionDef) -> None:
@@ -636,6 +639,14 @@ class _Extractor(ast.NodeVisitor):
             arg0 = node.args[0]
             if isinstance(arg0, ast.Name):
                 self.facts.lowered_names.append((arg0.id, via))
+                # a loop variable: the engine writes its programs as rows
+                # of a table and lowers them through one call (``for name,
+                # impl, ... in rows: displib.lower(impl, ...)``). What the
+                # variable may hold is what the enclosing function puts
+                # into its tuples and lists: those method handles are
+                # recorded like a handle passed directly
+                for handle in self._row_handles(arg0.id):
+                    self.facts.lowered_names.append((handle, via))
             elif isinstance(arg0, ast.Attribute):
                 # method handles lowered by reference — the engine's packed/
                 # sharded program constructors pass bound methods to
@@ -647,6 +658,23 @@ class _Extractor(ast.NodeVisitor):
                 self._lambda_via[id(arg0)] = via
 
         self.generic_visit(node)
+
+    def _row_handles(self, loop_var: str) -> list[str]:
+        """Terminal attrs of the ``self.<method>`` handles inside the tuple
+        and list literals of the enclosing function, when ``loop_var`` is
+        bound by one of its ``for`` targets (else nothing: a plain name is
+        a function's own)."""
+        if not self._func_nodes:
+            return []
+        nodes = list(ast.walk(self._func_nodes[-1]))
+        targets = {n.id for loop in nodes if isinstance(loop, ast.For)
+                   for n in ast.walk(loop.target) if isinstance(n, ast.Name)}
+        if loop_var not in targets:
+            return []
+        return [elt.attr for row in nodes if isinstance(row, (ast.Tuple, ast.List))
+                for elt in row.elts
+                if isinstance(elt, ast.Attribute)
+                and isinstance(elt.value, ast.Name) and elt.value.id == "self"]
 
     def _note_thread_entry(self, node: ast.Call, dotted: str | None) -> None:
         refs: list[tuple[str, tuple[str, str], int]] = []

@@ -11,7 +11,8 @@ likewise. jax.debug.print / jax.random are the traced-safe counterparts.
 Scope: per module — functions (a) decorated with ``jax.jit`` /
 ``partial(jax.jit, ...)``, or (b) passed by NAME as the first argument to
 ``jax.jit`` / ``jax.shard_map`` / ``dispatch.lower`` /
-``jit_under_mesh`` / ``pallas_call``, plus every ``def`` nested inside
+``jit_under_mesh`` / ``pallas_call`` (or as a row of a table whose loop
+variable is: ``facts._row_handles``), plus every ``def`` nested inside
 them. No interprocedural analysis: a helper called from a traced body is
 only scanned if it is itself lowered — the rule catches the direct form.
 """

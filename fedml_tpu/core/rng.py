@@ -22,11 +22,6 @@ def round_key(key: jax.Array, round_idx: int) -> jax.Array:
     return jax.random.fold_in(key, round_idx)
 
 
-def client_keys(key: jax.Array, num_clients: int) -> jax.Array:
-    """One independent key per client slot (stacked, vmap-able)."""
-    return jax.random.split(key, num_clients)
-
-
 def sample_clients(round_idx: int, client_num_in_total: int,
                    client_num_per_round: int,
                    eligible: np.ndarray | None = None) -> np.ndarray:

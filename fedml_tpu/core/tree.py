@@ -35,18 +35,6 @@ def tree_vectorize(tree: Pytree, exclude: Callable[[str], bool] | None = None) -
     return jnp.concatenate(vecs)
 
 
-def tree_unvectorize(vec: jnp.ndarray, like: Pytree) -> Pytree:
-    """Inverse of :func:`tree_vectorize` (with no exclusions)."""
-    leaves, treedef = jax.tree_util.tree_flatten(like)
-    out = []
-    i = 0
-    for leaf in leaves:
-        n = int(np.prod(leaf.shape)) if leaf.shape else 1
-        out.append(jnp.reshape(vec[i : i + n], leaf.shape).astype(leaf.dtype))
-        i += n
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 def tree_leaves_with_paths(tree: Pytree) -> list[tuple[str, jnp.ndarray]]:
     """List of (path-string, leaf) pairs in canonical traversal order."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
@@ -80,18 +68,9 @@ def tree_sub(a: Pytree, b: Pytree) -> Pytree:
     return jax.tree.map(jnp.subtract, a, b)
 
 
-def tree_scale(tree: Pytree, s) -> Pytree:
-    return jax.tree.map(lambda x: x * s, tree)
-
-
 def tree_dot(a: Pytree, b: Pytree) -> jnp.ndarray:
     parts = jax.tree.map(lambda x, y: jnp.vdot(x, y), a, b)
     return jax.tree_util.tree_reduce(jnp.add, parts, jnp.float32(0.0))
-
-
-def tree_norm(tree: Pytree) -> jnp.ndarray:
-    """Global L2 norm over all leaves."""
-    return jnp.sqrt(tree_dot(tree, tree))
 
 
 def tree_weighted_mean(stacked: Pytree, weights: jnp.ndarray) -> Pytree:
@@ -115,14 +94,6 @@ def tree_weighted_mean(stacked: Pytree, weights: jnp.ndarray) -> Pytree:
 def tree_stack(trees: Sequence[Pytree]) -> Pytree:
     """Stack a list of identically-structured pytrees along a new axis 0."""
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
-
-
-def tree_unstack(stacked: Pytree, n: int) -> list[Pytree]:
-    return [jax.tree.map(lambda x: x[i], stacked) for i in range(n)]
-
-
-def tree_cast(tree: Pytree, dtype) -> Pytree:
-    return jax.tree.map(lambda x: x.astype(dtype), tree)
 
 
 def tree_size(tree: Pytree) -> int:

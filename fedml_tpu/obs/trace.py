@@ -594,11 +594,6 @@ def uninstall_job(job: str) -> Tracer | None:
     return _job_tracers().uninstall(job)
 
 
-def job_tracers() -> dict[str, Tracer]:
-    """Snapshot of the installed job-scoped tracers (job -> tracer)."""
-    return _job_tracers().installed()
-
-
 def get() -> Tracer | None:
     """The calling thread's job-scoped tracer when one is installed, else
     the process tracer, else None. Call sites whose span *attributes* are

@@ -67,12 +67,6 @@ def _key_name(entry) -> str:
     return str(entry)
 
 
-def tree_paths(tree) -> list[tuple[str, Any]]:
-    """``[(joined '/' path, leaf), ...]`` in tree-flatten order."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return [("/".join(_key_name(k) for k in kp), leaf) for kp, leaf in flat]
-
-
 def match_partition_rules(rules, tree) -> Pytree:
     """Pytree of PartitionSpec matching ``tree``'s structure.
 

@@ -31,7 +31,8 @@ from fedml_tpu.algorithms.base import (
 from fedml_tpu.core import rng as rnglib
 from fedml_tpu.core import scan as scanlib
 from fedml_tpu.core.trainer import (
-    STATS_PREFIX, ClientTrainer, make_local_eval, make_local_train,
+    STATS_PREFIX, ClientTrainer, make_lane_step, make_local_eval,
+    make_local_train,
 )
 from fedml_tpu.obs import trace
 from fedml_tpu.parallel import mesh as meshlib
@@ -249,80 +250,95 @@ class FedSim:
                 "(expected 'vmap' or 'scan') — a silent fallback here would "
                 "benchmark or OOM the wrong execution mode"
             )
-        # -- heterogeneous population (fedml_tpu/population, docs/
-        # PERFORMANCE.md "Heterogeneous populations"): resolve the spec or
-        # trace into the round-view provider driving cohorts/budgets/dropout
+        # Each step settles one thing, sets the attributes it owns and reads
+        # what the steps before it have set.
+        self._resolve_population()
+        self._resolve_mesh(mesh)
+        self._resolve_aggregator(aggregator)
+        self._resolve_shard_plan()
+        trainer = self.trainer  # the plan may have rebound its module
+        self._local_train = local_train_fn or make_local_train(trainer)
+        self._can_eval = hasattr(trainer, "eval_batch")
+        self._local_eval = make_local_eval(trainer) if self._can_eval else None
+        # Pin steps-per-epoch to the global max so every round compiles once.
+        self._steps = cohortlib.steps_per_epoch(
+            train_data.max_client_size(), config.batch_size
+        )
+        self._resolve_lanes(local_train_fn)
+        self._place_dataset()
+        self._build_programs()
+        self._place_eval_data(test_arrays)
+
+    # -- the constructor's steps ---------------------------------------------
+
+    def _resolve_population(self):
+        """Heterogeneous population (fedml_tpu/population, docs/
+        PERFORMANCE.md "Heterogeneous populations"): resolve the spec or
+        trace into the round-view provider driving cohorts/budgets/dropout
+        (``_population``; None without one)."""
+        config = self.config
         self._population = None
         self._pop_view_cache: tuple | None = None
-        if config.population or config.population_trace:
-            from fedml_tpu import population as poplib
+        if not (config.population or config.population_trace):
+            return
+        from fedml_tpu import population as poplib
 
-            if config.population and config.population_trace:
-                raise ValueError(
-                    "SimConfig.population and SimConfig.population_trace "
-                    "are both set — one of them would silently win; pick "
-                    "the generative spec OR the trace replay"
-                )
-            if config.straggler_frac > 0:
-                raise ValueError(
-                    "SimConfig.population replaces the uniform "
-                    "straggler_frac draw with speed-model step budgets — "
-                    "configure per-client heterogeneity in exactly one "
-                    "place (drop straggler_frac)"
-                )
-            pop_seed = (config.population_seed
-                        if config.population_seed is not None
-                        else config.seed)
-            if config.population_trace:
-                self._population = poplib.load_trace(config.population_trace)
-                if self._population.num_clients != config.client_num_in_total:
-                    raise ValueError(
-                        f"population trace {config.population_trace} was "
-                        f"captured over {self._population.num_clients} "
-                        f"clients but client_num_in_total="
-                        f"{config.client_num_in_total} — a trace replays "
-                        "one population only"
-                    )
-                if self._population.jitter_active:
-                    # same contract as the generative spec path below: a
-                    # wire-captured schedule replayed on sim must not
-                    # silently lose its jitter dimension
-                    raise NotImplementedError(
-                        f"population trace {config.population_trace} "
-                        "records upload-arrival jitter — a wire-only "
-                        "knob; there is no wire on the sim engine "
-                        "(re-capture without jitter, or run the "
-                        "message-passing backends)"
-                    )
-            else:
-                spec = poplib.parse_population_spec(config.population)
-                if spec.jitter_active:
-                    raise NotImplementedError(
-                        "population jitter schedules upload-arrival delays "
-                        "— a wire-only knob; there is no wire on the sim "
-                        "engine (run the message-passing backends, or drop "
-                        "jitter from the spec)"
-                    )
-                self._population = poplib.Population(
-                    spec, config.client_num_in_total, pop_seed
-                )
-        robust_on = (config.robust_rule != "mean" or config.norm_bound > 0
-                     or config.dp_stddev > 0)
-        if robust_on and aggregator is not None:
+        if config.population and config.population_trace:
             raise ValueError(
-                "SimConfig robust defense flags (robust_rule/norm_bound/"
-                "dp_stddev) conflict with an explicit aggregator= — one of "
-                "them would silently win; configure the defense in exactly "
-                "one place"
+                "SimConfig.population and SimConfig.population_trace "
+                "are both set — one of them would silently win; pick "
+                "the generative spec OR the trace replay"
             )
-        if robust_on:
-            from fedml_tpu.algorithms.robust import RobustConfig, robust_aggregator
+        if config.straggler_frac > 0:
+            raise ValueError(
+                "SimConfig.population replaces the uniform "
+                "straggler_frac draw with speed-model step budgets — "
+                "configure per-client heterogeneity in exactly one "
+                "place (drop straggler_frac)"
+            )
+        pop_seed = (config.population_seed
+                    if config.population_seed is not None
+                    else config.seed)
+        if config.population_trace:
+            self._population = poplib.load_trace(config.population_trace)
+            if self._population.num_clients != config.client_num_in_total:
+                raise ValueError(
+                    f"population trace {config.population_trace} was "
+                    f"captured over {self._population.num_clients} "
+                    f"clients but client_num_in_total="
+                    f"{config.client_num_in_total} — a trace replays "
+                    "one population only"
+                )
+            if self._population.jitter_active:
+                # same contract as the generative spec path below: a
+                # wire-captured schedule replayed on sim must not
+                # silently lose its jitter dimension
+                raise NotImplementedError(
+                    f"population trace {config.population_trace} "
+                    "records upload-arrival jitter — a wire-only "
+                    "knob; there is no wire on the sim engine "
+                    "(re-capture without jitter, or run the "
+                    "message-passing backends)"
+                )
+        else:
+            spec = poplib.parse_population_spec(config.population)
+            if spec.jitter_active:
+                raise NotImplementedError(
+                    "population jitter schedules upload-arrival delays "
+                    "— a wire-only knob; there is no wire on the sim "
+                    "engine (run the message-passing backends, or drop "
+                    "jitter from the spec)"
+                )
+            self._population = poplib.Population(
+                spec, config.client_num_in_total, pop_seed
+            )
 
-            aggregator = robust_aggregator(RobustConfig(
-                norm_bound=config.norm_bound, stddev=config.dp_stddev,
-                rule=config.robust_rule,
-            ))
-        self.aggregator = aggregator or fedavg_aggregator()
+    def _resolve_mesh(self, mesh):
+        """The device mesh, the two shardings staging ships to, and the
+        cohort's size on it (``_c_pad``: the cohort axis pads to a multiple
+        of the mesh's client axis with zero-weight dummies,
+        ``_host_cohort_batches``)."""
+        config = self.config
         if config.mesh_shape is not None and mesh is not None:
             raise ValueError(
                 "SimConfig.mesh_shape and an explicit mesh= were both "
@@ -340,22 +356,52 @@ class FedSim:
             self.mesh = meshlib.shard_mesh((1, len(jax.devices())))
         else:
             self.mesh = meshlib.client_mesh()
-        if robust_on and config.robust_rule != "mean":
+        self._rep = meshlib.replicated(self.mesh)
+        self._shard = meshlib.cohort_batch_sharding(self.mesh)
+        self._client_shard = meshlib.client_sharded(self.mesh)
+        n_dev = self._n_client_shards = self.mesh.shape[meshlib.CLIENT_AXIS]
+        self._c_pad = -(-config.client_num_per_round // n_dev) * n_dev
+        # multi-controller (jax.distributed) jobs: every process stages the
+        # same host arrays but materializes only its addressable shards
+        self._multihost = jax.process_count() > 1
+
+    def _resolve_aggregator(self, aggregator):
+        """The aggregation rule (``aggregator``) with the robust defence and
+        the update codec wrapped around it, and whether it keeps a model per
+        client (``_per_client``)."""
+        config = self.config
+        robust_on = (config.robust_rule != "mean" or config.norm_bound > 0
+                     or config.dp_stddev > 0)
+        if robust_on and aggregator is not None:
+            raise ValueError(
+                "SimConfig robust defense flags (robust_rule/norm_bound/"
+                "dp_stddev) conflict with an explicit aggregator= — one of "
+                "them would silently win; configure the defense in exactly "
+                "one place"
+            )
+        if robust_on:
+            from fedml_tpu.algorithms.robust import RobustConfig, robust_aggregator
+
+            aggregator = robust_aggregator(RobustConfig(
+                norm_bound=config.norm_bound, stddev=config.dp_stddev,
+                rule=config.robust_rule,
+            ))
+        self.aggregator = aggregator or fedavg_aggregator()
+        if (robust_on and config.robust_rule != "mean"
+                and self._c_pad != config.client_num_per_round):
             # order-statistic rules run over the padded cohort stack; any
             # padding slots are zero-delta phantoms that bias the statistic
             # toward the current global — name it loudly
-            n_dev = self.mesh.shape[meshlib.CLIENT_AXIS]
-            c_pad = -(-config.client_num_per_round // n_dev) * n_dev
-            if c_pad != config.client_num_per_round:
-                logging.warning(
-                    "robust rule %r runs over a padded cohort stack: %d real "
-                    "clients + %d zero-delta padding slots (cohort not "
-                    "divisible by the %d-way client mesh) — the order "
-                    "statistic is biased toward the current global; prefer "
-                    "client_num_per_round divisible by the mesh",
-                    config.robust_rule, config.client_num_per_round,
-                    c_pad - config.client_num_per_round, n_dev,
-                )
+            logging.warning(
+                "robust rule %r runs over a padded cohort stack: %d real "
+                "clients + %d zero-delta padding slots (cohort not "
+                "divisible by the %d-way client mesh) — the order "
+                "statistic is biased toward the current global; prefer "
+                "client_num_per_round divisible by the mesh",
+                config.robust_rule, config.client_num_per_round,
+                self._c_pad - config.client_num_per_round,
+                self._n_client_shards,
+            )
         if (config.downlink_compressor
                 and config.downlink_compressor != "none"):
             raise ValueError(
@@ -389,14 +435,12 @@ class FedSim:
                     "message-passing backend (residuals keyed by assigned "
                     "client index)"
                 )
-            n_dev = self.mesh.shape[meshlib.CLIENT_AXIS]
-            c_pad = -(-config.client_num_per_round // n_dev) * n_dev
             self.aggregator = compressed_aggregator(
                 make_codec(config.compressor, topk_frac=config.topk_frac,
                            quantize_bits=config.quantize_bits),
                 inner=self.aggregator,
                 error_feedback=config.error_feedback,
-                num_slots=c_pad,
+                num_slots=self._c_pad,
             )
         # per-client persistent models (decentralized/gossip FL): each client
         # trains from its own round-(r-1) model instead of a broadcast global
@@ -423,13 +467,16 @@ class FedSim:
                 "mismatched topology would silently isolate clients"
             )
 
-        # -- partition-rule model parallelism (docs/PERFORMANCE.md
-        # "Sharded client models"): resolve the rule set into a
-        # PartitionSpec plan over the model variables, rebinding the
-        # trainer's module with the model axis when the plan carries
-        # block-boundary activation constraints (TP) -------------------------
+    def _resolve_shard_plan(self):
+        """Partition-rule model parallelism (docs/PERFORMANCE.md "Sharded
+        client models"): resolve the rule set into a PartitionSpec plan over
+        the model variables (``_var_specs``, ``_var_shardings``; ``_spmd``
+        when it shards anything; ``_shard_gather`` for an FSDP-style plan),
+        rebinding the trainer's module with the model axis when the plan
+        carries block-boundary activation constraints (TP)."""
         from fedml_tpu.parallel import dispatch as displib
 
+        config = self.config
         self._var_specs = None
         self._shard_gather = False
         self._spmd = False
@@ -460,19 +507,19 @@ class FedSim:
             # multi-controller (jax.distributed) meshes are supported: the
             # (hosts x clients x model) device grid comes from shard_mesh's
             # global jax.devices() order, pjit programs run global-view, and
-            # the jax.process_count()>1 capability check below routes model
+            # the jax.process_count()>1 capability check routes model
             # staging through stage_global (each process materializes only
             # its addressable shards of the rule-placed layout)
             ruleset = ruleslib.rule_set(config.shard_rules)
             self._shard_gather = ruleset.gather_compute
             if ruleset.act_spec is not None and hasattr(
-                trainer.module, "mp_axis"
+                self.trainer.module, "mp_axis"
             ):
-                trainer = dataclasses.replace(
-                    trainer,
-                    module=trainer.module.clone(mp_axis=meshlib.MODEL_AXIS),
+                self.trainer = dataclasses.replace(
+                    self.trainer,
+                    module=self.trainer.module.clone(
+                        mp_axis=meshlib.MODEL_AXIS),
                 )
-                self.trainer = trainer
             with self.mesh:
                 self._var_specs = ruleslib.match_partition_rules(
                     ruleset.rules, self._variables_shape_tree()
@@ -506,32 +553,18 @@ class FedSim:
                 "mesh_shape)",
                 self.mesh.shape[meshlib.MODEL_AXIS], meshlib.MODEL_AXIS,
             )
-        # eval programs: plain jit normally; under a shard plan they trace
-        # under the mesh context (module-side constraints) and consume the
-        # model in whatever layout the round program left it
-        jit_ = (
-            (lambda f: displib.jit_sharded(f, self.mesh))
-            if self._spmd else jax.jit
-        )
+        # sharded rounds (pjit) take the tiny [C] cohort vectors (weights,
+        # budgets) replicated — explicit in_shardings reject a mismatched
+        # committed layout; the client-mapped rounds take them over the
+        # client axis like the cohort's arrays
+        self._vector_sharding = (
+            self._rep if self._spmd else self._client_shard)
 
-        self._local_train = local_train_fn or make_local_train(trainer)
-        self._can_eval = hasattr(trainer, "eval_batch")
-        self._local_eval = make_local_eval(trainer) if self._can_eval else None
-        self._client_eval_fn = (
-            jit_(lambda v, d: jax.vmap(self._local_eval, in_axes=(None, 0))(
-                self._compute_view(v), d))
-            if self._can_eval
-            else None
-        )
-
-        # Pin steps-per-epoch to the global max so every round compiles once.
-        self._steps = cohortlib.steps_per_epoch(
-            train_data.max_client_size(), config.batch_size
-        )
-
-        self._rep = meshlib.replicated(self.mesh)
-        self._shard = meshlib.cohort_batch_sharding(self.mesh)
-        self._n_client_shards = self.mesh.shape[meshlib.CLIENT_AXIS]
+    def _resolve_lanes(self, local_train_fn):
+        """Packed-lane geometry (``SimConfig.pack_lanes``; ``_pack``): what
+        packing cannot be combined with, the lane length ``_s_lane`` and the
+        lane step the pass program scans."""
+        config = self.config
         if config.pack_lanes < 0:
             # -1 is NOT "auto" here (unlike pipeline_depth): a negative lane
             # count silently running the padded path would mislabel benchmarks
@@ -540,169 +573,73 @@ class FedSim:
                 "0 disables packing"
             )
         self._pack = config.pack_lanes > 0
-        if self._pack:
-            # One error per conflict, each leading with the SimConfig field
-            # (or constructor argument) that has to change — a config with
-            # several conflicts reports the first, fixes it, and gets the
-            # next precise message instead of one undifferentiated blob.
-            if self._per_client:
-                raise ValueError(
-                    f"aggregator={self.aggregator.name!r} (per-client) "
-                    f"conflicts with pack_lanes={config.pack_lanes}: packed "
-                    "lanes reset carries to the BROADCAST global params at "
-                    "client boundaries, but per-client aggregators (decentralized/"
-                    "gossip) keep a model per client — use the padded path "
-                    "(pack_lanes=0)"
-                )
-            if config.cohort_execution == "scan":
-                raise ValueError(
-                    "SimConfig.cohort_execution='scan' conflicts with "
-                    f"pack_lanes={config.pack_lanes}: packed lanes replace "
-                    "the cohort execution loop entirely — leave "
-                    "cohort_execution='vmap' (lanes are vmapped)"
-                )
-            if local_train_fn is not None:
-                raise ValueError(
-                    "local_train_fn conflicts with pack_lanes="
-                    f"{config.pack_lanes}: packed lanes drive "
-                    "ClientTrainer.train_step directly (boundary-aware lane "
-                    "steps) and cannot honor a custom round program (e.g. "
-                    "the GAN adversarial loop) — use the padded path "
-                    "(pack_lanes=0)"
-                )
-            if config.block_dispatch:
-                raise ValueError(
-                    "SimConfig.block_dispatch=True conflicts with "
-                    f"pack_lanes={config.pack_lanes}: packed rounds already "
-                    "dispatch one program per pass — leave block_dispatch "
-                    "off (or unset) with pack_lanes"
-                )
-            n_dev = self._n_client_shards
-            self._c_pad = -(-config.client_num_per_round // n_dev) * n_dev
-            # Fixed lane length (compile-once): fit the population's largest
-            # per-client step budget, with capacity-factor head room over the
-            # expected per-shard cohort load; overflow draws spill to extra
-            # sequential passes of the same compiled program.
-            sizes = train_data.client_sizes()
-            slots = self._steps * config.batch_size
-            d = np.ceil(
-                np.minimum(sizes, slots) / max(config.batch_size, 1)
-            ).astype(np.int64)
-            t = trainer.epochs * d
-            t_max = int(t.max()) if len(t) else 1
-            mean_t = float(t.mean()) if len(t) else 1.0
-            c_local = self._c_pad // n_dev
-            need = (
-                config.pack_capacity_factor * mean_t * c_local
-                / config.pack_lanes
+        if not self._pack:
+            return
+        # One error per conflict, each leading with the SimConfig field
+        # (or constructor argument) that has to change — a config with
+        # several conflicts reports the first, fixes it, and gets the
+        # next precise message instead of one undifferentiated blob.
+        if self._per_client:
+            raise ValueError(
+                f"aggregator={self.aggregator.name!r} (per-client) "
+                f"conflicts with pack_lanes={config.pack_lanes}: packed "
+                "lanes reset carries to the BROADCAST global params at "
+                "client boundaries, but per-client aggregators (decentralized/"
+                "gossip) keep a model per client — use the padded path "
+                "(pack_lanes=0)"
             )
-            self._s_lane = max(t_max, int(np.ceil(need)), 1)
-        # multi-controller (jax.distributed) jobs: every process stages the
-        # same host arrays but materializes only its addressable shards
-        self._multihost = jax.process_count() > 1
-        # Every compiled round program is lowered through the compile
-        # dispatcher (parallel/dispatch.py): pjit with explicit in/out
-        # shardings when the plan shards the model, the manual shard_map
-        # lowering otherwise — each device then runs an ordinary vmap over
-        # its local cohort slice and the client stacks are all-gathered for
-        # the aggregator. (Leaving the client axis to GSPMD on conv models
-        # hits an XLA limitation: vmap expresses per-client conv kernel
-        # gradients as feature-grouped convolutions, which the SPMD
-        # partitioner cannot split along the group axis.) Other mesh axes
-        # (e.g. ``silo`` intra-client DP) stay automatic.
-        from jax.sharding import PartitionSpec as P
-
-        cohort_spec = P(meshlib.CLIENT_AXIS)
-        # per-client mode: the model state is itself a stacked [C, ...] pytree
-        # sharded over the clients axis, in and out of the round program
-        var_spec = cohort_spec if self._per_client else P()
-        # clients in sequence under a rule that is a function of their
-        # weighted mean: the round program (not the sharded plan's two, nor
-        # the packed lanes') sums the mean in the cohort loop's carry and
-        # never builds the stack of their models (_cohort_mean)
-        self._mean_in_carry = (
-            config.cohort_execution == "scan" and not self._per_client
-            and not self._spmd
-            and getattr(self.aggregator, "aggregate_mean", None) is not None
+        if config.cohort_execution == "scan":
+            raise ValueError(
+                "SimConfig.cohort_execution='scan' conflicts with "
+                f"pack_lanes={config.pack_lanes}: packed lanes replace "
+                "the cohort execution loop entirely — leave "
+                "cohort_execution='vmap' (lanes are vmapped)"
+            )
+        if local_train_fn is not None:
+            raise ValueError(
+                "local_train_fn conflicts with pack_lanes="
+                f"{config.pack_lanes}: packed lanes drive "
+                "ClientTrainer.train_step directly (boundary-aware lane "
+                "steps) and cannot honor a custom round program (e.g. "
+                "the GAN adversarial loop) — use the padded path "
+                "(pack_lanes=0)"
+            )
+        if config.block_dispatch:
+            raise ValueError(
+                "SimConfig.block_dispatch=True conflicts with "
+                f"pack_lanes={config.pack_lanes}: packed rounds already "
+                "dispatch one program per pass — leave block_dispatch "
+                "off (or unset) with pack_lanes"
+            )
+        self._lane_step = make_lane_step(self.trainer)
+        # Fixed lane length (compile-once): fit the population's largest
+        # per-client step budget, with capacity-factor head room over the
+        # expected per-shard cohort load; overflow draws spill to extra
+        # sequential passes of the same compiled program.
+        sizes = self.train_data.client_sizes()
+        slots = self._steps * config.batch_size
+        d = np.ceil(
+            np.minimum(sizes, slots) / max(config.batch_size, 1)
+        ).astype(np.int64)
+        t = self.trainer.epochs * d
+        t_max = int(t.max()) if len(t) else 1
+        mean_t = float(t.mean()) if len(t) else 1.0
+        c_local = self._c_pad // self._n_client_shards
+        need = (
+            config.pack_capacity_factor * mean_t * c_local
+            / config.pack_lanes
         )
-        # shard_map round programs donate the model argument on every
-        # backend; the pjit programs below donate only where the backend
-        # implements it (XLA:CPU does not)
-        self._donate = (0,)
-        # ... but a round that sums the mean in its cohort loop's carry
-        # cannot write the sum over its model: clients start from the model
-        # until the last one has. Donated, XLA would copy the finished sum
-        # back into it (a model read and written a round, under no scope);
-        # not donated, the runtime would hold a third model, the output of
-        # the round it enqueues while this one runs. So such a round takes
-        # one argument more, ``spare``: a model's worth of dead buffers (the
-        # model of the round before, kept by _call_round) that it donates
-        # and sums into, and two models pass each other from round to round.
-        self._spare = None
-        spare_spec = (var_spec,) if self._mean_in_carry else ()
+        self._s_lane = max(t_max, int(np.ceil(need)), 1)
 
-        def round_donate(n_args):  # the spare comes last
-            return (n_args,) if self._mean_in_carry else self._donate
-
-        if self._spmd:
-            # Two-program sharded round: a pjit TRAIN program emits the
-            # cohort's update stack at a program boundary, then a pjit
-            # AGGREGATE program reduces it. The boundary layout follows
-            # the plan's contract: gather_compute (FSDP-style) plans use a
-            # REPLICATED boundary — all cross-shard movement is
-            # concat/slice, never a reassociated reduction, which is what
-            # keeps them bit-identical to the shard_map path
-            # (tools/shard_smoke.py) at the cost of a full [C, model]
-            # stack per device there (gather plans replicate params for
-            # compute anyway, so the boundary is not their binding
-            # memory constraint). TP plans instead keep the stack SHARDED
-            # (clients x each leaf's own model-axis spec) through the
-            # boundary — O(local shard) per chip end to end, the
-            # too-big-for-one-chip contract — accepting the ~1 ULP
-            # cross-shard reduce association TP already carries.
-            self._stack_spec = stack_spec = (
-                P() if self._shard_gather
-                else jax.tree_util.tree_map(
-                    lambda s: P(meshlib.CLIENT_AXIS, *s), self._var_specs,
-                    is_leaf=lambda x: isinstance(
-                        x, jax.sharding.PartitionSpec),
-                )
-            )
-            self._spmd_train_fn = displib.lower(
-                self._spmd_train_impl, mesh=self.mesh,
-                in_specs=(self._var_specs, cohort_spec, P(), P()),
-                out_specs=(stack_spec, P()),
-            )
-            # donate the old global (in/out specs match, and the train
-            # dispatch is ordered before the aggregate on the device
-            # stream, so aliasing is safe) plus the exclusively-owned
-            # stack/loss buffers — without it the big-model path holds two
-            # full model copies live across the aggregate
-            agg_donate = (
-                (0, 2, 3) if jax.default_backend() != "cpu" else ()
-            )
-            self._spmd_agg_fn = displib.lower(
-                self._spmd_agg_impl, mesh=self.mesh,
-                in_specs=(self._var_specs, P(), stack_spec, P(), P(), P(),
-                          P()),
-                out_specs=(self._var_specs, P(), P()),
-                donate_argnums=agg_donate,
-            )
-            self._round_fn = None
-        else:
-            self._round_fn = displib.lower(
-                self._round_impl, mesh=self.mesh,
-                in_specs=(var_spec, P(), cohort_spec, cohort_spec,
-                          cohort_spec, P()) + spare_spec,
-                out_specs=(var_spec, P(), P()),
-                donate_argnums=round_donate(6),
-            )
-        self._eval_fn = jit_(self._eval_impl) if self._can_eval else None
-
-        # Device-resident dataset + in-program cohort gather: the TPU-first
-        # answer to the reference's per-batch .to(device) traffic — ship the
-        # arrays once, then each round uploads only a [C, S, B] index map.
-        nbytes = sum(a.nbytes for a in train_data.arrays.values())
+    def _place_dataset(self):
+        """Device-resident dataset + in-program cohort gather: the TPU-first
+        answer to the reference's per-batch .to(device) traffic — ship the
+        arrays once, then each round uploads only a [C, S, B] index map.
+        Host or device data is settled here, once (``_on_device``): the
+        dispatch sites hand every program ``_data_args`` before what is
+        staged, the resident dataset or nothing."""
+        config = self.config
+        nbytes = sum(a.nbytes for a in self.train_data.arrays.values())
         self._on_device = (
             config.stage_on_device
             if config.stage_on_device is not None
@@ -714,127 +651,214 @@ class FedSim:
             else (self._on_device
                   and next(iter(self.mesh.devices.flat)).platform != "cpu")
         ) and self._on_device and not self._pack and not self._spmd
+        self._dataset = None
         if self._on_device:
             self._dataset = self._put(
-                {k: np.asarray(v) for k, v in train_data.arrays.items()},
+                {k: np.asarray(v) for k, v in self.train_data.arrays.items()},
                 self._rep,
             )
-            if self._spmd:
-                self._spmd_gather_train_fn = displib.lower(
-                    self._spmd_gather_train_impl, mesh=self.mesh,
-                    in_specs=(self._var_specs, P(), cohort_spec, P(), P()),
-                    out_specs=(self._stack_spec, P()),
-                )
-                self._gather_round_fn = None
-            else:
-                self._gather_round_fn = displib.lower(
-                    self._gather_round_impl, mesh=self.mesh,
-                    in_specs=(var_spec, P(), P(), cohort_spec, cohort_spec,
-                              cohort_spec, P()) + spare_spec,
-                    out_specs=(var_spec, P(), P()),
-                    donate_argnums=round_donate(7),
-                )
+        self._data_args = (self._dataset,) if self._on_device else ()
+
+    def _build_programs(self):
+        """The device programs this plan dispatches, as rows of one table:
+        the attribute a program is reached by, its traced function, its in
+        and out specs in the words defined first, and what it donates.
+        A program the plan does not dispatch is None."""
+        from jax.sharding import PartitionSpec as P
+
+        from fedml_tpu.parallel import dispatch as displib
+
+        sharded, gathered = self._spmd, self._on_device
+        # clients in sequence under a rule that is a function of their
+        # weighted mean: the round program (not the sharded plan's two, nor
+        # the packed lanes') sums the mean in the cohort loop's carry and
+        # never builds the stack of their models (_cohort_mean)
+        self._mean_in_carry = (
+            self.config.cohort_execution == "scan" and not self._per_client
+            and not sharded
+            and getattr(self.aggregator, "aggregate_mean", None) is not None
+        )
+        self._spare = None
+        # Every compiled round program is lowered through the compile
+        # dispatcher (parallel/dispatch.py): pjit with explicit in/out
+        # shardings when the plan shards the model, the manual shard_map
+        # lowering otherwise — each device then runs an ordinary vmap over
+        # its local cohort slice and the client stacks are all-gathered for
+        # the aggregator. (Leaving the client axis to GSPMD on conv models
+        # hits an XLA limitation: vmap expresses per-client conv kernel
+        # gradients as feature-grouped convolutions, which the SPMD
+        # partitioner cannot split along the group axis.) Other mesh axes
+        # (e.g. ``silo`` intra-client DP) stay automatic.
+        #
+        # ``rep``: one value everywhere (server state, keys, the resident
+        # dataset). ``cohort``: a cohort's [C, ...] array over the client
+        # axis (index maps, batch stacks, a lane plan: lanes ride the clients
+        # axis, binned per client shard, so gather maps never touch the
+        # model axes). ``vector``: a cohort's [C] weights, budgets or losses,
+        # as staging ships them (_vector_sharding).
+        rep, cohort = P(), P(meshlib.CLIENT_AXIS)
+        vector = rep if sharded else cohort
+        # ``model``: the model at rest, each leaf at its rule's spec under a
+        # shard plan. per-client mode: the model state is itself a stacked
+        # [C, ...] pytree sharded over the clients axis, in and out of the
+        # round program
+        model = (self._var_specs if sharded
+                 else cohort if self._per_client else rep)
+        # ``stack``: the clients' [C, ...] models where they cross from one
+        # program to the next; ``lane_buf``: the round buffers beside it
+        # (written mask + loss/weight scatter buffers). Client-mapped
+        # programs keep both over the client axis. Under a shard plan the
+        # boundary layout follows the plan's contract: gather_compute
+        # (FSDP-style) plans use a REPLICATED boundary — all cross-shard
+        # movement is concat/slice, never a reassociated reduction, which is
+        # what keeps them bit-identical to the shard_map path
+        # (tools/shard_smoke.py) at the cost of a full [C, model] stack per
+        # device there (gather plans replicate params for compute anyway, so
+        # the boundary is not their binding memory constraint). TP plans
+        # instead keep the stack SHARDED (clients x each leaf's own
+        # model-axis spec) through the boundary — O(local shard) per chip
+        # end to end, the too-big-for-one-chip contract — accepting the ~1
+        # ULP cross-shard reduce association TP already carries. The round
+        # buffers follow the STACK's boundary layout, not the lane layout:
+        # under gather plans they must arrive replicated at the aggregate
+        # program, or GSPMD shards the rebuilt per-client stack over clients
+        # and PARTITIONS the aggregator's reduce — a cross-shard partial-sum
+        # reassociation that breaks the gather plan's bit-identity contract
+        # (measured: 1 ULP). TP plans keep them lane-sharded (their reduce
+        # is partitioned anyway — the documented ~1 ULP TP caveat).
+        stack = lane_buf = rep if sharded and self._shard_gather else cohort
+        if sharded and not self._shard_gather:
+            stack = jax.tree_util.tree_map(
+                lambda s: P(meshlib.CLIENT_AXIS, *s), self._var_specs,
+                is_leaf=lambda x: isinstance(x, P),
+            )
+        bufs = (stack, lane_buf, lane_buf, lane_buf)
+        # ``dataset``: the resident dataset in front of what is staged. The
+        # host-staged and the device-gathered form of a program are one row,
+        # without it or with it, under the form's names and dispatch label:
+        dataset = (rep,) if gathered else ()
+        (round_fn, round_impl, self._round_label), train, lane_pass = (
+            (("_gather_round_fn", self._gather_round_impl, "gather"),
+             ("_spmd_gather_train_fn", self._spmd_gather_train_impl),
+             self._packed_gather_pass_impl) if gathered else
+            (("_round_fn", self._round_impl, "padded"),
+             ("_spmd_train_fn", self._spmd_train_impl),
+             self._packed_host_pass_impl))
+        at = len(dataset)  # what follows the dataset sits one place later
+
+        def donated(*argnums):
+            # shard_map programs donate on every backend; the pjit programs
+            # only where the backend implements it (XLA:CPU does not)
+            on_cpu = sharded and jax.default_backend() == "cpu"
+            return () if on_cpu else argnums
 
         if self._pack:
             # Packed-lane programs (docs/PERFORMANCE.md): a zero-buffer init,
             # a lane-scan pass (one per plan pass; the common draw needs one),
             # and the aggregation program consuming the SAME [C_pad, ...]
-            # update stack the padded round builds.
-            from fedml_tpu.core.trainer import make_lane_step
+            # update stack the padded round builds; on a sharded plan
+            # ("Packed lanes on sharded plans") the same three in GLOBAL
+            # view, GSPMD partitioning the model per the rule plan inside
+            # every lane step. The chained round buffers are exclusively
+            # owned (built by the buf program, consumed once per pass, then
+            # by the aggregation) — donate them so passes update the stack
+            # in place instead of holding two [C_pad, model] copies live.
+            rows = [
+                ("_packed_buf_fn", self._packed_buf_impl, (model,), bufs, ()),
+                ("_packed_pass_fn", lane_pass,
+                 (model,) + dataset + (cohort,) * 4 + bufs + (rep,), bufs,
+                 donated(*range(5 + at, 9 + at))),
+                ("_packed_agg_fn", self._packed_agg_impl,
+                 (model, rep) + bufs + (vector, vector, rep),
+                 (model, rep, rep), donated(2, 3, 4, 5)),
+            ]
+        elif sharded:
+            # Two-program sharded round: a pjit TRAIN program emits the
+            # cohort's update stack at a program boundary, then a pjit
+            # AGGREGATE program reduces it, donating the old global (in/out
+            # specs match, and the train dispatch is ordered before the
+            # aggregate on the device stream, so aliasing is safe) plus the
+            # exclusively-owned stack/loss buffers — without it the
+            # big-model path holds two full model copies live across the
+            # aggregate
+            rows = [
+                (*train, (model,) + dataset + (cohort, vector, rep),
+                 (stack, vector), ()),
+                ("_spmd_agg_fn", self._spmd_agg_impl,
+                 (model, rep, stack, vector, vector, vector, rep),
+                 (model, rep, rep), donated(0, 2, 3)),
+            ]
+        else:
+            # The round donates its model argument, but a round that sums
+            # the mean in its cohort loop's carry cannot write the sum over
+            # its model: clients start from the model until the last one
+            # has. Donated, XLA would copy the finished sum back into it (a
+            # model read and written a round, under no scope); not donated,
+            # the runtime would hold a third model, the output of the round
+            # it enqueues while this one runs. So such a round takes one
+            # argument more, ``spare``, last: a model's worth of dead
+            # buffers (the model of the round before, kept by _call_round)
+            # that it donates and sums into, and two models pass each other
+            # from round to round.
+            spare = (model,) if self._mean_in_carry else ()
+            rows = [(round_fn, round_impl,
+                     (model, rep) + dataset + (cohort, vector, vector, rep)
+                     + spare,
+                     (model, rep, rep), (6 + at,) if spare else (0,))]
+            if gathered:  # R rounds in one program, on [R, C, ...] arrays
+                rounds = P(None, meshlib.CLIENT_AXIS)
+                rows.append(("_block_fn", self._block_impl,
+                             (model, rep, rep, rounds, rounds, rounds, rep),
+                             (model, rep, rep), (0,)))
+        self._round_fn = self._gather_round_fn = self._block_fn = None
+        self._spmd_train_fn = self._spmd_gather_train_fn = None
+        self._spmd_agg_fn = self._packed_buf_fn = None
+        self._packed_pass_fn = self._packed_agg_fn = None
+        for name, impl, in_specs, out_specs, donate in rows:
+            setattr(self, name, displib.lower(
+                impl, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+                donate_argnums=donate,
+            ))
+        # one program a role for the dispatch sites, whichever form it has
+        self._round_program = getattr(self, round_fn)
+        self._train_program = getattr(self, train[0])
 
-            self._lane_step = make_lane_step(trainer)
-            if self._spmd:
-                # Packed lanes on a sharded plan (docs/PERFORMANCE.md
-                # "Packed lanes on sharded plans"): the same three-program
-                # family in GLOBAL view. Lane layout is client-axis-only —
-                # the planner still bins each shard's clients into that
-                # shard's lane block, so PackPass gather maps never touch
-                # the model axes — while GSPMD partitions the model per the
-                # rule plan inside every lane step. The update stack crosses
-                # the pass->aggregate boundary at the plan's stack layout
-                # (replicated for gather_compute exactness, sharded for TP
-                # memory), exactly like the padded sharded round above.
-                lane_spec = cohort_spec  # lanes ride the clients axis
-                # The round buffers (written mask + loss/weight scatter
-                # buffers) follow the STACK's boundary layout, not the lane
-                # layout: under gather plans they must arrive replicated at
-                # the aggregate program, or GSPMD shards the rebuilt
-                # per-client stack over clients and PARTITIONS the
-                # aggregator's reduce — a cross-shard partial-sum
-                # reassociation that breaks the gather plan's bit-identity
-                # contract (measured: 1 ULP). TP plans keep them
-                # lane-sharded (their reduce is partitioned anyway — the
-                # documented ~1 ULP TP caveat).
-                buf_spec = P() if self._shard_gather else lane_spec
-                bufs_specs = (self._stack_spec,) + (buf_spec,) * 3
-                self._packed_buf_fn = displib.lower(
-                    self._packed_buf_impl, mesh=self.mesh,
-                    in_specs=(self._var_specs,),
-                    out_specs=bufs_specs,
-                )
-                if self._on_device:
-                    pass_impl = self._packed_gather_pass_impl
-                    pass_specs = (
-                        (self._var_specs, P()) + (lane_spec,) * 4
-                        + bufs_specs + (P(),)
-                    )
-                    buf_args = (6, 7, 8, 9)  # (stack, written, lbuf, wbuf)
-                else:
-                    pass_impl = self._packed_host_pass_impl
-                    pass_specs = (
-                        (self._var_specs,) + (lane_spec,) * 4
-                        + bufs_specs + (P(),)
-                    )
-                    buf_args = (5, 6, 7, 8)
-                # pjit programs gate donation on the backend implementing
-                # it, like agg_donate above
-                pjit_donate = jax.default_backend() != "cpu"
-                self._packed_pass_fn = displib.lower(
-                    pass_impl, mesh=self.mesh,
-                    in_specs=pass_specs,
-                    out_specs=bufs_specs,
-                    donate_argnums=buf_args if pjit_donate else (),
-                )
-                self._packed_agg_fn = displib.lower(
-                    self._packed_agg_impl, mesh=self.mesh,
-                    in_specs=(self._var_specs, P()) + bufs_specs
-                    + (P(), P(), P()),
-                    out_specs=(self._var_specs, P(), P()),
-                    donate_argnums=(2, 3, 4, 5) if pjit_donate else (),
-                )
-            else:
-                self._packed_buf_fn = displib.lower(
-                    self._packed_buf_impl, mesh=self.mesh,
-                    in_specs=(P(),),
-                    out_specs=(cohort_spec,) * 4,
-                )
-                if self._on_device:
-                    pass_impl = self._packed_gather_pass_impl
-                    pass_specs = (P(), P()) + (cohort_spec,) * 8 + (P(),)
-                    buf_args = (6, 7, 8, 9)  # (stack, written, lbuf, wbuf)
-                else:
-                    pass_impl = self._packed_host_pass_impl
-                    pass_specs = (P(),) + (cohort_spec,) * 8 + (P(),)
-                    buf_args = (5, 6, 7, 8)
-                # The chained round buffers are exclusively owned (built by
-                # the buf program, consumed once per pass, then by the
-                # aggregation) — donate them so passes update the stack in
-                # place instead of holding two [C_pad, model] copies live.
-                self._packed_pass_fn = displib.lower(
-                    pass_impl, mesh=self.mesh,
-                    in_specs=pass_specs,
-                    out_specs=(cohort_spec,) * 4,
-                    donate_argnums=buf_args,
-                )
-                self._packed_agg_fn = displib.lower(
-                    self._packed_agg_impl, mesh=self.mesh,
-                    in_specs=(P(), P()) + (cohort_spec,) * 6 + (P(),),
-                    out_specs=(P(), P(), P()),
-                    donate_argnums=(2, 3, 4, 5),
-                )
+        # eval programs: plain jit normally; under a shard plan they trace
+        # under the mesh context (module-side constraints) and consume the
+        # model in whatever layout the round program left it
+        jit_ = (
+            (lambda f: displib.jit_sharded(f, self.mesh))
+            if sharded else jax.jit
+        )
+        self._eval_fn = self._client_eval_fn = None
+        self._eval_gather_fn = self._client_eval_gather_fn = None
+        if self._can_eval:
+            self._eval_fn = jit_(self._eval_impl)
+            self._client_eval_fn = jit_(
+                lambda v, d: jax.vmap(self._local_eval, in_axes=(None, 0))(
+                    self._compute_view(v), d))
+        if self._can_eval and gathered:
+            self._eval_gather_fn = jit_(self._eval_gather_impl)
+            # per-client analogue: gather each chunk's batches from the
+            # resident dataset, then the same vmapped local eval
+            self._client_eval_gather_fn = jit_(
+                lambda variables, dataset, idx: jax.vmap(
+                    self._local_eval, in_axes=(None, 0)
+                )(self._compute_view(variables),
+                  self._gather_batches(dataset, idx))
+            )
 
+    def _place_eval_data(self, test_arrays):
+        """The pooled test batches and what the pooled train eval runs on
+        (``_train_eval``: its program, then that program's arguments after
+        the model)."""
+        config = self.config
         self._test_batches = None
-        if test_arrays is not None and self._can_eval:
+        self._train_eval_idx = None
+        self._train_eval = None
+        if not self._can_eval:
+            return
+        if test_arrays is not None:
             b = cohortlib.batch_array(test_arrays, config.eval_batch_size)
             self._test_batches = (
                 self._put(b, self._rep) if self._on_device else b
@@ -842,36 +866,24 @@ class FedSim:
         # Pooled train eval: on-device mode gathers eval batches from the
         # already-resident dataset (an index map, not a second copy of the
         # training arrays in HBM); host mode keeps materialized batches.
-        self._train_eval_batches = None
-        self._train_eval_idx = None
-        if self._can_eval:
-            n_eval = train_data.num_samples
-            if config.train_eval_samples is not None:
-                n_eval = min(n_eval, config.train_eval_samples)
-            if self._on_device:
-                n = n_eval
-                bs = config.eval_batch_size
-                steps = cohortlib.steps_per_epoch(n, bs)
-                eidx = np.full(steps * bs, -1, np.int32)
-                eidx[:n] = np.arange(n, dtype=np.int32)
-                self._train_eval_idx = self._put(
-                    eidx.reshape(steps, bs), self._rep
-                )
-                self._eval_gather_fn = jit_(self._eval_gather_impl)
-                # per-client analogue: gather each chunk's batches from the
-                # resident dataset, then the same vmapped local eval
-                self._client_eval_gather_fn = jit_(
-                    lambda variables, dataset, idx: jax.vmap(
-                        self._local_eval, in_axes=(None, 0)
-                    )(self._compute_view(variables),
-                      self._gather_batches(dataset, idx))
-                )
-            else:
-                self._train_eval_batches = cohortlib.batch_array(
-                    {k: v[:n_eval] for k, v in train_data.arrays.items()},
-                    config.eval_batch_size,
-                )
-
+        n_eval = self.train_data.num_samples
+        if config.train_eval_samples is not None:
+            n_eval = min(n_eval, config.train_eval_samples)
+        if self._on_device:
+            bs = config.eval_batch_size
+            steps = cohortlib.steps_per_epoch(n_eval, bs)
+            eidx = np.full(steps * bs, -1, np.int32)
+            eidx[:n_eval] = np.arange(n_eval, dtype=np.int32)
+            self._train_eval_idx = self._put(
+                eidx.reshape(steps, bs), self._rep
+            )
+            self._train_eval = (
+                self._eval_gather_fn, self._dataset, self._train_eval_idx)
+        else:
+            self._train_eval = (self._eval_fn, cohortlib.batch_array(
+                {k: v[:n_eval] for k, v in self.train_data.arrays.items()},
+                config.eval_batch_size,
+            ))
 
     @property
     def pipeline_depth(self) -> int:
@@ -881,15 +893,19 @@ class FedSim:
         return 1 if d is None else max(0, int(d))
 
     def _put(self, value, sharding):
-        """device_put that also works when ``self.mesh`` spans processes
+        """device_put (to one sharding, or to a tree of them, a leaf each)
+        that also works when ``self.mesh`` spans processes
         (multi-controller): each process supplies only the shards it owns
         (parallel/multihost.py staging discipline)."""
         if not self._multihost:
             return jax.device_put(value, sharding)
         from fedml_tpu.parallel.multihost import stage_global
 
+        if isinstance(sharding, jax.sharding.Sharding):
+            sharding = jax.tree.map(lambda _: sharding, value)
         return jax.tree.map(
-            lambda leaf: stage_global(np.asarray(leaf), sharding), value
+            lambda leaf, sh: stage_global(np.asarray(leaf), sh),
+            value, sharding,
         )
 
     # -- jitted programs -----------------------------------------------------
@@ -1400,26 +1416,9 @@ class FedSim:
         return v, s, ms
 
     def _get_block_fn(self, n_rounds: int):
-        """Compiled R-round block program (cached per R)."""
-        from jax.sharding import PartitionSpec as P
-
-        from fedml_tpu.parallel import dispatch as displib
-
-        if not hasattr(self, "_block_fns"):
-            self._block_fns = {}
-        if n_rounds not in self._block_fns:
-            cohort_spec = P(None, meshlib.CLIENT_AXIS)
-            var_spec = (
-                P(meshlib.CLIENT_AXIS) if self._per_client else P()
-            )
-            self._block_fns[n_rounds] = displib.lower(
-                self._block_impl, mesh=self.mesh,
-                in_specs=(var_spec, P(), P(), cohort_spec, cohort_spec,
-                          cohort_spec, P()),
-                out_specs=(var_spec, P(), P()),
-                donate_argnums=self._donate,
-            )
-        return self._block_fns[n_rounds]
+        """The R-round block program: one row of ``_build_programs``' table
+        for every R (jit keeps a compiled program per R)."""
+        return self._block_fn
 
     def _stage_block(self, start_round: int, n_rounds: int, root_rng):
         """Host staging for one R-round block: stacked [R, C_pad, ...]
@@ -1518,24 +1517,7 @@ class FedSim:
         """Abstract model variables (shapes/dtypes only) for partition-rule
         planning: ``jax.eval_shape`` over ``trainer.init``, so planning a
         too-big-for-one-chip model never materializes it."""
-        sample = {
-            name: jax.ShapeDtypeStruct(
-                (min(self.config.batch_size, arr.shape[0]),) + arr.shape[1:],
-                arr.dtype,
-            )
-            for name, arr in self.train_data.arrays.items()
-        }
-        sample.setdefault(
-            "mask",
-            jax.ShapeDtypeStruct(
-                (min(self.config.batch_size,
-                     self.train_data.num_samples),), np.float32
-            ),
-        )
-        return jax.eval_shape(
-            partial(self.trainer.init, jax.random.key(self.config.seed)),
-            sample,
-        )
+        return jax.eval_shape(self.init_variables)
 
     def init_round_variables(self, overrides: Pytree | None = None) -> Pytree:
         """Model state in the engine's layout: a replicated global model, or —
@@ -1552,27 +1534,18 @@ class FedSim:
             from fedml_tpu.obs.checkpoint import graft_params
 
             v = graft_params(jax.tree.map(np.asarray, dict(v)), dict(overrides))
-        if not self._per_client:
-            if self._spmd:
-                if self._multihost:
-                    # multi-controller capability path: every process holds
-                    # the same host init; stage_global materializes only the
-                    # addressable shards of each leaf's rule placement
-                    from fedml_tpu.parallel.multihost import stage_global
-
-                    return jax.tree.map(
-                        lambda leaf, sh: stage_global(np.asarray(leaf), sh),
-                        v, self._var_shardings,
-                    )
-                # sharded-at-rest layout: each leaf placed per its rule
-                return jax.device_put(v, self._var_shardings)
-            return self._put(v, self._rep)
-        n_dev = self.mesh.shape[meshlib.CLIENT_AXIS]
-        c_pad = -(-self.config.client_num_in_total // n_dev) * n_dev
-        stacked = jax.tree.map(
-            lambda l: np.broadcast_to(np.asarray(l)[None], (c_pad,) + l.shape), v
-        )
-        return self._put(stacked, meshlib.client_sharded(self.mesh))
+        if self._per_client:
+            # every client is in every round's cohort (_resolve_aggregator),
+            # so the clients' padded count is the cohort's
+            stacked = jax.tree.map(
+                lambda l: np.broadcast_to(
+                    np.asarray(l)[None], (self._c_pad,) + l.shape), v
+            )
+            return self._put(stacked, self._client_shard)
+        # under a shard plan the sharded-at-rest layout: each leaf placed per
+        # its rule (multi-controller: every process holds the same host init
+        # and materializes only the addressable shards of that placement)
+        return self._put(v, self._var_shardings if self._spmd else self._rep)
 
     def consensus(self, variables: Pytree) -> Pytree:
         """A single evaluable model: identity in broadcast mode; the node
@@ -1584,33 +1557,36 @@ class FedSim:
 
     def stage_cohort(self, cohort, round_idx: int):
         """Stage an explicit cohort's data on device: stack, apply straggler
-        budgets, pad to the mesh's client axis, ship. Also used by
-        HierarchicalFedAvg for per-group cohorts."""
-        with trace.span("engine/stage/cohort", round=round_idx):
-            batches, weights, num_steps = self._host_cohort_batches(
-                cohort, round_idx)
-        # sharded rounds (pjit) take the tiny [C] cohort vectors replicated
-        # — explicit in_shardings reject a mismatched committed layout
-        scalar_sharding = (
-            self._rep if self._spmd else meshlib.client_sharded(self.mesh)
-        )
-        with trace.span("engine/stage/put", round=round_idx):
-            batches = self._put(batches, self._shard)
-            weights = self._put(weights, scalar_sharding)
-            num_steps = self._put(num_steps, scalar_sharding)
-        return batches, weights, num_steps
+        budgets, pad to the mesh's client axis, ship."""
+        return self._stage_host_cohort(
+            self._host_cohort_batches, self._shard, cohort, round_idx)
 
-    def _host_cohort_batches(self, cohort, round_idx: int):
-        """Host side of :meth:`stage_cohort`: the cohort's batch stack,
-        weights and step budgets, padded to the mesh."""
+    def _stage_host_cohort(self, host_side, sharding, cohort, round_idx: int):
+        """Build a cohort's data, weights and budgets on the host
+        (``host_side``) and ship them, the data to ``sharding``."""
+        with trace.span("engine/stage/cohort", round=round_idx):
+            data, weights, num_steps = host_side(cohort, round_idx)
+        with trace.span("engine/stage/put", round=round_idx):
+            return (
+                self._put(data, sharding),
+                self._put(weights, self._vector_sharding),
+                self._put(num_steps, self._vector_sharding),
+            )
+
+    def _host_cohort(self, build, fill, cohort, round_idx: int):
+        """What the two host-side builders share around ``build``
+        (``cohortlib.stack_cohort`` or ``cohort_index_map``): the round's
+        shuffle, the budgets and weights, and the padding, whose data slots
+        hold ``fill``."""
         cfg = self.config
         shuffle = (
             np.random.RandomState(cfg.seed * 1_000_003 + round_idx)
             if cfg.shuffle_each_round
             else None
         )
-        batches, weights = cohortlib.stack_cohort(
-            self.train_data, cohort, cfg.batch_size, steps=self._steps, rng=shuffle
+        data, weights = build(
+            self.train_data, cohort, cfg.batch_size, steps=self._steps,
+            rng=shuffle,
         )
         # budgets first: their cohort-identity check fails loudly before
         # the dropout weight mask could hit a shape mismatch
@@ -1619,17 +1595,19 @@ class FedSim:
         # Pad the cohort axis to a multiple of the mesh's client axis with
         # zero-weight dummy clients (fully masked, excluded from the weighted
         # aggregation) so the stack shards evenly over devices.
-        n_dev = self.mesh.shape[meshlib.CLIENT_AXIS]
-        C = len(cohort)
-        pad = (-C) % n_dev
+        pad = (-len(cohort)) % self._n_client_shards
         if pad:
-            batches = {
-                k: np.concatenate([v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-                for k, v in batches.items()
-            }
+            data = jax.tree.map(
+                lambda v: np.concatenate(
+                    [v, np.full((pad,) + v.shape[1:], fill, v.dtype)]), data)
             weights = np.concatenate([weights, np.zeros(pad, np.float32)])
             num_steps = np.concatenate([num_steps, np.zeros(pad, np.int32)])
-        return batches, weights, num_steps
+        return data, weights, num_steps
+
+    def _host_cohort_batches(self, cohort, round_idx: int):
+        """Host side of :meth:`stage_cohort`: the cohort's batch stack,
+        weights and step budgets, padded to the mesh."""
+        return self._host_cohort(cohortlib.stack_cohort, 0, cohort, round_idx)
 
     def _population_view(self, round_idx: int):
         """The round's realized population state (cached per round — the
@@ -1713,43 +1691,15 @@ class FedSim:
         Vectorized (cohortlib.cohort_index_map): a fixed number of numpy ops
         per round regardless of cohort size — the builder run_round,
         run_block, and evaluate_per_client all share."""
-        cfg = self.config
-        shuffle = (
-            np.random.RandomState(cfg.seed * 1_000_003 + round_idx)
-            if cfg.shuffle_each_round
-            else None
-        )
-        idx, weights = cohortlib.cohort_index_map(
-            self.train_data, cohort, cfg.batch_size, steps=self._steps,
-            rng=shuffle,
-        )
-        num_steps = self._round_budgets(cohort, round_idx)
-        weights = self._population_weights(weights, round_idx)
-        n_dev = self.mesh.shape[meshlib.CLIENT_AXIS]
-        pad = (-len(cohort)) % n_dev
-        if pad:
-            idx = np.concatenate(
-                [idx, np.full((pad,) + idx.shape[1:], -1, np.int32)]
-            )
-            weights = np.concatenate([weights, np.zeros(pad, np.float32)])
-            num_steps = np.concatenate([num_steps, np.zeros(pad, np.int32)])
-        return idx, weights, num_steps
+        return self._host_cohort(
+            cohortlib.cohort_index_map, -1, cohort, round_idx)
 
     def stage_cohort_indices(self, cohort, round_idx: int):
         """Device staging for the on-device-dataset path: instead of the full
         [C, S, B, ...] batch stack, upload only a [C, S, B] int32 index map
         (-1 = empty slot); the round program gathers rows in HBM."""
-        with trace.span("engine/stage/cohort", round=round_idx):
-            idx, weights, num_steps = self._host_cohort_indices(
-                cohort, round_idx)
-        sharded = meshlib.client_sharded(self.mesh)
-        scalar_sharding = self._rep if self._spmd else sharded
-        with trace.span("engine/stage/put", round=round_idx):
-            return (
-                self._put(idx, sharded),
-                self._put(weights, scalar_sharding),
-                self._put(num_steps, scalar_sharding),
-            )
+        return self._stage_host_cohort(
+            self._host_cohort_indices, self._client_shard, cohort, round_idx)
 
     def _sample_round_cohort(self, round_idx: int) -> np.ndarray:
         cfg = self.config
@@ -1799,11 +1749,9 @@ class FedSim:
         with trace.span("engine/stage", round=round_idx, packed=self._pack):
             if self._pack:
                 return self._stage_packed_round(cohort, round_idx, rkey)
-            if self._on_device:
-                staged = self.stage_cohort_indices(cohort, round_idx)
-            else:
-                staged = self.stage_cohort(cohort, round_idx)
-            return staged + (rkey,)
+            stage = (self.stage_cohort_indices if self._on_device
+                     else self.stage_cohort)
+            return stage(cohort, round_idx) + (rkey,)
 
     def _pack_round_plan(self, cohort, round_idx: int):
         """Host-only planning for one packed round: the round's [C_pad, S, B]
@@ -1856,31 +1804,22 @@ class FedSim:
                     round=round_idx)
         trace.counter("engine/overflow_passes", len(plan.passes) - 1,
                       round=round_idx)
-        lane_shard = meshlib.client_sharded(self.mesh)
-        # sharded (pjit) packed rounds take the tiny [C_pad] cohort vectors
-        # replicated, matching the aggregate program's in specs (same
-        # contract as stage_cohort's scalar_sharding)
-        scalar_sharding = self._rep if self._spmd else lane_shard
         passes = []
         for pp in plan.passes:
-            pidx = cohortlib.pack_index_map(idx, pp)
-            if self._on_device:
-                data = self._put(pidx, lane_shard)
-            else:
-                data = self._put(
-                    cohortlib.gather_index_stack(self.train_data.arrays, pidx),
-                    lane_shard,
-                )
+            data = cohortlib.pack_index_map(idx, pp)
+            if not self._on_device:  # ship the batches, not their index map
+                data = cohortlib.gather_index_stack(
+                    self.train_data.arrays, data)
             passes.append((
-                data,
-                self._put(pp.slot, lane_shard),
-                self._put(pp.gidx, lane_shard),
-                self._put(pp.boundary, lane_shard),
+                self._put(data, self._client_shard),
+                self._put(pp.slot, self._client_shard),
+                self._put(pp.gidx, self._client_shard),
+                self._put(pp.boundary, self._client_shard),
             ))
         return PackedStaged(
             passes=tuple(passes),
-            weights=self._put(weights, scalar_sharding),
-            num_steps=self._put(num_steps, scalar_sharding),
+            weights=self._put(weights, self._vector_sharding),
+            num_steps=self._put(num_steps, self._vector_sharding),
             rkey=rkey,
             stats={
                 "n_passes": len(plan.passes),
@@ -1900,26 +1839,13 @@ class FedSim:
         if self._spmd:
             # sharded round: train dispatch, then aggregate dispatch — both
             # enqueue asynchronously, so the split costs no host sync.
-            # Normalize caller-held layouts first (a checkpoint restore or
-            # a fresh aggregator state may arrive in another sharding;
-            # device_put short-circuits when it already matches). Multihost
-            # runs skip this: cross-process resharding is not a device_put,
-            # and init_round_variables already places the model globally.
-            if not self._multihost:
-                global_variables = jax.device_put(
-                    global_variables, self._var_shardings)
-                server_state = jax.device_put(server_state, self._rep)
+            global_variables, server_state = self._in_plan_layout(
+                global_variables, server_state)
             with trace.span("engine/dispatch", program="spmd_train",
                             n_rounds=1):
-                if self._on_device:
-                    stack, losses = self._spmd_gather_train_fn(
-                        global_variables, self._dataset, data, num_steps,
-                        rkey,
-                    )
-                else:
-                    stack, losses = self._spmd_train_fn(
-                        global_variables, data, num_steps, rkey
-                    )
+                stack, losses = self._train_program(
+                    global_variables, *self._data_args, data, num_steps, rkey,
+                )
             # the round's second dispatch: n_rounds=0, so that summing
             # n_rounds over dispatches counts each round once
             with trace.span("engine/dispatch", program="spmd_agg",
@@ -1928,22 +1854,29 @@ class FedSim:
                     global_variables, server_state, stack, losses, weights,
                     num_steps, rkey,
                 )
-        if self._on_device:
-            with trace.span("engine/dispatch", program="gather", n_rounds=1):
-                return self._call_round(
-                    self._gather_round_fn, global_variables, server_state,
-                    self._dataset, data, weights, num_steps, rkey,
-                )
-        with trace.span("engine/dispatch", program="padded", n_rounds=1):
+        with trace.span("engine/dispatch", program=self._round_label,
+                        n_rounds=1):
             return self._call_round(
-                self._round_fn, global_variables, server_state, data, weights,
-                num_steps, rkey,
+                self._round_program, global_variables, server_state,
+                *self._data_args, data, weights, num_steps, rkey,
             )
+
+    def _in_plan_layout(self, global_variables, server_state):
+        """The caller's model and server state in a shard plan's at-rest
+        layout: a checkpoint restore or a fresh aggregator state may arrive
+        in another sharding (device_put short-circuits when it already
+        matches). Multihost runs skip this: cross-process resharding is not
+        a device_put, and init_round_variables already places the model
+        globally."""
+        if self._multihost:
+            return global_variables, server_state
+        return (jax.device_put(global_variables, self._var_shardings),
+                jax.device_put(server_state, self._rep))
 
     def _call_round(self, program, global_variables, *args):
         """``program(global_variables, *args)`` for a round program, which
         consumes its model argument. The stack's round donates it. The
-        running mean's round (``__init__``: ``spare``) is handed the model
+        running mean's round (``_build_programs``: ``spare``) is handed the model
         of the round before to sum into, and its own model is kept for the
         round after: the caller's arrays are gone one call later. The first
         round, or one given the same arrays again, sums into new zeros."""
@@ -1961,26 +1894,15 @@ class FedSim:
         """One packed round: zero buffers, P lane-scan passes chaining the
         update stack, then the aggregation program. All dispatches enqueue
         asynchronously, so the extra program boundaries cost no host sync."""
-        if self._spmd and not self._multihost:
-            # sharded packed round: normalize caller-held layouts to the
-            # rule-placed at-rest layout, like run_staged_round's padded
-            # sharded branch (multihost callers stage through
-            # init_round_variables, which already places globally)
-            global_variables = jax.device_put(
-                global_variables, self._var_shardings)
-            server_state = jax.device_put(server_state, self._rep)
+        if self._spmd:
+            global_variables, server_state = self._in_plan_layout(
+                global_variables, server_state)
         bufs = self._packed_buf_fn(global_variables)
         for data, slot, gidx, boundary in staged.passes:
-            if self._on_device:
-                bufs = self._packed_pass_fn(
-                    global_variables, self._dataset, data, slot, gidx,
-                    boundary, *bufs, staged.rkey,
-                )
-            else:
-                bufs = self._packed_pass_fn(
-                    global_variables, data, slot, gidx, boundary, *bufs,
-                    staged.rkey,
-                )
+            bufs = self._packed_pass_fn(
+                global_variables, *self._data_args, data, slot, gidx,
+                boundary, *bufs, staged.rkey,
+            )
         return self._packed_agg_fn(
             global_variables, server_state, *bufs, staged.weights,
             staged.num_steps, staged.rkey,
@@ -2148,11 +2070,8 @@ class FedSim:
         # is async, so the train and test programs overlap on device and the
         # host pays ONE round-trip (device_get) instead of four synchronous
         # float() fetches
-        train_m = (
-            self._eval_gather_fn(variables, self._dataset, self._train_eval_idx)
-            if self._train_eval_idx is not None
-            else self._eval_fn(variables, self._train_eval_batches)
-        )
+        train_eval, *args = self._train_eval
+        train_m = train_eval(variables, *args)
         test_m = (
             self._eval_fn(variables, self._test_batches)
             if self._test_batches is not None

@@ -236,3 +236,151 @@ def test_rules_that_look_at_each_client_declare_nothing(rule):
             make_codec("q8"), inner=fedavg_aggregator(), error_feedback=False),
     }[rule]()
     assert isinstance(agg, Aggregator) and agg.aggregate_mean is None
+
+
+# -- the table of device programs (``FedSim._build_programs``) ----------------
+# One letter a spec. M: the model at rest (replicated; over the client axis
+# where every client keeps its own; each leaf's own spec under shard rules).
+# R: replicated. C: a cohort's array, over the client axis. V: a cohort's [C]
+# vector (weights, budgets, losses), which a pjit program takes replicated.
+# S: the clients' stack where it crosses from one program to the next (under
+# shard rules replicated for a gather plan, clients x each leaf's own spec for
+# a TP plan). L: a lane buffer beside the stack. B: a block's [R, C, ...] array.
+ON_CPU = ()  # a pjit program donates only where the backend implements it
+PROGRAM_PLANS = {
+    "padded host": (dict(stage_on_device=False), {
+        "_round_fn": ("MRCVVR", "MRR", (0,))}),
+    "padded gathered": (dict(), {
+        "_gather_round_fn": ("MRRCVVR", "MRR", (0,))}),
+    "scan carry": (dict(cohort_execution="scan"), {
+        "_gather_round_fn": ("MRRCVVRM", "MRR", (7,))}),
+    "scan carry host": (dict(cohort_execution="scan", stage_on_device=False), {
+        "_round_fn": ("MRCVVRM", "MRR", (6,))}),
+    "per client": (dict(rule="gossip"), {
+        "_gather_round_fn": ("MRRCVVR", "MRR", (0,))}),
+    "resnet_fsdp": (dict(shard_rules="resnet_fsdp"), {
+        "_spmd_gather_train_fn": ("MRCVR", "SV", ()),
+        "_spmd_agg_fn": ("MRSVVVR", "MRR", ON_CPU)}),
+    "resnet_fsdp host": (dict(shard_rules="resnet_fsdp", stage_on_device=False), {
+        "_spmd_train_fn": ("MCVR", "SV", ()),
+        "_spmd_agg_fn": ("MRSVVVR", "MRR", ON_CPU)}),
+    "packed": (dict(pack_lanes=2), {
+        "_packed_buf_fn": ("M", "SLLL", ()),
+        "_packed_pass_fn": ("MRCCCCSLLLR", "SLLL", (6, 7, 8, 9)),
+        "_packed_agg_fn": ("MRSLLLVVR", "MRR", (2, 3, 4, 5))}),
+    "packed host": (dict(pack_lanes=2, stage_on_device=False), {
+        "_packed_buf_fn": ("M", "SLLL", ()),
+        "_packed_pass_fn": ("MCCCCSLLLR", "SLLL", (5, 6, 7, 8)),
+        "_packed_agg_fn": ("MRSLLLVVR", "MRR", (2, 3, 4, 5))}),
+    "packed on resnet_fsdp": (dict(pack_lanes=2, shard_rules="resnet_fsdp"), {
+        "_packed_buf_fn": ("M", "SLLL", ()),
+        "_packed_pass_fn": ("MRCCCCSLLLR", "SLLL", ON_CPU),
+        "_packed_agg_fn": ("MRSLLLVVR", "MRR", ON_CPU)}),
+    "packed on transformer_tp": (dict(pack_lanes=2, shard_rules="transformer_tp"), {
+        "_packed_buf_fn": ("M", "SLLL", ()),
+        "_packed_pass_fn": ("MRCCCCSLLLR", "SLLL", ON_CPU),
+        "_packed_agg_fn": ("MRSLLLVVR", "MRR", ON_CPU)}),
+    "block of 3": (dict(block_dispatch=True), {
+        "_gather_round_fn": ("MRRCVVR", "MRR", (0,)),
+        "block:3": ("MRRBBBR", "MRR", (0,))}),
+    "block of 3 under the scan carry": (dict(block_dispatch=True, cohort_execution="scan"), {
+        "_gather_round_fn": ("MRRCVVRM", "MRR", (7,)),
+        "block:3": ("MRRBBBR", "MRR", (0,))}),
+}
+ROUND_PROGRAMS = ("_round_fn", "_gather_round_fn", "_spmd_train_fn", "_spmd_gather_train_fn",
+                  "_spmd_agg_fn", "_packed_buf_fn", "_packed_pass_fn", "_packed_agg_fn")
+
+
+def _plan_sim(rule="fedavg", shard_rules=None, **over):
+    """A FedSim of the plan: blobs under a two-device client mesh, or, under
+    shard rules, a model they match on a 2 x 2 (clients, model) mesh."""
+    from jax.sharding import PartitionSpec as P
+
+    from fedml_tpu.algorithms.decentralized import gossip_aggregator
+    from fedml_tpu.models.transformer import TransformerLM
+    from fedml_tpu.topology.topology import ring_topology
+
+    class TinyCNN(nn.Module):
+        @nn.compact
+        def __call__(self, x, train: bool = False):
+            x = nn.relu(nn.Conv(8, (3, 3))(x))
+            return nn.Dense(4)(x.reshape((x.shape[0], -1)))
+
+    rng = np.random.RandomState(0)
+    part = {i: np.arange(8 * i, 8 * i + 8) for i in range(4)}
+    cfg = SimConfig(client_num_in_total=4, client_num_per_round=4, batch_size=4,
+                    comm_round=3, frequency_of_the_test=3, seed=0, **over)
+    if shard_rules == "transformer_tp":
+        x = rng.randint(0, 32, (32, 8)).astype(np.int32)
+        train = FederatedArrays({"x": x, "y": x, "mask": np.ones(x.shape, np.float32)}, part)
+        module, task = TransformerLM(vocab_size=32, embed_dim=16, num_layers=1, num_heads=2,
+                                     max_len=8), "nwp"
+    elif shard_rules:
+        train = FederatedArrays({"x": rng.rand(32, 8, 8, 3).astype(np.float32),
+                                 "y": rng.randint(0, 4, 32).astype(np.int32)}, part)
+        module, task = TinyCNN(), "classification"
+    else:
+        train, _ = gaussian_blobs(n_clients=4, samples_per_client=8, num_classes=4, seed=3)
+        module, task = LogisticRegression(num_classes=4), "classification"
+    trainer = ClientTrainer(module=module, task=task, optimizer=optax.sgd(0.1), epochs=1)
+    if shard_rules:
+        cfg = dataclasses.replace(cfg, mesh_shape=(2, 2), shard_rules=shard_rules)
+        sim = FedSim(trainer, train, None, cfg)
+    else:
+        aggregator = gossip_aggregator(ring_topology(4)) if rule == "gossip" else None
+        sim = FedSim(trainer, train, None, cfg, aggregator=aggregator,
+                     mesh=meshlib.client_mesh(jax.devices()[:2]))
+    clients = P(meshlib.CLIENT_AXIS)
+    gather = bool(shard_rules) and shard_rules.endswith("fsdp")
+    letters = {"R": P(), "C": clients, "B": P(None, meshlib.CLIENT_AXIS),
+               "M": clients if rule == "gossip" else P(), "V": clients, "S": clients, "L": clients}
+    if shard_rules:
+        is_spec = lambda x: isinstance(x, P)  # noqa: E731
+        assert any(s != P() for s in jax.tree.leaves(sim._var_specs, is_leaf=is_spec))
+        letters.update(
+            M=sim._var_specs, V=P(), L=P() if gather else clients,
+            S=P() if gather else jax.tree.map(
+                lambda s: P(meshlib.CLIENT_AXIS, *s), sim._var_specs, is_leaf=is_spec))
+    return sim, letters
+
+
+@pytest.mark.parametrize("plan", PROGRAM_PLANS)
+def test_each_plan_builds_its_programs_with_these_specs_and_donations(monkeypatch, plan):
+    """Which device programs a plan builds, under the names their readers know
+    (``chip_smoke.py``, ``tools/lower_hash.py``, ``tests/test_phase_scopes.py``),
+    each one's in and out specs and what it donates; and that the pooled and
+    the per-client eval come in the forms the plan's data asks for."""
+    from fedml_tpu.parallel import dispatch as displib
+
+    over, want = PROGRAM_PLANS[plan]
+    lowered, real = [], displib.lower
+
+    def spy(fn, **kw):
+        lowered.append((real(fn, **kw), fn.__name__, kw))
+        return lowered[-1][0]
+
+    monkeypatch.setattr(displib, "lower", spy)
+    sim, letters = _plan_sim(**over)
+    for name, (ins, outs, donated) in want.items():
+        program = sim._get_block_fn(3) if name == "block:3" else getattr(sim, name)
+        (impl, kw), = [(i, kw) for p, i, kw in lowered if p is program]
+        gathered = "gather_" if sim._on_device and name != "_spmd_agg_fn" else ""
+        assert impl == {"_packed_pass_fn": f"_packed_{gathered or 'host_'}pass_impl",
+                        "block:3": "_block_impl"}.get(name, name[:-2] + "impl"), (name, impl)
+        assert kw["mesh"] is sim.mesh
+        assert tuple(kw["in_specs"]) == tuple(letters[c] for c in ins), (name, kw["in_specs"])
+        assert tuple(kw["out_specs"]) == tuple(letters[c] for c in outs), (name, kw["out_specs"])
+        assert tuple(kw.get("donate_argnums", ())) == donated == program.donate_argnums, name
+    # what the plan does not dispatch is not built (before PR 47 a gathered
+    # program's host-staged twin was, and the padded round under lanes)
+    for name in set(ROUND_PROGRAMS) - set(want):
+        assert getattr(sim, name) is None, name
+    assert (sim._get_block_fn(3) is None) == ("_gather_round_fn" not in want)
+    # the eval programs: plain jit, or under a shard plan jit under the mesh
+    for name in ("_eval_fn", "_client_eval_fn", "_eval_gather_fn", "_client_eval_gather_fn"):
+        program = getattr(sim, name)
+        if "gather" in name and not sim._on_device:
+            assert program is None, name
+        else:
+            assert isinstance(program, displib.Lowered) == bool(over.get("shard_rules")), name
+            assert callable(program)

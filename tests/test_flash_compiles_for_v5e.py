@@ -116,3 +116,48 @@ def test_the_mixers_chains_compile_for_the_v5e(monkeypatch, one_chip, op, b, t, 
     assert sum(kernel in text for kernel in kda.CHAIN_KERNELS) == 2
     if t % kda.BLOCK == 0:
         assert " copy(" not in text and " transpose(" not in text and " pad(" not in text
+
+
+def test_lower_hash_is_steady_and_sees_a_renamed_scope(monkeypatch, one_chip):
+    """``tools/lower_hash.py``, the check that a host-side change moved no
+    device program: a ``FedSim`` built on the described chip twice gives one
+    hash of its round program, kernel payloads and file lines left out, and a
+    scope under another name gives another."""
+    import numpy as np
+    import optax
+
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.models.transformer import TransformerLM
+    from fedml_tpu.obs import trace
+    from fedml_tpu.sim.cohort import FederatedArrays
+    from fedml_tpu.sim.engine import FedSim, SimConfig
+    from tools import lower_hash
+
+    x = np.random.RandomState(0).randint(0, 31, (8, 128)).astype(np.int32)
+    train = FederatedArrays(
+        {"x": x, "y": np.roll(x, -1, 1), "mask": np.ones(x.shape, np.float32)},
+        {c: np.arange(4 * c, 4 * c + 4) for c in range(2)})
+    trainer = ClientTrainer(
+        module=TransformerLM(vocab_size=31, embed_dim=256, num_layers=1, num_heads=2,
+                             max_len=128, attn_impl="flash"),
+        task="nwp", epochs=1, optimizer=optax.sgd(0.01, momentum=0.9))
+    cfg = SimConfig(client_num_in_total=2, client_num_per_round=2, batch_size=2, comm_round=1,
+                    frequency_of_the_test=1000, seed=0, cohort_execution="scan")
+    monkeypatch.setattr(FedSim, "_put", lower_hash.put_shapes)
+    lower_hash.as_on_the_chip(monkeypatch.setattr)
+    mesh = lower_hash.described_chip_mesh(next(iter(one_chip.device_set)))
+
+    def texts():
+        sim = FedSim(trainer, train, None, cfg, mesh=mesh)
+        assert sim._block_dispatch  # as on the chip: the mesh's platform says so
+        lowered = lower_hash.lower_program(sim, "round")
+        return lower_hash.strip(lowered.as_text(debug_info=True)), lowered.as_text()
+
+    stripped, whole = texts()
+    assert whole.count("tpu_custom_call") == 2 and "engine.py" not in stripped
+    assert f'"{trace.SCOPE_OPT}/' in stripped or f"/{trace.SCOPE_OPT}/" in stripped
+    assert lower_hash.sha256(texts()[0]) == lower_hash.sha256(stripped)
+    monkeypatch.setattr(trace, "SCOPE_OPT", "fed/renamed")
+    renamed = texts()[0]
+    assert "fed/renamed" in renamed
+    assert lower_hash.sha256(renamed) != lower_hash.sha256(stripped)

@@ -366,8 +366,10 @@ def test_t1_budget_parses_durations_and_headroom():
     report = build_report(parse_log(log))
     assert report["total_s"] == 696.39
     assert report["over_budget"] is False
-    assert report["budget_headroom_s"] == pytest.approx(23.61)
-    assert report["timeout_headroom_s"] == pytest.approx(173.61)
+    # against the driver's command: a working budget of 1,100 s under its
+    # 1,470 s kill
+    assert report["budget_headroom_s"] == pytest.approx(403.61)
+    assert report["timeout_headroom_s"] == pytest.approx(773.61)
     # call + setup phases aggregate per test id; files roll tests up
     assert report["slowest_tests"][0]["test"] == "tests/test_b.py::test_y[q8]"
     assert report["slowest_tests"][1]["seconds"] == pytest.approx(12.84)

@@ -267,6 +267,38 @@ def test_traced_purity_method_handle_lowered_by_reference(tmp_path):
     assert "_packed_agg_impl" in live[0].message
 
 
+def test_traced_purity_method_handles_lowered_as_rows_of_a_table(tmp_path):
+    # the engine's table of programs (FedSim._build_programs): the handles
+    # sit in rows and one lowering call takes the loop's variable, so what
+    # the rows hold is scanned, a row's handle named apart from it included
+    live, _, _ = lint(tmp_path, {"m.py": """
+        import time
+
+        from fedml_tpu.parallel import dispatch as displib
+
+        class Engine:
+            def _round_impl(self, x):
+                return x + time.time()  # in a row: fires
+
+            def _train_impl(self, x):
+                print(x)                # in a tuple a row unpacks: fires
+                return x
+
+            def _host_helper(self, x):
+                time.time()             # in no row: clean
+                return x
+
+            def build(self):
+                train = ("_train_fn", self._train_impl)
+                rows = [("_round_fn", self._round_impl, ()), (*train, ())]
+                for name, impl, specs in rows:
+                    setattr(self, name, displib.lower(
+                        impl, mesh=None, in_specs=specs, out_specs=()))
+                self._host_helper(0)
+        """}, select=["traced-purity"])
+    assert sorted(f.message.split("`")[1] for f in live) == ["_round_impl", "_train_impl"]
+
+
 def test_traced_purity_module_wide_bans(tmp_path):
     # banned-module-calls: np.random.* is illegal at ANY scope in modules
     # under the configured prefix (the population subsystem's replay-
