@@ -1,0 +1,5 @@
+"""``loop_cohort_time_pct`` read in ``keyevl2_silo2``: ops no ``fed/*`` phase
+claims whose innermost loop is the cohort's ``lax.map``. The accepted reader
+under the cell's name (PERF.md section 7)."""
+
+from benchmark.layer_metrics.loop_cohort_time_pct import read  # noqa: F401

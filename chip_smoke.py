@@ -26,7 +26,8 @@ b. The kernel path through the library surface: ``FedSim`` +
    (D2048 L8 H16 T1024 V32000 bf16, flash attention at the kernel's own tiles) on
    one device; the lowered round program must contain the Mosaic custom call;
    and ``flash_attention`` against ``attention_reference``, forward and all
-   three gradients of the custom VJP.
+   three gradients of the custom VJP; the staircase call and the masked call
+   under a selected set (``[1, 32, 8192, 128]`` on 4 KV heads) likewise.
 c. With four or more devices: phase a as it is (the default mesh takes every
    chip) and phase b's round under three sharded plans over all of them.
    With fewer it says so by name and does not run.
@@ -386,6 +387,61 @@ def check_staircase_against_reference(shape=(1, 8, 4096, 128), stair=(1024, 64))
     return {n: float(f"{e:.3g}") for n, e in errs.items()}
 
 
+def check_selected_against_reference(shape=(1, 32, 8192, 128), kv_heads=4, topk=2048) -> dict:
+    """``flash_attention_selected`` (``ops/dsa.py``'s masked call: 32 query
+    heads on 4 KV heads, each query a chosen 2,048 of its earlier keys, the
+    set handed over bit-packed with its tiles' counts): output, log-sum-exp
+    and the three gradients against ``attention_reference`` under the same
+    mask, a KV head's group at a time so that the reference's float32 scores
+    fit."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops import dsa
+    from fedml_tpu.ops.attention import attention_reference, flash_attention_selected
+
+    f32 = jnp.float32
+    b, h, t, d = shape
+    group = h // kv_heads
+    kq, kk, kv, kg, ks = jax.random.split(jax.random.key(2), 5)
+    q, g = (jax.random.normal(key, shape, jnp.bfloat16) for key in (kq, kg))
+    k, v = (jax.random.normal(key, (b, kv_heads, t, d), jnp.bfloat16) for key in (kk, kv))
+    block = min(512, t)
+    rows = jax.lax.map(  # a random score a pair: each query's topk largest among its earlier keys
+        lambda i: dsa._choose(jax.random.normal(jax.random.fold_in(ks, i), (b, block, t), f32),
+                              i * block, topk)[0], jnp.arange(t // block))
+    chosen = rows.transpose(1, 0, 2, 3).reshape(b, t, t)
+    selection = jax.jit(lambda m: dsa.selection_from_mask(m, block))(chosen)
+
+    def both(fn):
+        def loss(q, k, v, g):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out.astype(f32) * g.astype(f32)) + jnp.sum(lse), (out, lse)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    (_, got_aux), got = both(
+        lambda q, k, v: flash_attention_selected(q, k, v, selection))(q, k, v, g)
+    reference = both(lambda q, k, v: attention_reference(
+        q.astype(f32), k.astype(f32), v.astype(f32), selected=chosen, with_lse=True))
+    parts = []
+    with jax.default_matmul_precision("highest"):
+        for n in range(kv_heads):
+            heads = slice(n * group, (n + 1) * group)
+            (_, aux), grads = reference(q[:, heads], k[:, n:n + 1], v[:, n:n + 1], g[:, heads])
+            parts.append((*aux, *grads))
+    want = [jnp.concatenate(x, axis=1) for x in zip(*parts)]
+    errs = {}
+    for name, a, b_ in zip(("out", "lse", "dq", "dk", "dv"), (*got_aux, *got), want):
+        a, b_ = a.astype(f32), b_.astype(f32)
+        assert bool(jnp.all(jnp.isfinite(a))), f"selected {name} not finite"
+        errs[name] = float(jnp.max(jnp.abs(a - b_)) / jnp.max(jnp.abs(b_)))
+    say(f"  selected ({topk} of the earlier keys) with lse at {shape} on {kv_heads} KV heads bf16, "
+        "max|diff|/max|ref|: " + ", ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    for name, e in errs.items():
+        assert e <= 2e-2, (name, e)
+    return {n: float(f"{e:.3g}") for n, e in errs.items()}
+
+
 def phase_b() -> dict:
     import jax
 
@@ -404,6 +460,8 @@ def phase_b() -> dict:
         free_device_memory()
     out["kernel_rel_err"] = check_flash_against_reference()
     out["staircase_rel_err"] = check_staircase_against_reference()
+    free_device_memory()
+    out["selected_rel_err"] = check_selected_against_reference()
     free_device_memory()
     return out
 

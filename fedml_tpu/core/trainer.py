@@ -49,6 +49,10 @@ STATS_PREFIX = "stats/"
 # position i predicting the token after the next one. ``init``
 # drops it, and ``loss_fn`` adds ``weight * lm_loss`` of those logits against
 # the targets one step further on; metrics and eval are of the main logits.
+# The collection takes any loss a module owes the trainer: an entry ``{"loss":
+# scalar, "weight": scalar}`` is a loss the module computed itself (the index
+# loss of a sparse-attention indexer, which no logits express), and
+# ``loss_fn`` adds ``weight * loss``. One entry a name, any number of names.
 MTP_COLLECTION = "mtp"
 
 
@@ -247,11 +251,12 @@ class ClientTrainer:
         stats = _flat_stats(new_model_state.pop(STATS_COLLECTION, {}))
         with jax.named_scope(trace.SCOPE_LOSS):
             loss = self.loss_and_metrics[0](logits, batch)
-        mtp = new_model_state.pop(MTP_COLLECTION, None)
-        if mtp:
-            (ahead,) = mtp.values()
+        for owed in (new_model_state.pop(MTP_COLLECTION, None) or {}).values():
+            if "loss" in owed:  # a loss the module computed itself
+                loss = loss + owed["weight"] * owed["loss"]
+                continue
             with jax.named_scope(trace.SCOPE_MTP), jax.named_scope(trace.SCOPE_LOSS):
-                loss = loss + ahead["weight"] * lm_loss(ahead["logits"], one_token_further(batch))
+                loss = loss + owed["weight"] * lm_loss(owed["logits"], one_token_further(batch))
         if self.prox_mu > 0.0:
             from fedml_tpu.core import tree as treelib
 

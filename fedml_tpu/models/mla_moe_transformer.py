@@ -86,6 +86,25 @@ heads of ``head_dim`` columns with rotate-half positions on q and k:
               and the summaries of every ``eva_chunk`` of the windows before it
     x1      = x + concat_heads(o) W_o
 
+A sixth mixer, "dsa", makes the Keye-VL-2.0 language model's block (every
+layer routed through a **softmax** router, ``router`` "softmax": the
+``experts_per_token`` largest of ``u W_r`` and a softmax over those, no
+selection bias and no such leaf; no shared expert): the "gqa" mixer's
+attention over a learned selection of keys (:class:`Indexer`; the mathematics
+is ``ops/dsa.py``'s). With ``hbar = stop_gradient(h)``, ``index_heads`` J heads
+of ``index_dim`` d columns:
+
+    qI = hbar W_qI -> [J, T, d];  kI = layernorm(hbar W_kI) -> [T, d]    one key head
+    qI, kI = rope(qI), rope(kI)          rotate-half pairs over the d columns, theta
+    wI = (hbar W_w) * J^-1/2 * d^-1/2 -> [T, J]
+    I[t, s] = sum_j wI[t, j] relu(<qI[t, j], kI[s]>)                     s <= t, float32
+    S_t = the ``index_topk`` largest of I[t, :t + 1];  o = attention over S_t alone
+    L_I = mean over t of KL(mean over heads of the attention's softmax || softmax of I over S_t)
+
+While training the layers' ``L_I`` are summed and handed to the trainer with
+weight 1 (``core/trainer.py`` ``MTP_COLLECTION``): the one loss the indexer's
+leaves learn from, and it moves no other leaf.
+
 With ``num_pred_heads`` P > 1 the head has P x V columns and the logits are
 ``[B, T, P, V]``: head p at position t predicts token t + 1 + p, and the
 trainer's ``lm_loss`` takes ``y`` and ``mask`` of ``[B, T, P]`` as they are.
@@ -108,11 +127,12 @@ from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
 from fedml_tpu.models.moe_transformer import (
     GroupedAttention, Kernel, RMSNorm, RoutedExperts, rope as rope_half)
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import eva, kda, moe, remat, shortconv
+from fedml_tpu.ops import dsa, eva, kda, moe, remat, shortconv
 from fedml_tpu.ops.attention import attention_reference, flash_attention_head_parallel
 from fedml_tpu.ops.head_loss import decoder_head
 
-MLA, KDA, CONV, GQA, EVA = "mla", "kda", "conv", "gqa", "eva"
+MLA, KDA, CONV, GQA, EVA, DSA = "mla", "kda", "conv", "gqa", "eva", "dsa"
+SIGMOID, SOFTMAX = "sigmoid", "softmax"  # MLABlock.router
 
 
 def rope_interleaved(x, theta: float):
@@ -285,6 +305,31 @@ class EvaAttention(nn.Module):
             return nn.Dense(h.shape[-1], use_bias=False, name="o", dtype=self.dtype)(o), mass
 
 
+class Indexer(nn.Module):
+    """The lightning indexer of the "dsa" mixer (the module docstring's
+    equations): ``(qI [B, J, T, d], kI [B, T, d], wI [B, T, J])`` from the
+    normed stream, which the caller hands over under ``stop_gradient``."""
+
+    heads: int
+    dim: int
+    rope_theta: float
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, _ = h.shape
+
+        def dense(name, width):
+            return nn.Dense(width, use_bias=False, name=name, dtype=self.dtype)(h)
+
+        q = dense("q", self.heads * self.dim).reshape(b, t, self.heads, self.dim)
+        k = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype, name="k_norm")(dense("k", self.dim))
+        q, k = rope_half(q.transpose(0, 2, 1, 3), self.rope_theta), rope_half(k, self.rope_theta)
+        w = dense("w", self.heads) * (self.heads ** -0.5 * self.dim ** -0.5)
+        return q, k, w.astype(self.dtype)
+
+
 class GatedMLP(nn.Module):
     """``(silu(u G) * (u U)) D``: the leading dense layer's feed-forward and
     the shared expert."""
@@ -330,6 +375,11 @@ class MLABlock(nn.Module):
     eva_window: int = 0
     eva_chunk: int = 0
     norm_unit_offset: bool = False
+    router: str = SIGMOID  # scores under a selection bias | SOFTMAX: over the chosen logits
+    index_heads: int = 0  # of the DSA mixer's indexer
+    index_dim: int = 0
+    index_topk: int = 0
+    index_loss: bool = False  # the DSA mixer also gives its index loss (a training step)
 
     @nn.compact
     def __call__(self, x):
@@ -348,6 +398,24 @@ class MLABlock(nn.Module):
             mixer_stats = {"kda/decay_floor": floor}
         elif self.mixer == CONV:
             mixed = ShortConv(self.conv_size, self.dtype, name="conv")(h)
+        elif self.mixer == DSA:
+            with jax.named_scope(trace.SCOPE_DSA):
+                with jax.named_scope(trace.SCOPE_DSA_INDEX):
+                    index = Indexer(self.index_heads, self.index_dim, self.rope_theta,
+                                    self.rms_eps, self.dtype, name="indexer")(
+                                        jax.lax.stop_gradient(h))
+
+                def attend(q, k, v):
+                    out, found = dsa.sparse_attention(
+                        q, k, v, *index, topk=self.index_topk, impl=self.attn_impl,
+                        with_loss=self.index_loss)
+                    mixer_stats.update(found)
+                    return out
+
+                mixed = GroupedAttention(
+                    self.num_heads, self.kv_heads, self.head_dim, rope_theta=self.rope_theta,
+                    attn_impl=self.attn_impl, dtype=self.dtype, qk_norm_eps=self.rms_eps,
+                    attend=attend, name="attn")(h)
         elif self.mixer == GQA:
             with jax.named_scope(trace.SCOPE_GQA):
                 mixed = GroupedAttention(
@@ -369,10 +437,11 @@ class MLABlock(nn.Module):
                 return x + GatedMLP(self.dense_dim, self.dtype, name="mlp")(u).astype(x.dtype), (
                     mixer_stats)
         u = u.reshape(b * t, d)
-        ids, weights = moe.route(
-            u, Kernel((d, self.num_experts), name="router")(), self.experts_per_token,
-            select_bias=Kernel((1, self.num_experts), name="select_bias")()[0],
-            scale=self.route_scale)
+        router = Kernel((d, self.num_experts), name="router")()
+        scoring = {} if self.router == SOFTMAX else {  # SOFTMAX: over the chosen logits, no bias
+            "select_bias": Kernel((1, self.num_experts), name="select_bias")()[0],
+            "scale": self.route_scale}
+        ids, weights = moe.route(u, router, self.experts_per_token, **scoring)
         if self.shared_dim:
             with jax.named_scope(trace.SCOPE_MOE_SHARED):
                 shared = GatedMLP(self.shared_dim, self.dtype, name="shared")(u)
@@ -388,8 +457,9 @@ class MLAMoETransformerLM(nn.Module):
     """Causal LM of ``dense_layers`` dense then ``routed_layers`` routed
     :class:`MLABlock` layers, with ``mtp_depth`` (0 or 1) multi-token-
     prediction modules of one routed block each. ``mixers`` gives each
-    layer's mixer in order ("mla" | "kda" | "conv" | "gqa" | "eva"; None: latent
-    attention in all). ``shared_dim`` 0: no shared expert. ``tie_head``: the
+    layer's mixer in order ("mla" | "kda" | "conv" | "gqa" | "eva" | "dsa"; None:
+    latent attention in all). ``router`` "softmax": the routed layers' softmax
+    router, which has no ``select_bias`` leaf. ``shared_dim`` 0: no shared expert. ``tie_head``: the
     logits are the final norm's output times the embedding's transpose (in
     float32, as the embedding is), and the tree has no ``head``."""
 
@@ -432,6 +502,10 @@ class MLAMoETransformerLM(nn.Module):
     eva_chunk: int = 4
     norm_unit_offset: bool = False  # every RMSNorm as x / rms(x) * (1 + g)
     num_pred_heads: int = 1  # P > 1: the head has P x V columns, logits [B, T, P, V]
+    router: str = SIGMOID
+    index_heads: int = 0  # of the "dsa" mixer's indexer: heads, columns a head, keys a query
+    index_dim: int = 0
+    index_topk: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -448,9 +522,16 @@ class MLAMoETransformerLM(nn.Module):
 
         layers = self.dense_layers + self.routed_layers
         mixers = (MLA,) * layers if self.mixers is None else tuple(self.mixers)
-        if len(mixers) != layers or set(mixers) - {MLA, KDA, CONV, GQA, EVA}:
+        if len(mixers) != layers or set(mixers) - {MLA, KDA, CONV, GQA, EVA, DSA}:
             raise ValueError(
-                f"mixers must name {layers} layers' mixers, each mla, kda, conv or gqa, or eva")
+                f"mixers must name {layers} layers' mixers, each mla, kda, conv or gqa, or eva "
+                "or dsa")
+        if DSA in mixers and not (self.index_heads and self.index_dim and self.index_topk):
+            raise ValueError("a dsa mixer needs index_heads, index_dim and index_topk")
+        if self.router not in (SIGMOID, SOFTMAX):
+            raise ValueError(f"router is sigmoid or softmax, not {self.router!r}")
+        # the losses a module hands the trainer exist in a training step alone
+        hands_losses = train and self.is_mutable_collection(MTP_COLLECTION)
 
         def block(routed, name, mixer=MLA):
             return block_cls(
@@ -459,7 +540,9 @@ class MLAMoETransformerLM(nn.Module):
                 self.expert_dim, self.shared_dim, self.route_scale, self.experts_first, held,
                 self.rope_theta, self.rms_eps, self.attn_impl, self.dtype, mixer,
                 self.kda_heads, self.kda_head_dim, self.conv_size, self.kv_heads, self.head_dim,
-                self.eva_window, self.eva_chunk, self.norm_unit_offset, name=name)
+                self.eva_window, self.eva_chunk, self.norm_unit_offset, self.router,
+                self.index_heads, self.index_dim, self.index_topk,
+                hands_losses and mixer == DSA, name=name)
 
         def logits(h, norm):
             h = RMSNorm(self.rms_eps, self.head_dtype, self.norm_unit_offset, name=norm)(h)
@@ -472,8 +555,7 @@ class MLAMoETransformerLM(nn.Module):
             h, layer_stats = block(i >= self.dense_layers, f"block_{i}", mixer)(h)
             stats.append(layer_stats)
         # params of every module are made at init, whatever ``train`` says
-        if self.mtp_depth and (self.is_initializing()
-                               or (train and self.is_mutable_collection(MTP_COLLECTION))):
+        if self.mtp_depth and (self.is_initializing() or hands_losses):
             with jax.named_scope(trace.SCOPE_MTP):
                 norm = lambda name, y: RMSNorm(self.rms_eps, self.dtype, name=name)(y)  # noqa: E731
                 g = jnp.concatenate(
@@ -486,10 +568,15 @@ class MLAMoETransformerLM(nn.Module):
                          {"logits": logits(g, "mtp_norm_f"),
                           "weight": jnp.float32(self.mtp_loss_weight)},
                          reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        index_kl = [s["dsa/index_kl"] for s in stats if "dsa/index_kl" in s]
+        if index_kl:  # the sparse-attention layers' index losses, summed: the indexers' one loss
+            self.sow(MTP_COLLECTION, "index",
+                     {"loss": sum(index_kl), "weight": jnp.float32(1.0)},
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
         # for the engine's counters: one value a routed block (the MTP module's
         # last) under "moe", one a delta-attention block under "kda", one an
-        # EVA block under "eva"
-        for group in ("moe", "kda", "eva"):
+        # EVA block under "eva", three a sparse-attention block under "dsa"
+        for group in ("moe", "kda", "eva", "dsa"):
             found = [{k: v for k, v in s.items() if k.startswith(group + "/")} for s in stats]
             found = [s for s in found if s]
             if found:

@@ -79,7 +79,9 @@ class GroupedAttention(nn.Module):
     ``qk_norm_eps`` the queries and keys are RMS-normalised a head before the
     rotation, each kind under one learned scale of ``head_dim`` that the
     heads share (``q_norm``, ``k_norm``); without it the module has no such
-    leaves and computes what it did."""
+    leaves and computes what it did. ``attend`` (``(q, k, v) -> [B, H, T,
+    D]``, all three rotated and normalised as above) stands in for the causal
+    attention itself where a caller chooses the keys (``ops/dsa.py``)."""
 
     num_heads: int
     num_kv_heads: int
@@ -89,6 +91,7 @@ class GroupedAttention(nn.Module):
     attn_impl: str = "xla"  # xla | flash
     dtype: jnp.dtype = jnp.float32
     qk_norm_eps: float | None = None  # None: q and k go on as projected
+    attend: Callable | None = None  # None: every earlier key (inside ``window``)
 
     @nn.compact
     def __call__(self, h):
@@ -105,7 +108,9 @@ class GroupedAttention(nn.Module):
             k = RMSNorm(self.qk_norm_eps, self.dtype, name="k_norm")(k)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
-        if self.attn_impl == "flash":
+        if self.attend is not None:
+            a = self.attend(q, k, v)
+        elif self.attn_impl == "flash":
             a = flash_attention_head_parallel(
                 q, k, v, axis=None, causal=True, window=self.window)
         else:

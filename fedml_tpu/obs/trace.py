@@ -70,7 +70,7 @@ __all__ = [
     "lane_traces",
     "CHROME_TRACE_NAME", "JSONL_TRACE_NAME", "META_EVENT_NAME",
     "SCOPES", "MOE_SCOPES", "MLA_SCOPES", "KDA_SCOPES", "SHORTCONV_SCOPES", "EVA_SCOPES",
-    "LOOP_SCOPES",
+    "DSA_SCOPES", "LOOP_SCOPES",
     "LOOP_CARRY_NOTE",
     "COHORT_AGGREGATE_NOTE",
     "FLASH_KERNEL_NAME",
@@ -151,6 +151,23 @@ SCOPE_EVA_SUMMARY = "attn/eva/summary"
 SCOPE_EVA_MERGE = "attn/eva/merge"
 SCOPE_MLP_DENSE = "mlp/dense"
 EVA_SCOPES = (SCOPE_EVA, SCOPE_EVA_SUMMARY, SCOPE_EVA_MERGE, SCOPE_MLP_DENSE)
+# Scopes of the decoder whose mixer is grouped-query attention over a learned
+# selection of keys (the "dsa" mixer of models/mla_moe_transformer.py;
+# ops/dsa.py), inside SCOPE_FWD_BWD: the mixer whole (the attention's four
+# projections, norms and rotation, the indexer, the selection, the masked
+# flash kernels and the index loss); inside it the indexer (its three
+# projections, norm and rotation, and the index scores made for the
+# selection, which bear the scores' scope too); the selection (the threshold
+# of each row's scores, the chosen set's bits and counts); and the index
+# loss, forward and backward: its own pass over the index scores, the pass
+# over q k^T that gives the heads' mean distribution, and the three gradients
+SCOPE_DSA = "attn/dsa"
+SCOPE_DSA_INDEX = "attn/dsa/index"
+SCOPE_DSA_SCORES = "attn/dsa/index/scores"
+SCOPE_DSA_SELECT = "attn/dsa/select"
+SCOPE_DSA_INDEX_LOSS = "attn/dsa/index_loss"
+DSA_SCOPES = (SCOPE_DSA, SCOPE_DSA_INDEX, SCOPE_DSA_SCORES, SCOPE_DSA_SELECT,
+              SCOPE_DSA_INDEX_LOSS)
 # The loops of a round's path (sim/engine.py, core/trainer.py), opened by
 # :func:`loop` around the call that makes the loop and nothing wider. Never
 # under ``fed/``: the ops inside keep their phase (a reader classes an op by

@@ -22,7 +22,12 @@ summarise the windows before the query's own (``ops/eva.py``), where ``t_q``
 and ``t_k`` differ; a row that sees no key gives zeros and a log-sum-exp of
 about ``NEG_INF / 2``. :func:`flash_attention_lse` hands the log-sum-exp out
 as a second, differentiable output, so that two calls over two key sets can
-share one softmax.
+share one softmax. A fourth mask comes from the data and not from integers:
+:func:`flash_attention_selected` shows query ``i`` the keys of a set chosen for
+it (``ops/dsa.py``), one set a query shared by every head, handed over as a
+:class:`Selection`: the set's bits packed 32 to a word (:func:`selection_layout`),
+once with a query's keys along a row and once with a key's queries, and the
+count of chosen pairs in every tile, by which a tile that holds none is skipped.
 Forward runs the pallas kernel ``flash_fwd``, which also writes each query
 row's log-sum-exp (``B*H*T`` f32, the backward's one extra residual);
 backward is a custom VJP of one more pallas kernel, ``flash_bwd_dkv`` (a key
@@ -44,9 +49,11 @@ Mosaic-compiled; any other backend is refused (see :func:`_interpret_on`).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -94,7 +101,7 @@ def _pick_block(t: int, preferred: int, dtype) -> int:
 
 def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = None,
                         window: int | None = None, stair: tuple | None = None,
-                        with_lse: bool = False):
+                        with_lse: bool = False, selected=None):
     """Plain XLA attention, the numerical oracle for the kernels.
 
     Causal convention (shared with the pallas kernel): query i attends to
@@ -108,7 +115,9 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
     ``stair=(s_q, s_k)`` (without ``causal``): key j is visible to query i iff
     j < s_k * (i // s_q), both counted from 0; a row that sees no key gives
     zeros. ``with_lse``: ``(out, lse [B, H, T_q] float32)``, the log-sum-exp
-    of each row's visible scores (about ``NEG_INF`` where it sees none)."""
+    of each row's visible scores (about ``NEG_INF`` where it sees none).
+    ``selected`` (a bool ``[B, T_q, T_k]``, with neither ``causal`` nor
+    ``stair``): query i sees key j iff ``selected[b, i, j]``, in every head."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     group = _kv_group(q.shape[1], k.shape[1])
@@ -127,6 +136,10 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale: float | None = 
         steps = jnp.arange(s.shape[-2])[:, None] // stair[0]
         mask = jnp.arange(s.shape[-1])[None] < stair[1] * steps
         s = jnp.where(mask, s, NEG_INF)
+    if selected is not None:
+        if causal or stair:
+            raise ValueError("attention: a selected set is the whole mask")
+        s = jnp.where(selected[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if stair:
         p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)
@@ -195,9 +208,64 @@ def _fold(p, lanes):
         jnp.add, (p[:, c:c + lanes] for c in range(0, p.shape[1], lanes)))
 
 
+class Selection(NamedTuple):
+    """The keys chosen for each query, one set a query whatever the head, as
+    the kernels of :func:`flash_attention_selected` read it. ``rows`` ``[B,
+    T_q, W_k]`` int32: query ``i``'s row holds the bit of every key, packed by
+    :func:`selection_layout` of ``T_k``; ``cols`` ``[B, T_k, W_q]``: the same
+    set with a key's queries along its row (the backward's tiles are
+    transposed); ``tiles`` ``[B, T_q / block_q, T_k / block_k]`` int32: chosen
+    pairs in each tile, whose shape fixes the kernels' blocks. A key after its
+    query is never chosen. ``ops/dsa.py`` ``selection_from_mask`` makes one."""
+
+    rows: jax.Array
+    cols: jax.Array
+    tiles: jax.Array
+
+
+def selection_layout(t: int) -> tuple[int, int]:
+    """``(lanes, planes)`` of the bits of ``t`` positions packed into int32
+    words: position ``s`` is bit ``(s // lanes) % planes`` of the word in
+    column ``(s // lanes // planes) * lanes + s % lanes``. A run of ``lanes``
+    positions is then one bit plane of ``lanes`` adjacent words, and a kernel
+    unpacks a tile by a shift and a mask of whole registers, with no move
+    across lanes (``lanes`` 128 wherever 128 divides ``t``)."""
+    lanes = 128 if t % 128 == 0 else t
+    planes = min(32, t // lanes)
+    while (t // lanes) % planes:
+        planes -= 1
+    return lanes, planes
+
+
+def _selected_tile(ref, blk, block, layout):
+    """Bool ``[rows, block]``: block ``blk`` (a traced index) of the packed
+    positions along the rows of ``ref`` ``[rows, W]``."""
+    lanes, planes = layout
+    pieces = []
+    for i in range(block // lanes):
+        piece = blk * (block // lanes) + i
+        group = _step_of(piece, planes)
+        word = ref[:, pl.ds(pl.multiple_of(group * lanes, lanes), lanes)]
+        plane = jnp.broadcast_to(piece - group * planes, word.shape)
+        pieces.append(jax.lax.shift_right_logical(word, plane) & 1)
+    return (pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)) != 0
+
+
+def _tile_counts(ref, heads, num_qb):
+    """``(i, j) -> tiles[b, i, j]`` of a :class:`Selection` inside a kernel
+    whose grid axis 0 is batch x ``heads``: the counts lie in SMEM as ``[B *
+    nq, nk]``."""
+    base = (pl.program_id(0) // heads) * num_qb
+    return lambda i, j: ref[base + i, j]
+
+
+def _flash_fwd_selected_kernel(q_ref, k_ref, v_ref, rows_ref, tiles_ref, *rest, **static):
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sel=(rows_ref, tiles_ref), **static)
+
+
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
-    *, block_k, causal, sm_scale, window=None, stair=None,
+    *, block_k, causal, sm_scale, window=None, stair=None, selected=None, sel=None,
 ):
     # q_ref: [block_q, D_qk]; k_ref: [T_k, D_qk] and v_ref: [T_k, D_v] (the
     # head's whole sequence); o_ref: [block_q, D_v]; lse_ref: [1, block_q];
@@ -237,7 +305,9 @@ def _flash_fwd_kernel(
         k = k_ref[cols, :]
         v = v_ref[cols, :]
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
-        if masked and stair:
+        if masked == "selected":
+            s = jnp.where(_selected_tile(sel[0], j, block_k, selected[0]), s, NEG_INF)
+        elif masked and stair:
             s = jnp.where(k_idx < seen_to - j * block_k, s, NEG_INF)
         elif masked:
             # the tile's column of query i's own position
@@ -259,7 +329,14 @@ def _flash_fwd_kernel(
     def loop(lo, hi, masked):
         jax.lax.fori_loop(lo, hi, lambda j, c: step(masked, j), None)
 
-    if causal:
+    if selected:
+        # every causal tile that holds a chosen pair, under the set's own bits
+        # (which hide what the diagonal would): the count says which hold none
+        tiles = _tile_counts(sel[1], selected[1], pl.num_programs(1))
+        last = _fwd_kb_ranges(iq, block_q, block_k, off, num_kb, None)[3]
+        jax.lax.fori_loop(0, last, lambda j, c: pl.when(tiles(iq, j) > 0)(
+            lambda: step("selected", j)), None)
+    elif causal:
         # under a window the key blocks its edge cuts, then the ones every
         # row sees whole (no mask arithmetic), then the ones the diagonal cuts
         start, whole_start, whole_end, last = _fwd_kb_ranges(
@@ -308,6 +385,16 @@ def _rows_spec(block):
     bytes a head in HBM, and the tile's last two dimensions are the array's
     own, so Mosaic takes every ``block`` that :func:`_pick_block` passes."""
     return pl.BlockSpec((None, None, 1, block), lambda i, j: (i, j, 0, 0))
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)  # a Selection's tile counts, whole
+
+
+def _selected_rows(block, width, heads):
+    """A block of a :class:`Selection`'s packed rows: grid step ``(i, j)`` (a
+    flat batch x head, a block) reads rows ``j`` of batch ``i // heads``, so a
+    batch's heads read the same words."""
+    return pl.BlockSpec((None, block, width), lambda i, j: (i // heads, j, 0))
 
 
 def _mosaic_params(dtype, *whole_sequences, scratch=(), buffers=2):
@@ -372,9 +459,11 @@ def _fwd_blocks(t_q, t_k, dtype, block_q=None, block_k=None, stair=None):
 
 
 @jax.named_scope(trace.SCOPE_FLASH_FWD)
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, stair=None):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, stair=None,
+               selected=None):
     """``(out [B, H, T, D_v], lse [B, H, T] f32)``; ``block_q`` / ``block_k``
-    of ``None`` are chosen by :func:`_fwd_blocks`."""
+    of ``None`` are chosen by :func:`_fwd_blocks`. ``selected``: a
+    :class:`Selection` (with ``causal``), whose tiles are the blocks."""
     b, h, t, d = q.shape
     h_kv, t_k, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(h, h_kv)
@@ -387,19 +476,25 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
     kf = k.reshape(b * h_kv, t_k, d)
     vf = v.reshape(b * h_kv, t_k, d_v)
     kernel = functools.partial(
-        _flash_fwd_kernel,
+        _flash_fwd_selected_kernel if selected else _flash_fwd_kernel,
         block_k=block_k,
         causal=causal,
         sm_scale=sm_scale,
         window=window,
         **({"stair": stair} if stair else {}),
+        **({"selected": (selection_layout(t_k), h)} if selected else {}),
     )
-    _note_call("fwd", q, t_k, d_v, group, causal, window, block_q, block_k, stair)
+    _note_call("fwd", q, t_k, d_v, group, causal, window, block_q, block_k, stair,
+               selected is not None)
+    more_specs, more = [], ()
+    if selected:
+        more_specs = [_selected_rows(block_q, selected.rows.shape[2], h), _SMEM]
+        more = (selected.rows, selected.tiles.reshape(b * nq, t_k // block_k))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq),
         in_specs=[_head_block(block_q, d), _head_seq(t_k, d, group),
-                  _head_seq(t_k, d_v, group)],
+                  _head_seq(t_k, d_v, group), *more_specs],
         out_specs=[_head_block(block_q, d_v), _rows_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype),
@@ -411,7 +506,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=No
         interpret=interpret,
         name=trace.FLASH_KERNEL_NAME,
         compiler_params=_mosaic_params(k.dtype, (t_k, d), (t_k, d_v)),
-    )(qf, kf, vf)
+    )(qf, kf, vf, *more)
     return out.reshape(b, h, t, d_v), lse.reshape(b, h, t)
 
 
@@ -529,11 +624,15 @@ def _stair_qb_ranges(jk, block_q, block_k, num_qb, stair):
     return first, first_whole, num_qb, num_qb
 
 
-def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k, stair=None):
+def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k, stair=None,
+               selected=False):
     """Record, while the program is traced, what one attention call will do:
     its kind, its two widths, its grouping, how many of the square's tiles
     the kernel visits and what it ``writes`` (``obs/trace.py``
-    :func:`program_note`; docs/OBSERVABILITY.md)."""
+    :func:`program_note`; docs/OBSERVABILITY.md). Under a selected set the
+    tiles counted are the causal ones, every one under the set's bits: which
+    of them hold no chosen pair and are skipped only the data says (the
+    ``dsa/tiles_nonempty`` counters)."""
     t_q = q.shape[2]
     nq, nk, off = t_q // block_q, t_k // block_k, t_k - t_q
     if stair and kernel == "dkv":
@@ -548,11 +647,11 @@ def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k, sta
         ranges = [_fwd_kb_ranges(i, block_q, block_k, off, nk, window) for i in range(nq)]
     visited = sum(r[3] - r[0] for r in ranges)
     # tiles that run mask arithmetic: the two cut ranges either side of the whole one
-    masked = visited - sum(r[2] - r[1] for r in ranges)
+    masked = visited if selected else visited - sum(r[2] - r[1] for r in ranges)
     trace.program_note(
         "attn/call", kernel=kernel,
         writes=("dq", "dk", "dv") if kernel == "dkv" else ("out", "lse"),
-        kind=("stair" if stair else "window" if window is not None
+        kind=("selected" if selected else "stair" if stair else "window" if window is not None
               else "global" if causal else "full"),
         window=window, stair=stair, shape=tuple(q.shape), t_k=t_k, d_qk=q.shape[3], d_v=d_v,
         q_heads_per_kv_head=group,
@@ -561,9 +660,16 @@ def _note_call(kernel, q, t_k, d_v, group, causal, window, block_q, block_k, sta
     )
 
 
+def _flash_bwd_dkv_selected_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, cols_ref,
+                                   tiles_ref, *rest, **static):
+    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                          sel=(cols_ref, tiles_ref), **static)
+
+
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_acc, dk_acc, dv_acc, *, block_q, causal, sm_scale, window=None, stair=None,
+    selected=None, sel=None,
 ):
     # k_ref/dk_ref: [block_k, D_qk]; v_ref/dv_ref: [block_k, D_v]; q_ref/dq_ref:
     # [T_q, D_qk] and do_ref: [T_q, D_v] (the head's whole sequence);
@@ -595,7 +701,9 @@ def _flash_bwd_dkv_kernel(
         do = do_ref[rows, :]
         s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         p = jnp.exp(s * sm_scale - lse_ref[i])
-        if masked:
+        if masked == "selected":
+            p = jnp.where(_selected_tile(sel[0], i, block_q, selected[0]), p, 0.0)
+        elif masked:
             # select, not multiply: a fully masked row's lse is about
             # NEG_INF and exp() of its scores is inf
             q_lo = i * block_q if stair else off + i * block_q
@@ -611,7 +719,13 @@ def _flash_bwd_dkv_kernel(
     def loop(lo, hi, masked):
         jax.lax.fori_loop(lo, hi, lambda i, c: step(masked, i), None)
 
-    if causal:
+    if selected:
+        # as the forward: the causal tiles that hold a chosen pair
+        tiles = _tile_counts(sel[1], selected[1], num_qb)
+        first = _dkv_qb_ranges(jk, block_q, block_k, off, num_qb, None)[0]
+        jax.lax.fori_loop(first, num_qb, lambda i, c: pl.when(tiles(i, jk) > 0)(
+            lambda: step("selected", i)), None)
+    elif causal:
         # query blocks whose last row reaches this key block's first column,
         # of those the ones whose first row sees its last column (no mask),
         # and under a window the ones its edge cuts, then none
@@ -675,7 +789,7 @@ def _bwd_blocks(t_q, t_k, dtype, fwd_blocks, stair=None):
 
 @jax.named_scope(trace.SCOPE_BLOCKWISE_BWD)
 def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
-               window=None, stair=None, g_lse=None):
+               window=None, stair=None, g_lse=None, selected=None):
     """``(dq, dk, dv)`` from one kernel; ``block_q`` / ``block_k`` divide
     ``t_q`` / ``t_k`` (:func:`_bwd_blocks` picks them through
     :func:`_pick_block`). Under grouped KV heads ``flash_bwd_dkv`` writes each
@@ -698,16 +812,23 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpr
     # a head's every [1, block_q] tile of lse / delta, resident like q
     rows_seq = pl.BlockSpec((None, nq, 1, block_q), lambda i, j: (i, 0, 0, 0))
     part = jnp.float32 if group > 1 else None  # a query head's part of dK, dV
-    _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k, stair)
+    _note_call("dkv", q, t_k, d_v, group, causal, window, block_q, block_k, stair,
+               selected is not None)
+    more_specs = []
+    if selected:
+        more_specs = [_selected_rows(block_k, selected.cols.shape[2], h), _SMEM]
+        args += (selected.cols, selected.tiles.reshape(b * nq, nk))
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale,
+            _flash_bwd_dkv_selected_kernel if selected else _flash_bwd_dkv_kernel,
+            block_q=block_q, causal=causal, sm_scale=sm_scale,
             window=window, **({"stair": stair} if stair else {}),
+            **({"selected": (selection_layout(t_q), h)} if selected else {}),
         ),
         grid=(b * h, nk),
         in_specs=[_head_seq(t_q, d, buffers=1), _head_block(block_k, d, group),
                   _head_block(block_k, d_v, group), _head_seq(t_q, d_v, buffers=1),
-                  rows_seq, rows_seq],
+                  rows_seq, rows_seq, *more_specs],
         out_specs=[_head_seq(t_q, d, buffers=1), _head_block(block_k, d),
                    _head_block(block_k, d_v)],
         out_shape=[jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
@@ -807,6 +928,50 @@ def _lse_bwd_rule(causal, sm_scale, block_q, block_k, window, stair, keep, res, 
 
 
 flash_attention_lse.defvjp(_lse_fwd_rule, _lse_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def flash_attention_selected(q, k, v, selection: Selection, sm_scale=None):
+    """``(out, lse)`` as :func:`flash_attention_lse` gives them, query ``i``
+    seeing the keys ``selection`` chose for it (one set a query, every head
+    under it; none after the query; ``T_q == T_k``). The two kernels run the
+    causal tiles under the set's packed bits, a tile of no chosen pair skipped
+    by its count, on the blocks ``selection.tiles`` was counted in. No
+    gradient reaches the selection."""
+    return _selected_fwd_rule(q, k, v, selection, sm_scale)[0]
+
+
+def _selection_blocks(q, k, selection):
+    if q.shape[2] != k.shape[2]:
+        raise ValueError("attention: a selected set needs as many queries as keys")
+    nq, nk = selection.tiles.shape[1:]
+    return q.shape[2] // nq, k.shape[2] // nk
+
+
+def _selected_fwd_rule(q, k, v, selection, sm_scale):
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    interpret = _interpret_on(jax.default_backend())
+    out, lse = _flash_fwd(q, k, v, True, sm_scale, *_selection_blocks(q, k, selection),
+                          interpret, selected=selection)
+    q, k, v, out, lse = (remat.keep(name, x) for name, x in zip(
+        remat.ATTN_RESIDUALS, (q, k, v, out, lse)))
+    return (out, lse), (q, k, v, out, lse, selection)
+
+
+def _selected_bwd_rule(sm_scale, res, g):
+    q, k, v, out, lse, selection = res
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    interpret = _interpret_on(jax.default_backend())
+    grads = _flash_bwd(q, k, v, out, lse, g[0], True, sm_scale,
+                       *_selection_blocks(q, k, selection), interpret, g_lse=g[1],
+                       selected=selection)
+    no_grad = jax.tree.map(lambda x: np.zeros(x.shape, jax.dtypes.float0), selection)
+    return (*grads, no_grad)
+
+
+flash_attention_selected.defvjp(_selected_fwd_rule, _selected_bwd_rule)
 
 
 def flash_attention_head_parallel(

@@ -59,6 +59,19 @@ log-sum-exp; q is one array under two shapes and is kept once. The summaries
 and the merge cost little and are remade only as far as the backward reads
 them.
 
+A sparse-attention block (``ops/dsa.py``) keeps, beside the flash kernels'
+five, **its selection** (``dsa/rows``, ``dsa/cols``, ``dsa/tiles``: the chosen
+set's packed bits both ways and the tiles' counts, 16.8 MB a layer at T
+8,192): kept for agreement as ``moe/ids`` is, and for time. A selection made
+again from index scores that differ in their last bits would choose another
+key near rank ``topk`` while the backward kernel read the first forward's
+log-sum-exp, so every reader of the set (the masked kernels both ways, the
+index loss) reads the kept bits, and the recomputed forward runs neither the
+index scores nor the selection. And the index loss's three gradients
+(``dsa/dq_index``, ``dsa/dk_index``, ``dsa/dw_index``, 18.1 MB a layer): the
+loss's forward rule makes them in its one pass over the scores, so the
+backward is a scaling and the second forward runs no such pass.
+
 Outside a rematerialised block a tag is an identity that lowers to nothing,
 so a model with ``remat`` off compiles to the program it had without tags.
 The list is fixed here and follows no option: a name costs memory, and what
@@ -98,8 +111,14 @@ SHORTCONV_IN = "shortconv/in"
 EVA_LOCAL = ("eva/q", "eva/k", "eva/v", "eva/local_out", "eva/local_lse")
 EVA_REMOTE = ("eva/q", "eva/k_sum", "eva/v_sum", "eva/remote_out", "eva/remote_lse")
 
+# ops/dsa.py: the chosen set as the masked kernels read it (select), and the
+# index loss's gradients by the indexer's three outputs (index_loss)
+DSA_SELECTION = ("dsa/rows", "dsa/cols", "dsa/tiles")
+DSA_INDEX_GRADS = ("dsa/dq_index", "dsa/dk_index", "dsa/dw_index")
+
 KEPT = (*ATTN_RESIDUALS, MOE_ORDER, MOE_POS, MOE_SIZES, MOE_GATE_OUT, MOE_UP_OUT, MOE_IDS,
-        *KDA_KEPT, SHORTCONV_IN, *dict.fromkeys(EVA_LOCAL + EVA_REMOTE))
+        *KDA_KEPT, SHORTCONV_IN, *dict.fromkeys(EVA_LOCAL + EVA_REMOTE),
+        *DSA_SELECTION, *DSA_INDEX_GRADS)
 NOTE = "remat/kept"
 
 

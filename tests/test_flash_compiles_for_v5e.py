@@ -77,6 +77,33 @@ def test_flash_forward_and_backward_compile_for_the_v5e(
     assert "flash_fwd" in text and "flash_bwd_dkv" in text and "flash_bwd_dq" not in text
 
 
+@pytest.mark.parametrize("q_shape,kv_heads,tile", [
+    pytest.param((1, 32, 8192, 128), 4, 512, id="keye"),
+    pytest.param((2, 4, 256, 128), 2, 128, id="two-planes-two-batches")])
+def test_the_masked_kernels_compile_for_the_v5e(monkeypatch, one_chip, q_shape, kv_heads, tile):
+    """``flash_attention_selected`` at the sparse-attention cell's shape (the
+    set's packed words read by a dynamic lane slice and a shift, the tiles'
+    counts from SMEM, no VMEM asked for beside the causal kernels' own) and at
+    a length whose bits fill two planes."""
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    b, _, t, d = q_shape
+    lanes, planes = att.selection_layout(t)
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, rows, cols, tiles):
+        out, lse = att.flash_attention_selected(q, k, v, att.Selection(rows, cols, tiles))
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    words = on_chip((b, t, t // planes), jnp.int32)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip(q_shape), on_chip((b, kv_heads, t, d)), on_chip((b, kv_heads, t, d)), words,
+        words, on_chip((b, t // tile, t // tile), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text and "vmem_limit" not in text
+
+
 # the delta-attention mixer's three chains (ops/kda.py): the cell's shape
 # ([1, 8192, 32 x 128] bfloat16: whole blocks of 2,048 tokens), a length that is
 # a part of one block, one that is no multiple of a block, float32, one head

@@ -151,9 +151,11 @@ def test_kept_note_names_and_bytes(monkeypatch):
         remat.MOE_ORDER: rows * 4, remat.MOE_POS: rows * 4, remat.MOE_SIZES: E * 4,
         remat.MOE_GATE_OUT: rows * F * 4, remat.MOE_UP_OUT: rows * F * 4,
         remat.MOE_IDS: rows * 4}
-    # every name but a delta-attention, short-convolution or EVA block's: this model has none
+    # every name but a delta-attention, short-convolution, EVA or sparse-attention block's:
+    # this model has none
     assert set(notes) == (set(remat.KEPT) - set(remat.KDA_KEPT) - {remat.SHORTCONV_IN}
-                          - set(remat.EVA_LOCAL + remat.EVA_REMOTE))
+                          - set(remat.EVA_LOCAL + remat.EVA_REMOTE)
+                          - set(remat.DSA_SELECTION + remat.DSA_INDEX_GRADS))
     assert notes["attn/out"]["shape"] == (2, 4, T, 16)
     assert notes[remat.MOE_GATE_OUT]["dtype"] == "float32"
 
