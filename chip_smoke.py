@@ -390,14 +390,15 @@ def check_staircase_against_reference(shape=(1, 8, 4096, 128), stair=(1024, 64))
 def check_selected_against_reference(shape=(1, 32, 8192, 128), kv_heads=4, topk=2048) -> dict:
     """``flash_attention_selected`` (``ops/dsa.py``'s masked call: 32 query
     heads on 4 KV heads, each query a chosen 2,048 of its earlier keys, the
-    set handed over bit-packed with its tiles' counts): output, log-sum-exp
-    and the three gradients against ``attention_reference`` under the same
-    mask, a KV head's group at a time so that the reference's float32 scores
-    fit."""
+    set made by the selection kernel of ``ops/dsa_select.py`` and handed over
+    bit-packed with its tiles' counts): the set bit for bit against
+    ``lax.top_k``'s through ``dsa._choose``, then output, log-sum-exp and the
+    three gradients against ``attention_reference`` under the same mask, a KV
+    head's group at a time so that the reference's float32 scores fit."""
     import jax
     import jax.numpy as jnp
 
-    from fedml_tpu.ops import dsa
+    from fedml_tpu.ops import dsa, dsa_select
     from fedml_tpu.ops.attention import attention_reference, flash_attention_selected
 
     f32 = jnp.float32
@@ -407,11 +408,31 @@ def check_selected_against_reference(shape=(1, 32, 8192, 128), kv_heads=4, topk=
     q, g = (jax.random.normal(key, shape, jnp.bfloat16) for key in (kq, kg))
     k, v = (jax.random.normal(key, (b, kv_heads, t, d), jnp.bfloat16) for key in (kk, kv))
     block = min(512, t)
-    rows = jax.lax.map(  # a random score a pair: each query's topk largest among its earlier keys
-        lambda i: dsa._choose(jax.random.normal(jax.random.fold_in(ks, i), (b, block, t), f32),
-                              i * block, topk)[0], jnp.arange(t // block))
-    chosen = rows.transpose(1, 0, 2, 3).reshape(b, t, t)
-    selection = jax.jit(lambda m: dsa.selection_from_mask(m, block))(chosen)
+
+    def scores(i):  # a random score a pair: each query's topk largest among its earlier keys
+        return jax.random.normal(jax.random.fold_in(ks, i), (b, block, t), f32)
+
+    def one(i):  # the kernel's words and flags, _choose's set, and whether that is lax.top_k's
+        lo, s = i * block, scores(i)
+        words, _, flags = dsa_select.select_rows(s, lo, topk, t)  # 128 rows a grid step
+        plain = dsa._choose(s, lo, topk)[0]
+        valid = jnp.arange(t)[None] <= (lo + jnp.arange(block))[:, None]
+        _, ids = jax.lax.top_k(jnp.where(valid, s, -jnp.inf), min(topk, t))
+        top = jnp.zeros((b, block, t), bool).at[
+            jnp.arange(b)[:, None, None], jnp.arange(block)[None, :, None], ids].set(True) & valid
+        return words, flags, plain, jnp.all(plain == top)
+
+    words, flags, chosen, same = jax.lax.map(one, jnp.arange(t // block))
+    selection = jax.jit(lambda w: dsa.selection_from_rows(w, block))(
+        words.transpose(1, 0, 2, 3).reshape(b, t, -1))
+    chosen = chosen.transpose(1, 0, 2, 3).reshape(b, t, t)
+    assert bool(jnp.all(same)), "dsa._choose's set is not lax.top_k's"
+    for name, a, b_ in zip(("rows", "cols", "tiles"), selection,
+                           jax.jit(lambda m: dsa.selection_from_mask(m, block))(chosen)):
+        assert bool(jnp.all(a == b_)), f"the selection kernel's {name} are not lax.top_k's set"
+    say(f"  selection kernel at [{b}, {t}, {t}], topk {topk}: rows, cols and tiles equal "
+        f"lax.top_k's set bit for bit; blocks skipped / searched / tied "
+        f"{[int(jnp.sum(flags == f)) for f in (0, 1, 2)]}")
 
     def both(fn):
         def loss(q, k, v, g):

@@ -575,7 +575,7 @@ class MLAMoETransformerLM(nn.Module):
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
         # for the engine's counters: one value a routed block (the MTP module's
         # last) under "moe", one a delta-attention block under "kda", one an
-        # EVA block under "eva", three a sparse-attention block under "dsa"
+        # EVA block under "eva", four a sparse-attention block under "dsa"
         for group in ("moe", "kda", "eva", "dsa"):
             found = [{k: v for k, v in s.items() if k.startswith(group + "/")} for s in stats]
             found = [s for s in found if s]

@@ -23,12 +23,23 @@ made a block of queries at a time against the keys up to the block's causal
 group (``CAUSAL_GROUPS`` groups of blocks, each with the keys its last query
 can see: 62.5% of the square at four groups where the triangle is 53%), in
 plain XLA. **The selection finds each row's ``topk``-th largest score and not
-the order of the rest** (:func:`_threshold`: the float's bits as an unsigned
-key, four bits a pass, fifteen compare-and-count passes fused into one read:
-eight reads of the block, where ``lax.top_k`` at k 2,048 is a full sort on the
-TPU), then takes everything above the threshold and, of the scores equal to
-it, the lowest positions (a prefix sum, run only in a block where a row has
-more equal scores than it needs). What leaves is a
+the order of the rest**, then takes everything above that threshold and, of
+the scores equal to it, the lowest positions (``lax.top_k`` at k 2,048 is a
+full sort on the TPU). Under ``impl`` "flash" that is one Mosaic kernel
+(``ops/dsa_select.py``, PR 49): 128 rows of a block's scores are read once and
+held in VMEM with their ordered integer keys while the threshold is searched a
+bit a pass over the lane tiles up to the diagonal (a pass costs no HBM traffic
+there, so what is paid is the compares an element: 32 at a bit a pass where
+four bits a pass, the form that reads HBM least, pays 120), the cut among
+equal scores is searched the same way over the position's bits only in a
+block that has such a row, and the set leaves packed, with the row's share of
+the indexer's softmax and a flag a block. The set's columns and tile counts
+are made from the packed words by XLA (:func:`selection_from_rows`); no ``[T,
+T]`` array of bits is written. Under "xla" it is :func:`_choose` in ``jnp``
+(:func:`_threshold`: four bits a pass, fifteen compare-and-counts a pass; in
+the compiled cell seventeen fusions a block, each a read of the block from
+HBM at two thirds of its bandwidth, PERF.md section 6, PR 49; a prefix sum
+for the ties): the plain path and the kernel's second oracle. What leaves either way is an
 ``ops/attention.py`` :class:`Selection`: the set's bits packed 32 to a word
 both ways and the count of chosen pairs a tile, 16.8 MB a layer at T 8,192,
 kept by name under ``remat`` so that the set is made once
@@ -54,15 +65,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import remat
+from fedml_tpu.ops import dsa_select, remat
 from fedml_tpu.ops.attention import (
     Selection, _fwd_blocks, attention_reference, flash_attention_selected, selection_layout)
 
 NOTE = "dsa/call"
-# how a row's topk-th largest score is found, for the note: _threshold. Step 0
-# of PR 48 timed lax.top_k's last value in its place on the chip (PERF.md
-# section 6: 24.2 ms a layer against 11.6) and it went
-SELECT_IMPL = "radix"
+# what runs under attn/dsa/select, for the note, by ``impl``: the Mosaic kernel
+# of ops/dsa_select.py (PR 49: a block of scores held in VMEM while its
+# threshold is searched a bit a pass, the set written packed) or _choose in
+# jnp (PR 48: four bits a pass, each a read of the block from HBM; lax.top_k's
+# last value in its place took 24.2 ms a layer against 11.6, PERF.md section 6)
+SELECT_IMPL = {"flash": "mosaic", "xla": "radix"}
 TILE = 512  # the selection's tiles are the masked kernels' blocks (attention._fwd_blocks' own side)
 LOSS_ROWS = 256  # queries a step of the index loss's pass: [B, H, 256, T] float32 scores
 CAUSAL_GROUPS = 4
@@ -89,19 +102,43 @@ def unpack_bits(words, c: int):
     return bits.reshape(*words.shape[:-3], c) != 0
 
 
+def _whole_runs(t: int, block: int) -> None:
+    if t % block or block % selection_layout(t)[0]:
+        raise ValueError(f"dsa: a block of {block} is not whole runs of "
+                         f"{selection_layout(t)[0]} packed positions of {t}")
+
+
 def selection_from_mask(chosen, block_q: int, block_k: int | None = None) -> Selection:
     """The :class:`Selection` of a bool ``[B, T_q, T_k]`` set (no key after its
     query), its pairs counted in tiles of ``block_q x block_k``. A tile's keys
     and queries must be whole runs of the packing's lanes."""
     b, t_q, t_k = chosen.shape
     block_k = block_k or block_q
-    for t, block in ((t_q, block_q), (t_k, block_k)):
-        if t % block or block % selection_layout(t)[0]:
-            raise ValueError(f"dsa: a block of {block} is not whole runs of "
-                             f"{selection_layout(t)[0]} packed positions of {t}")
+    _whole_runs(t_q, block_q)
+    _whole_runs(t_k, block_k)
     tiles = jnp.sum(chosen.reshape(b, t_q // block_q, block_q, t_k // block_k, block_k),
                     axis=(2, 4), dtype=jnp.int32)
     return Selection(pack_bits(chosen), pack_bits(chosen.swapaxes(1, 2)), tiles)
+
+
+def selection_from_rows(rows, block: int) -> Selection:
+    """The :class:`Selection` whose packed ``rows`` ``[B, T, W]`` are given (a
+    square set), its pairs counted in tiles of ``block x block``: the columns
+    and the counts from the words, no ``[T, T]`` array of bits between."""
+    b, t, _ = rows.shape
+    lanes, planes = selection_layout(t)
+    groups = t // lanes // planes
+    _whole_runs(t, block)
+    plane = jnp.arange(planes, dtype=jnp.int32)
+    # words with a key's lane down the rows: [b, g_k, l, g_q, p_q, r]; then key
+    # plane p_k of each becomes bit p_q of the key's word
+    by_key = rows.reshape(b, groups, planes, lanes, groups, lanes).transpose(0, 4, 5, 1, 2, 3)
+    bit = (by_key[:, :, None] >> plane[:, None, None, None, None]) & 1
+    cols = jnp.sum(bit << plane[:, None], axis=5, dtype=jnp.int32).reshape(rows.shape)
+    by_tile = rows.reshape(b, t // block, block, groups, 1, lanes)
+    per_plane = jnp.sum((by_tile >> plane[:, None]) & 1, axis=(2, 5), dtype=jnp.int32)
+    tiles = per_plane.reshape(b, t // block, t // block, block // lanes).sum(-1)
+    return Selection(rows, cols, tiles)
 
 
 # -- the index scores and the selection ---------------------------------------
@@ -142,10 +179,12 @@ def _threshold(u, want):
 
 
 def _choose(scores, first_row, topk: int):
-    """``(chosen bool [B, R, K], mass [B, R])`` of a block of rows
+    """``(chosen bool [B, R, K], mass [B, R], tied bool)`` of a block of rows
     ``first_row ...`` of the index scores against keys ``0 ... K - 1``:
-    ``S_t`` and the share of the row's softmax over every visible key that
-    lies on it."""
+    ``S_t``, the share of the row's softmax over every visible key that lies
+    on it, and whether a row held more keys equal to its threshold than it
+    needed (the block took the prefix sum). The plain form: what ``impl``
+    "xla" runs, and the kernel's oracle beside ``lax.top_k``."""
     rows, keys = scores.shape[-2:]
     pos = first_row + jnp.arange(rows)
     valid = jnp.arange(keys)[None] <= pos[:, None]
@@ -156,12 +195,13 @@ def _choose(scores, first_row, topk: int):
     tau = _threshold(u, jnp.broadcast_to(want, u.shape[:-1]))
     above, ties = u > tau[..., None], u == tau[..., None]
     need = want - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    tied = jnp.any(jnp.sum(ties, axis=-1, dtype=jnp.int32) != need)
     chosen = jax.lax.cond(
-        jnp.any(jnp.sum(ties, axis=-1, dtype=jnp.int32) != need),
+        tied,
         lambda: above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= need[..., None])),
         lambda: above | ties)
     lse = lambda m: jax.nn.logsumexp(jnp.where(m, scores, -jnp.inf), axis=-1)  # noqa: E731
-    return chosen, jnp.exp(lse(chosen) - lse(valid))
+    return chosen, jnp.exp(lse(chosen) - lse(valid)), tied
 
 
 def _causal_groups(t: int, rows: int) -> list:
@@ -179,13 +219,20 @@ def _rows(x, lo, n, axis):
     return jax.lax.dynamic_slice_in_dim(x, lo, n, axis=axis)
 
 
-def select(qi, ki, wi, topk: int, block: int):
-    """``(Selection, mass)``: every query's ``S_t`` as the kernels read it, its
-    tiles ``block x block``, and the mean over the queries ``t >= topk`` of the
-    share of ``softmax(I[t, :t + 1])`` that lies on ``S_t`` (1 where ``T <=
-    topk``)."""
+def select(qi, ki, wi, topk: int, block: int, impl: str = "flash"):
+    """``(Selection, mass, tie_blocks)``: every query's ``S_t`` as the kernels
+    read it, its tiles ``block x block``; the mean over the queries ``t >=
+    topk`` of the share of ``softmax(I[t, :t + 1])`` that lies on ``S_t`` (1
+    where ``T <= topk``); and the blocks that took the tie path over the
+    blocks searched (one whose rows all have ``t < topk`` is not searched).
+    ``impl`` "flash": a block of scores goes through
+    ``dsa_select.select_rows`` and leaves as packed words, and a counted block
+    is a grid step of the kernel (128 rows at the cell's shape); "xla":
+    :func:`_choose` and :func:`selection_from_mask`, a counted block ``block``
+    rows, so the share reads higher there on the same scores."""
     b, _, t, _ = qi.shape
-    chosen, mass = [], []
+    kernel = impl == "flash"
+    chosen, mass, flags = [], [], []
     for first, n, keys in _causal_groups(t, block):
 
         def one(i, first=first, keys=keys):
@@ -194,17 +241,27 @@ def select(qi, ki, wi, topk: int, block: int):
                 scores, _ = index_scores(_rows(qi, lo, block, 2), ki[:, :keys],
                                          _rows(wi, lo, block, 1))
             with jax.named_scope(trace.SCOPE_DSA_SELECT):
-                got, share = _choose(scores, lo, topk)
-                return jnp.pad(got, ((0, 0), (0, 0), (0, t - keys))), share
+                if kernel:
+                    return dsa_select.select_rows(scores, lo, topk, t)
+                got, share, tied = _choose(scores, lo, topk)
+                flag = jnp.where(lo + block <= topk, dsa_select.SKIPPED,
+                                 jnp.where(tied, dsa_select.TIED, dsa_select.SEARCHED))
+                return (jnp.pad(got, ((0, 0), (0, 0), (0, t - keys))), share,
+                        jnp.full((b, 1), flag, jnp.int32))
 
-        got, share = jax.lax.map(one, jnp.arange(n))  # [n, B, block, T], [n, B, block]
+        got, share, flag = jax.lax.map(one, jnp.arange(n))  # [n, B, block, ...], [n, B, steps]
         chosen.append(got)
         mass.append(share)
+        flags.append(flag.reshape(-1))  # a group's steps follow its keys (dsa_select.tiling)
     with jax.named_scope(trace.SCOPE_DSA_SELECT):
-        chosen = jnp.concatenate(chosen).transpose(1, 0, 2, 3).reshape(b, t, t)
+        chosen = jnp.concatenate(chosen).transpose(1, 0, 2, 3).reshape(b, t, -1)
         mass = jnp.concatenate(mass).transpose(1, 0, 2).reshape(b, t)
         mass = jnp.mean(mass[:, topk:]) if t > topk else jnp.float32(1.0)
-        return selection_from_mask(chosen, block), mass
+        flags = jnp.concatenate(flags)
+        tie_blocks = (jnp.sum(flags == dsa_select.TIED)
+                      / jnp.maximum(jnp.sum(flags != dsa_select.SKIPPED), 1))
+        selection = (selection_from_rows if kernel else selection_from_mask)(chosen, block)
+        return selection, mass, tie_blocks.astype(jnp.float32)
 
 
 # -- the index loss -----------------------------------------------------------
@@ -287,18 +344,21 @@ def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, impl: str = "flash",
     """``(out [B, H, T, D], stats)``: attention of ``q`` ``[B, H, T, D]`` over
     the keys the indexer chose for each query (the module docstring), and
     ``dsa/tiles_nonempty`` (tiles holding a chosen pair over the causal
-    tiles), ``dsa/index_mass`` and, ``with_loss``, ``dsa/index_kl`` (``L_I``,
-    the one entry a gradient passes through). ``impl`` "flash": the masked
-    kernels; "xla": :func:`attention_reference` under the unpacked set."""
+    tiles), ``dsa/index_mass``, ``dsa/select_tie_blocks`` (the selection's
+    blocks that took the tie path over the blocks searched) and,
+    ``with_loss``, ``dsa/index_kl`` (``L_I``, the one entry a gradient passes
+    through). ``impl`` "flash": the selection kernel and the masked kernels;
+    "xla": :func:`_choose` and :func:`attention_reference` under the unpacked
+    set."""
     b, h, t, d = q.shape
     sm_scale = d ** -0.5
     block = _fwd_blocks(t, t, q.dtype, TILE, TILE)[0]
     trace.program_note(
-        NOTE, impl=impl, select=SELECT_IMPL, shape=(b, h, t, d), kv_heads=k.shape[1],
+        NOTE, impl=impl, select=SELECT_IMPL[impl], shape=(b, h, t, d), kv_heads=k.shape[1],
         index_heads=qi.shape[1], index_dim=qi.shape[3], topk=topk, tile=(block, block),
         dtype=jnp.dtype(q.dtype).name, index_dtype=jnp.dtype(qi.dtype).name,
         selection_bytes=b * (2 * t * (t // 32) + (t // block) ** 2) * 4)
-    selection, mass = select(qi, ki, wi, topk, block)
+    selection, mass, tie_blocks = select(qi, ki, wi, topk, block, impl)
     # kept by a rematerialised block (ops/remat.py): the set is made once
     selection = Selection(*(remat.keep(name, x) for name, x in zip(
         remat.DSA_SELECTION, selection)))
@@ -316,6 +376,7 @@ def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, impl: str = "flash",
     stats = {
         "dsa/tiles_nonempty": jnp.sum(selection.tiles > 0) / (b * nq * (nq + 1) / 2.0),
         "dsa/index_mass": jax.lax.stop_gradient(mass),
+        "dsa/select_tie_blocks": tie_blocks,
     }
     if with_loss:
         no_grad = jax.lax.stop_gradient

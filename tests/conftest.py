@@ -48,7 +48,9 @@ def rng():
 # too, so a PR that appends a cell after such a test was written names the test
 # here; the next ``benchmark`` PR moves the entry into the table.
 # test_benchmark_loop_reduce.py (PR 40) holds the manifest to its five cells;
-# test_benchmark_lfm2.py (PR 42) reads its entries as the lists' last.
+# test_benchmark_lfm2.py (PR 42) reads its entries as the lists' last;
+# test_benchmark_keye.py (PR 48) holds the metrics of its cell alone to its own list
+# (PR 49 appended ``dsa_select_tie_blocks_pct``, which test_benchmark_dsa_select.py holds).
 READS_TAILS_SINCE = {
     ("test_benchmark_loop_reduce", "test_manifest_lists_the_five_for_the_cells_they_read"): {
         "configs": "kimi_linear_48b_a3b_cut", "workloads": "kimilinear_silo2",
@@ -56,6 +58,9 @@ READS_TAILS_SINCE = {
     ("test_benchmark_lfm2", "test_manifest_entries_and_the_configuration_file"): {
         "configs": "lfm2_24b_a2b_cut", "workloads": "lfm2moe_silo2",
         "per_layer": "loop_steps_time_pct_lfm2"},
+    ("test_benchmark_keye", "test_manifest_entries_and_the_configuration_file"): {
+        "configs": "keye_vl2_30b_a3b_cut", "workloads": "keyevl2_silo2",
+        "per_layer": "moe_experts_roofline_keye"},
 }
 
 

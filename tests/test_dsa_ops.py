@@ -1,6 +1,7 @@
 """``ops/dsa.py`` and the masked flash kernels (``ops/attention.py``
 ``flash_attention_selected``) on the CPU: the threshold selection against
-``lax.top_k``'s set, the packed set both ways, the kernels under a set with an
+``lax.top_k``'s set, the selection kernel (``ops/dsa_select.py``) against both
+bit for bit, the packed set both ways, the kernels under a set with an
 empty tile against ``attention_reference``, the index loss against its
 definition, and that a call without the new argument is the accepted call
 (which leaves each loss moves is ``tests/test_keye.py``'s, through the model).
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 import fedml_tpu.ops.attention as att
-from fedml_tpu.ops import dsa
+from fedml_tpu.ops import dsa, dsa_select
 
 T, K = 128, 16
 
@@ -30,19 +31,23 @@ def _top_k_set(scores, topk):
     return jnp.zeros((t, t), bool).at[rows, ids].set(True) & causal
 
 
+def _case_scores(case, t):
+    scores, topk = _rnd(1, t, t), K
+    if case == "exact_ties":
+        scores = jnp.round(2 * scores) / 2 * jnp.where(_rnd(2, t, t) > 1, -0.0, 1.0)
+    elif case == "all_equal":
+        scores = jnp.full((t, t), -1.5)
+    elif case == "topk_over_t":
+        topk = 2 * t
+    return scores, topk
+
+
 @pytest.mark.parametrize("case", ["tie_free", "exact_ties", "all_equal", "topk_over_t"])
 def test_the_selection_is_top_ks_set(case):
     """Tie-free rows, rows with exact ties (quantised scores, zeros of both
     signs), rows of one value (the lowest positions win) and ``t < topk``."""
-    scores = _rnd(1, T, T)
-    topk = K
-    if case == "exact_ties":
-        scores = jnp.round(2 * scores) / 2 * jnp.where(_rnd(2, T, T) > 1, -0.0, 1.0)
-    elif case == "all_equal":
-        scores = jnp.full((T, T), -1.5)
-    elif case == "topk_over_t":
-        topk = 2 * T
-    chosen, mass = jax.jit(lambda s: dsa._choose(s[None], 0, topk))(scores)
+    scores, topk = _case_scores(case, T)
+    chosen, mass, _ = jax.jit(lambda s: dsa._choose(s[None], 0, topk))(scores)
     want = _top_k_set(jnp.where(scores == 0.0, 0.0, scores), topk)
     np.testing.assert_array_equal(chosen[0], want)
     assert set(np.asarray(chosen[0].sum(-1))) == set(np.minimum(np.arange(T) + 1, topk))
@@ -52,6 +57,121 @@ def test_the_selection_is_top_ks_set(case):
         np.testing.assert_allclose(mass, 1.0, rtol=1e-6)
     else:
         assert 0.0 < float(mass[0, -1]) < 1.0 and float(mass[0, 0]) == 1.0
+
+
+
+def _kernel_selection(scores, topk, rows, groups, tile):
+    """The kernel over ``groups`` causal groups of equal rows, each against
+    the keys its last query sees, ``rows`` query rows a grid step, as
+    ``dsa.select`` calls it: ``(Selection, mass [1, T], flags [1, steps])``."""
+    t = scores.shape[-1]
+    per = t // groups
+    words, mass, flags = zip(*(dsa_select.select_rows(
+        scores[None, g * per:(g + 1) * per, :(g + 1) * per], g * per, topk, t, block=rows)
+        for g in range(groups)))
+    return (dsa.selection_from_rows(jnp.concatenate(words, 1), tile),
+            jnp.concatenate(mass, 1), jnp.concatenate(flags, 1))
+
+
+@pytest.mark.parametrize("groups", [1, 2, 4])
+@pytest.mark.parametrize("rows", [32, 128])
+@pytest.mark.parametrize("case", ["tie_free", "exact_ties", "all_equal", "topk_over_t"])
+def test_the_kernels_selection_is_chooses_and_top_ks_bit_for_bit(case, rows, groups):
+    """The Mosaic kernel (interpreted here) in blocks of ``rows`` x the keys of
+    1, 2 and 4 causal groups of T 512 (``[32 | 128, 128 ... 512]``): packed
+    rows, columns and tile counts equal to ``selection_from_mask`` of
+    ``_choose``'s set and of ``lax.top_k``'s, ties to the lower position; the
+    mass within float32 rounding; the tie path taken where a row ties and
+    nowhere else; every block skipped where ``t < topk`` on all its rows."""
+    t, tile = 512, 128
+    scores, topk = _case_scores(case, t)
+    got, mass, flags = jax.jit(
+        lambda s: _kernel_selection(s, topk, rows, groups, tile))(scores)
+    chosen, want_mass, tied = jax.jit(lambda s: dsa._choose(s[None], 0, topk))(scores)
+    for want in (chosen, _top_k_set(jnp.where(scores == 0.0, 0.0, scores), topk)[None]):
+        for a, b in zip(got, dsa.selection_from_mask(want, tile)):
+            np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(mass, want_mass, rtol=2e-6)
+    assert flags.shape == (1, t // rows)
+    took = {"tie_free": {dsa_select.SEARCHED}, "exact_ties": {dsa_select.SEARCHED, dsa_select.TIED},
+            "all_equal": {dsa_select.TIED}, "topk_over_t": {dsa_select.SKIPPED}}[case]
+    assert set(np.asarray(flags).ravel()) <= took and (dsa_select.TIED in took) == bool(tied)
+    if case == "exact_ties":
+        assert (np.asarray(flags) == dsa_select.TIED).any()
+
+
+def test_blocks_below_topk_skip_and_select_counts_the_tie_blocks():
+    """``topk`` 64 over blocks of 32 rows: the first two blocks hold no row past
+    ``topk`` and skip the search, the others search; ``select`` reports no
+    tied block where no ReLU is ever zero (three heads' zeros would tie), every
+    searched block on integer scores and a skipped block left out of the
+    count, through the kernel as through ``_choose``."""
+    scores = _rnd(1, 256, 256)
+    words, _, flags = jax.jit(lambda s: dsa_select.select_rows(s[None], 0, 64, 256, block=32))(
+        scores)
+    np.testing.assert_array_equal(flags[0], [dsa_select.SKIPPED] * 2 + [dsa_select.SEARCHED] * 6)
+    np.testing.assert_array_equal(dsa.unpack_bits(words, 256)[0], _top_k_set(scores, 64))
+    qi, ki, wi = (jnp.abs(x) for x in (_rnd(4, 1, 3, 256, 8), _rnd(5, 1, 256, 8),
+                                       _rnd(6, 1, 256, 3)))
+    whole = lambda x: jnp.round(2 * x)  # noqa: E731  (sums of products of small integers tie)
+    for impl in ("flash", "xla"):
+        run = jax.jit(lambda *a, impl=impl: dsa.select(*a, K, 128, impl)[1:])
+        assert float(run(qi, ki, wi)[1]) == 0.0
+        mass, tie_blocks = run(whole(qi), whole(ki), whole(wi))
+        assert float(tie_blocks) == 1.0 and 0.0 < float(mass) < 1.0
+        # the first of two blocks holds no row past ``topk``: not searched, so not counted
+        assert float(jax.jit(lambda *a, impl=impl: dsa.select(*a, 128, 128, impl)[2])(
+            whole(qi), whole(ki), whole(wi))) == 1.0
+    same = [jax.jit(lambda *a, impl=impl: dsa.select(*a, K, 128, impl)[0])(
+        whole(qi), whole(ki), whole(wi)) for impl in ("flash", "xla")]
+    for a, b in zip(*same):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("rows, keys, t, block, message", [
+    (128, 192, 384, None, "cannot tile"),  # keys that are no whole runs of the packed lanes
+    (12, 128, 128, None, "cannot tile"),  # a step of rows that is no whole sublane tile
+    (128, 256, 128, None, "cannot tile"),  # more keys than the sequence has
+    (8, 2 ** 18, 2 ** 18, None, "MiB"),  # what the default scoped VMEM cannot hold 8 rows of
+], ids=["keys", "rows", "keys_over_t", "vmem"])
+def test_a_shape_the_kernel_cannot_tile_is_refused(rows, keys, t, block, message):
+    with pytest.raises(ValueError, match=message):
+        dsa_select.tiling(rows, keys, t, block)
+    with pytest.raises(ValueError, match=message):
+        dsa_select.select_rows(jnp.zeros((1, rows, keys)), 0, K, t, block=block)
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((512, 8192, 8192), (128, 512)),  # the cell's last causal group: 12 MiB, at the limit
+    ((512, 8192, 8192, 256), (128, 512)),  # a step of 256 rows asked for is halved to fit
+    ((512, 12288, 16384), (64, 512)), ((512, 16384, 16384), (64, 512)),  # past 8,192 keys fewer rows
+    ((512, 2 ** 17, 2 ** 17), (8, 512)),  # down to one sublane tile
+    ((128, 128, 128), (128, 128)), ((64, 256, 256), (64, 256)), ((96, 384, 3072), (96, 384)),
+])
+def test_a_step_takes_as_many_rows_as_the_default_vmem_holds(shape, want):
+    assert dsa_select.tiling(*shape) == want
+    step, keys = want[0], shape[1]
+    assert 3 * step * keys * 4 <= dsa_select.VMEM_BYTES
+
+
+def test_groups_of_different_steps_select_top_ks_set():
+    """Four causal groups whose keys give them steps of two sizes (the limit
+    lowered so that toy sizes meet it, as T 16,384 meets the real one): the
+    plain path's set, and a flag a step of either size."""
+    t = 512
+    qi, ki, wi = (jnp.abs(x) for x in (_rnd(4, 1, 3, t, 8), _rnd(5, 1, t, 8), _rnd(6, 1, t, 3)))
+    want = jax.jit(lambda *a: dsa.select(*a, K, 128, "xla"))(qi, ki, wi)
+    limit, dsa_select.VMEM_BYTES = dsa_select.VMEM_BYTES, 3 * 128 * 256 * 4
+    try:
+        assert [dsa_select.tiling(128, keys, t)[0] for keys in (128, 256, 384, 512)] == [
+            128, 128, 64, 64]
+        got = jax.jit(lambda *a: dsa.select(*a, K, 128, "flash"))(qi, ki, wi)
+    finally:
+        dsa_select.VMEM_BYTES = limit
+    for a, b in zip(got[0], want[0]):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(got[1], want[1], rtol=2e-6)
+    assert float(got[2]) == float(want[2]) == 0.0
 
 
 def test_threshold_is_the_kth_largest_key_and_keys_keep_the_floats_order():
@@ -74,7 +194,7 @@ def test_packed_bits_and_select_against_the_reference():
     qi, ki, wi = _rnd(4, 1, 3, 384, 8), _rnd(5, 1, 384, 8), _rnd(6, 1, 384, 3)
     assert dsa._causal_groups(384, 128) == [(0, 1, 128), (1, 1, 256), (2, 1, 384)]
     assert dsa._causal_groups(8192, 512) == [(4 * g, 4, 2048 * (g + 1)) for g in range(4)]
-    selection, mass = jax.jit(lambda *a: dsa.select(*a, K, 128))(qi, ki, wi)
+    selection, mass, _ = jax.jit(lambda *a: dsa.select(*a, K, 128))(qi, ki, wi)
     _, _, want = jax.jit(lambda *a: dsa.dsa_reference(*a, topk=K))(q, k, v, qi, ki, wi)
     np.testing.assert_array_equal(dsa.unpack_bits(selection.rows, 384), want)
     np.testing.assert_array_equal(dsa.unpack_bits(selection.cols, 384), want.swapaxes(1, 2))
@@ -171,6 +291,8 @@ def test_sparse_attention_and_the_index_loss_against_the_reference(monkeypatch):
     np.testing.assert_allclose(stats["dsa/index_kl"], kl, rtol=1e-5)
     assert float(kl) > 0.0 and 0.0 < float(stats["dsa/index_mass"]) < 1.0
     assert float(stats["dsa/tiles_nonempty"]) == 1.0
+    # three heads' ReLUs are all zero on an eighth of the keys: early rows tie at 0.0
+    assert 0.0 < float(stats["dsa/select_tie_blocks"]) <= 1.0
     for a, b in zip(got_g, want_g):
         np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.abs(b).max()) + 1e-7)
     # "xla" runs the same set through attention_reference
@@ -178,8 +300,10 @@ def test_sparse_attention_and_the_index_loss_against_the_reference(monkeypatch):
         *a, topk=K, impl="xla", with_loss=False))(q, k, v, qi, ki, wi)
     np.testing.assert_allclose(
         out_x, jax.jit(lambda *a: dsa.dsa_reference(*a, topk=K)[0])(q, k, v, qi, ki, wi), atol=2e-6)
-    note = [n for n in att.trace.program_notes("dsa/call") if n["shape"] == (2, 4, t, 8)
-            and n["tile"] == (T, T)][-1]
+    notes = {n.get("impl"): n for n in att.trace.program_notes("dsa/call")
+             if n["shape"] == (2, 4, t, 8) and n["tile"] == (T, T)}
+    note = notes["flash"]
     assert (note["topk"], note["index_heads"], note["index_dim"], note["kv_heads"]) == (K, 3, 8, 2)
-    assert note["select"] == dsa.SELECT_IMPL
+    assert note["select"] == dsa.SELECT_IMPL["flash"] == "mosaic"
+    assert notes["xla"]["select"] == dsa.SELECT_IMPL["xla"] == "radix"
     assert note["selection_bytes"] == 2 * (2 * t * (t // 32) + 4) * 4

@@ -104,6 +104,29 @@ def test_the_masked_kernels_compile_for_the_v5e(monkeypatch, one_chip, q_shape, 
     assert "flash_fwd" in text and "flash_bwd_dkv" in text and "vmem_limit" not in text
 
 
+
+@pytest.mark.parametrize("rows,keys,t", [
+    pytest.param(512, 8192, 8192, id="keye-last-group"),
+    pytest.param(512, 2048, 8192, id="keye-first-group"),
+    pytest.param(512, 16384, 16384, id="64-row-steps"),
+    pytest.param(128, 256, 256, id="two-planes")])
+def test_the_selection_kernel_compiles_for_the_v5e(monkeypatch, one_chip, rows, keys, t):
+    """``dsa_select.select_rows`` on a block of the sparse-attention cell's
+    index scores (128 rows a grid step against the keys of the last and the
+    first causal group: the float32 block twice and its int32 keys, 12 MiB at
+    8,192 keys), at twice the keys, where a step takes 64 rows, and at a toy
+    length: Mosaic takes the dynamic lane slices, the
+    int32 lane sums and the nested loops, inside its default scoped VMEM."""
+    from fedml_tpu.ops import dsa_select
+
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    text = jax.jit(lambda s, lo: dsa_select.select_rows(s, lo[0], min(2048, t // 4), t)).lower(
+        jax.ShapeDtypeStruct((1, rows, keys), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "dsa_select" in text and "vmem_limit" not in text
+
+
 # the delta-attention mixer's three chains (ops/kda.py): the cell's shape
 # ([1, 8192, 32 x 128] bfloat16: whole blocks of 2,048 tokens), a length that is
 # a part of one block, one that is no multiple of a block, float32, one head
