@@ -394,7 +394,10 @@ def check_selected_against_reference(shape=(1, 32, 8192, 128), kv_heads=4, topk=
     bit-packed with its tiles' counts): the set bit for bit against
     ``lax.top_k``'s through ``dsa._choose``, then output, log-sum-exp and the
     three gradients against ``attention_reference`` under the same mask, a KV
-    head's group at a time so that the reference's float32 scores fit."""
+    head's group at a time so that the reference's float32 scores fit; then
+    the index loss's pass over that set and log-sum-exp, the Mosaic kernels of
+    ``ops/dsa_index_loss.py`` against the plain pass (``impl`` "xla"): the loss
+    and its three gradients, 16 index heads of 64."""
     import jax
     import jax.numpy as jnp
 
@@ -460,7 +463,42 @@ def check_selected_against_reference(shape=(1, 32, 8192, 128), kv_heads=4, topk=
         "max|diff|/max|ref|: " + ", ".join(f"{n}={e:.2e}" for n, e in errs.items()))
     for name, e in errs.items():
         assert e <= 2e-2, (name, e)
+    errs.update(_check_index_loss(q, k, got_aux[1], selection))
     return {n: float(f"{e:.3g}") for n, e in errs.items()}
+
+
+def _check_index_loss(q, k, lse, selection, index_heads=16, index_dim=64) -> dict:
+    """``dsa.index_loss`` under ``impl`` "flash" against "xla" on one set of
+    operands: ``L_I`` to float32 rounding, the gradients to bfloat16's."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops import dsa
+
+    b, _, t, d = q.shape
+    kq, kk, kw = jax.random.split(jax.random.key(3), 3)
+    qi = jax.random.normal(kq, (b, index_heads, t, index_dim), jnp.bfloat16)
+    ki = jax.random.normal(kk, (b, t, index_dim), jnp.bfloat16)
+    wi = (jax.random.normal(kw, (b, t, index_heads)) * (index_heads * index_dim) ** -0.5).astype(
+        jnp.bfloat16)
+    assert dsa.index_loss_impl("flash", q, k, qi) == "mosaic"
+
+    def run(impl):
+        loss = lambda qi, ki, wi: dsa.index_loss(  # noqa: E731
+            qi, ki, wi, q, k, lse, selection, d ** -0.5, impl)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(qi, ki, wi)
+
+    (got, got_g), (want, want_g) = run("flash"), run("xla")
+    errs = {"L_I": abs(float(got) - float(want)) / abs(float(want))}
+    for name, a, b_ in zip(("d_qI", "d_kI", "d_wI"), got_g, want_g):
+        a, b_ = a.astype(jnp.float32), b_.astype(jnp.float32)
+        assert bool(jnp.all(jnp.isfinite(a))), f"index loss {name} not finite"
+        errs[name] = float(jnp.max(jnp.abs(a - b_)) / jnp.max(jnp.abs(b_)))
+    say(f"  index loss at {tuple(q.shape)} with {index_heads} x {index_dim} index heads bf16, "
+        f"kernels against the plain pass: L_I {float(got):.6f} against {float(want):.6f}, "
+        "max|diff|/max|ref|: " + ", ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    assert errs["L_I"] <= 1e-4 and max(errs.values()) <= 2e-2, errs
+    return errs
 
 
 def phase_b() -> dict:

@@ -48,7 +48,15 @@ kept by name under ``remat`` so that the set is made once
 one more blocked pass (its own scores, the heads' ``exp(scale q k^T - lse)``
 from the kernel's log-sum-exp, their mean, the KL and its gradient by the
 scores), the loss and its gradients by ``qI``, ``kI`` and ``wI``; the
-backward rule scales them by the loss's cotangent.
+backward rule scales them by the loss's cotangent. Under ``impl`` "flash"
+that pass is two Mosaic kernels (``ops/dsa_index_loss.py``, PR 50: every
+``[keys, queries]`` tile of the 32 heads' scores and of the indexer's made
+and spent in VMEM, the causal tiles alone walked) wherever they can tile the
+shape; under "xla", and at any other shape, ``LOSS_ROWS`` queries a step
+against their causal group's keys in ``jnp`` (:func:`_index_loss_plain`:
+``[heads, 256, keys]`` float32 arrays through HBM, 12.6 ms a layer at the
+cell's shape where the kernels take about half, PERF.md section 6, PR 50):
+the plain path and the kernels' second oracle.
 
 Scopes (``obs/trace.py`` ``DSA_SCOPES``): ``attn/dsa/index/scores``,
 ``attn/dsa/select``, ``attn/dsa/index_loss``; every call leaves a ``dsa/call``
@@ -65,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import dsa_select, remat
+from fedml_tpu.ops import dsa_index_loss, dsa_select, remat
 from fedml_tpu.ops.attention import (
     Selection, _fwd_blocks, attention_reference, flash_attention_selected, selection_layout)
 
@@ -77,7 +85,7 @@ NOTE = "dsa/call"
 # last value in its place took 24.2 ms a layer against 11.6, PERF.md section 6)
 SELECT_IMPL = {"flash": "mosaic", "xla": "radix"}
 TILE = 512  # the selection's tiles are the masked kernels' blocks (attention._fwd_blocks' own side)
-LOSS_ROWS = 256  # queries a step of the index loss's pass: [B, H, 256, T] float32 scores
+LOSS_ROWS = 256  # queries a step of the index loss's plain pass: [B, H, 256, T] float32 scores
 CAUSAL_GROUPS = 4
 
 
@@ -267,16 +275,45 @@ def select(qi, ki, wi, topk: int, block: int, impl: str = "flash"):
 # -- the index loss -----------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def index_loss(qi, ki, wi, q, k, lse, rows, sm_scale: float):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def index_loss(qi, ki, wi, q, k, lse, selection: Selection, sm_scale: float, impl: str = "xla"):
     """``L_I`` (a float32 scalar: the mean over the batch's queries) from the
     indexer's outputs, the attention's ``q`` ``[B, H, T, D]``, ``k`` ``[B, H_kv,
     T, D]`` and log-sum-exp ``[B, H, T]`` over the chosen keys, and the chosen
-    set's packed ``rows``. Differentiable by ``qi``, ``ki`` and ``wi`` alone."""
-    return _index_loss_fwd(qi, ki, wi, q, k, lse, rows, sm_scale)[0]
+    set. Differentiable by ``qi``, ``ki`` and ``wi`` alone. ``impl`` "flash":
+    the pass is ``dsa_index_loss.index_loss_grads``' two Mosaic kernels over
+    the set's packed ``cols`` wherever they can tile the shape; "xla", and any
+    other shape: the blocked pass in ``jnp`` over its packed ``rows``, the
+    plain path and the kernels' second oracle."""
+    return _index_loss_fwd(qi, ki, wi, q, k, lse, selection, sm_scale, impl)[0]
 
 
-def _index_loss_fwd(qi, ki, wi, q, k, lse, rows, sm_scale):
+def index_loss_impl(impl: str, q, k, qi) -> str:
+    """What runs the index loss's pass under ``impl`` at these operands'
+    shapes, for the ``dsa/call`` note: "mosaic" or "xla" (the shape alone
+    decides where the kernels cannot tile it)."""
+    if impl != "flash":
+        return "xla"
+    try:
+        dsa_index_loss.tiling(q.shape[2], q.shape[1], k.shape[1], q.shape[3], qi.shape[1],
+                              qi.shape[3], q.dtype, qi.dtype)
+    except ValueError:
+        return "xla"
+    return "mosaic"
+
+
+def _index_loss_fwd(qi, ki, wi, q, k, lse, selection, sm_scale, impl):
+    if index_loss_impl(impl, q, k, qi) == "mosaic":
+        with jax.named_scope(trace.SCOPE_DSA_INDEX_LOSS):
+            kl, *grads = dsa_index_loss.index_loss_grads(
+                qi, ki, wi, q, k, lse, selection.cols, sm_scale)
+            grads = tuple(remat.keep(name, x) for name, x in zip(remat.DSA_INDEX_GRADS, grads))
+    else:
+        kl, grads = _index_loss_plain(qi, ki, wi, q, k, lse, selection.rows, sm_scale)
+    return kl, (grads, q, k, lse, selection)
+
+
+def _index_loss_plain(qi, ki, wi, q, k, lse, rows, sm_scale):
     b, _, t, d = qi.shape
     h, h_kv = q.shape[1], k.shape[1]
     block = min(LOSS_ROWS, t)
@@ -322,15 +359,15 @@ def _index_loss_fwd(qi, ki, wi, q, k, lse, rows, sm_scale):
         d_wi = jnp.concatenate(d_wi).transpose(1, 0, 2, 3).reshape(wi.shape)
         grads = tuple(remat.keep(name, x) for name, x in zip(
             remat.DSA_INDEX_GRADS, (d_qi, d_ki.astype(ki.dtype), d_wi)))
-    return kl / (b * t), (grads, q, k, lse, rows)
+    return kl / (b * t), grads
 
 
-def _index_loss_bwd(sm_scale, res, g):
-    grads, q, k, lse, rows = res
+def _index_loss_bwd(sm_scale, impl, res, g):
+    grads, q, k, lse, selection = res
     with jax.named_scope(trace.SCOPE_DSA_INDEX_LOSS):
         scaled = tuple((g * x.astype(jnp.float32)).astype(x.dtype) for x in grads)
     return (*scaled, jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(lse),
-            np.zeros(rows.shape, jax.dtypes.float0))
+            jax.tree.map(lambda x: np.zeros(x.shape, jax.dtypes.float0), selection))
 
 
 index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
@@ -354,7 +391,8 @@ def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, impl: str = "flash",
     sm_scale = d ** -0.5
     block = _fwd_blocks(t, t, q.dtype, TILE, TILE)[0]
     trace.program_note(
-        NOTE, impl=impl, select=SELECT_IMPL[impl], shape=(b, h, t, d), kv_heads=k.shape[1],
+        NOTE, impl=impl, select=SELECT_IMPL[impl], index_loss=index_loss_impl(impl, q, k, qi),
+        shape=(b, h, t, d), kv_heads=k.shape[1],
         index_heads=qi.shape[1], index_dim=qi.shape[3], topk=topk, tile=(block, block),
         dtype=jnp.dtype(q.dtype).name, index_dtype=jnp.dtype(qi.dtype).name,
         selection_bytes=b * (2 * t * (t // 32) + (t // block) ** 2) * 4)
@@ -381,7 +419,7 @@ def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, impl: str = "flash",
     if with_loss:
         no_grad = jax.lax.stop_gradient
         stats["dsa/index_kl"] = index_loss(qi, ki, wi, no_grad(q), no_grad(k), no_grad(lse),
-                                           selection.rows, sm_scale)
+                                           selection, sm_scale, impl)
     return out, stats
 
 

@@ -50,7 +50,8 @@ def rng():
 # test_benchmark_loop_reduce.py (PR 40) holds the manifest to its five cells;
 # test_benchmark_lfm2.py (PR 42) reads its entries as the lists' last;
 # test_benchmark_keye.py (PR 48) holds the metrics of its cell alone to its own list
-# (PR 49 appended ``dsa_select_tie_blocks_pct``, which test_benchmark_dsa_select.py holds).
+# (PR 49 appended ``dsa_select_tie_blocks_pct``, which test_benchmark_dsa_select.py holds;
+# PR 50 ``dsa_index_loss_roofline``, which test_benchmark_dsa_index_loss.py holds as the list's last).
 READS_TAILS_SINCE = {
     ("test_benchmark_loop_reduce", "test_manifest_lists_the_five_for_the_cells_they_read"): {
         "configs": "kimi_linear_48b_a3b_cut", "workloads": "kimilinear_silo2",

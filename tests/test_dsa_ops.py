@@ -3,9 +3,13 @@
 ``lax.top_k``'s set, the selection kernel (``ops/dsa_select.py``) against both
 bit for bit, the packed set both ways, the kernels under a set with an
 empty tile against ``attention_reference``, the index loss against its
-definition, and that a call without the new argument is the accepted call
+definition, its pass as Mosaic kernels (``ops/dsa_index_loss.py``) against the
+plain pass and the definition, and that a call without the new argument is the
+accepted call
 (which leaves each loss moves is ``tests/test_keye.py``'s, through the model).
 Toy sizes; the kernels run interpreted."""
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +17,7 @@ import numpy as np
 import pytest
 
 import fedml_tpu.ops.attention as att
-from fedml_tpu.ops import dsa, dsa_select
+from fedml_tpu.ops import dsa, dsa_index_loss, dsa_select
 
 T, K = 128, 16
 
@@ -306,4 +310,175 @@ def test_sparse_attention_and_the_index_loss_against_the_reference(monkeypatch):
     assert (note["topk"], note["index_heads"], note["index_dim"], note["kv_heads"]) == (K, 3, 8, 2)
     assert note["select"] == dsa.SELECT_IMPL["flash"] == "mosaic"
     assert notes["xla"]["select"] == dsa.SELECT_IMPL["xla"] == "radix"
+    assert (note["index_loss"], notes["xla"]["index_loss"]) == ("mosaic", "xla")
     assert note["selection_bytes"] == 2 * (2 * t * (t // 32) + 4) * 4
+
+
+# -- the index loss's pass as Mosaic kernels ----------------------------------
+
+# (batch, T, topk, queries x keys of a tile or None for the module's, dtype, quantised indexer)
+LOSS_CASES = {
+    "two_groups_batch_of_two": (2, 256, K, (128, 128), jnp.float32, False),
+    "four_groups": (1, 512, K, (128, 256), jnp.float32, False),
+    "rows_below_topk": (1, 256, 512, (128, 128), jnp.float32, False),
+    "rows_that_tie": (1, 256, K, (128, 64), jnp.float32, True),
+    "bfloat16": (2, 256, K, (128, 128), jnp.bfloat16, False),
+    "the_modules_tiles": (1, 256, K, None, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("case", LOSS_CASES)
+def test_the_index_loss_kernels_against_the_plain_pass_and_the_definition(monkeypatch, case):
+    """``L_I``, ``d_qI``, ``d_kI`` and ``d_wI`` from the two kernels
+    (interpreted here) against ``impl`` "xla" on the same operands (the set,
+    ``q``, ``k`` and the flash kernel's log-sum-exp) and against
+    ``dsa_reference`` differentiated by jax: two and four causal groups of the
+    plain pass, a batch of two, every row with ``t < topk`` (each takes all it
+    sees), an indexer of small integers (rows tie at their threshold, and at
+    0.0), bfloat16 operands, and the module's own tiles."""
+    b, t, topk, block, dtype, quantised = LOSS_CASES[case]
+    monkeypatch.setattr(dsa, "LOSS_ROWS", 128)
+    if block:
+        monkeypatch.setattr(dsa_index_loss, "ROWS", block[0])
+        monkeypatch.setattr(dsa_index_loss, "KEYS", block[1])
+    assert len(dsa._causal_groups(t, 128)) == (4 if t == 512 else 2)
+    q, k, v = _rnd(1, b, 4, t, 8), _rnd(2, b, 2, t, 8), _rnd(3, b, 2, t, 8)
+    qi, ki, wi = _rnd(4, b, 3, t, 8), _rnd(5, b, t, 8), 0.3 * _rnd(6, b, t, 3)
+    if quantised:
+        qi, ki, wi = jnp.round(2 * qi), jnp.round(2 * ki), jnp.round(4 * jnp.abs(wi)) / 4
+    q, k, v, qi, ki, wi = (x.astype(dtype) for x in (q, k, v, qi, ki, wi))
+
+    def program(impl):
+        def run(q, k, v, qi, ki, wi):
+            selection, _, tie_blocks = dsa.select(qi, ki, wi, topk, 128, "flash")
+            _, lse = att.flash_attention_selected(q, k, v, selection, 8 ** -0.5)
+            loss = lambda qi, ki, wi: dsa.index_loss(  # noqa: E731
+                qi, ki, wi, q, k, lse, selection, 8 ** -0.5, impl)
+            return jax.value_and_grad(loss, (0, 1, 2))(qi, ki, wi), tie_blocks
+        return jax.jit(run)(q, k, v, qi, ki, wi)
+
+    reference = jax.jit(jax.value_and_grad(
+        lambda qi, ki, wi: dsa.dsa_reference(q, k, v, qi, ki, wi, topk=topk)[1], (0, 1, 2)))
+    with jax.default_matmul_precision("highest"):
+        (got, got_g), tie_blocks = program("flash")
+        (plain, plain_g), _ = program("xla")
+        want, want_g = reference(qi, ki, wi)
+    assert float(tie_blocks) == 1.0 or not quantised  # every searched block holds a tied row
+    rough = dtype == jnp.bfloat16  # the gradients leave in the operands' dtype
+    np.testing.assert_allclose(got, plain, rtol=2e-6)
+    np.testing.assert_allclose(got, want, rtol=2e-2 if rough else 2e-5)
+    assert float(want) > 0.0
+    for a, same, ref in zip(got_g, plain_g, want_g):
+        assert a.dtype == same.dtype == dtype and a.shape == ref.shape
+        a, same, ref = (np.asarray(x, np.float32) for x in (a, same, ref))
+        np.testing.assert_allclose(a, same, atol=(1e-2 if rough else 2e-6) * np.abs(same).max())
+        if not quantised:  # the definition's ``where(scores == 0.0, 0.0, scores)`` passes no
+            # gradient at a score of exactly 0.0; the pass, plain or kernel, does
+            np.testing.assert_allclose(
+                a, ref, atol=(2e-2 if rough else 2e-5) * np.abs(ref).max() + 1e-9)
+
+
+@pytest.mark.parametrize("t, dtype, block, want", [
+    (8192, jnp.bfloat16, None, (128, 512)),  # the cell's
+    (8192, jnp.bfloat16, (256, 512), ValueError),  # sixteen [512, 256] float32 tiles are 8 MiB
+    (8192, jnp.bfloat16, (256, 256), (256, 256)),
+    (16384, jnp.bfloat16, None, ValueError),  # d_kI whole and a tile's words pass what it may hold
+    (2048, jnp.bfloat16, None, (128, 512)),
+    (256, jnp.float32, None, (128, 256)), (384, jnp.float32, None, (128, 384)),
+    (96, jnp.float32, None, (96, 96)),  # one run of packed lanes, one tile
+    (1000, jnp.bfloat16, None, ValueError),  # no divisor of whole 16-row tiles
+], ids=["cell", "tiles_too_large", "square_tiles", "twice_the_cell", "a_quarter", "t256", "t384",
+        "t96", "t1000"])
+def test_the_index_loss_kernels_tiles_and_the_shapes_they_refuse(monkeypatch, t, dtype, block,
+                                                                 want):
+    if block:
+        monkeypatch.setattr(dsa_index_loss, "ROWS", block[0])
+        monkeypatch.setattr(dsa_index_loss, "KEYS", block[1])
+    args = (t, 32, 4, 128, 16, 64, dtype, dtype)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="dsa|flash attention"):
+            dsa_index_loss.tiling(*args)
+    else:
+        assert dsa_index_loss.tiling(*args) == want
+        assert t % want[0] == 0 and want[0] % att.selection_layout(t)[0] == 0
+
+
+def _abstract_loss(t, impl, dtype=jnp.bfloat16):
+    """The jaxpr of the index loss and its three gradients at the cell's
+    widths and a length of ``t``, from shapes alone."""
+    on = jax.ShapeDtypeStruct
+    words = on((1, t, t // 32), jnp.int32)
+    selection = att.Selection(words, words, on((1, t // 512, t // 512), jnp.int32))
+    loss = lambda qi, ki, wi, q, k, lse, selection: dsa.index_loss(  # noqa: E731
+        qi, ki, wi, q, k, lse, selection, 128 ** -0.5, impl)
+    return jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(
+        on((1, 16, t, 64), dtype), on((1, t, 64), dtype), on((1, t, 16), dtype),
+        on((1, 32, t, 128), dtype), on((1, 4, t, 128), dtype), on((1, 32, t), jnp.float32),
+        selection)
+
+
+def _outside_kernels(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls, a kernel's body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _outside_kernels(sub)
+
+
+def _scores_like(jaxpr):
+    """Shapes of the float32 ``[.., heads, rows, keys]`` arrays outside a kernel."""
+    return [v.aval.shape for e in _outside_kernels(jaxpr.jaxpr) for v in e.outvars
+            if v.aval.dtype == jnp.float32 and len(v.aval.shape) >= 4
+            and v.aval.shape[-1] >= 2048 and v.aval.shape[-2] >= 128]
+
+
+def _largest_float32(jaxpr):
+    return max(int(np.prod(v.aval.shape)) for e in _outside_kernels(jaxpr.jaxpr)
+               for v in e.outvars if v.aval.dtype == jnp.float32)
+
+
+def test_the_flash_path_writes_no_heads_rows_keys_array_and_xla_is_the_parents_pass():
+    """At the cell's shape the ``flash`` path is two kernels and no float32
+    array outside them is larger than ``d_kI`` (``[8192, 64]``), where the
+    plain pass forms ``[1, 4, 8, 256, keys]`` and ``[1, 16, 256, keys]``
+    float32 arrays a step; ``impl`` "xla" holds no kernel and traces to the
+    parent's jaxpr (PR 49's: the same primitives with the same result shapes
+    in the same order, at a toy shape; the digest is of the parent's tree)."""
+    flash, plain = _abstract_loss(8192, "flash"), _abstract_loss(8192, "xla")
+    kernels = [e.params["name"] for e in _outside_kernels(flash.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert kernels == [dsa_index_loss.LSE_NAME, dsa_index_loss.NAME]
+    # the largest either way: d_qI in float32 while the backward rule scales it
+    assert _scores_like(flash) == [] and _largest_float32(flash) == 16 * 8192 * 64
+    assert {(1, 4, 8, 256, 8192), (1, 16, 256, 8192)} <= set(_scores_like(plain))
+    assert _largest_float32(plain) == 32 * 256 * 8192
+    assert not [e for e in _outside_kernels(plain.jaxpr) if e.primitive.name == "pallas_call"]
+    on = jax.ShapeDtypeStruct
+    words = on((2, 512, 128), jnp.int32)
+    toy = jax.make_jaxpr(jax.value_and_grad(
+        lambda qi, ki, wi, q, k, lse, s: dsa.index_loss(qi, ki, wi, q, k, lse, s, 0.35, "xla"),
+        (0, 1, 2)))(
+        on((2, 3, 512, 8), jnp.float32), on((2, 512, 8), jnp.float32), on((2, 512, 3), jnp.float32),
+        on((2, 4, 512, 8), jnp.float32), on((2, 2, 512, 8), jnp.float32),
+        on((2, 4, 512), jnp.float32), att.Selection(words, words, on((2, 4, 4), jnp.int32)))
+    lines = [e.primitive.name + ":" + ",".join(str(v.aval.shape) for v in e.outvars)
+             for e in _outside_kernels(toy.jaxpr)]
+    assert len(lines) == 255
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] == "a291d77b198609e7"
+
+
+@pytest.mark.parametrize("t, dtype", [(16384, jnp.bfloat16), (1000, jnp.bfloat16)],
+                         ids=["vmem", "sublanes"])
+def test_a_shape_the_index_loss_kernels_cannot_tile_takes_the_plain_path(t, dtype):
+    """By the shape alone, no knob: the note says which path a call took."""
+    q, k, qi = (jax.ShapeDtypeStruct(s, dtype) for s in (
+        (1, 32, t, 128), (1, 4, t, 128), (1, 16, t, 64)))
+    assert dsa.index_loss_impl("flash", q, k, qi) == "xla"
+    assert dsa.index_loss_impl("xla", q, k, qi) == "xla"
+    small = [jax.ShapeDtypeStruct((*x.shape[:2], 8192, *x.shape[3:]), dtype) for x in (q, k, qi)]
+    assert dsa.index_loss_impl("flash", *small) == "mosaic"
+    if t == 16384:
+        jaxpr = _abstract_loss(t, "flash")
+        assert not [e for e in _outside_kernels(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+        assert _largest_float32(jaxpr) == 32 * 256 * t

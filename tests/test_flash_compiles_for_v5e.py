@@ -127,6 +127,31 @@ def test_the_selection_kernel_compiles_for_the_v5e(monkeypatch, one_chip, rows, 
     assert "dsa_select" in text and "vmem_limit" not in text
 
 
+@pytest.mark.parametrize("b,t,heads,kv_heads,d,index_heads,index_dim,dtype", [
+    pytest.param(1, 8192, 32, 4, 128, 16, 64, jnp.bfloat16, id="keye"),
+    pytest.param(2, 2048, 32, 4, 128, 16, 64, jnp.bfloat16, id="batch-of-two-T2048"),
+    pytest.param(1, 256, 4, 2, 128, 3, 64, jnp.float32, id="T256-f32")])
+def test_the_index_loss_kernels_compile_for_the_v5e(monkeypatch, one_chip, b, t, heads, kv_heads,
+                                                    d, index_heads, index_dim, dtype):
+    """``dsa_index_loss.index_loss_grads`` at the sparse-attention cell's shape
+    (tiles of 512 keys x 128 queries; ``d_kI^T`` whole, a query block's ``q``
+    and sixteen float32 tiles of ``relu(z)`` resident: 11.4 MiB), at a batch of
+    two and at a toy length in float32: Mosaic takes the walk from SMEM, the
+    64-wide contractions and the resident output inside its default scoped
+    VMEM, and the custom calls bear the names the trace is read by."""
+    from fedml_tpu.ops import dsa_index_loss
+
+    monkeypatch.setattr(att, "_interpret_on", lambda platform: False)
+    on = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    text = jax.jit(lambda *a: dsa_index_loss.index_loss_grads(*a, d ** -0.5)).lower(
+        on((b, index_heads, t, index_dim)), on((b, t, index_dim)), on((b, t, index_heads)),
+        on((b, heads, t, d)), on((b, kv_heads, t, d)), on((b, heads, t), jnp.float32),
+        on((b, t, t // 32), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "dsa_index_loss" in text and "dsa_index_loss_lse" in text
+    assert "vmem_limit" not in text and "flash_fwd" not in text and "flash_bwd" not in text
+
+
 # the delta-attention mixer's three chains (ops/kda.py): the cell's shape
 # ([1, 8192, 32 x 128] bfloat16: whole blocks of 2,048 tokens), a length that is
 # a part of one block, one that is no multiple of a block, float32, one head

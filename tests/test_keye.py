@@ -15,7 +15,7 @@ from benchmark.reference import dsa_moe_lm as reference
 from fedml_tpu.core.trainer import MTP_COLLECTION, STATS_COLLECTION
 from fedml_tpu.models.mla_moe_transformer import DSA, SOFTMAX, MLABlock, MLAMoETransformerLM
 from fedml_tpu.obs import trace
-from fedml_tpu.ops import remat
+from fedml_tpu.ops import dsa_index_loss, remat
 
 V, D, H, HKV, DH, E, F, T, TOPK, LAYERS = 48, 32, 4, 2, 8, 8, 16, 128, 16, 2
 ARCH = reference.Arch(num_heads=H, num_kv_heads=HKV, index_heads=3, topk=TOPK, top_k=2,
@@ -154,25 +154,32 @@ def _counts(policy, x, params):
     selecting = sum(trace.SCOPE_DSA_SELECT in str(e.source_info.name_stack)
                     for e in eqns  # the jitted kernel call (differentiation leaves an empty twin)
                     if e.params.get("name") == "_select_rows" and e.outvars)
-    return {"select": selecting, **{n: names.count(n) for n in ("pallas_call", "top_k")}}
+    passes = sum(e.primitive.name == "pallas_call" and e.params["name"] == dsa_index_loss.NAME
+                 for e in eqns)
+    return {"select": selecting, "index_loss": passes,
+            **{n: names.count(n) for n in ("pallas_call", "top_k")}}
 
 
 def test_kept_names_the_selection_made_once_and_the_kernel_count(seeded):
     """A rematerialised block keeps the chosen set's three arrays and the index
     loss's three gradients beside the flash kernels' five, so its backward
     makes no second selection (one selection kernel under ``attn/dsa/select``
-    a block, the plain model's count; two under a bare checkpoint) and runs
-    no attention kernel twice: **thirteen ``pallas_call``s a block's training
-    step** (the selection; the masked forward and backward; the routed
-    layer's three grouped products forward, six backward and the down product
-    remade: one more than the parent's "gqa" block over the same routed layer
-    holds (``tests/test_lfm2_moe.py``); twelve without ``remat``, seventeen
-    under a bare checkpoint)."""
+    a block, the plain model's count; two under a bare checkpoint), runs the
+    index loss's pass once (its main kernel once a block: the second forward
+    finds the three gradients kept; twice under a bare checkpoint) and runs
+    no attention kernel twice: **fifteen ``pallas_call``s a block's training
+    step** (the selection; the index loss's two, PR 50; the masked forward and
+    backward; the routed layer's three grouped products forward, six backward
+    and the down product remade: three more than the parent's "gqa" block over
+    the same routed layer holds (``tests/test_lfm2_moe.py``); fourteen without
+    ``remat``, twenty-one under a bare checkpoint)."""
     params, x = seeded
     plain, names, bare = (_counts(policy, x, params) for policy in ("none", "names", "bare"))
     assert (plain["select"], names["select"], bare["select"]) == (LAYERS, LAYERS, 2 * LAYERS)
+    assert (plain["index_loss"], names["index_loss"], bare["index_loss"]) == (
+        LAYERS, LAYERS, 2 * LAYERS)
     assert (plain["pallas_call"], names["pallas_call"], bare["pallas_call"]) == (
-        12 * LAYERS, 13 * LAYERS, 17 * LAYERS)
+        14 * LAYERS, 15 * LAYERS, 21 * LAYERS)
     assert plain["top_k"] == LAYERS  # the routers' alone: the selection sorts nothing
     kept = {n["kept"] for n in trace.program_notes(remat.NOTE)}
     assert {*remat.DSA_SELECTION, *remat.DSA_INDEX_GRADS, *remat.ATTN_RESIDUALS,
